@@ -11,7 +11,9 @@ operators hash-exchange records over the TCP mesh
 (parallel/process_mesh.py), so every key's state lives on exactly one
 process (and one thread shard within it, PATHWAY_THREADS). The env
 contract (PATHWAY_PROCESSES / PATHWAY_PROCESS_ID / PATHWAY_FIRST_PORT /
-PATHWAY_THREADS) matches the reference.
+PATHWAY_THREADS) matches the reference. On a TPU host each process owns
+one chip (`parallel/supervisor.py chip_env`); the launcher itself never
+opens the device.
 """
 
 from __future__ import annotations
@@ -21,6 +23,8 @@ import os
 import shlex
 import subprocess
 import sys
+
+from pathway_tpu.parallel.supervisor import chip_env
 
 
 def _command_of(args: argparse.Namespace) -> list[str]:
@@ -40,7 +44,7 @@ def _spawn(args: argparse.Namespace) -> int:
     env_base["PATHWAY_FIRST_PORT"] = str(args.first_port)
     procs: list[subprocess.Popen] = []
     for pid in range(args.processes):
-        env = dict(env_base)
+        env = {**env_base, **chip_env(pid, args.processes, env_base)}
         env["PATHWAY_PROCESS_ID"] = str(pid)
         procs.append(subprocess.Popen([sys.executable, *command], env=env))
     rc = 0

@@ -39,9 +39,11 @@ in tier-1 without TPU hardware.
 
 from __future__ import annotations
 
+import os
 import threading
 import time as _time
 from concurrent.futures import Future, ThreadPoolExecutor
+from pathlib import Path
 from typing import Any, Callable
 
 from pathway_tpu.engine import faults
@@ -50,13 +52,20 @@ from pathway_tpu.analysis import lockgraph as _lockgraph
 
 __all__ = [
     "BucketPolicy",
+    "DeviceCompileError",
     "DeviceProgram",
     "DevicePlane",
     "SlotPool",
     "WaveCoalescer",
+    "compile_cache_dir",
     "get_device_plane",
     "reset_quarantines",
 ]
+
+
+class DeviceCompileError(RuntimeError):
+    """A (program, bucket) whose first compile failed was called again
+    before its re-probe cooldown ran out."""
 
 
 class BucketPolicy:
@@ -121,14 +130,33 @@ class DeviceProgram:
     hide it. The invariant the tier-1 guard pins: streaming ragged
     batches inside one bucket never grows the ledger past 1.
 
-    **Graceful degradation**: a dispatch that fails (XLA error, device
-    loss, or an injected ``device.dispatch.{name}`` fault) *quarantines*
-    the (program, bucket) entry and the wave falls back to the HOST
-    path — the un-jitted function, op-by-op, slower but correct. While
-    quarantined, calls for that bucket go straight to the host path;
-    after an exponentially growing cooldown (``PROBE_BASE_S`` doubling
-    up to ``PROBE_CAP_S``) one call is admitted as a re-probe, and a
-    successful probe lifts the quarantine.
+    **Graceful degradation**: a dispatch that fails on a signature that
+    has run before (device loss, out of memory) or on an injected
+    ``device.dispatch.{name}`` fault *quarantines* the (program, bucket)
+    entry and the wave falls back to the HOST path — the un-jitted
+    function, op-by-op, slower but correct. While quarantined, calls for
+    that bucket go straight to the host path; after an exponentially
+    growing cooldown (``PROBE_BASE_S`` doubling up to ``PROBE_CAP_S``)
+    one call is admitted as a re-probe, and a successful probe lifts the
+    quarantine.
+
+    **A first compile that fails raises.** An exception on a fresh
+    signature — the call that traces and compiles — other than an
+    injected fault is a bug in the program (a shape the compiler
+    refuses, a kernel that does not fit), not a device fault: a program
+    that has never run must not turn into a slow success on the host.
+    The call re-raises, the quarantine record is marked
+    ``never_compiled`` and, until a re-probe compiles, further calls for
+    that bucket raise :class:`DeviceCompileError` instead of being
+    served from the host.
+
+    The host re-run is also refused when the failed call already
+    consumed a donated argument (``cb/step`` donates the KV cache): the
+    buffer is gone, so the original exception propagates to the caller,
+    which owns the recovery of that buffer.
+
+    Every quarantine, of either kind, is written to the global error log
+    with the program, the bucket and ``last_error``.
     """
 
     # re-probe backoff for quarantined buckets (class-level so tests and
@@ -160,6 +188,9 @@ class DeviceProgram:
         )
         # bucket key -> compilations charged to it
         self.compile_counts: dict[Any, int] = {}
+        # bucket key -> host seconds its fresh-signature calls spent in
+        # trace + compile (or the persistent cache's load) + enqueue
+        self.compile_seconds: dict[Any, float] = {}
         self._seen_sigs: set[Any] = set()
         # bucket key -> {"failures": n, "reopen_at": t, "last_error": str}
         self.quarantine: dict[Any, dict[str, Any]] = {}
@@ -173,6 +204,11 @@ class DeviceProgram:
             return int(self._jit._cache_size())
         except Exception:  # noqa: BLE001 — private accessor
             return None
+
+    def lowered_text(self, *args: Any, **kwargs: Any) -> str:
+        """The program lowered for these arguments, as StableHLO text —
+        what a check reads to see which kernels the program calls."""
+        return self._jit.lower(*args, **kwargs).as_text()
 
     @staticmethod
     def _signature(args: tuple, kwargs: dict) -> Any:
@@ -188,17 +224,15 @@ class DeviceProgram:
         return (treedef, tuple(leaf(x) for x in flat))
 
     def __call__(self, *args: Any, bucket: Any = None, **kwargs: Any) -> Any:
-        if self.quarantine and not self._admit_probe(bucket):
-            # quarantined bucket, cooldown still running: host path
-            with self._lock:
-                self.host_fallbacks += 1
-            if _obs.PLANE is not None:
-                _obs.PLANE.metrics.counter(
-                    "pathway_device_host_fallbacks_total",
-                    {"program": self.name},
-                    help="dispatches served by the host path",
+        blocked = self._blocked(bucket) if self.quarantine else None
+        if blocked is not None:
+            if blocked["never_compiled"]:
+                raise DeviceCompileError(
+                    f"device program {self.name!r} bucket {bucket!r} has "
+                    f"never compiled: {blocked['last_error']}"
                 )
-            return self._fn(*args, **kwargs)
+            # quarantined bucket, cooldown still running: host path
+            return self._host_path(args, kwargs)
         # bookkeeping only under the lock; the dispatch itself runs
         # outside it so overlapping stages never serialize here
         sig = self._signature(args, kwargs)
@@ -209,10 +243,15 @@ class DeviceProgram:
                 self.compile_counts[bucket] = (
                     self.compile_counts.get(bucket, 0) + 1
                 )
+        t0 = _time.perf_counter()
         try:
             faults.check(f"device.dispatch.{self.name}")
             out = self._jit(*args, **kwargs)
-        except Exception as e:  # noqa: BLE001 — any dispatch failure degrades
+        except Exception as e:  # noqa: BLE001 — quarantined, logged; see below
+            never_compiled = fresh_sig and not isinstance(
+                e, faults.FaultInjected
+            )
+            error = f"{type(e).__name__}: {e}"
             with self._lock:
                 if fresh_sig:
                     # the compile never happened; let a successful
@@ -224,36 +263,50 @@ class DeviceProgram:
                     else:
                         self.compile_counts.pop(bucket, None)
                 q = self.quarantine.setdefault(
-                    bucket, {"failures": 0, "reopen_at": 0.0, "last_error": ""}
+                    bucket,
+                    {"failures": 0, "reopen_at": 0.0, "last_error": "",
+                     "never_compiled": False},
                 )
                 q["failures"] += 1
-                q["last_error"] = f"{type(e).__name__}: {e}"
+                q["last_error"] = error
+                q["never_compiled"] = never_compiled
                 q["reopen_at"] = _time.monotonic() + self._cooldown(
                     q["failures"]
                 )
-                self.host_fallbacks += 1
                 failures = q["failures"]
+            from pathway_tpu.internals.errors import global_error_log
+
+            global_error_log().log(
+                f"device program {self.name!r} bucket {bucket!r} "
+                f"quarantined after {failures} failure(s)"
+                + (
+                    ", first compile failed, not served from the host"
+                    if never_compiled
+                    else ""
+                )
+                + f": {error[:600]}"
+            )
             if _obs.PLANE is not None:
                 _obs.PLANE.record(
                     "device.quarantine", program=self.name,
                     bucket=repr(bucket), failures=failures,
-                    error=f"{type(e).__name__}: {e}"[:300],
+                    error=error[:300],
                 )
                 _obs.PLANE.metrics.counter(
                     "pathway_device_dispatch_failures_total",
                     {"program": self.name},
-                    help="device dispatches that degraded to the host path",
+                    help="device dispatches that failed and quarantined "
+                    "their bucket",
                 )
-                # this dispatch is ALSO served by the host path below —
-                # the fallback counter must agree with host_fallbacks
-                _obs.PLANE.metrics.counter(
-                    "pathway_device_host_fallbacks_total",
-                    {"program": self.name},
-                    help="dispatches served by the host path",
-                )
-            return self._fn(*args, **kwargs)
+            if never_compiled or self._donation_consumed(args):
+                raise
+            return self._host_path(args, kwargs)
         with self._lock:
             lifted = self.quarantine.pop(bucket, None) is not None
+            if fresh_sig:
+                self.compile_seconds[bucket] = self.compile_seconds.get(
+                    bucket, 0.0
+                ) + (_time.perf_counter() - t0)
         if _obs.PLANE is not None:
             if lifted:
                 _obs.PLANE.record(
@@ -294,19 +347,45 @@ class DeviceProgram:
             self.quarantine.clear()
         return n
 
-    def _admit_probe(self, bucket: Any) -> bool:
-        """True when the bucket is healthy, or quarantined but due for a
+    def _blocked(self, bucket: Any) -> dict[str, Any] | None:
+        """None when the bucket is healthy, or quarantined but due for a
         re-probe (which is then claimed: the cooldown moves forward so
-        concurrent callers don't stampede the device)."""
+        concurrent callers don't stampede the device); otherwise a copy
+        of the quarantine record that keeps this call off the device."""
         with self._lock:
             q = self.quarantine.get(bucket)
             if q is None:
-                return True
+                return None
             now = _time.monotonic()
             if now < q["reopen_at"]:
-                return False
+                return dict(q)
             q["reopen_at"] = now + self._cooldown(q["failures"])
-            return True
+            return None
+
+    def _host_path(self, args: tuple, kwargs: dict) -> Any:
+        """Serve one dispatch from the un-jitted function."""
+        with self._lock:
+            self.host_fallbacks += 1
+        if _obs.PLANE is not None:
+            _obs.PLANE.metrics.counter(
+                "pathway_device_host_fallbacks_total",
+                {"program": self.name},
+                help="dispatches served by the host path",
+            )
+        return self._fn(*args, **kwargs)
+
+    def _donation_consumed(self, args: tuple) -> bool:
+        """True when a failed call already deleted a donated argument —
+        the host path has nothing left to run on."""
+        import jax
+
+        return any(
+            leaf.is_deleted()
+            for i in self.donate_argnums
+            if i < len(args)
+            for leaf in jax.tree_util.tree_leaves(args[i])
+            if isinstance(leaf, jax.Array)
+        )
 
     @property
     def total_compiles(self) -> int:
@@ -587,20 +666,34 @@ class DevicePlane:
             prog = self.programs.setdefault(name, fresh)
         return prog
 
-    def compile_counts(self) -> dict[tuple[str, Any], int]:
-        """{(program_name, bucket): compilations} across the plane — the
-        observable the no-recompile regression guard asserts on.
-        Snapshotted under each program's lock: dispatch-pool threads
-        mutate the ledgers (incl. pops on failed dispatches)."""
-        out: dict[tuple[str, Any], int] = {}
+    def _ledger(
+        self, attr: str, snap: Callable[[Any], Any] = lambda v: v
+    ) -> dict[tuple[str, Any], Any]:
+        """{(program_name, bucket): snap(value)} of one per-bucket ledger
+        across the plane. Snapshotted under each program's lock:
+        dispatch-pool threads mutate the ledgers (incl. pops on failed
+        dispatches)."""
+        out: dict[tuple[str, Any], Any] = {}
         with self._lock:
             progs = list(self.programs.items())
         for name, prog in progs:
             with prog._lock:
-                items = list(prog.compile_counts.items())
-            for bucket, n in items:
-                out[(name, bucket)] = n
+                items = [
+                    (b, snap(v)) for b, v in getattr(prog, attr).items()
+                ]
+            for bucket, value in items:
+                out[(name, bucket)] = value
         return out
+
+    def compile_counts(self) -> dict[tuple[str, Any], int]:
+        """{(program_name, bucket): compilations} across the plane — the
+        observable the no-recompile regression guard asserts on."""
+        return self._ledger("compile_counts")
+
+    def compile_seconds(self) -> dict[tuple[str, Any], float]:
+        """{(program_name, bucket): seconds} beside :meth:`compile_counts`
+        (see DeviceProgram.compile_seconds)."""
+        return self._ledger("compile_seconds")
 
     def reset_quarantines(self) -> int:
         """Clear quarantine state across every registered program (the
@@ -620,18 +713,8 @@ class DevicePlane:
 
     def quarantined(self) -> dict[tuple[str, Any], dict[str, Any]]:
         """{(program_name, bucket): quarantine record} for every entry
-        currently degraded to the host path (see DeviceProgram).
-        Snapshotted under each program's lock — the failure/re-probe
-        paths insert and pop entries from dispatch-pool threads."""
-        out: dict[tuple[str, Any], dict[str, Any]] = {}
-        with self._lock:
-            progs = list(self.programs.items())
-        for name, prog in progs:
-            with prog._lock:
-                items = [(b, dict(q)) for b, q in prog.quarantine.items()]
-            for bucket, q in items:
-                out[(name, bucket)] = q
-        return out
+        currently kept off the device (see DeviceProgram)."""
+        return self._ledger("quarantine", dict)
 
     def coalescer(
         self, flush_fn: Callable[[list], list], max_batch: int = 4096,
@@ -760,10 +843,38 @@ _plane_lock = _lockgraph.register_lock(
 )
 
 
+def compile_cache_dir() -> str:
+    """Where this process keeps XLA's persistent compile cache:
+    ``JAX_COMPILATION_CACHE_DIR`` when the environment names one, else
+    ``<checkout>/.pathway-cache/xla``. The path is part of the cache key,
+    so it is fixed: no temporary directory, process id or timestamp."""
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") or str(
+        Path(__file__).resolve().parents[2] / ".pathway-cache" / "xla"
+    )
+
+
+def _configure_compile_cache() -> None:
+    """Point JAX at :func:`compile_cache_dir`, once, when the plane is
+    built. Where the environment names the directory JAX has already
+    read it and no code sets another. Every program is kept, however
+    small or quick to compile: a server restart recompiles nothing."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", compile_cache_dir())
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    # a compile that ran before the plane existed (a model's parameter
+    # init) latched "no cache" for the process; start over
+    compilation_cache.reset_cache()
+
+
 def get_device_plane() -> DevicePlane:
     global _plane
     with _plane_lock:
         if _plane is None:
+            _configure_compile_cache()
             _plane = DevicePlane()
         return _plane
 

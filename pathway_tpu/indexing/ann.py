@@ -67,6 +67,13 @@ _LIVE_RETRAINS: "weakref.WeakSet[IvfPqIndex]" = weakref.WeakSet()
 
 
 @atexit.register
+def _mesh_has_peers() -> bool:
+    """The list-sharded search has something to shard over."""
+    import jax
+
+    return len(jax.devices()) > 1
+
+
 def _drain_retrain_threads() -> None:
     for idx in list(_LIVE_RETRAINS):
         t = idx._retrain_thread
@@ -851,7 +858,7 @@ class IvfPqIndex(VectorSlabIndex):
             # tiered placement takes precedence over the mesh-sharded
             # view: the hot sub-cube is the device-resident shard
             return self._ann_topk_tiered(qmat, k, gen, ts, nprobe)
-        if self._shard_search:
+        if self._shard_search and _mesh_has_peers():
             try:
                 result = self._ann_topk_sharded(qmat, k, gen, nprobe)
                 self._sharded_failures = 0
@@ -865,21 +872,29 @@ class IvfPqIndex(VectorSlabIndex):
                     # index that will never search sharded again
                     self._sharded_view = None
                     self._sharded_key = None
-                self._log_device_error(e, permanent=not self._shard_search)
+                self._log_device_error(
+                    e, "ann_ivf_search_sharded",
+                    disabled=not self._shard_search,
+                )
         if self._ann_use_device:
             try:
                 result = self._ann_topk_device(qmat, k, gen, nprobe)
                 self._ann_device_failures = 0
                 return result
-            except (ImportError, NotImplementedError) as e:
-                self._ann_use_device = False
-                self._log_device_error(e, permanent=True)
             except Exception as e:  # noqa: BLE001 — transient (OOM…)
-                self._ann_device_failures += 1
-                if self._ann_device_failures >= 3:
-                    self._ann_use_device = False
-                self._log_device_error(e, permanent=not self._ann_use_device)
+                self._note_ann_device_failure(e)
         return self._ann_topk_host(qmat, k, gen, nprobe)
+
+    def _note_ann_device_failure(self, e: Exception) -> None:
+        """Three consecutive failures turn the ANN device path off for
+        this index; each failure, and the switch-off, reach the error
+        log."""
+        self._ann_device_failures += 1
+        if self._ann_device_failures >= 3:
+            self._ann_use_device = False
+        self._log_device_error(
+            e, "ann_ivf_search", disabled=not self._ann_use_device
+        )
 
     def _candidates(self, k: int, gen: _Generation) -> int:
         return max(_ivf.auto_candidates(k), gen.cap)
@@ -891,10 +906,6 @@ class IvfPqIndex(VectorSlabIndex):
         The placed view is cached per (generation, mutation count) —
         mutations invalidate it lazily, so the rebuild cost lands on the
         first search after a write, not on the wave path."""
-        import jax
-
-        if len(jax.devices()) < 2:
-            raise NotImplementedError("sharded ANN needs a multi-device mesh")
         from pathway_tpu.parallel.mesh import default_mesh
 
         with self._gen_lock:
@@ -977,16 +988,8 @@ class IvfPqIndex(VectorSlabIndex):
                     )
                     self._ann_device_failures = 0
                     return result
-                except (ImportError, NotImplementedError) as e:
-                    self._ann_use_device = False
-                    self._log_device_error(e, permanent=True)
                 except Exception as e:  # noqa: BLE001 — transient (OOM…)
-                    self._ann_device_failures += 1
-                    if self._ann_device_failures >= 3:
-                        self._ann_use_device = False
-                    self._log_device_error(
-                        e, permanent=not self._ann_use_device
-                    )
+                    self._note_ann_device_failure(e)
             m = gen.cube.shape[2]
             codes = np.empty((union.size, gen.cap, m), np.uint8)
             for i, lst in enumerate(union):

@@ -3,8 +3,9 @@ src/engine/dataflow/config.rs env-first config).
 
 Env vars mirror the reference's: PATHWAY_THREADS, PATHWAY_PROCESSES,
 PATHWAY_PROCESS_ID, PATHWAY_FIRST_PORT, PATHWAY_PERSISTENT_STORAGE,
-PATHWAY_RUN_ID. TPU additions: PATHWAY_DEVICE (cpu|tpu), PATHWAY_MESH
-(e.g. "dp=2,tp=4" for the device mesh used by the numeric plane).
+PATHWAY_RUN_ID. TPU addition: PATHWAY_MESH (e.g. "dp=2,tp=4" for the
+device mesh used by the numeric plane). Which device the numeric plane
+runs on is JAX's choice (JAX_PLATFORMS), not a setting here.
 """
 
 from __future__ import annotations
@@ -25,7 +26,6 @@ class PathwayConfig:
     license_key: str | None = None
     monitoring_server: str | None = None
     ignore_asserts: bool = False
-    device: str = "cpu"
     mesh_spec: str | None = None
     terminate_on_error: bool = False
 
@@ -59,7 +59,6 @@ def get_config(refresh: bool = False) -> PathwayConfig:
             persistent_storage_path=os.environ.get("PATHWAY_PERSISTENT_STORAGE"),
             license_key=os.environ.get("PATHWAY_LICENSE_KEY"),
             monitoring_server=os.environ.get("PATHWAY_MONITORING_SERVER"),
-            device=os.environ.get("PATHWAY_DEVICE", "cpu"),
             mesh_spec=os.environ.get("PATHWAY_MESH"),
         )
     return _config

@@ -8,13 +8,6 @@ jit-compiled forward/train steps over a `jax.sharding.Mesh` (dp x tp), and a
 decode path with a KV cache for on-TPU generation.
 """
 
-# jax version shims (jax.shard_map on old releases) before any
-# submodule builds a sharded program
-from pathway_tpu.internals import jax_compat as _jax_compat
-
-_jax_compat.install()
-
-
 from pathway_tpu.models.transformer import (
     TransformerConfig,
     TransformerLM,

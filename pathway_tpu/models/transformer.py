@@ -89,34 +89,50 @@ def lm_config(**kw) -> TransformerConfig:
 # ------------------------------------------------------------------ params
 
 
-def _init_block(rng: Array, cfg: TransformerConfig) -> Params:
+def _init_block(
+    rng: Array, cfg: TransformerConfig, dtype: Any = jnp.float32
+) -> Params:
     d, f = cfg.d_model, cfg.d_ff
     ks = jax.random.split(rng, 6)
     s = 1.0 / math.sqrt(d)
     return {
-        "qkv": jax.random.normal(ks[0], (d, 3 * d), jnp.float32) * s,
-        "o": jax.random.normal(ks[1], (d, d), jnp.float32) * s,
-        "ff_in": jax.random.normal(ks[2], (d, f), jnp.float32) * s,
-        "ff_out": jax.random.normal(ks[3], (f, d), jnp.float32) * (1.0 / math.sqrt(f)),
-        "ln1_scale": jnp.ones((d,), jnp.float32),
-        "ln2_scale": jnp.ones((d,), jnp.float32),
+        "qkv": (jax.random.normal(ks[0], (d, 3 * d), jnp.float32) * s).astype(dtype),
+        "o": (jax.random.normal(ks[1], (d, d), jnp.float32) * s).astype(dtype),
+        "ff_in": (jax.random.normal(ks[2], (d, f), jnp.float32) * s).astype(dtype),
+        "ff_out": (
+            jax.random.normal(ks[3], (f, d), jnp.float32) * (1.0 / math.sqrt(f))
+        ).astype(dtype),
+        "ln1_scale": jnp.ones((d,), dtype),
+        "ln2_scale": jnp.ones((d,), dtype),
     }
 
 
-def init_params(rng: Array, cfg: TransformerConfig) -> Params:
+def init_params(
+    rng: Array, cfg: TransformerConfig, dtype: Any = jnp.float32
+) -> Params:
+    """Random parameters. Every leaf is drawn in float32 and cast to
+    `dtype` before the next is drawn, so a bf16 tree of a 2B-parameter
+    decoder peaks at its own size plus one float32 leaf instead of the
+    whole float32 tree (8 GB of a 16 GB chip)."""
     ks = jax.random.split(rng, cfg.n_layers + 3)
     e = cfg.embed_dim or cfg.d_model
     params: Params = {
-        "tok_embed": jax.random.normal(
-            ks[0], (cfg.vocab_size, cfg.d_model), jnp.float32
-        )
-        * 0.02,
-        "pos_embed": jax.random.normal(ks[1], (cfg.max_len, cfg.d_model), jnp.float32)
-        * 0.02,
-        "ln_f_scale": jnp.ones((cfg.d_model,), jnp.float32),
-        "head": jax.random.normal(ks[2], (cfg.d_model, e), jnp.float32)
-        * (1.0 / math.sqrt(cfg.d_model)),
-        "blocks": [_init_block(ks[3 + i], cfg) for i in range(cfg.n_layers)],
+        "tok_embed": (
+            jax.random.normal(ks[0], (cfg.vocab_size, cfg.d_model), jnp.float32)
+            * 0.02
+        ).astype(dtype),
+        "pos_embed": (
+            jax.random.normal(ks[1], (cfg.max_len, cfg.d_model), jnp.float32)
+            * 0.02
+        ).astype(dtype),
+        "ln_f_scale": jnp.ones((cfg.d_model,), dtype),
+        "head": (
+            jax.random.normal(ks[2], (cfg.d_model, e), jnp.float32)
+            * (1.0 / math.sqrt(cfg.d_model))
+        ).astype(dtype),
+        "blocks": [
+            _init_block(ks[3 + i], cfg, dtype) for i in range(cfg.n_layers)
+        ],
     }
     return params
 

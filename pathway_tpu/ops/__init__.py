@@ -8,13 +8,6 @@ fused distance + top-k, segment reductions, and sharded variants that ride the
 ICI via `shard_map` collectives.
 """
 
-# jax version shims (jax.shard_map on old releases) before any
-# submodule builds a sharded program
-from pathway_tpu.internals import jax_compat as _jax_compat
-
-_jax_compat.install()
-
-
 from pathway_tpu.ops.distances import (
     cosine_distances,
     dot_products,

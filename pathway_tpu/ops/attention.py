@@ -26,13 +26,7 @@ import math
 import jax
 import jax.numpy as jnp
 
-try:  # pallas is optional at import time (host-only wheels)
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAS_PALLAS = True
-except Exception:  # pragma: no cover
-    _HAS_PALLAS = False
+from jax.experimental import pallas as pl
 
 
 def _attn_kernel(qkv_ref, bias_ref, out_ref, *, n_heads: int, head_dim: int,
@@ -82,16 +76,19 @@ def fused_qkv_attention(
 ) -> jax.Array:
     """Bidirectional MHA over a fused qkv tensor; returns ctx [b, s, d].
 
-    VMEM per grid step ~ block_b * s * 3d * 2B; default block_b=16 at
-    (s=64, d=384) is ~2.4 MB. Falls back to `reference_attention` when
-    pallas is unavailable.
+    VMEM per grid step: the qkv block (block_b * s * 3d * 2 B) and the
+    ctx block (a third of that), each double-buffered by the pipeline,
+    plus one head's f32 scores [block_b, s, s] and their exp/probs. At
+    the largest serving bucket (block_b=16, s=128, d=384) that is
+    9.4 MB + 3.1 MB + ~3 MB. Mosaic (libtpu 0.0.34, TPU v5e) compiles
+    that and every smaller bucket — s in 16..128, batch 8..4096, where
+    batch 8 halves block_b to 8 — and `chip_smoke.py` checks each
+    against `reference_attention` on the chip.
     """
     b, s, d3 = qkv.shape
     d = d3 // 3
     head_dim = d // n_heads
     scale = 1.0 / math.sqrt(head_dim)
-    if not _HAS_PALLAS:
-        return reference_attention(qkv, token_mask, n_heads)
     while b % block_b != 0:
         block_b //= 2
     bias = jnp.where(token_mask == 0, -1e30, 0.0).astype(jnp.float32)
@@ -115,7 +112,7 @@ def reference_attention(
     qkv: jax.Array, token_mask: jax.Array, n_heads: int
 ) -> jax.Array:
     """Plain-XLA einsum attention over the same fused-qkv contract —
-    the CPU/fallback path and the numerical reference for tests."""
+    the path off the TPU and the numerical reference for tests."""
     b, s, d3 = qkv.shape
     d = d3 // 3
     dh = d // n_heads

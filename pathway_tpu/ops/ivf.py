@@ -350,17 +350,14 @@ def build_ivf_pq(
     cap = 1 << math.ceil(math.log2(max(8, 2 * ((n + L - 1) // L))))
     assign = assign_lists_balanced(docs, centroids, cap)
     cube, valid, slots = pack_lists(assign, codes, L, cap=cap)
-    try:
-        import jax.numpy as jnp
+    import jax.numpy as jnp
 
-        # f32, not bf16: the rescore exists to restore exact order among
-        # near-tied winners, and bf16-rounded rows (2^-8 resolution) cap
-        # recall@10 at ~0.95 on clustered corpora. Rescore traffic is
-        # c*d per query, so f32 costs capacity only — and the capacity
-        # story belongs to the PQ codes, not the rescore rows.
-        full = jnp.asarray(docs, jnp.float32)
-    except ImportError:  # host-only fallback
-        full = docs
+    # f32, not bf16: the rescore exists to restore exact order among
+    # near-tied winners, and bf16-rounded rows (2^-8 resolution) cap
+    # recall@10 at ~0.95 on clustered corpora. Rescore traffic is
+    # c*d per query, so f32 costs capacity only — and the capacity
+    # story belongs to the PQ codes, not the rescore rows.
+    full = jnp.asarray(docs, jnp.float32)
     return IvfPqArrays(
         centroids=centroids,
         codes=cube,

@@ -19,9 +19,9 @@ fancier pair function. A custom jax `scorer(q[B,d], cands[B,C,d]) ->
 [B,C]` (e.g. a learned cross-encoder head) drops in through the same
 bucketed dispatch.
 
-Degradation: 3-strike to the numpy mirror (`rerank_scores_host`),
-permanent on ImportError/NotImplementedError — the same ladder as
-every other device op in the repo.
+Degradation: three consecutive failures switch to the numpy mirror
+(`rerank_scores_host`) for good and say so in the error log — the same
+ladder as every other device op in the repo.
 """
 
 from __future__ import annotations
@@ -110,14 +110,11 @@ class BatchedReranker:
                 out = self._scores_device(q, cands, valid)
                 self._failures = 0
                 return out
-            except (ImportError, NotImplementedError) as e:
-                self._use_device = False
-                self._log(e, permanent=True)
             except Exception as e:  # noqa: BLE001 — transient (OOM…)
                 self._failures += 1
                 if self._failures >= 3:
                     self._use_device = False
-                self._log(e, permanent=not self._use_device)
+                self._log(e, disabled=not self._use_device)
         if self._scorer is not None:
             raise RuntimeError(
                 "custom rerank scorer has no host mirror and the device "
@@ -161,11 +158,15 @@ class BatchedReranker:
         )
         return np.asarray(s)[:B, :C]
 
-    @staticmethod
-    def _log(e: Exception, permanent: bool) -> None:
+    def _log(self, e: Exception, *, disabled: bool) -> None:
         from pathway_tpu.internals.errors import global_error_log
 
         global_error_log().log(
             f"device rerank failed ({type(e).__name__}: {e}); "
-            + ("numpy mirror from now on" if permanent else "retrying")
+            + (
+                f"device program {self.name!r} DISABLED for this reranker: "
+                "the numpy mirror scores every later wave"
+                if disabled
+                else "served by the numpy mirror, will retry"
+            )
         )

@@ -10,10 +10,7 @@ with `all_to_all` over the mesh (ICI intra-pod, DCN across pods).
 The package namespace is lazy (PEP 562): importing `pathway_tpu.parallel`
 must NOT pull in jax, because every Session imports `process_mesh` (a
 pure-socket module) and mesh-less pipelines would otherwise pay the whole
-jax-ecosystem import on their first wave. The jax version shim
-(`jax_compat.install()`, required before any submodule builds a sharded
-program) runs inside exchange.py itself — the one submodule that calls
-`shard_map` — and again at first attribute access here.
+jax-ecosystem import on their first wave.
 """
 
 _EXPORTS = {
@@ -33,9 +30,6 @@ def __getattr__(name: str):
     target = _EXPORTS.get(name)
     if target is None:
         raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
-    from pathway_tpu.internals import jax_compat as _jax_compat
-
-    _jax_compat.install()
     import importlib
 
     mod = importlib.import_module(f"pathway_tpu.parallel.{target}")

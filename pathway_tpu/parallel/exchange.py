@@ -37,12 +37,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from pathway_tpu.internals import jax_compat as _jax_compat
-
-# jax.shard_map must resolve on old releases before any program below is
-# built; the package __init__ is lazy and no longer guarantees this ran.
-_jax_compat.install()
-
 Array = jax.Array
 
 

@@ -33,11 +33,64 @@ import tempfile
 import time
 from typing import Any, Sequence
 
-__all__ = ["SupervisedMeshFailed", "run_supervised"]
+__all__ = ["SupervisedMeshFailed", "chip_env", "run_supervised"]
 
 
 class SupervisedMeshFailed(RuntimeError):
     """The mesh kept failing past ``max_restarts`` generations."""
+
+
+def _local_tpu_chips() -> list[int]:
+    """Chip numbers of this host's TPU device files (``/dev/vfio/N`` on
+    v5e, ``/dev/accelN`` before it), read without touching JAX: the
+    launcher must not open the device its workers need."""
+    chips = set()
+    for directory, prefix in (("/dev/vfio", ""), ("/dev", "accel")):
+        try:
+            names = os.listdir(directory)
+        except OSError:
+            continue
+        for name in names:
+            if name.startswith(prefix) and name[len(prefix):].isdigit():
+                chips.add(int(name[len(prefix):]))
+    return sorted(chips)
+
+
+def chip_env(pid: int, n: int, env: dict[str, str]) -> dict[str, str]:
+    """What worker ``pid`` of ``n`` adds to its environment to own one
+    TPU chip. A chip belongs to one process at a time, so ``n`` workers
+    started with one environment all reach for the same chip and every
+    one but the first dies at backend start-up ("Unable to initialize
+    backend 'tpu' ... libtpu multi-process lockfile" — measured on a
+    one-chip v5e). With at least ``n`` chips on the host, worker k is
+    bound to the k-th chip as a one-chip process of its own; with fewer
+    the environment is left alone and the worker that loses the chip
+    fails by that name — it neither hangs nor continues on the CPU. A
+    run that is held to the CPU, or that already places its workers
+    (``TPU_VISIBLE_CHIPS`` and friends set by the caller), is left alone
+    too."""
+    if n <= 1 or "tpu" not in (env.get("JAX_PLATFORMS") or "tpu"):
+        return {}
+    if any(
+        k in env
+        for k in ("TPU_VISIBLE_CHIPS", "TPU_VISIBLE_DEVICES", "TPU_PROCESS_BOUNDS")
+    ):
+        return {}
+    chips = _local_tpu_chips()
+    if len(chips) < n:
+        return {}
+    port = 8476 + pid
+    return {
+        "TPU_VISIBLE_CHIPS": str(chips[pid]),
+        "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+        "TPU_PROCESS_BOUNDS": "1,1,1",
+        "TPU_PROCESS_ADDRESSES": f"localhost:{port}",
+        "TPU_PROCESS_PORT": str(port),
+        "CLOUD_TPU_TASK_ID": "0",
+        # libtpu's one-process-per-host lockfile does not know about
+        # disjoint chip sets
+        "ALLOW_MULTIPLE_LIBTPU_LOAD": "1",
+    }
 
 
 def _spawn(
@@ -51,6 +104,7 @@ def _spawn(
     for pid in range(n):
         penv = {
             **env,
+            **chip_env(pid, n, env),
             "PATHWAY_PROCESSES": str(n),
             "PATHWAY_PROCESS_ID": str(pid),
             "PATHWAY_FIRST_PORT": str(first_port),

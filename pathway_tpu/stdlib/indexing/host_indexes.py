@@ -76,6 +76,10 @@ class VectorSlabIndex(HostIndex):
     `approx` selects `lax.approx_max_k` for the top-k phase.
     """
 
+    # consecutive failed device searches; a class default so that indexes
+    # unpickled from older operator snapshots have it too
+    _device_failures = 0
+
     def __init__(
         self,
         dimensions: int | None = None,
@@ -286,25 +290,32 @@ class VectorSlabIndex(HostIndex):
                 result = self._topk_device(qmat, k)
                 self._device_failures = 0
                 return result
-            except (ImportError, NotImplementedError) as e:
-                # backend genuinely unavailable: disable for good
-                self.use_device = False
-                self._log_device_error(e, permanent=True)
             except Exception as e:  # noqa: BLE001 — possibly transient (OOM…)
-                failures = getattr(self, "_device_failures", 0) + 1
-                self._device_failures = failures
-                if failures >= 3:
+                self._device_failures += 1
+                if self._device_failures >= 3:
                     self.use_device = False  # three strikes: stop retrying
-                self._log_device_error(e, permanent=not self.use_device)
+                self._log_device_error(
+                    e, "knn_slab_search", disabled=not self.use_device
+                )
         return self._topk_host(qmat, k)
 
-    def _log_device_error(self, e: Exception, permanent: bool) -> None:
+    def _log_device_error(
+        self, e: Exception, path: str, *, disabled: bool
+    ) -> None:
+        """One error-log line per failed device search. `path` names the
+        device path that failed; a `disabled` line is the last this
+        index writes about it — every later search is a host scan."""
         from pathway_tpu.internals.errors import global_error_log
 
-        state = "disabled" if permanent else "will retry"
+        state = (
+            f"DISABLED for this {type(self).__name__}: every later search "
+            "is a host scan"
+            if disabled
+            else "will retry"
+        )
         global_error_log().log(
-            f"KNN device search failed ({type(e).__name__}: {e}); "
-            f"falling back to host scan, device path {state}"
+            f"KNN device search failed ({type(e).__name__}: {e}); served "
+            f"by the host scan, device path {path!r} {state}"
         )
 
     def _topk_device(self, qmat: np.ndarray, k: int):

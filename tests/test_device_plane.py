@@ -532,6 +532,76 @@ def test_quarantine_reset_is_the_generation_boundary_slate_wipe(monkeypatch):
     assert prog.compile_counts.get(4) == 1
 
 
+def test_first_compile_failure_raises_and_reaches_the_error_log(monkeypatch):
+    """A program the compiler refuses on its first call is a bug in the
+    program, not a device fault: the call raises, nothing is served from
+    the host, the quarantine record and last_error reach the error log,
+    and until the re-probe the bucket raises DeviceCompileError."""
+    from pathway_tpu.engine.device_plane import DeviceCompileError
+    from pathway_tpu.internals.errors import global_error_log
+
+    monkeypatch.setattr(DeviceProgram, "PROBE_BASE_S", 120.0)
+    monkeypatch.setattr(DeviceProgram, "PROBE_CAP_S", 120.0)
+    host_calls = []
+
+    def refuses_wide(x):
+        if x.shape[0] > 4:  # a trace-time refusal, as a compiler's would be
+            raise ValueError("block does not fit")
+        host_calls.append(x.shape)
+        return x * 2
+
+    plane = DevicePlane()
+    prog = plane.program("refuses_wide", refuses_wide)
+    log = global_error_log().entries
+    before = len(log)
+    with pytest.raises(ValueError, match="block does not fit"):
+        prog(np.arange(8), bucket=8)
+    assert prog.host_fallbacks == 0
+    q = plane.quarantined()[("refuses_wide", 8)]
+    assert q["never_compiled"] and "block does not fit" in q["last_error"]
+    assert prog.compile_counts == {}
+    (entry,) = log[before:]
+    assert "refuses_wide" in entry and "bucket 8" in entry
+    assert "first compile failed" in entry and "block does not fit" in entry
+    # cooldown running: loud, not a host success
+    with pytest.raises(DeviceCompileError, match="never compiled"):
+        prog(np.arange(8), bucket=8)
+    assert prog.host_fallbacks == 0
+    # the other bucket of the same program is untouched
+    host_calls.clear()
+    np.testing.assert_array_equal(
+        np.asarray(prog(np.arange(4), bucket=4)), np.arange(4) * 2
+    )
+    assert prog.compile_counts == {4: 1} and prog.host_fallbacks == 0
+    assert prog.compile_seconds[4] > 0.0
+
+
+def test_failed_call_that_consumed_its_donation_is_not_rerun_on_host(
+    monkeypatch,
+):
+    """The host re-run needs the arguments; a failed call that already
+    deleted a donated buffer re-raises instead of touching it."""
+    import jax.numpy as jnp
+
+    monkeypatch.setattr(DeviceProgram, "PROBE_BASE_S", 120.0)
+    plane = DevicePlane()
+    prog = plane.program(
+        "donating", lambda buf, x: (buf + x, x), donate_argnums=(0,)
+    )
+    buf, x = jnp.zeros(4), jnp.ones(4)
+    buf, _ = prog(buf, x, bucket=4)  # compiles: the signature is now seen
+
+    def lose_device(buf, x):
+        buf.delete()  # what a donating executable does before it fails
+        raise RuntimeError("device lost")
+
+    monkeypatch.setattr(prog, "_jit", lose_device)
+    with pytest.raises(RuntimeError, match="device lost"):
+        prog(buf, x, bucket=4)
+    assert prog.host_fallbacks == 0
+    assert not plane.quarantined()[("donating", 4)]["never_compiled"]
+
+
 def test_plane_wide_quarantine_reset_spans_programs():
     """The supervisor's generation-boundary hook is the module-level
     reset_quarantines(): it sweeps every registered program on the
