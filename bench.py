@@ -579,7 +579,7 @@ print("PAGERANK", N_E / total, update_ms)
 def _run_pagerank_once(repo: str, env_extra: dict) -> tuple[float, float]:
     env = dict(os.environ)
     env.update(env_extra)
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    env["JAX_PLATFORMS"] = "cpu"  # never the chip: the parent may hold it
     env.setdefault("JAX_COMPILATION_CACHE_DIR", _XLA_CACHE)
     script = _PAGERANK_SCRIPT.format(repo=repo, n_clusters=50, k=40, deg=6)
     r = subprocess.run(
@@ -821,7 +821,7 @@ def _run_engine_script_once(
     """Returns (rows_per_sec, peak_rss_mb) of one subprocess run."""
     env = dict(os.environ)
     env.update(env_extra)
-    env.setdefault("JAX_PLATFORMS", "cpu")  # engine configs never touch the chip
+    env["JAX_PLATFORMS"] = "cpu"  # never the chip: the parent may hold it
     env.setdefault("JAX_COMPILATION_CACHE_DIR", _XLA_CACHE)
     env.setdefault("JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES", "0")
     env.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0")

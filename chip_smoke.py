@@ -258,9 +258,10 @@ def _device_ids(x: Any) -> list[int]:
     return sorted(d.id for d in x.devices())
 
 
-def serve_and_ask(widths: Widths, checks: Checks, report: dict) -> None:
+def serve_and_ask(widths: Widths, checks: Checks, report: dict) -> Any:
     """Build the RAG service, serve it from a thread, send the traffic,
-    stop it, and check what the device plane did."""
+    stop it, and check what the device plane did. Returns the chat, whose
+    decoder the several-chip leg decodes with again."""
     import jax
     import jax.numpy as jnp
 
@@ -417,6 +418,7 @@ def serve_and_ask(widths: Widths, checks: Checks, report: dict) -> None:
     }
     if cache is not None:
         plane.restore(chat._cb._cache_key, cache)
+    return chat
 
 
 def check_device_plane(
@@ -468,17 +470,26 @@ def check_device_plane(
 # ------------------------------------------------------------ several chips
 
 
-def check_sharded_legs(n_devices: int, checks: Checks, report: dict) -> None:
+def check_sharded_legs(
+    n_devices: int, chat: Any, checks: Checks, report: dict
+) -> None:
     """On a multi-chip host: the sharded legs of ``__graft_entry__`` and a
     mesh-spanning decode, on the real devices, in this process. Each leg
-    reports the devices its sharded operands occupy; fewer than all of
-    them (at least four) is a failure."""
+    reports the devices that hold what its program produced; fewer than
+    all of them (at least four) is a failure.
+
+    The decode is the served decoder itself — the chat's configuration
+    and parameters, no width or depth cut — in a slot pool spread over
+    the mesh, against the same prompts through a single-device pool. The
+    parameters are copied to every chip first: the batcher leaves them
+    where its caller put them."""
     import jax
+    from jax.sharding import NamedSharding, PartitionSpec as P
 
     import __graft_entry__ as graft
     from pathway_tpu.engine.device_plane import get_device_plane
-    from pathway_tpu.models import lm_config, transformer
     from pathway_tpu.parallel import device_exchange as dx
+    from pathway_tpu.parallel.mesh import default_mesh
     from pathway_tpu.serving.continuous_batching import ContinuousBatcher
 
     legs = graft.sharded_legs(n_devices)
@@ -492,28 +503,32 @@ def check_sharded_legs(n_devices: int, checks: Checks, report: dict) -> None:
             )
         )
 
-    class Tok:
-        def tokenize(self, s: str) -> list[int]:
-            return [2 + (ord(c) % 40) for c in s][:12]
-
-    cfg = lm_config(
-        vocab_size=128, d_model=16, n_heads=2, n_layers=1, d_ff=32, max_len=32
-    )
-    params = transformer.init_params(jax.random.PRNGKey(0), cfg)
     plane = get_device_plane()
-    tokens = {}
+    everywhere = NamedSharding(default_mesh(("data",)), P())
+    prompts = [f"prompt {i} " + "word " * (3 * i) for i in range(4)]
+    slots_per_chip, tokens = 2, {}
     for span in (False, True):
         cb = ContinuousBatcher(
-            params=params, cfg=cfg, tokenizer=Tok(), n_steps=3, n_slots=2,
-            mesh_span=span,
+            params=jax.device_put(chat.params, everywhere) if span
+            else chat.params,
+            cfg=chat.config, tokenizer=chat.tokenizer, n_steps=3,
+            n_slots=slots_per_chip, mesh_span=span,
         )
         try:
-            futs = [cb.submit(f"prompt {i}") for i in range(4)]
+            futs = [cb.submit(p) for p in prompts]
             tokens[span] = [f.result(timeout=600) for f in futs]
             cb.drain()
             if span:
+                # the cache as the last decode step handed it back
                 cache = plane.lease(cb._cache_key, lambda: None)
                 legs["continuous_batcher_mesh_span"] = _device_ids(cache["k"])
+                local = {
+                    sh.data.shape[1] for sh in cache["k"].addressable_shards
+                }
+                checks.check(
+                    f"each chip holds {slots_per_chip} decode slots",
+                    local == {slots_per_chip}, local,
+                )
                 plane.restore(cb._cache_key, cache)
         finally:
             cb.close()
@@ -521,6 +536,11 @@ def check_sharded_legs(n_devices: int, checks: Checks, report: dict) -> None:
         "mesh-spanning decode matches the single-device pool",
         tokens[True] == tokens[False], tokens,
     )
+    report["mesh_span_decode"] = {
+        "d_model": chat.config.d_model, "n_layers": chat.config.n_layers,
+        "vocab_size": chat.config.vocab_size,
+        "slots": slots_per_chip * n_devices, "requests": len(prompts),
+    }
     report["sharded_legs"] = legs
     need = max(4, n_devices)
     for leg, ids in legs.items():
@@ -612,9 +632,12 @@ def run_smoke(widths: Widths = FULL, *, require_tpu: bool = True) -> int:
     report["kernel_check"] = timed(
         "kernel_check", check_kernel, widths, dev.platform == "tpu", checks
     )
-    timed("build_serve_ask_stop", serve_and_ask, widths, checks, report)
+    chat = timed("build_serve_ask_stop", serve_and_ask, widths, checks, report)
     if len(devices) > 1:
-        timed("sharded_legs", check_sharded_legs, len(devices), checks, report)
+        timed(
+            "sharded_legs", check_sharded_legs, len(devices), chat, checks,
+            report,
+        )
     report["phase_seconds"] = phases
     report["compile_cache"] = {
         "dir": cache_dir,

@@ -44,7 +44,7 @@ def _spawn(args: argparse.Namespace) -> int:
     env_base["PATHWAY_FIRST_PORT"] = str(args.first_port)
     procs: list[subprocess.Popen] = []
     for pid in range(args.processes):
-        env = {**env_base, **chip_env(pid, args.processes, env_base)}
+        env = {**env_base, **chip_env(pid, args.processes, args.first_port, env_base)}
         env["PATHWAY_PROCESS_ID"] = str(pid)
         procs.append(subprocess.Popen([sys.executable, *command], env=env))
     rc = 0

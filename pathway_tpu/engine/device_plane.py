@@ -52,7 +52,6 @@ from pathway_tpu.analysis import lockgraph as _lockgraph
 
 __all__ = [
     "BucketPolicy",
-    "DeviceCompileError",
     "DeviceProgram",
     "DevicePlane",
     "SlotPool",
@@ -61,11 +60,6 @@ __all__ = [
     "get_device_plane",
     "reset_quarantines",
 ]
-
-
-class DeviceCompileError(RuntimeError):
-    """A (program, bucket) whose first compile failed was called again
-    before its re-probe cooldown ran out."""
 
 
 class BucketPolicy:
@@ -145,18 +139,16 @@ class DeviceProgram:
     injected fault is a bug in the program (a shape the compiler
     refuses, a kernel that does not fit), not a device fault: a program
     that has never run must not turn into a slow success on the host.
-    The call re-raises, the quarantine record is marked
-    ``never_compiled`` and, until a re-probe compiles, further calls for
-    that bucket raise :class:`DeviceCompileError` instead of being
-    served from the host.
+    The call re-raises and nothing is quarantined: the next call for
+    that bucket is a first compile again, and raises again.
 
     The host re-run is also refused when the failed call already
     consumed a donated argument (``cb/step`` donates the KV cache): the
     buffer is gone, so the original exception propagates to the caller,
     which owns the recovery of that buffer.
 
-    Every quarantine, of either kind, is written to the global error log
-    with the program, the bucket and ``last_error``.
+    Every quarantine and every failed first compile is written to the
+    global error log with the program, the bucket and the error.
     """
 
     # re-probe backoff for quarantined buckets (class-level so tests and
@@ -224,13 +216,7 @@ class DeviceProgram:
         return (treedef, tuple(leaf(x) for x in flat))
 
     def __call__(self, *args: Any, bucket: Any = None, **kwargs: Any) -> Any:
-        blocked = self._blocked(bucket) if self.quarantine else None
-        if blocked is not None:
-            if blocked["never_compiled"]:
-                raise DeviceCompileError(
-                    f"device program {self.name!r} bucket {bucket!r} has "
-                    f"never compiled: {blocked['last_error']}"
-                )
+        if self.quarantine and not self._admit_probe(bucket):
             # quarantined bucket, cooldown still running: host path
             return self._host_path(args, kwargs)
         # bookkeeping only under the lock; the dispatch itself runs
@@ -248,43 +234,43 @@ class DeviceProgram:
             faults.check(f"device.dispatch.{self.name}")
             out = self._jit(*args, **kwargs)
         except Exception as e:  # noqa: BLE001 — quarantined, logged; see below
-            never_compiled = fresh_sig and not isinstance(
+            from pathway_tpu.internals.errors import global_error_log
+
+            error = f"{type(e).__name__}: {e}"
+            first_compile = fresh_sig and not isinstance(
                 e, faults.FaultInjected
             )
-            error = f"{type(e).__name__}: {e}"
             with self._lock:
                 if fresh_sig:
                     # the compile never happened; let a successful
-                    # re-probe charge the ledger instead
+                    # later call charge the ledger instead
                     self._seen_sigs.discard(sig)
                     n = self.compile_counts.get(bucket, 0) - 1
                     if n > 0:
                         self.compile_counts[bucket] = n
                     else:
                         self.compile_counts.pop(bucket, None)
-                q = self.quarantine.setdefault(
-                    bucket,
-                    {"failures": 0, "reopen_at": 0.0, "last_error": "",
-                     "never_compiled": False},
+                if not first_compile:
+                    q = self.quarantine.setdefault(
+                        bucket,
+                        {"failures": 0, "reopen_at": 0.0, "last_error": ""},
+                    )
+                    q["failures"] += 1
+                    q["last_error"] = error
+                    q["reopen_at"] = _time.monotonic() + self._cooldown(
+                        q["failures"]
+                    )
+                    failures = q["failures"]
+            if first_compile:
+                global_error_log().log(
+                    f"device program {self.name!r} bucket {bucket!r}: "
+                    f"first compile failed, not served from the host: "
+                    f"{error[:600]}"
                 )
-                q["failures"] += 1
-                q["last_error"] = error
-                q["never_compiled"] = never_compiled
-                q["reopen_at"] = _time.monotonic() + self._cooldown(
-                    q["failures"]
-                )
-                failures = q["failures"]
-            from pathway_tpu.internals.errors import global_error_log
-
+                raise
             global_error_log().log(
                 f"device program {self.name!r} bucket {bucket!r} "
-                f"quarantined after {failures} failure(s)"
-                + (
-                    ", first compile failed, not served from the host"
-                    if never_compiled
-                    else ""
-                )
-                + f": {error[:600]}"
+                f"quarantined after {failures} failure(s): {error[:600]}"
             )
             if _obs.PLANE is not None:
                 _obs.PLANE.record(
@@ -298,7 +284,7 @@ class DeviceProgram:
                     help="device dispatches that failed and quarantined "
                     "their bucket",
                 )
-            if never_compiled or self._donation_consumed(args):
+            if self._donation_consumed(args):
                 raise
             return self._host_path(args, kwargs)
         with self._lock:
@@ -347,20 +333,19 @@ class DeviceProgram:
             self.quarantine.clear()
         return n
 
-    def _blocked(self, bucket: Any) -> dict[str, Any] | None:
-        """None when the bucket is healthy, or quarantined but due for a
+    def _admit_probe(self, bucket: Any) -> bool:
+        """True when the bucket is healthy, or quarantined but due for a
         re-probe (which is then claimed: the cooldown moves forward so
-        concurrent callers don't stampede the device); otherwise a copy
-        of the quarantine record that keeps this call off the device."""
+        concurrent callers don't stampede the device)."""
         with self._lock:
             q = self.quarantine.get(bucket)
             if q is None:
-                return None
+                return True
             now = _time.monotonic()
             if now < q["reopen_at"]:
-                return dict(q)
+                return False
             q["reopen_at"] = now + self._cooldown(q["failures"])
-            return None
+            return True
 
     def _host_path(self, args: tuple, kwargs: dict) -> Any:
         """Serve one dispatch from the un-jitted function."""

@@ -67,13 +67,6 @@ _LIVE_RETRAINS: "weakref.WeakSet[IvfPqIndex]" = weakref.WeakSet()
 
 
 @atexit.register
-def _mesh_has_peers() -> bool:
-    """The list-sharded search has something to shard over."""
-    import jax
-
-    return len(jax.devices()) > 1
-
-
 def _drain_retrain_threads() -> None:
     for idx in list(_LIVE_RETRAINS):
         t = idx._retrain_thread
@@ -95,6 +88,13 @@ def _drain_tier_daemons() -> None:
         t = idx._tier_thread
         if t is not None and t.is_alive():
             t.join(timeout=10)
+
+
+def _mesh_has_peers() -> bool:
+    """The list-sharded search has something to shard over."""
+    import jax
+
+    return len(jax.devices()) > 1
 
 
 def _tier_loop(ref: "weakref.ref[IvfPqIndex]", stop: threading.Event,

@@ -71,23 +71,11 @@ def run(
     runtime_typechecking: bool = True,
     terminate_on_error: bool = False,
     autocommit_duration_ms: int | None = None,
-    device: str | None = None,
     observability: bool | None = None,
     profile: bool | str | None = None,
-    **kwargs: Any,
 ) -> None:
     import time as _time
 
-    if device is not None:
-        # JAX picks the device (JAX_PLATFORMS); `device` states what the
-        # caller expects and the run refuses to start on anything else
-        import jax
-
-        if jax.default_backend() != device:
-            raise RuntimeError(
-                f"pw.run(device={device!r}): JAX's default backend is "
-                f"{jax.default_backend()!r} ({jax.devices()})"
-            )
     profile_path = _arm_observability(observability, profile)
     _build_t0 = _time.perf_counter()
     session = Session()

@@ -40,35 +40,41 @@ class SupervisedMeshFailed(RuntimeError):
     """The mesh kept failing past ``max_restarts`` generations."""
 
 
-def _local_tpu_chips() -> list[int]:
-    """Chip numbers of this host's TPU device files (``/dev/vfio/N`` on
-    v5e, ``/dev/accelN`` before it), read without touching JAX: the
-    launcher must not open the device its workers need."""
-    chips = set()
+def _local_tpu_chip_count() -> int:
+    """How many TPU device files this host has (``/dev/vfio/N`` on v5e,
+    ``/dev/accelN`` before it), read without touching JAX: the launcher
+    must not open the device its workers need. Only the count is used:
+    the numbers in the names are IOMMU group ids, not chip indices."""
+    found = 0
     for directory, prefix in (("/dev/vfio", ""), ("/dev", "accel")):
         try:
             names = os.listdir(directory)
         except OSError:
             continue
-        for name in names:
-            if name.startswith(prefix) and name[len(prefix):].isdigit():
-                chips.add(int(name[len(prefix):]))
-    return sorted(chips)
+        found += sum(
+            1 for name in names
+            if name.startswith(prefix) and name[len(prefix):].isdigit()
+        )
+    return found
 
 
-def chip_env(pid: int, n: int, env: dict[str, str]) -> dict[str, str]:
+def chip_env(
+    pid: int, n: int, first_port: int, env: dict[str, str]
+) -> dict[str, str]:
     """What worker ``pid`` of ``n`` adds to its environment to own one
     TPU chip. A chip belongs to one process at a time, so ``n`` workers
     started with one environment all reach for the same chip and every
     one but the first dies at backend start-up ("Unable to initialize
     backend 'tpu' ... libtpu multi-process lockfile" — measured on a
     one-chip v5e). With at least ``n`` chips on the host, worker k is
-    bound to the k-th chip as a one-chip process of its own; with fewer
-    the environment is left alone and the worker that loses the chip
-    fails by that name — it neither hangs nor continues on the CPU. A
-    run that is held to the CPU, or that already places its workers
-    (``TPU_VISIBLE_CHIPS`` and friends set by the caller), is left alone
-    too."""
+    bound to chip index k as a one-chip process of its own, on the port
+    after the mesh's own (``first_port`` .. ``first_port + n - 1``, see
+    process_mesh.py), so two launches that differ in ``first_port`` do
+    not meet; with fewer chips the environment is left alone and the
+    worker that loses the chip fails by that name — it neither hangs nor
+    continues on the CPU. A run that is held to the CPU, or that already
+    places its workers (``TPU_VISIBLE_CHIPS`` and friends set by the
+    caller), is left alone too."""
     if n <= 1 or "tpu" not in (env.get("JAX_PLATFORMS") or "tpu"):
         return {}
     if any(
@@ -76,12 +82,11 @@ def chip_env(pid: int, n: int, env: dict[str, str]) -> dict[str, str]:
         for k in ("TPU_VISIBLE_CHIPS", "TPU_VISIBLE_DEVICES", "TPU_PROCESS_BOUNDS")
     ):
         return {}
-    chips = _local_tpu_chips()
-    if len(chips) < n:
+    if _local_tpu_chip_count() < n:
         return {}
-    port = 8476 + pid
+    port = first_port + n + pid
     return {
-        "TPU_VISIBLE_CHIPS": str(chips[pid]),
+        "TPU_VISIBLE_CHIPS": str(pid),
         "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
         "TPU_PROCESS_BOUNDS": "1,1,1",
         "TPU_PROCESS_ADDRESSES": f"localhost:{port}",
@@ -104,7 +109,7 @@ def _spawn(
     for pid in range(n):
         penv = {
             **env,
-            **chip_env(pid, n, env),
+            **chip_env(pid, n, first_port, env),
             "PATHWAY_PROCESSES": str(n),
             "PATHWAY_PROCESS_ID": str(pid),
             "PATHWAY_FIRST_PORT": str(first_port),

@@ -142,28 +142,55 @@ def test_imports_initialise_no_backend():
     assert out == "0"
 
 
-def test_chip_env_binds_worker_k_to_chip_k_when_there_are_chips_enough(
+def test_chip_env_binds_worker_k_to_chip_index_k_when_there_are_chips_enough(
     monkeypatch,
 ):
     from pathway_tpu.parallel import supervisor
 
-    monkeypatch.setattr(supervisor, "_local_tpu_chips", lambda: [0, 1, 2, 3])
+    # the device files of a host whose IOMMU groups are not 0..3: only
+    # their number counts
+    monkeypatch.setattr(
+        supervisor.os, "listdir",
+        lambda d: ["vfio", "12", "13", "14", "15"] if d == "/dev/vfio" else [],
+    )
     env = {"JAX_PLATFORMS": "tpu,cpu"}
-    bound = [supervisor.chip_env(k, 4, env) for k in range(4)]
+    bound = [supervisor.chip_env(k, 4, 10000, env) for k in range(4)]
     assert [b["TPU_VISIBLE_CHIPS"] for b in bound] == ["0", "1", "2", "3"]
-    assert len({b["TPU_PROCESS_PORT"] for b in bound}) == 4
+    # after the mesh's own ports, so launches on other ports do not meet
+    assert [b["TPU_PROCESS_PORT"] for b in bound] == [
+        "10004", "10005", "10006", "10007",
+    ]
+    other = supervisor.chip_env(0, 4, 20000, env)
+    assert other["TPU_PROCESS_ADDRESSES"] == "localhost:20004"
     assert all(b["TPU_PROCESS_BOUNDS"] == "1,1,1" for b in bound)
     # one worker, a CPU run, or a caller that already placed its workers
-    assert supervisor.chip_env(0, 1, env) == {}
-    assert supervisor.chip_env(1, 4, {"JAX_PLATFORMS": "cpu"}) == {}
-    assert supervisor.chip_env(1, 4, {"TPU_VISIBLE_CHIPS": "2"}) == {}
+    assert supervisor.chip_env(0, 1, 10000, env) == {}
+    assert supervisor.chip_env(1, 4, 10000, {"JAX_PLATFORMS": "cpu"}) == {}
+    assert supervisor.chip_env(1, 4, 10000, {"TPU_VISIBLE_CHIPS": "2"}) == {}
     # fewer chips than workers: left alone, the loser fails by name
-    monkeypatch.setattr(supervisor, "_local_tpu_chips", lambda: [1])
-    assert supervisor.chip_env(1, 2, env) == {}
+    assert supervisor.chip_env(1, 8, 10000, env) == {}
 
 
-def test_run_refuses_a_device_jax_does_not_have():
+def test_exit_drains_retrains_and_starts_no_backend():
+    """What `indexing.ann` registers with atexit is the two drains — a
+    live retrain thread racing interpreter exit aborts the process — and
+    nothing that would open a device on the way out."""
+    out = _python(
+        "import atexit, json\n"
+        "seen = []\n"
+        "register = atexit.register\n"
+        "atexit.register = lambda f, *a, **k: (seen.append(f), register(f, *a, **k))[1]\n"
+        "import pathway_tpu.indexing.ann as ann\n"
+        "print(json.dumps(sorted(f.__name__ for f in seen if f.__module__ == ann.__name__)))",
+        {},
+    )
+    assert json.loads(out) == ["_drain_retrain_threads", "_drain_tier_daemons"]
+
+
+def test_run_takes_no_device_argument():
+    """JAX picks the device; a `device=` that was accepted and ignored
+    would be a quiet CPU run at the front door."""
     import pathway_tpu as pw
 
-    with pytest.raises(RuntimeError, match="default backend is 'cpu'"):
+    with pytest.raises(TypeError, match="device"):
         pw.run(device="tpu")

@@ -532,16 +532,13 @@ def test_quarantine_reset_is_the_generation_boundary_slate_wipe(monkeypatch):
     assert prog.compile_counts.get(4) == 1
 
 
-def test_first_compile_failure_raises_and_reaches_the_error_log(monkeypatch):
+def test_first_compile_failure_raises_and_reaches_the_error_log():
     """A program the compiler refuses on its first call is a bug in the
     program, not a device fault: the call raises, nothing is served from
-    the host, the quarantine record and last_error reach the error log,
-    and until the re-probe the bucket raises DeviceCompileError."""
-    from pathway_tpu.engine.device_plane import DeviceCompileError
+    the host or quarantined, the program, bucket and error reach the
+    error log, and the next call is a first compile again."""
     from pathway_tpu.internals.errors import global_error_log
 
-    monkeypatch.setattr(DeviceProgram, "PROBE_BASE_S", 120.0)
-    monkeypatch.setattr(DeviceProgram, "PROBE_CAP_S", 120.0)
     host_calls = []
 
     def refuses_wide(x):
@@ -556,17 +553,15 @@ def test_first_compile_failure_raises_and_reaches_the_error_log(monkeypatch):
     before = len(log)
     with pytest.raises(ValueError, match="block does not fit"):
         prog(np.arange(8), bucket=8)
-    assert prog.host_fallbacks == 0
-    q = plane.quarantined()[("refuses_wide", 8)]
-    assert q["never_compiled"] and "block does not fit" in q["last_error"]
+    assert prog.host_fallbacks == 0 and plane.quarantined() == {}
     assert prog.compile_counts == {}
     (entry,) = log[before:]
     assert "refuses_wide" in entry and "bucket 8" in entry
     assert "first compile failed" in entry and "block does not fit" in entry
-    # cooldown running: loud, not a host success
-    with pytest.raises(DeviceCompileError, match="never compiled"):
+    # again: loud, not a host success
+    with pytest.raises(ValueError, match="block does not fit"):
         prog(np.arange(8), bucket=8)
-    assert prog.host_fallbacks == 0
+    assert prog.host_fallbacks == 0 and len(log) == before + 2
     # the other bucket of the same program is untouched
     host_calls.clear()
     np.testing.assert_array_equal(
@@ -599,7 +594,7 @@ def test_failed_call_that_consumed_its_donation_is_not_rerun_on_host(
     with pytest.raises(RuntimeError, match="device lost"):
         prog(buf, x, bucket=4)
     assert prog.host_fallbacks == 0
-    assert not plane.quarantined()[("donating", 4)]["never_compiled"]
+    assert ("donating", 4) in plane.quarantined()
 
 
 def test_plane_wide_quarantine_reset_spans_programs():
