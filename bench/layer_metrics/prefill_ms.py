@@ -1,0 +1,10 @@
+"""Model step: device time of the prefill program per execution (ms),
+from the traced window's ``XLA Modules`` line."""
+
+
+def read(ctx):
+    trace = ctx["trace"]
+    p = (trace or {}).get("programs", {}).get("prefill_into_slot")
+    if not p or not p["count"]:
+        return None
+    return 1e3 * p["total_s"] / p["count"]

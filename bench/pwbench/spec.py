@@ -1,0 +1,74 @@
+"""Finds a cell's files by the names in ``BENCHMARK.json``."""
+
+from __future__ import annotations
+
+import importlib.util
+import json
+from pathlib import Path
+from typing import Any
+
+BENCH = Path(__file__).resolve().parents[1]
+ROOT = BENCH.parent
+
+
+def _load(path: Path) -> Any:
+    with open(path) as f:
+        return json.load(f)
+
+
+class Cell:
+    """One entry of ``workloads`` with its configuration, its traffic mix
+    and the metrics that apply to it."""
+
+    def __init__(self, bench_file: Path, name: str):
+        bench = _load(bench_file)
+        base = bench_file.parent
+        cells = {w["name"]: w for w in bench["workloads"]}
+        if name not in cells:
+            raise SystemExit(
+                f"unknown workload {name!r}; {bench_file} has {sorted(cells)}"
+            )
+        self.bench = bench
+        self.name = name
+        self.entry = cells[name]
+        self.chips = int(self.entry["chips"])
+        cfg_entry = {c["name"]: c for c in bench["configs"]}[self.entry["config"]]
+        self.config = _load(base / cfg_entry["file"])
+        self.config["_file"] = str(base / cfg_entry["file"])
+        self.traffic_dir = base / bench["paths"][0] / "traffic"
+        self.mix = _load(self.traffic_dir / f"{self.entry['traffic']}.json")
+        self.readers_dir = base / bench["paths"][0] / "layer_metrics"
+        self.run_seconds = int(bench["run_seconds"])
+
+    def _applies(self, metric: dict) -> bool:
+        return "workloads" not in metric or self.name in metric["workloads"]
+
+    @property
+    def end_to_end(self) -> list[dict]:
+        return [m for m in self.bench["end_to_end"] if self._applies(m)]
+
+    @property
+    def per_layer(self) -> list[dict]:
+        return [m for m in self.bench["per_layer"] if self._applies(m)]
+
+    def reader(self, metric_name: str) -> Any:
+        """The reader of a per-layer metric: the file named by the part of
+        the metric's name before its first dot."""
+        stem = metric_name.split(".", 1)[0]
+        path = self.readers_dir / f"{stem}.py"
+        spec = importlib.util.spec_from_file_location(f"_reader_{stem}", path)
+        if spec is None or spec.loader is None:
+            raise FileNotFoundError(path)
+        mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+        return mod.read
+
+
+def peaks(device_kind: str) -> dict:
+    table = _load(BENCH / "peaks.json")
+    if device_kind not in table:
+        raise SystemExit(
+            f"device kind {device_kind!r} is not in bench/peaks.json "
+            f"({sorted(table)}): add its published peaks with their source"
+        )
+    return table[device_kind]
