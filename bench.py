@@ -155,10 +155,8 @@ def bench_knn(n_docs: int = 1_000_000, dim: int = 256, k: int = 10) -> float:
     0.994 at this exact scale/config, small-scale invariant pinned in
     tests/test_indexing.py). The measurement pipelines
     dispatches and syncs once per trial: that is the latency a loaded
-    server sees. The device-side compute per dispatch is ~0.4 ms (see
-    bench_knn_single_dispatch's trace-derived knn_p50_device_ms); the
-    gap up to the pipelined p50 is per-dispatch host submission cost,
-    amortized 100-deep here.
+    server sees; per-dispatch host submission cost is amortized
+    100-deep here.
     """
     from pathway_tpu.ops.topk import knn_search_quantized, quantize_docs
 
@@ -197,54 +195,13 @@ def bench_knn(n_docs: int = 1_000_000, dim: int = 256, k: int = 10) -> float:
     return float(np.median(trials))
 
 
-def _trace_device_ms(trace_dir: str, name_prefix: str) -> float | None:
-    """Median device-side duration (ms) of jit programs matching
-    name_prefix in a jax.profiler trace directory. None when the trace
-    has no device lane (e.g. CPU-only runs)."""
-    import glob
-    import gzip
-
-    paths = sorted(
-        glob.glob(os.path.join(trace_dir, "**", "*.trace.json.gz"),
-                  recursive=True)
-    )
-    if not paths:
-        return None
-    with gzip.open(paths[-1]) as f:
-        tr = json.load(f)
-    events = tr.get("traceEvents", [])
-    device_pids = {
-        e["pid"]
-        for e in events
-        if e.get("ph") == "M"
-        and e.get("name") == "process_name"
-        and "TPU" in e.get("args", {}).get("name", "")
-    }
-    durs = [
-        e["dur"]
-        for e in events
-        if e.get("ph") == "X"
-        and e.get("pid") in device_pids
-        and e.get("name", "").startswith(f"jit_{name_prefix}")
-    ]
-    if not durs:
-        return None
-    return float(np.median(durs)) / 1000.0
-
-
 def bench_knn_single_dispatch(
     n_docs: int = 1_000_000, dim: int = 256, k: int = 10
-) -> tuple[float, float | None]:
-    """(p50 of ONE dispatch+sync, trace-derived device-side compute ms).
-
-    The un-pipelined number includes host submission and the readback;
-    the device-side compute for the 1M-doc scan+rescore is read from
-    the jax.profiler trace (not measured on the current chip).
-    `knn_p50_device_ms` is the number comparable to the reference's
-    usearch query latency (usearch_integration.rs:109), and the pipelined
-    p50 is what a loaded server observes per query batch."""
-    import tempfile as _tf
-
+) -> float:
+    """p50 (ms) of ONE dispatch+sync: the un-pipelined number includes
+    host submission and the readback; the pipelined p50 (`bench_knn`) is
+    what a loaded server observes per query batch. Device time per
+    program comes from bench/pwbench/trace_reduce.py, not from here."""
     from pathway_tpu.ops.topk import QuantizedDocs, knn_search_quantized
 
     rng = np.random.default_rng(1)
@@ -269,17 +226,7 @@ def bench_knn_single_dispatch(
         t0 = time.perf_counter()
         _sync(call())
         lat.append((time.perf_counter() - t0) * 1000.0)
-    device_ms = None
-    try:
-        with _tf.TemporaryDirectory() as td:
-            jax.profiler.start_trace(td)
-            for _ in range(5):
-                _sync(call())
-            jax.profiler.stop_trace()
-            device_ms = _trace_device_ms(td, "knn_search_quantized")
-    except Exception as e:  # noqa: BLE001 — profiling must never fail the bench
-        print(f"# knn device trace skipped: {e}", file=sys.stderr)
-    return float(np.median(lat)), device_ms
+    return float(np.median(lat))
 
 
 def bench_lm_decode(
@@ -2284,7 +2231,7 @@ def main() -> None:
     # on-device reranker
     tiered_rungs = bench_ann_tiered(dataflow.setdefault("stats", {}))
     dev = jax.devices()[0]
-    decode_rate = knn_p50 = knn_single = knn_device = embed_rate = None
+    decode_rate = knn_p50 = knn_single = embed_rate = None
     decode_fail = None
     if not skip_device:
         # config 5 FIRST: the 2B decoder needs the most contiguous HBM
@@ -2294,7 +2241,7 @@ def main() -> None:
             decode_fail = f"failed: {type(e).__name__}: {e}"
             print(f"# lm decode bench failed: {e}", file=sys.stderr)
         knn_p50 = bench_knn()  # before embed: HBM clean for the 1M-doc matrix
-        knn_single, knn_device = bench_knn_single_dispatch()
+        knn_single = bench_knn_single_dispatch()
         embed_rate = bench_embed()
     # ANN rungs LAST: the 10M corpus leans on host RAM / HBM that the
     # device rungs above want clean
@@ -2329,19 +2276,6 @@ def main() -> None:
         # included, not compute alone
         "knn_p50_single_dispatch_ms": (
             round(knn_single, 3) if knn_single is not None else None
-        ),
-        # device-side compute from the jax.profiler trace: the
-        # number comparable to the reference's usearch latency
-        "knn_p50_device_ms": (
-            round(knn_device, 3) if knn_device is not None else None
-        ),
-        # target ratio is defined on device compute only — when
-        # the trace is unavailable the ratio is null rather than
-        # silently switching to a different quantity
-        "knn_vs_target": (
-            round(KNN_TARGET_MS / max(knn_device, 1e-9), 3)
-            if knn_device is not None
-            else None
         ),
         "knn_vs_target_pipelined": (
             round(KNN_TARGET_MS / max(knn_p50, 1e-9), 3)

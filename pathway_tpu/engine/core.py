@@ -630,11 +630,12 @@ class Graph:
                 continue  # the head's cone fire covers this member
             cone = node._cone
             t0 = perf_counter_ns()
-            if cone is not None:
-                dispatches += cone.fire(time)
-            else:
-                node.finish_time(time)
-                dispatches += 1
+            with _obs.wave_span(node):
+                if cone is not None:
+                    dispatches += cone.fire(time)
+                else:
+                    node.finish_time(time)
+                    dispatches += 1
             elapsed = perf_counter_ns() - t0
             node.time_ns += elapsed
             if plane is not None:
@@ -658,26 +659,21 @@ class Graph:
         # Cone heads drain through their cone first so late segments keep
         # cone semantics; the members' own finish_time/on_end still run
         # (no-ops once drained) — absorbed nodes are NOT skipped here.
+        from time import perf_counter_ns
+
         plane = _obs.PLANE
-        if plane is None:
-            for node in self.nodes:
+        for node in self.nodes:
+            t0 = perf_counter_ns()
+            with _obs.wave_span(node):
                 if node._cone is not None:
                     node._cone.fire(time)
                 node.finish_time(time)
                 node.on_end(time)
-            return
-        from time import perf_counter_ns
-
-        for node in self.nodes:
-            t0 = perf_counter_ns()
-            if node._cone is not None:
-                node._cone.fire(time)
-            node.finish_time(time)
-            node.on_end(time)
-            # record the end-flush span for the profiler/histograms but
-            # do NOT fold it into time_ns: the seconds-total stat must
-            # read the same whether instrumentation is on or off
-            plane.wave(node, time, perf_counter_ns() - t0)
+            if plane is not None:
+                # record the end-flush span for the profiler/histograms
+                # but do NOT fold it into time_ns: the seconds-total stat
+                # must read the same whether instrumentation is on or off
+                plane.wave(node, time, perf_counter_ns() - t0)
 
 
 class InputNode(Node):

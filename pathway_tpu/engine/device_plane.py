@@ -40,6 +40,7 @@ in tier-1 without TPU hardware.
 from __future__ import annotations
 
 import os
+import re as _re
 import threading
 import time as _time
 from concurrent.futures import Future, ThreadPoolExecutor
@@ -113,6 +114,32 @@ class BucketPolicy:
         return self._round_up(max(longest, 1), self.min_seq, cap)
 
 
+def _named_for_trace(fn: Callable, plane_name: str) -> Callable:
+    """`fn` under the name its XLA module should carry. jit names a
+    module ``jit_<fn.__name__>``, and a ``functools.partial`` has no
+    ``__name__`` (every program was ``jit__unknown`` in a device trace):
+    a partial takes the name of the function it wraps, a lambda the
+    plane's name for the program. A function with a name of its own is
+    returned as it is."""
+    import functools
+
+    inner = fn
+    while isinstance(inner, functools.partial):
+        inner = inner.func
+    name = getattr(inner, "__name__", None)
+    if name is None or name == "<lambda>":
+        name = _re.sub(r"\W", "_", plane_name)
+    if getattr(fn, "__name__", None) == name:
+        return fn
+
+    @functools.wraps(fn)  # keeps the signature jit resolves argnames from
+    def named(*args: Any, **kwargs: Any) -> Any:
+        return fn(*args, **kwargs)
+
+    named.__name__ = named.__qualname__ = name
+    return named
+
+
 class DeviceProgram:
     """One jitted program plus its per-bucket compile ledger and
     quarantine state.
@@ -174,7 +201,7 @@ class DeviceProgram:
             kw["donate_argnums"] = self.donate_argnums
         if static_argnames:
             kw["static_argnames"] = tuple(static_argnames)
-        self._jit = jax.jit(fn, **kw)
+        self._jit = jax.jit(_named_for_trace(fn, name), **kw)
         self._lock = _lockgraph.register_lock(
             "device_plane.program", threading.Lock()
         )
@@ -496,6 +523,10 @@ class SlotPool:
         self.joined_inflight = 0  # acquired while others were mid-flight
         self.high_water = 0
         self._ever_used: set[int] = set()
+        # the scheduler that drives this pool hangs its own counters here
+        # (ContinuousBatcher.stats), so /statistics and /metrics find them
+        # beside the pool's and they go when the pool is dropped
+        self.scheduler_stats: dict[str, float] | None = None
 
     @property
     def active(self) -> int:
@@ -732,6 +763,16 @@ class DevicePlane:
         with self._lock:
             pools = list(self._slot_pools.items())
         return {name: pool.snapshot() for name, pool in pools}
+
+    def scheduler_stats(self) -> dict[str, dict[str, float]]:
+        """{pool_name: the driving scheduler's counters} for the pools
+        whose scheduler published them (`SlotPool.scheduler_stats`)."""
+        with self._lock:
+            pools = list(self._slot_pools.items())
+        return {
+            name: dict(pool.scheduler_stats) for name, pool in pools
+            if pool.scheduler_stats is not None
+        }
 
     def unique_name(self, prefix: str) -> str:
         """Collision-proof program name for per-instance registrations

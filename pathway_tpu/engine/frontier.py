@@ -496,17 +496,18 @@ class FrontierScheduler:
         nid, below = divmod(slot, 2)
         node = self.nodes[nid]
         t0 = perf_counter_ns()
-        if below:
-            for _kind, payload in pend.payloads:
-                if payload is not None:
-                    node.inject_remote(t, payload)
-        else:
-            for kind, payload in pend.payloads:
-                if kind == "local" and payload is not None:
-                    node.push(payload)
-            self._restore_stash(node, pend)
-            node.finish_time(t)
-            self.completed_through[nid] = t
+        with _obs.wave_span(node):
+            if below:
+                for _kind, payload in pend.payloads:
+                    if payload is not None:
+                        node.inject_remote(t, payload)
+            else:
+                for kind, payload in pend.payloads:
+                    if kind == "local" and payload is not None:
+                        node.push(payload)
+                self._restore_stash(node, pend)
+                node.finish_time(t)
+                self.completed_through[nid] = t
         elapsed = perf_counter_ns() - t0
         if not below:
             node.time_ns += elapsed

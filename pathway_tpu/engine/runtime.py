@@ -976,9 +976,12 @@ class Runtime:
                         f"{what}: deferred device wave unresolved after "
                         f"{self._ASYNC_STALL_S:.0f}s"
                     )
+                t0 = _time.perf_counter()
                 _time.sleep(0.0005)
                 if _obs.PLANE is not None:
-                    _obs.PLANE.stage_seconds("idle", 0.0005)
+                    _obs.PLANE.stage_seconds(
+                        "idle", _time.perf_counter() - t0
+                    )
             else:
                 stalls += 1
                 if stalls > 10_000:
@@ -1035,9 +1038,12 @@ class Runtime:
                         "static frontier pump: deferred device wave "
                         f"unresolved after {self._ASYNC_STALL_S:.0f}s"
                     )
+                t0 = _time.perf_counter()
                 _time.sleep(0.0005)  # a deferred wave is still computing
                 if _obs.PLANE is not None:
-                    _obs.PLANE.stage_seconds("idle", 0.0005)
+                    _obs.PLANE.stage_seconds(
+                        "idle", _time.perf_counter() - t0
+                    )
             else:
                 stalls += 1
                 if stalls > 10_000:

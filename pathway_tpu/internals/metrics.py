@@ -158,6 +158,17 @@ def _device_lines(out: _Lines) -> None:
                     "pathway_serving_slot_pool",
                     {"pool": pname, "stat": stat}, snap[stat],
                 )
+    schedulers = plane.scheduler_stats()
+    if schedulers:
+        # the slot scheduler's own counts and phase clocks
+        # (ContinuousBatcher.stats), cumulative since it was built
+        out.typ("pathway_serving_batcher", "gauge")
+        for pname, stats in schedulers.items():
+            for stat, value in stats.items():
+                out.sample(
+                    "pathway_serving_batcher",
+                    {"pool": pname, "stat": stat}, value,
+                )
 
 
 _BREAKER_STATES = {"closed": 0, "open": 1, "half_open": 2}
@@ -311,6 +322,7 @@ def render_statistics(session: Any, started_at: float) -> dict:
                 for (prog, bucket), q in dp_mod._plane.quarantined().items()
             },
             "slot_pools": dp_mod._plane.slot_pools(),
+            "batchers": dp_mod._plane.scheduler_stats(),
         }
     policies = _obs.retry_policies()
     if policies:

@@ -73,6 +73,8 @@ __all__ = [
     "dump_flight",
     "pretime",
     "pretimes",
+    "span",
+    "wave_span",
     "register_retry_policy",
     "retry_policies",
 ]
@@ -102,6 +104,46 @@ _PRETIMES_LOCK = _lockgraph.register_lock(
 # add per policy construction) so /metrics can export breaker states
 # without the policies holding a reference cycle.
 _RETRY_POLICIES: "weakref.WeakSet[Any]" = weakref.WeakSet()
+
+
+# ----------------------------------------------------------- host spans
+#
+# Every span the program writes into a profiler trace, as a closed set
+# (docs/observability.md "Profiler spans"): a name is a constant or
+# `SPAN_WAVE + node.describe()`, never a request's or a row's identity
+# (those ride as keyword metadata), so a reduction can group by name.
+SPAN_CB_ADMIT_PREP = "cb.admit.prep"
+SPAN_CB_ADMIT_DISPATCH = "cb.admit.dispatch"
+SPAN_CB_ADMIT_WAIT = "cb.admit.wait"
+SPAN_CB_STEP_PREP = "cb.step.prep"
+SPAN_CB_STEP_DISPATCH = "cb.step.dispatch"
+SPAN_CB_STEP_WAIT = "cb.step.wait"
+SPAN_CB_ACCOUNT = "cb.account"
+SPAN_EMBED_ENCODE_BATCH = "embed.encode_batch"
+SPAN_KNN_REFRESH = "knn.refresh"
+SPAN_KNN_SEARCH = "knn.search"
+SPAN_WAVE = "wave "
+
+_TRACE_ANNOTATION: Any = None
+
+
+def span(name: str, **meta: Any) -> Any:
+    """A host span on the JAX profiler's clock: a context manager that
+    shows in the trace when a profiler session is active and costs a
+    fraction of a microsecond when none is. `meta` rides as the span's
+    metadata, not in its name. Independent of `PLANE`: "tracing on" means
+    a profiler session, nothing else."""
+    global _TRACE_ANNOTATION
+    if _TRACE_ANNOTATION is None:
+        from jax.profiler import TraceAnnotation
+
+        _TRACE_ANNOTATION = TraceAnnotation
+    return _TRACE_ANNOTATION(name, **meta)
+
+
+def wave_span(node: Any) -> Any:
+    """The span of one (operator, wave): one name per operator."""
+    return span(SPAN_WAVE + node.describe())
 
 
 def pretime(stage: str, seconds: float) -> None:

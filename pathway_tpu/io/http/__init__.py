@@ -48,7 +48,7 @@ _ROUTE_STATS_LOCK = _lockgraph.register_lock(
 
 def route_stats() -> dict[str, dict]:
     """Snapshot of per-route ingress counters ({route: {pending,
-    max_pending, requests, responses, timeouts}})."""
+    max_pending, requests, responses, timeouts, residence_s}})."""
     with _ROUTE_STATS_LOCK:
         return {r: dict(s) for r, s in _ROUTE_STATS.items()}
 
@@ -217,6 +217,8 @@ def rest_connector(
     stats = {
         "pending": 0, "max_pending": 0, "requests": 0, "responses": 0,
         "timeouts": 0,
+        # seconds from handler entry to the reply, summed over the 200s
+        "residence_s": 0.0,
     }
     with _ROUTE_STATS_LOCK:
         _ROUTE_STATS[route] = stats
@@ -231,6 +233,7 @@ def rest_connector(
             )
 
     async def handler(request: "web.Request") -> "web.Response":
+        t_in = _time.monotonic()
         if request.method in ("POST", "PUT", "PATCH"):
             try:
                 payload = await request.json()
@@ -311,7 +314,11 @@ def rest_connector(
                     sess.remove(key, tuple(row))
             if isinstance(result, Json):
                 result = result.value
-            return web.json_response(result, dumps=lambda obj: Json.dumps(obj))
+            reply = web.json_response(
+                result, dumps=lambda obj: Json.dumps(obj)
+            )
+            stats["residence_s"] += _time.monotonic() - t_in
+            return reply
         finally:
             if admitted:
                 gateway.release(route)

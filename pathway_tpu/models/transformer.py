@@ -188,10 +188,16 @@ def cast_params(params: Params, dtype: Any = jnp.bfloat16) -> Params:
 # ----------------------------------------------------------------- forward
 
 
+# The block's parts run under `jax.named_scope` (norm, attn, ff,
+# cache_write, logits): operation metadata that a profiler trace shows per
+# operation and that changes nothing in the compiled program.
+
+
 def _rmsnorm(x: Array, scale: Array) -> Array:
-    x32 = x.astype(jnp.float32)
-    var = jnp.mean(x32 * x32, axis=-1, keepdims=True)
-    return (x32 * jax.lax.rsqrt(var + 1e-6) * scale).astype(x.dtype)
+    with jax.named_scope("norm"):
+        x32 = x.astype(jnp.float32)
+        var = jnp.mean(x32 * x32, axis=-1, keepdims=True)
+        return (x32 * jax.lax.rsqrt(var + 1e-6) * scale).astype(x.dtype)
 
 
 _FUSED_ATTN_ENV: bool | None = None
@@ -271,21 +277,24 @@ def _attention(
 
 
 def _ffn(x: Array, block: Params, cfg: TransformerConfig) -> Array:
-    hline = jnp.einsum(
-        "bsd,df->bsf", x, block["ff_in"].astype(cfg.dtype),
-        preferred_element_type=jnp.float32,
-    )
-    hline = jax.nn.gelu(hline).astype(cfg.dtype)
-    return jnp.einsum(
-        "bsf,fd->bsd", hline, block["ff_out"].astype(cfg.dtype),
-        preferred_element_type=jnp.float32,
-    ).astype(cfg.dtype)
+    with jax.named_scope("ff"):
+        hline = jnp.einsum(
+            "bsd,df->bsf", x, block["ff_in"].astype(cfg.dtype),
+            preferred_element_type=jnp.float32,
+        )
+        hline = jax.nn.gelu(hline).astype(cfg.dtype)
+        return jnp.einsum(
+            "bsf,fd->bsd", hline, block["ff_out"].astype(cfg.dtype),
+            preferred_element_type=jnp.float32,
+        ).astype(cfg.dtype)
 
 
 def _block_fwd(
     x: Array, block: Params, cfg: TransformerConfig, mask: Array, token_mask: Array
 ) -> Array:
-    x = x + _attention(_rmsnorm(x, block["ln1_scale"]), block, cfg, mask, token_mask)
+    xin = _rmsnorm(x, block["ln1_scale"])
+    with jax.named_scope("attn"):
+        x = x + _attention(xin, block, cfg, mask, token_mask)
     x = x + _ffn(_rmsnorm(x, block["ln2_scale"]), block, cfg)
     return x
 
@@ -454,40 +463,44 @@ def decode_step(
         )[:, None, None, :]
     for li, block in enumerate(params["blocks"]):
         xin = _rmsnorm(x, block["ln1_scale"])
-        qkv = jnp.einsum(
-            "bsd,de->bse", xin, block["qkv"].astype(cfg.dtype),
-            preferred_element_type=jnp.float32,
-        ).astype(cfg.dtype)
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        q = q.reshape(b, 1, h, dh)
-        k = k.reshape(b, 1, h, dh)
-        v = v.reshape(b, 1, h, dh)
-        cache["k"] = jax.lax.dynamic_update_slice(
-            cache["k"], k[None], (li, 0, pos, 0, 0)
-        )
-        cache["v"] = jax.lax.dynamic_update_slice(
-            cache["v"], v[None], (li, 0, pos, 0, 0)
-        )
-        keys, vals = cache["k"][li], cache["v"][li]  # [b, S, h, dh]
-        scores = jnp.einsum(
-            "bqhd,bkhd->bhqk", q, keys, preferred_element_type=jnp.float32
-        ) / math.sqrt(dh)
-        scores = jnp.where(kmask, scores, -1e30)
-        probs = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)
-        ctx = jnp.einsum(
-            "bhqk,bkhd->bqhd", probs, vals, preferred_element_type=jnp.float32
-        ).astype(cfg.dtype).reshape(b, 1, cfg.d_model)
-        attn_out = jnp.einsum(
-            "bsd,de->bse", ctx, block["o"].astype(cfg.dtype),
-            preferred_element_type=jnp.float32,
-        ).astype(cfg.dtype)
-        x = x + attn_out
+        with jax.named_scope("attn"):
+            qkv = jnp.einsum(
+                "bsd,de->bse", xin, block["qkv"].astype(cfg.dtype),
+                preferred_element_type=jnp.float32,
+            ).astype(cfg.dtype)
+            q, k, v = jnp.split(qkv, 3, axis=-1)
+            q = q.reshape(b, 1, h, dh)
+            k = k.reshape(b, 1, h, dh)
+            v = v.reshape(b, 1, h, dh)
+        with jax.named_scope("cache_write"):
+            cache["k"] = jax.lax.dynamic_update_slice(
+                cache["k"], k[None], (li, 0, pos, 0, 0)
+            )
+            cache["v"] = jax.lax.dynamic_update_slice(
+                cache["v"], v[None], (li, 0, pos, 0, 0)
+            )
+        with jax.named_scope("attn"):
+            keys, vals = cache["k"][li], cache["v"][li]  # [b, S, h, dh]
+            scores = jnp.einsum(
+                "bqhd,bkhd->bhqk", q, keys, preferred_element_type=jnp.float32
+            ) / math.sqrt(dh)
+            scores = jnp.where(kmask, scores, -1e30)
+            probs = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)
+            ctx = jnp.einsum(
+                "bhqk,bkhd->bqhd", probs, vals, preferred_element_type=jnp.float32
+            ).astype(cfg.dtype).reshape(b, 1, cfg.d_model)
+            attn_out = jnp.einsum(
+                "bsd,de->bse", ctx, block["o"].astype(cfg.dtype),
+                preferred_element_type=jnp.float32,
+            ).astype(cfg.dtype)
+            x = x + attn_out
         x = x + _ffn(_rmsnorm(x, block["ln2_scale"]), block, cfg)
     hline = _rmsnorm(x, params["ln_f_scale"])
-    lg = jnp.einsum(
-        "bsd,vd->bsv", hline, params["tok_embed"].astype(cfg.dtype),
-        preferred_element_type=jnp.float32,
-    )
+    with jax.named_scope("logits"):
+        lg = jnp.einsum(
+            "bsd,vd->bsv", hline, params["tok_embed"].astype(cfg.dtype),
+            preferred_element_type=jnp.float32,
+        )
     return lg[:, 0, :], cache
 
 
@@ -521,39 +534,43 @@ def prefill(
         mask = _build_mask(prompt_mask, causal=True)
     for li, block in enumerate(params["blocks"]):
         xin = _rmsnorm(x, block["ln1_scale"])
-        qkv = jnp.einsum(
-            "bsd,de->bse", xin, block["qkv"].astype(cfg.dtype),
-            preferred_element_type=jnp.float32,
-        ).astype(cfg.dtype)
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        q = q.reshape(b, p, h, dh)
-        k = k.reshape(b, p, h, dh)
-        v = v.reshape(b, p, h, dh)
-        cache["k"] = jax.lax.dynamic_update_slice(
-            cache["k"], k[None], (li, 0, 0, 0, 0)
-        )
-        cache["v"] = jax.lax.dynamic_update_slice(
-            cache["v"], v[None], (li, 0, 0, 0, 0)
-        )
-        scores = jnp.einsum(
-            "bqhd,bkhd->bhqk", q, k, preferred_element_type=jnp.float32
-        ) / math.sqrt(dh)
-        scores = jnp.where(mask, scores, -1e30)
-        probs = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)
-        ctx = jnp.einsum(
-            "bhqk,bkhd->bqhd", probs, v, preferred_element_type=jnp.float32
-        ).astype(cfg.dtype).reshape(b, p, cfg.d_model)
-        attn_out = jnp.einsum(
-            "bsd,de->bse", ctx, block["o"].astype(cfg.dtype),
-            preferred_element_type=jnp.float32,
-        ).astype(cfg.dtype)
-        x = x + attn_out
+        with jax.named_scope("attn"):
+            qkv = jnp.einsum(
+                "bsd,de->bse", xin, block["qkv"].astype(cfg.dtype),
+                preferred_element_type=jnp.float32,
+            ).astype(cfg.dtype)
+            q, k, v = jnp.split(qkv, 3, axis=-1)
+            q = q.reshape(b, p, h, dh)
+            k = k.reshape(b, p, h, dh)
+            v = v.reshape(b, p, h, dh)
+        with jax.named_scope("cache_write"):
+            cache["k"] = jax.lax.dynamic_update_slice(
+                cache["k"], k[None], (li, 0, 0, 0, 0)
+            )
+            cache["v"] = jax.lax.dynamic_update_slice(
+                cache["v"], v[None], (li, 0, 0, 0, 0)
+            )
+        with jax.named_scope("attn"):
+            scores = jnp.einsum(
+                "bqhd,bkhd->bhqk", q, k, preferred_element_type=jnp.float32
+            ) / math.sqrt(dh)
+            scores = jnp.where(mask, scores, -1e30)
+            probs = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)
+            ctx = jnp.einsum(
+                "bhqk,bkhd->bqhd", probs, v, preferred_element_type=jnp.float32
+            ).astype(cfg.dtype).reshape(b, p, cfg.d_model)
+            attn_out = jnp.einsum(
+                "bsd,de->bse", ctx, block["o"].astype(cfg.dtype),
+                preferred_element_type=jnp.float32,
+            ).astype(cfg.dtype)
+            x = x + attn_out
         x = x + _ffn(_rmsnorm(x, block["ln2_scale"]), block, cfg)
     hlast = _rmsnorm(x[:, -1:, :], params["ln_f_scale"])
-    lg = jnp.einsum(
-        "bsd,vd->bsv", hlast, params["tok_embed"].astype(cfg.dtype),
-        preferred_element_type=jnp.float32,
-    )
+    with jax.named_scope("logits"):
+        lg = jnp.einsum(
+            "bsd,vd->bsv", hlast, params["tok_embed"].astype(cfg.dtype),
+            preferred_element_type=jnp.float32,
+        )
     return lg[:, 0, :], cache
 
 
@@ -651,13 +668,15 @@ def prefill_into_slot(
     decoded token [1] int32, cache); argmax decoding, matching the
     temperature-0 `generate_serving` path bit for bit per row."""
     lg, mini = prefill(params, prompt_ids, init_kv_cache(cfg, 1), cfg, prompt_mask)
-    cache["k"] = jax.lax.dynamic_update_slice(
-        cache["k"], mini["k"], (0, slot, 0, 0, 0)
-    )
-    cache["v"] = jax.lax.dynamic_update_slice(
-        cache["v"], mini["v"], (0, slot, 0, 0, 0)
-    )
-    return jnp.argmax(lg, -1).astype(jnp.int32), cache
+    with jax.named_scope("cache_write"):
+        cache["k"] = jax.lax.dynamic_update_slice(
+            cache["k"], mini["k"], (0, slot, 0, 0, 0)
+        )
+        cache["v"] = jax.lax.dynamic_update_slice(
+            cache["v"], mini["v"], (0, slot, 0, 0, 0)
+        )
+    with jax.named_scope("logits"):
+        return jnp.argmax(lg, -1).astype(jnp.int32), cache
 
 
 def decode_step_slots(
@@ -691,37 +710,41 @@ def decode_step_slots(
     rows = jnp.arange(b)
     for li, block in enumerate(params["blocks"]):
         xin = _rmsnorm(x, block["ln1_scale"])
-        qkv = jnp.einsum(
-            "bsd,de->bse", xin, block["qkv"].astype(cfg.dtype),
-            preferred_element_type=jnp.float32,
-        ).astype(cfg.dtype)
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        q = q.reshape(b, 1, h, dh)
-        k = k.reshape(b, h, dh)
-        v = v.reshape(b, h, dh)
-        cache["k"] = cache["k"].at[li, rows, pos].set(k)
-        cache["v"] = cache["v"].at[li, rows, pos].set(v)
-        keys, vals = cache["k"][li], cache["v"][li]  # [b, S, h, dh]
-        scores = jnp.einsum(
-            "bqhd,bkhd->bhqk", q, keys, preferred_element_type=jnp.float32
-        ) / math.sqrt(dh)
-        scores = jnp.where(kmask, scores, -1e30)
-        probs = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)
-        ctx = jnp.einsum(
-            "bhqk,bkhd->bqhd", probs, vals, preferred_element_type=jnp.float32
-        ).astype(cfg.dtype).reshape(b, 1, cfg.d_model)
-        attn_out = jnp.einsum(
-            "bsd,de->bse", ctx, block["o"].astype(cfg.dtype),
-            preferred_element_type=jnp.float32,
-        ).astype(cfg.dtype)
-        x = x + attn_out
+        with jax.named_scope("attn"):
+            qkv = jnp.einsum(
+                "bsd,de->bse", xin, block["qkv"].astype(cfg.dtype),
+                preferred_element_type=jnp.float32,
+            ).astype(cfg.dtype)
+            q, k, v = jnp.split(qkv, 3, axis=-1)
+            q = q.reshape(b, 1, h, dh)
+            k = k.reshape(b, h, dh)
+            v = v.reshape(b, h, dh)
+        with jax.named_scope("cache_write"):
+            cache["k"] = cache["k"].at[li, rows, pos].set(k)
+            cache["v"] = cache["v"].at[li, rows, pos].set(v)
+        with jax.named_scope("attn"):
+            keys, vals = cache["k"][li], cache["v"][li]  # [b, S, h, dh]
+            scores = jnp.einsum(
+                "bqhd,bkhd->bhqk", q, keys, preferred_element_type=jnp.float32
+            ) / math.sqrt(dh)
+            scores = jnp.where(kmask, scores, -1e30)
+            probs = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)
+            ctx = jnp.einsum(
+                "bhqk,bkhd->bqhd", probs, vals, preferred_element_type=jnp.float32
+            ).astype(cfg.dtype).reshape(b, 1, cfg.d_model)
+            attn_out = jnp.einsum(
+                "bsd,de->bse", ctx, block["o"].astype(cfg.dtype),
+                preferred_element_type=jnp.float32,
+            ).astype(cfg.dtype)
+            x = x + attn_out
         x = x + _ffn(_rmsnorm(x, block["ln2_scale"]), block, cfg)
     hline = _rmsnorm(x, params["ln_f_scale"])
-    lg = jnp.einsum(
-        "bsd,vd->bsv", hline, params["tok_embed"].astype(cfg.dtype),
-        preferred_element_type=jnp.float32,
-    )
-    return jnp.argmax(lg[:, 0, :], -1).astype(jnp.int32), cache
+    with jax.named_scope("logits"):
+        lg = jnp.einsum(
+            "bsd,vd->bsv", hline, params["tok_embed"].astype(cfg.dtype),
+            preferred_element_type=jnp.float32,
+        )
+        return jnp.argmax(lg[:, 0, :], -1).astype(jnp.int32), cache
 
 
 class TransformerLM:

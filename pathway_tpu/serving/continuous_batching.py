@@ -48,6 +48,15 @@ byte-identical to the single-device pool (the slot axis is batch — rows
 never read each other's slots). Off by default: behavior without the
 flag is exactly the pre-mesh pool.
 
+**The scheduler times itself.** The decode loop is cut into contiguous
+leaf phases (:data:`PHASES`): each opens a profiler span (visible when a
+JAX profiler session is active) and adds its wall time to a cumulative
+``stats`` key, always on like the counts beside it. ``loop_s`` is the
+loop's own wall time, ``host_cpu_s`` the thread's CPU time outside the
+two waits for the device, and ``queue_wait_s`` / ``first_token_s`` /
+``residence_s`` sum each request's life from ``submit`` (counted by
+``prefills`` and ``completed``). Catalog: docs/observability.md.
+
 Decoding is temperature-0 (argmax) here; sampled generation keeps the
 wave-aligned path (a per-request RNG stream inside a shared step program
 is future work and the chat constructor routes accordingly).
@@ -57,6 +66,7 @@ from __future__ import annotations
 
 import os
 import threading
+import time
 from collections import deque
 from concurrent.futures import Future
 from typing import Any
@@ -81,13 +91,60 @@ def mesh_slots_on() -> bool:
     return os.environ.get("PATHWAY_MESH_SLOTS", "0") == "1"
 
 
+# `stats` key (seconds, cumulative) -> the profiler span of that phase of
+# the decode loop. Leaf phases only, one after another and never nested: a
+# trace reduction gives an idle gap to the span that covers most of it.
+PHASES = {
+    "admit_prep_s": _obs.SPAN_CB_ADMIT_PREP,
+    "admit_dispatch_s": _obs.SPAN_CB_ADMIT_DISPATCH,
+    "admit_wait_s": _obs.SPAN_CB_ADMIT_WAIT,
+    "step_prep_s": _obs.SPAN_CB_STEP_PREP,
+    "step_dispatch_s": _obs.SPAN_CB_STEP_DISPATCH,
+    "step_wait_s": _obs.SPAN_CB_STEP_WAIT,
+    "account_s": _obs.SPAN_CB_ACCOUNT,
+}
+_WAITS = ("admit_wait_s", "step_wait_s")  # blocked on the device
+
+
+class _Phase:
+    """One pass through a phase: its span, its wall time into `stats`.
+    A wait also closes and reopens the thread's CPU clock around itself,
+    so that `host_cpu_s` is what the thread burnt outside the waits."""
+
+    __slots__ = ("owner", "key", "wait", "span", "t0")
+
+    def __init__(self, owner: "ContinuousBatcher", key: str, meta: dict):
+        self.owner = owner
+        self.key = key
+        self.wait = key in _WAITS
+        self.span = _obs.span(PHASES[key], **meta)
+
+    def __enter__(self) -> None:
+        self.span.__enter__()
+        if self.wait:
+            self.owner._cpu_tick()
+        self.t0 = time.perf_counter()
+
+    def __exit__(self, *exc: Any) -> None:
+        self.owner.stats[self.key] += time.perf_counter() - self.t0
+        if self.wait:
+            self.owner._cpu_mark = time.thread_time()
+        self.span.__exit__(*exc)
+
+
 class _Request:
     __slots__ = (
         "row", "length", "future", "tokens", "token", "steps_done", "slot",
-        "pad_len", "width",
+        "pad_len", "width", "id", "t_submit", "t_admit", "t_first",
     )
 
-    def __init__(self, row: list, future: Future):
+    def __init__(self, row: list, future: Future, t_submit: float):
+        self.id = 0  # the batcher's `submitted` count when it came in
+        # on time.monotonic(): `submit` entered, a slot acquired, the
+        # prefill's token on the host
+        self.t_submit = t_submit
+        self.t_admit = 0.0
+        self.t_first = 0.0
         self.row = row  # token ids (already budget-truncated)
         self.length = len(row)
         self.future = future
@@ -167,21 +224,31 @@ class ContinuousBatcher:
         self._active: dict[int, _Request] = {}  # slot -> request
         self._running = False
         self._thread: threading.Thread | None = None
-        self.stats = {
+        # every key from the start: readers copy the dict from other
+        # threads while the loop adds to it, so no key may appear later
+        self.stats: dict[str, float] = {
             "submitted": 0, "completed": 0, "decode_steps": 0,
             "prefills": 0, "max_queue": 0,
+            **dict.fromkeys(PHASES, 0.0),
+            "loop_s": 0.0, "host_cpu_s": 0.0,
+            "queue_wait_s": 0.0, "first_token_s": 0.0, "residence_s": 0.0,
         }
+        self.pool.scheduler_stats = self.stats
+        self._loop_mark = 0.0  # perf_counter at the last `loop_s` tick
+        self._cpu_mark = 0.0  # thread_time at the last `host_cpu_s` tick
 
     # ------------------------------------------------------------- surface
 
     def submit(self, prompt: str) -> Future:
         """Queue one prompt; the future resolves to the token string."""
+        t_submit = time.monotonic()
         row = list(self.tokenizer.tokenize(prompt))[-self.budget:]
         fut: Future = Future()
-        req = _Request(row, fut)
+        req = _Request(row, fut, t_submit)
         with self._lock:
             self._queue.append(req)
             self.stats["submitted"] += 1
+            req.id = self.stats["submitted"]
             self.stats["max_queue"] = max(
                 self.stats["max_queue"], len(self._queue)
             )
@@ -243,18 +310,31 @@ class ContinuousBatcher:
             arrs = [jax.device_put(a, row) for a in arrs]
         return arrs
 
+    def _phase(self, key: str, **meta: Any) -> _Phase:
+        """Context manager of one phase of the loop (a key of PHASES)."""
+        return _Phase(self, key, meta)
+
+    def _loop_tick(self) -> None:
+        now = time.perf_counter()
+        self.stats["loop_s"] += now - self._loop_mark
+        self._loop_mark = now
+
+    def _cpu_tick(self) -> None:
+        now = time.thread_time()
+        self.stats["host_cpu_s"] += now - self._cpu_mark
+        self._cpu_mark = now
+
     def _loop(self) -> None:
-        import jax.numpy as jnp
         import numpy as np
 
-        from pathway_tpu.models import transformer
-
         cache = self._plane.lease(self._cache_key, self._init_cache)
+        self._loop_mark = time.perf_counter()
+        self._cpu_mark = time.thread_time()
         try:
             while True:
                 # ---- step boundary: re-fill freed slots from the queue
                 while True:
-                    with self._lock:
+                    with self._phase("admit_prep_s"), self._lock:
                         if not self._queue:
                             break
                         slot = self.pool.acquire()
@@ -263,44 +343,52 @@ class ContinuousBatcher:
                         req = self._queue.popleft()
                         self._active[slot] = req
                         req.slot = slot
+                        req.t_admit = time.monotonic()
                     cache = self._admit(req, slot, cache)
-                with self._lock:
-                    if not self._active:
-                        # nothing left; exit under the lock so a submit
-                        # racing this check either sees _running=True
-                        # (we loop again) or starts a fresh thread
-                        if self._queue:
-                            continue
-                        self._running = False
-                        return
-                    batch = dict(self._active)
-                # ---- one decode step over every occupied slot
-                tok = np.zeros(self.n_slots, np.int32)
-                pos = np.zeros(self.n_slots, np.int32)
-                pad = np.zeros(self.n_slots, np.int32)
-                for slot, req in batch.items():
-                    tok[slot] = req.token
-                    pos[slot] = req.width + req.steps_done
-                    pad[slot] = req.pad_len
-                tok_d, pos_d, pad_d = self._step_vectors(tok, pos, pad)
-                nxt, cache = self._step(
-                    self.params, cache, tok_d, pos_d, pad_d,
-                    bucket=self.n_slots,
-                )
-                nxt = np.asarray(nxt)
-                self.stats["decode_steps"] += 1
-                if _obs.PLANE is not None:
-                    _obs.PLANE.metrics.counter(
-                        "pathway_serving_decode_steps_total",
-                        {"pool": self.pool.name},
-                        help="continuous-batching decode steps dispatched",
+                    self._loop_tick()
+                with self._phase("step_prep_s"):
+                    with self._lock:
+                        if not self._active:
+                            # nothing left; exit under the lock so a
+                            # submit racing this check either sees
+                            # _running=True (we loop again) or starts a
+                            # fresh thread
+                            if self._queue:
+                                continue
+                            self._running = False
+                            return
+                        batch = dict(self._active)
+                    # ---- one decode step over every occupied slot
+                    tok = np.zeros(self.n_slots, np.int32)
+                    pos = np.zeros(self.n_slots, np.int32)
+                    pad = np.zeros(self.n_slots, np.int32)
+                    for slot, req in batch.items():
+                        tok[slot] = req.token
+                        pos[slot] = req.width + req.steps_done
+                        pad[slot] = req.pad_len
+                    tok_d, pos_d, pad_d = self._step_vectors(tok, pos, pad)
+                with self._phase("step_dispatch_s"):
+                    nxt, cache = self._step(
+                        self.params, cache, tok_d, pos_d, pad_d,
+                        bucket=self.n_slots,
                     )
-                for slot, req in batch.items():
-                    req.steps_done += 1
-                    req.tokens.append(int(nxt[slot]))
-                    req.token = int(nxt[slot])
-                    if len(req.tokens) >= self.n_steps:
-                        self._finish(slot, req)
+                with self._phase("step_wait_s"):
+                    nxt = np.asarray(nxt)
+                with self._phase("account_s"):
+                    self.stats["decode_steps"] += 1
+                    if _obs.PLANE is not None:
+                        _obs.PLANE.metrics.counter(
+                            "pathway_serving_decode_steps_total",
+                            {"pool": self.pool.name},
+                            help="continuous-batching decode steps dispatched",
+                        )
+                    for slot, req in batch.items():
+                        req.steps_done += 1
+                        req.tokens.append(int(nxt[slot]))
+                        req.token = int(nxt[slot])
+                        if len(req.tokens) >= self.n_steps:
+                            self._finish(slot, req)
+                self._loop_tick()
         except BaseException as e:  # noqa: BLE001 — fail every waiter loudly
             with self._lock:
                 self._running = False
@@ -319,6 +407,8 @@ class ContinuousBatcher:
             if isinstance(e, (KeyboardInterrupt, SystemExit)):
                 raise
         finally:
+            self._loop_tick()
+            self._cpu_tick()
             # restore the cache lease ONLY if our namespace still exists:
             # a finalizer may have dropped it while this thread was
             # mid-generation, and restore() would re-create the lease
@@ -339,26 +429,63 @@ class ContinuousBatcher:
 
         from pathway_tpu.xpacks.llm.embedders import pad_left_rows
 
-        ids, mask = pad_left_rows([req.row], self.budget, n_rows=1)
-        req.width = ids.shape[1]
-        req.pad_len = req.width - req.length
-        first, cache = self._prefill(
-            self.params, jnp.asarray(ids), jnp.asarray(mask), cache,
-            jnp.asarray(slot, jnp.int32), bucket=(1, req.width),
-        )
-        req.token = int(np.asarray(first)[0])
-        req.tokens.append(req.token)
-        self.stats["prefills"] += 1
-        if len(req.tokens) >= self.n_steps:  # n_steps == 1
-            self._finish(slot, req)
+        with self._phase("admit_prep_s", req=req.id, slot=slot):
+            ids, mask = pad_left_rows([req.row], self.budget, n_rows=1)
+            req.width = ids.shape[1]
+            req.pad_len = req.width - req.length
+            ids_d, mask_d = jnp.asarray(ids), jnp.asarray(mask)
+            slot_d = jnp.asarray(slot, jnp.int32)
+        # who is being admitted rides as span metadata, never in the name
+        meta = {"req": req.id, "slot": slot, "width": req.width}
+        with self._phase("admit_dispatch_s", **meta):
+            first, cache = self._prefill(
+                self.params, ids_d, mask_d, cache, slot_d,
+                bucket=(1, req.width),
+            )
+        with self._phase("admit_wait_s", **meta):
+            first = np.asarray(first)
+        with self._phase("account_s"):
+            req.t_first = time.monotonic()
+            req.token = int(first[0])
+            req.tokens.append(req.token)
+            self.stats["prefills"] += 1
+            self.stats["queue_wait_s"] += req.t_admit - req.t_submit
+            self.stats["first_token_s"] += req.t_first - req.t_submit
+            if len(req.tokens) >= self.n_steps:  # n_steps == 1
+                self._finish(slot, req)
         return cache
 
     def _finish(self, slot: int, req: _Request) -> None:
+        total = time.monotonic() - req.t_submit
         with self._lock:
             self._active.pop(slot, None)
             self.stats["completed"] += 1
+            self.stats["residence_s"] += total
         self.pool.release(slot)
         if not req.future.done():
             req.future.set_result(
                 " ".join(f"<{int(t)}>" for t in req.tokens)
+            )
+        plane = _obs.PLANE
+        if plane is not None:
+            # per request, never per step
+            queued = req.t_admit - req.t_submit
+            first = req.t_first - req.t_submit
+            pool = {"pool": self.pool.name}
+            plane.metrics.observe(
+                "pathway_serving_queue_wait_seconds", queued, pool,
+                help="submit until a decode slot was acquired",
+            )
+            plane.metrics.observe(
+                "pathway_serving_first_token_seconds", first, pool,
+                help="submit until the prefill's token was on the host",
+            )
+            plane.metrics.observe(
+                "pathway_serving_request_seconds", total, pool,
+                help="submit until the last token (residence in the batcher)",
+            )
+            plane.record(
+                "serving.request", export=False, req=req.id, slot=slot,
+                queue_us=int(queued * 1e6), first_us=int(first * 1e6),
+                total_us=int(total * 1e6), width=req.width,
             )

@@ -28,6 +28,7 @@ from typing import Any, Callable
 
 import numpy as np
 
+from pathway_tpu.internals import observability as _obs
 from pathway_tpu.internals.keys import Key
 from pathway_tpu.stdlib.indexing.filters import compile_filter
 
@@ -325,7 +326,8 @@ class VectorSlabIndex(HostIndex):
         from pathway_tpu.ops.topk import knn_search_masked
 
         if self._device_dirty:
-            self._refresh_device()
+            with _obs.span(_obs.SPAN_KNN_REFRESH):
+                self._refresh_device()
         plane = get_device_plane()
         # query batches are as ragged as the waves that carry them: pad
         # to the row bucket so (slab, qbucket, k) bounds the jit cache.
@@ -340,16 +342,17 @@ class VectorSlabIndex(HostIndex):
             "knn_slab_search", knn_search_masked,
             static_argnames=("k", "metric"),
         )
-        res = prog(
-            jnp.asarray(qpad),
-            self._device_docs,
-            self._device_valid,
-            k=min(k, int(self._device_docs.shape[0])),
-            metric=self.metric if self.metric != "cosine" else "cos",
-            bucket=(int(self._device_docs.shape[0]), qbucket, k, self.dim),
-        )
-        idxs = np.asarray(res.indices)[:n_q]
-        dists = np.asarray(res.distances)[:n_q]
+        with _obs.span(_obs.SPAN_KNN_SEARCH, rows=n_q):  # launch + readback
+            res = prog(
+                jnp.asarray(qpad),
+                self._device_docs,
+                self._device_valid,
+                k=min(k, int(self._device_docs.shape[0])),
+                metric=self.metric if self.metric != "cosine" else "cos",
+                bucket=(int(self._device_docs.shape[0]), qbucket, k, self.dim),
+            )
+            idxs = np.asarray(res.indices)[:n_q]
+            dists = np.asarray(res.distances)[:n_q]
         out = []
         for r in range(idxs.shape[0]):
             keep = np.isfinite(dists[r])

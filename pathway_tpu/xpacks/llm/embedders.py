@@ -21,6 +21,7 @@ from typing import Any
 import numpy as np
 
 import pathway_tpu as pw
+from pathway_tpu.internals import observability as _obs
 from pathway_tpu.internals import udfs
 from pathway_tpu.internals.expression import ColumnExpression
 from pathway_tpu.xpacks.llm._utils import _coerce_sync
@@ -257,21 +258,24 @@ class JaxEmbedder(BaseEmbedder):
     def _encode_batch(self, texts: list[str]) -> list[np.ndarray]:
         import jax.numpy as jnp
 
-        ids, mask = self.tokenizer.batch([t or "." for t in texts])
-        # pad rows + seq up to the plane's power-of-two buckets: ragged
-        # live waves hit a bounded set of XLA programs
-        (ids, mask), rows = self._plane.pad_rows([ids, mask], ids.shape[0])
-        seq = ids.shape[1]
-        bucket = bucket_len(seq, self.config.max_len)
-        if bucket != seq:
-            ids = np.pad(ids, ((0, 0), (0, bucket - seq)))
-            mask = np.pad(mask, ((0, 0), (0, bucket - seq)))
-        out = np.asarray(
-            self._encode(
-                self.params, jnp.asarray(ids), jnp.asarray(mask),
-                bucket=(rows, bucket),
+        with _obs.span(_obs.SPAN_EMBED_ENCODE_BATCH, rows=len(texts)):
+            ids, mask = self.tokenizer.batch([t or "." for t in texts])
+            # pad rows + seq up to the plane's power-of-two buckets:
+            # ragged live waves hit a bounded set of XLA programs
+            (ids, mask), rows = self._plane.pad_rows(
+                [ids, mask], ids.shape[0]
             )
-        )
+            seq = ids.shape[1]
+            bucket = bucket_len(seq, self.config.max_len)
+            if bucket != seq:
+                ids = np.pad(ids, ((0, 0), (0, bucket - seq)))
+                mask = np.pad(mask, ((0, 0), (0, bucket - seq)))
+            out = np.asarray(
+                self._encode(
+                    self.params, jnp.asarray(ids), jnp.asarray(mask),
+                    bucket=(rows, bucket),
+                )
+            )
         return [out[i] for i in range(len(texts))]
 
     async def __wrapped__(self, input: str, **kwargs: Any) -> np.ndarray:
