@@ -7,7 +7,8 @@ dispatches through one process-wide :class:`DevicePlane`. The plane owns
 four concerns the operators used to improvise separately:
 
 * **Shape-bucketed coalescing** — live-data waves are ragged; padding
-  every batch up to a power-of-two bucket (rows and sequence length)
+  every batch up to a bucket (rows to a power of two; sequence lengths
+  to a power of two up to 512 and to four rungs an octave above it)
   means the jit cache sees a bounded set of shapes however the stream
   arrives. :class:`BucketPolicy` is the single rounding rule, and every
   :class:`DeviceProgram` records compilations per bucket so tests can
@@ -67,11 +68,20 @@ class BucketPolicy:
     """The single shape-rounding rule of the serving path.
 
     Rows round up to a power of two between ``min_rows`` and
-    ``max_rows``; sequence lengths round up to a power of two between
-    ``min_seq`` and the caller's cap (the model context). Distinct live
-    batch sizes therefore hit at most ``log2(max/min)`` jit entries per
-    program instead of one per size.
+    ``max_rows``. Sequence lengths round up to the next rung of one
+    ladder between ``min_seq`` and the caller's cap (the model context):
+    powers of two up to ``SEQ_OCTAVE_ABOVE``, four rungs an octave above
+    it (:meth:`seq_bucket`). Distinct live batch sizes therefore hit at
+    most ``log2(max/min)`` jit entries per program instead of one per
+    size, and a long prompt is padded by a quarter at most, not doubled.
     """
+
+    # Sequence rungs are powers of two up to here and a quarter octave
+    # apart above: a program's time grows at least linearly with its
+    # width, so doubling a long prompt's width doubles what it costs,
+    # while under a few hundred tokens the dispatch costs more than the
+    # padding and fewer shapes are worth more than tighter ones.
+    SEQ_OCTAVE_ABOVE = 512
 
     def __init__(self, min_rows: int = 8, max_rows: int = 4096, min_seq: int = 16):
         if min_rows < 1 or max_rows < min_rows:
@@ -110,8 +120,17 @@ class BucketPolicy:
 
     def seq_bucket(self, longest: int, cap: int) -> int:
         """Padded sequence length for rows whose longest is `longest`,
-        bounded by the model cap."""
-        return self._round_up(max(longest, 1), self.min_seq, cap)
+        bounded by the model cap: a function of the length and the cap
+        only. Powers of two from ``min_seq`` up to 512; above 512 four
+        rungs an octave, each a multiple of 128 (640, 768, 896, 1024,
+        1280, 1536, 1792, 2048, 2560, ...), so the padding above 512
+        stays under a quarter of the length."""
+        n = max(longest, 1)
+        b = self._round_up(n, self.min_seq, self.SEQ_OCTAVE_ABOVE)
+        if b < n:
+            step = (1 << ((n - 1).bit_length() - 1)) // 4  # octave below n
+            b = -(-n // step) * step
+        return min(b, cap)
 
 
 def _named_for_trace(fn: Callable, plane_name: str) -> Callable:

@@ -57,6 +57,18 @@ two waits for the device, and ``queue_wait_s`` / ``first_token_s`` /
 ``residence_s`` sum each request's life from ``submit`` (counted by
 ``prefills`` and ``completed``). Catalog: docs/observability.md.
 
+**The step program is loaded when the batcher is built.** It has one
+shape, known at construction, so the batcher's thread starts once with
+an empty queue: it leases the slot cache, dispatches the step with no
+slot occupied (traced, fetched from the persistent compile cache or
+compiled, and loaded, off the caller's thread) and returns the lease. A
+``submit`` arriving meanwhile queues and is served by that same thread.
+It counts as no decode step: its time goes to ``preload_s`` alone. A
+prefill's shape follows a prompt's length, which nothing knows before
+the prompt arrives: no prefill program is loaded ahead of its first
+prompt. ``prompt_tokens`` and ``padded_tokens`` count what the admitted
+prompts held and the widths they ran at.
+
 Decoding is temperature-0 (argmax) here; sampled generation keeps the
 wave-aligned path (a per-request RNG stream inside a shared step program
 is future work and the chat constructor routes accordingly).
@@ -164,7 +176,12 @@ class ContinuousBatcher:
     format). A background decode thread runs only while requests are in
     flight: it re-fills freed slots from the queue at every step
     boundary, advances all occupied slots one token per dispatch, and
-    exits (restoring the cache lease) when the pool drains.
+    exits (restoring the cache lease) when the pool drains. It runs
+    once at construction too, to load the step program (module
+    docstring). A load that fails there follows the plane's rule for a
+    failed first compile: it is written to the global error log, and
+    the first request's step is a first compile again, which fails
+    that request's future.
     """
 
     def __init__(
@@ -232,10 +249,15 @@ class ContinuousBatcher:
             **dict.fromkeys(PHASES, 0.0),
             "loop_s": 0.0, "host_cpu_s": 0.0,
             "queue_wait_s": 0.0, "first_token_s": 0.0, "residence_s": 0.0,
+            # real tokens of the admitted prompts, and their widths
+            "prompt_tokens": 0, "padded_tokens": 0,
+            "preload_s": 0.0,  # the step program's load at construction
         }
         self.pool.scheduler_stats = self.stats
         self._loop_mark = 0.0  # perf_counter at the last `loop_s` tick
         self._cpu_mark = 0.0  # thread_time at the last `host_cpu_s` tick
+        with self._lock:
+            self._start_thread(preload=True)
 
     # ------------------------------------------------------------- surface
 
@@ -253,13 +275,19 @@ class ContinuousBatcher:
                 self.stats["max_queue"], len(self._queue)
             )
             if not self._running:
-                self._running = True
-                self._thread = threading.Thread(
-                    target=self._loop, daemon=True,
-                    name=f"pw-cb-{self.name}",
-                )
-                self._thread.start()
+                self._start_thread()
         return fut
+
+    def _start_thread(self, preload: bool = False) -> None:
+        """Start the decode thread; the caller holds the lock. It waits
+        for its predecessor, which may still be handing the cache lease
+        back: a second lease would be a second slot cache on the device."""
+        self._running = True
+        self._thread = threading.Thread(
+            target=self._loop, args=(preload, self._thread), daemon=True,
+            name=f"pw-cb-{self.name}",
+        )
+        self._thread.start()
 
     def queue_depth(self) -> int:
         with self._lock:
@@ -324,13 +352,45 @@ class ContinuousBatcher:
         self.stats["host_cpu_s"] += now - self._cpu_mark
         self._cpu_mark = now
 
-    def _loop(self) -> None:
+    def _preload(self, cache: Any) -> Any:
+        """Dispatch the step once with no slot occupied and wait for it:
+        the program is traced, compiled or fetched, and loaded. The rows
+        it writes (position 0 of every slot) are what every step writes
+        for a free slot; a prefill overwrites its slot's whole row."""
         import numpy as np
 
-        cache = self._plane.lease(self._cache_key, self._init_cache)
-        self._loop_mark = time.perf_counter()
-        self._cpu_mark = time.thread_time()
+        zeros = np.zeros(self.n_slots, np.int32)
+        nxt, cache = self._step(
+            self.params, cache, *self._step_vectors(zeros, zeros, zeros),
+            bucket=self.n_slots,
+        )
+        np.asarray(nxt)
+        return cache
+
+    def _loop(
+        self, preload: bool = False, after: threading.Thread | None = None
+    ) -> None:
+        import numpy as np
+
+        if after is not None:
+            after.join()
+        cache = None
+        # set when the loop's clocks start: the construction's pass counts
+        # nothing into `stats` but `preload_s` unless a request came in
+        serving = False
         try:
+            t0 = time.perf_counter()
+            cache = self._plane.lease(self._cache_key, self._init_cache)
+            if preload:
+                cache = self._preload(cache)
+                self.stats["preload_s"] += time.perf_counter() - t0
+                with self._lock:
+                    if not self._queue:
+                        self._running = False
+                        return
+            self._loop_mark = time.perf_counter()
+            self._cpu_mark = time.thread_time()
+            serving = True
             while True:
                 # ---- step boundary: re-fill freed slots from the queue
                 while True:
@@ -407,8 +467,9 @@ class ContinuousBatcher:
             if isinstance(e, (KeyboardInterrupt, SystemExit)):
                 raise
         finally:
-            self._loop_tick()
-            self._cpu_tick()
+            if serving:
+                self._loop_tick()
+                self._cpu_tick()
             # restore the cache lease ONLY if our namespace still exists:
             # a finalizer may have dropped it while this thread was
             # mid-generation, and restore() would re-create the lease
@@ -418,7 +479,7 @@ class ContinuousBatcher:
                 alive = (
                     self._plane._slot_pools.get(self.pool.name) is self.pool
                 )
-            if alive:
+            if alive and cache is not None:
                 self._plane.restore(self._cache_key, cache)
 
     def _admit(self, req: _Request, slot: int, cache: Any):
@@ -449,6 +510,8 @@ class ContinuousBatcher:
             req.token = int(first[0])
             req.tokens.append(req.token)
             self.stats["prefills"] += 1
+            self.stats["prompt_tokens"] += req.length
+            self.stats["padded_tokens"] += req.width
             self.stats["queue_wait_s"] += req.t_admit - req.t_submit
             self.stats["first_token_s"] += req.t_first - req.t_submit
             if len(req.tokens) >= self.n_steps:  # n_steps == 1

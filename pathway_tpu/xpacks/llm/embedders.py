@@ -162,9 +162,10 @@ from pathway_tpu.engine.device_plane import (  # noqa: E402
 
 
 def bucket_len(longest: int, cap: int) -> int:
-    """Power-of-two sequence bucket (>=16) so the jit cache sees few
-    distinct shapes as lengths vary — shared by the embedder's right-pad
-    and the chat's left-pad batching (the device plane's BucketPolicy)."""
+    """Sequence bucket (>=16: a power of two up to 512, four rungs an
+    octave above it, never over `cap`) so the jit cache sees few distinct
+    shapes as lengths vary — shared by the embedder's right-pad and the
+    chat's left-pad batching (the device plane's BucketPolicy)."""
     return get_device_plane().buckets.seq_bucket(longest, cap)
 
 
@@ -173,8 +174,9 @@ def pad_left_rows(
     n_rows: int | None = None,
 ):
     """Left-pad variable-length token rows into (ids, mask) int32 arrays
-    at a bucketed width (generation convention — real tokens end at the
-    last column, so last-position logits are every row's next token).
+    at a bucketed width (`bucket_len` of the longest row; generation
+    convention — real tokens end at the last column, so last-position
+    logits are every row's next token).
     The batch dimension pads with all-masked rows so arbitrary wave
     sizes hit few jit shapes: to exactly `n_rows` (callers pass the
     device plane's row bucket), to a multiple of `pad_rows_to`, or to
@@ -260,8 +262,8 @@ class JaxEmbedder(BaseEmbedder):
 
         with _obs.span(_obs.SPAN_EMBED_ENCODE_BATCH, rows=len(texts)):
             ids, mask = self.tokenizer.batch([t or "." for t in texts])
-            # pad rows + seq up to the plane's power-of-two buckets:
-            # ragged live waves hit a bounded set of XLA programs
+            # pad rows + seq up to the plane's buckets: ragged live
+            # waves hit a bounded set of XLA programs
             (ids, mask), rows = self._plane.pad_rows(
                 [ids, mask], ids.shape[0]
             )

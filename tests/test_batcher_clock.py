@@ -1,7 +1,7 @@
 """The clock inside the slot scheduler (ISSUE 26): phase counters and
 profiler spans of `ContinuousBatcher`, device programs that carry their
-names, the per-request clocks, and the four per-layer readers of
-bench/layer_metrics that read them.
+names, the per-request clocks, and the per-layer readers of
+bench/layer_metrics that read them (ISSUE 28 added `prefill_pad_pct`).
 
 A CPU trace has no device plane; what the chip shows as `XLA Modules`
 events is here the `hlo_module` stat of each XLA:CPU operation, and it
@@ -85,12 +85,19 @@ def _traced(tmp_path, work):
 
 def test_stats_hold_every_key_from_construction():
     """Readers copy `stats` from other threads while the loop adds to it:
-    no key may appear after `__init__`, and all clocks start at 0."""
+    no key may appear after `__init__`, and all clocks start at 0 but
+    `preload_s`, which the construction itself fills."""
     cb = _chat()._cb
-    counts = {"submitted", "completed", "decode_steps", "prefills", "max_queue"}
-    clocks = set(PHASES) | {"loop_s", "host_cpu_s"} | set(REQUEST_CLOCKS)
+    counts = {
+        "submitted", "completed", "decode_steps", "prefills", "max_queue",
+        "prompt_tokens", "padded_tokens",
+    }
+    clocks = (
+        set(PHASES) | {"loop_s", "host_cpu_s", "preload_s"}
+        | set(REQUEST_CLOCKS)
+    )
     assert set(cb.stats) == counts | clocks
-    assert all(v == 0 for v in cb.stats.values())
+    assert all(v == 0 for k, v in cb.stats.items() if k != "preload_s")
     keys_before = list(cb.stats)
     _run(cb)
     assert list(cb.stats) == keys_before
@@ -362,6 +369,8 @@ WORKED = {
     "decode_steps": 900, "prefills": 100, "completed": 100,
     "loop_s": 51.0, "admit_wait_s": 6.0, "step_wait_s": 40.0,
     "host_cpu_s": 4.0, "queue_wait_s": 150.0, "residence_s": 330.0,
+    # 100 prompts of 1,242 tokens, each at the 1280 rung
+    "prompt_tokens": 124_200, "padded_tokens": 128_000,
 }
 RECORDS = [_rec(0.0, 3.5), _rec(1.0, 4.7), _rec(2.0, 9.0, status=500)]
 
@@ -382,6 +391,13 @@ RECORDS = [_rec(0.0, 3.5), _rec(1.0, 4.7), _rec(2.0, 9.0, status=500)]
     ("outside_batcher_ms", {**WORKED, "completed": 0}, RECORDS, None),
     ("outside_batcher_ms", WORKED, RECORDS[2:], None),  # no 200 to average
     ("outside_batcher_ms", {"completed": 5}, RECORDS, None),
+    ("prefill_pad_pct", WORKED, (), 2.96875),  # 3,800 of 128,000
+    # the same prompts at the cap's width, 2016: what the ladder removed
+    ("prefill_pad_pct", {**WORKED, "padded_tokens": 201_600}, (), 38.3928571),
+    ("prefill_pad_pct", {**WORKED, "padded_tokens": 124_200}, (), 0.0),
+    ("prefill_pad_pct", {**WORKED, "padded_tokens": 0}, (), None),
+    ("prefill_pad_pct", {"prefills": 10, "padded_tokens": 20_160}, (), None),
+    ("prefill_pad_pct", {"prefills": 10}, (), None),  # the parent commit
 ])
 def test_reader_worked_numbers_and_empty_divisors(name, batcher, records, want):
     """A divisor of 0 and a program without the counters (the parent
@@ -390,18 +406,45 @@ def test_reader_worked_numbers_and_empty_divisors(name, batcher, records, want):
     assert got == (pytest.approx(want) if want is not None else None)
 
 
-def test_benchmark_lists_the_four_metrics_for_the_backlog_cell():
-    """Only the top file: ``bench/rehearsal/BENCHMARK.json`` is an accepted
-    benchmark file, so the tiny preset gains the entries in a ``benchmark``
-    PR (PERF.md section 7)."""
+def _listed(names):
     import json
 
     bench = json.loads((BENCH.parent / "BENCHMARK.json").read_text())
     entries = {m["name"]: m for m in bench["per_layer"]}
-    for name in ("host_per_dispatch_ms", "host_stall_pct", "queue_wait_ms",
-                 "outside_batcher_ms"):
+    for name in names:
         m = entries[f"{name}.tput"]
         assert m["workloads"] == ["rag-cerebras-6b7.backlog"]
         assert m["moves"] == "answers_per_s"
         assert m["source"] == "program_counter" and m["better"] == "lower"
         assert (BENCH / "layer_metrics" / f"{name}.py").is_file()
+    return entries
+
+
+def test_benchmark_lists_the_four_metrics_for_the_backlog_cell():
+    """Only the top file: ``bench/rehearsal/BENCHMARK.json`` is an accepted
+    benchmark file, so the tiny preset gains the entries in a ``benchmark``
+    PR (PERF.md section 7)."""
+    _listed(("host_per_dispatch_ms", "host_stall_pct", "queue_wait_ms",
+             "outside_batcher_ms"))
+
+
+def test_benchmark_lists_prefill_pad_pct_in_the_batchers_layer():
+    entries = _listed(("prefill_pad_pct",))
+    assert entries["prefill_pad_pct.tput"]["layer"] == (
+        entries["slot_occupancy_pct.tput"]["layer"]
+    )
+
+
+def test_prefill_pad_pct_reads_the_batcher_it_was_written_for():
+    """The reader over a real batcher's counters, as the harness takes
+    them: the difference of two copies of `stats`."""
+    cb = _chat()._cb
+    before = dict(cb.stats)
+    _run(cb)
+    grown = {k: v - before[k] for k, v in cb.stats.items() if v != before[k]}
+    tokens = sum(len(cb.tokenizer.tokenize(p)) for p in PROMPTS)
+    assert grown["prompt_tokens"] == tokens
+    assert grown["padded_tokens"] == 16 * len(PROMPTS)
+    assert _reader("prefill_pad_pct")(_ctx(grown)) == pytest.approx(
+        100.0 * (1 - tokens / (16 * len(PROMPTS)))
+    )

@@ -227,3 +227,188 @@ def test_cb_chat_through_a_pipeline():
         return rows
 
     assert run_once(True) == run_once(False)
+
+
+# ------------------------------------------------- the prompt-length ladder
+
+# wide enough in positions for a rung above 512, tiny in everything else
+LONG = dict(
+    vocab_size=256, d_model=16, n_heads=2, n_layers=1, d_ff=32, max_len=1100
+)
+
+
+def _cb_chat(**kw):
+    return _chat(continuous_batching=True, decode_slots=2, **kw)
+
+
+def _words(n_tokens: int, salt: str = "w") -> str:
+    """A prompt the hash tokenizer turns into `n_tokens` ids (one leads)."""
+    return " ".join(f"{salt}{j}" for j in range(n_tokens - 1))
+
+
+def test_long_prompt_runs_at_its_rung_and_matches_the_cap_width(monkeypatch):
+    """A 600-token prompt runs at width 640, not at the cap's 1,096, and
+    gives the tokens the cap's width gives: the left padding and
+    `pad_len` are carried through the prefill and every step."""
+    from pathway_tpu.xpacks.llm import embedders
+
+    prompt = _words(600)
+    widths = []
+
+    def run():
+        cb = _cb_chat(config=lm_config(**LONG))._cb
+        admit = cb._admit
+
+        def logged(req, slot, cache):
+            out = admit(req, slot, cache)
+            widths.append((req.length, req.width, req.pad_len))
+            return out
+
+        cb._admit = logged
+        got = cb.submit(prompt).result(timeout=120)
+        cb.drain()
+        return got, cb
+
+    at_rung, cb = run()
+    assert widths == [(600, 640, 40)]
+    assert cb._prefill.compile_counts == {(1, 640): 1}
+    assert cb.stats["prompt_tokens"] == 600
+    assert cb.stats["padded_tokens"] == 640
+    monkeypatch.setattr(embedders, "bucket_len", lambda longest, cap: cap)
+    at_cap, cb = run()
+    assert widths[1:] == [(600, 1096, 496)]
+    assert cb._prefill.compile_counts == {(1, 1096): 1}
+    assert at_rung == at_cap and len(at_rung.split()) == 4
+
+
+def test_prompts_inside_one_rung_cost_one_compile():
+    cb = _cb_chat(config=lm_config(**LONG))._cb
+    lengths = [513, 600, 640, 577]
+    futs = [cb.submit(_words(n, salt=f"p{n}x")) for n in lengths]
+    assert all(len(f.result(timeout=120).split()) == 4 for f in futs)
+    cb.drain()
+    assert cb._prefill.compile_counts == {(1, 640): 1}
+    assert cb._step.compile_counts == {2: 1}
+    assert cb.stats["prompt_tokens"] == sum(lengths)
+    assert cb.stats["padded_tokens"] == 640 * len(lengths)
+    # the next rung is a program of its own, loaded when first asked for
+    cb.submit(_words(641)).result(timeout=120)
+    cb.drain()
+    assert cb._prefill.compile_counts == {(1, 640): 1, (1, 768): 1}
+
+
+# ------------------------------------- the step program at construction
+
+
+def test_construction_loads_the_step_program_and_counts_no_step():
+    chat = _cb_chat()
+    cb = chat._cb
+    cb.drain()  # the construction's own pass of the thread
+    assert not cb._thread.is_alive() and not cb._running
+    assert cb._step.compile_counts == {2: 1}
+    assert cb._prefill.compile_counts == {}  # no rung nobody asked for
+    assert cb.stats["preload_s"] > 0
+    assert all(v == 0 for k, v in cb.stats.items() if k != "preload_s")
+    assert cb.pool.snapshot()["active"] == 0
+    # the lease is back: one slot cache, which the first request takes
+    assert len(chat._plane._leases[cb._cache_key]) == 1
+    preload_s = cb.stats["preload_s"]
+    assert cb.submit("a b c").result(timeout=60)
+    cb.drain()
+    assert cb._step.compile_counts == {2: 1}  # the step was loaded already
+    assert cb.stats["preload_s"] == preload_s
+    assert cb.stats["decode_steps"] == 3 and cb.stats["loop_s"] > 0
+    assert len(chat._plane._leases[cb._cache_key]) == 1
+
+
+def test_submit_racing_the_construction_is_answered_by_the_same_thread():
+    """No second thread and no second lease, wherever the construction's
+    pass stands when the request comes in."""
+    wa = _chat(continuous_batching=False)._generate_batch(["a b c"])
+    for _ in range(4):
+        chat = _cb_chat()
+        cb = chat._cb
+        first = cb._thread
+        fut = cb.submit("a b c")
+        assert [fut.result(timeout=60)] == wa
+        cb.drain()
+        if cb._thread is first:
+            break
+    assert cb._thread is first, "the construction's pass never saw a submit"
+    assert cb._step.compile_counts == {2: 1}
+    assert len(chat._plane._leases[cb._cache_key]) == 1
+    assert cb.stats["preload_s"] > 0 and cb.stats["decode_steps"] == 3
+
+
+def test_second_thread_waits_for_the_lease_of_the_first(monkeypatch):
+    """A thread started while its predecessor still hands the cache back
+    joins it first: one slot cache on the device, never two."""
+    import threading
+
+    chat = _cb_chat()
+    cb = chat._cb
+    cb.drain()
+    made = []
+    init = cb._init_cache
+    cb._init_cache = lambda: made.append(1) or init()
+    plane = chat._plane
+    restore = plane.restore
+    at_gate, gate = threading.Event(), threading.Event()
+
+    def held(key, buf):
+        if key == cb._cache_key:
+            at_gate.set()
+            assert gate.wait(30)
+        restore(key, buf)
+
+    monkeypatch.setattr(plane, "restore", held)
+    assert cb.submit("a b c").result(timeout=60)
+    assert at_gate.wait(30)  # the first thread is out of its loop
+    assert not cb._running
+    fut = cb.submit("d e f")  # starts the second
+    _time.sleep(0.2)
+    assert made == [] and not fut.done()
+    gate.set()
+    assert fut.result(timeout=60)
+    cb.drain()
+    assert made == []
+    assert len(plane._leases[cb._cache_key]) == 1
+
+
+def test_failed_step_load_is_logged_and_fails_the_first_request(monkeypatch):
+    """A first compile that fails at construction follows the plane's
+    rule: it is written to the global error log, the batcher keeps no
+    state of it, and the first request's step is a first compile again,
+    which fails that request's future."""
+    from pathway_tpu.internals.errors import global_error_log
+    from pathway_tpu.models import transformer
+
+    real, broken = transformer.decode_step_slots, [True]
+
+    def refused(*a, **kw):
+        if broken[0]:
+            raise ValueError("step does not fit")
+        return real(*a, **kw)
+
+    monkeypatch.setattr(transformer, "decode_step_slots", refused)
+    log = global_error_log().entries
+    before = len(log)
+    chat = _cb_chat()
+    cb = chat._cb
+    cb.drain()
+    assert not cb._running and cb.stats["preload_s"] == 0
+    assert cb._step.compile_counts == {} and cb._step.host_fallbacks == 0
+    assert "first compile failed" in log[before] and cb._step.name in log[before]
+    assert len(chat._plane._leases[cb._cache_key]) == 1  # the lease is back
+    fut = cb.submit("a b c")
+    with pytest.raises(ValueError, match="step does not fit"):
+        fut.result(timeout=60)
+    cb.drain()
+    assert cb.stats["submitted"] == 1 and cb.stats["completed"] == 0
+    assert cb.pool.snapshot()["active"] == 0
+    assert len(chat._plane._leases[cb._cache_key]) == 1
+    # the program repaired, the same batcher serves: nothing was latched
+    broken[0] = False
+    assert cb.submit("a b c").result(timeout=60)
+    cb.drain()
+    assert cb._step.compile_counts == {2: 1}

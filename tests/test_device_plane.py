@@ -2,8 +2,8 @@
 
 Pins the four pillars of the dispatch subsystem:
 
-  * shape-bucketed coalescing: ragged live batches pad to power-of-two
-    buckets, so the jit cache (and the per-bucket compile ledger) sees a
+  * shape-bucketed coalescing: ragged live batches pad to buckets (rows
+    to a power of two, sequences to the rungs of one ladder), so the jit cache (and the per-bucket compile ledger) sees a
     bounded set of shapes — the CPU-runnable no-recompile guard;
   * padding hygiene: padded rows never leak into results;
   * donated persistent buffers: the decoder KV cache and the KNN slab
@@ -51,6 +51,44 @@ def test_seq_bucket_boundaries():
     assert b.seq_bucket(17, cap=512) == 32
     assert b.seq_bucket(100, cap=512) == 128
     assert b.seq_bucket(1000, cap=512) == 512  # cap wins
+
+
+@pytest.mark.parametrize("longest,cap,want", [
+    # up to 512: powers of two from min_seq, as ever
+    (1, 2016, 16), (16, 2016, 16), (17, 2016, 32), (100, 2016, 128),
+    (512, 2016, 512),
+    # above 512: four rungs an octave
+    (513, 2016, 640), (640, 2016, 640), (641, 2016, 768), (1024, 2016, 1024),
+    (1025, 2016, 1280), (1250, 2016, 1280), (1281, 2016, 1536),
+    (1793, 4096, 2048), (2049, 4096, 2560), (3585, 8192, 4096),
+    # the cap wins: the cell's budget, and a rung is not a cap
+    (1793, 2016, 2016), (2016, 2016, 2016), (5000, 2016, 2016),
+    (1000, 512, 512), (600, 600, 600),
+])
+def test_seq_bucket_rungs(longest, cap, want):
+    assert BucketPolicy().seq_bucket(longest, cap) == want
+
+
+def test_seq_bucket_ladder_is_monotone_tight_and_aligned():
+    """Over every length up to 9,000: never under the length, never
+    falling as the length grows, under a quarter of padding above 512,
+    and every rung above 512 a multiple of 128 unless it is the cap."""
+    b = BucketPolicy()
+    for cap in (2016, 1 << 20):
+        last = 0
+        for n in range(1, 9001):
+            got = b.seq_bucket(n, cap)
+            assert got >= last
+            last = got
+            if got == cap:
+                continue
+            assert got >= n
+            if n > 512:
+                assert got % 128 == 0 and got < 1.25 * n
+    rungs = sorted({b.seq_bucket(n, 1 << 20) for n in range(513, 4097)})
+    assert rungs == [
+        640, 768, 896, 1024, 1280, 1536, 1792, 2048, 2560, 3072, 3584, 4096,
+    ]
 
 
 def test_pad_rows_pads_with_zeros_to_bucket():
