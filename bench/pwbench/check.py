@@ -28,7 +28,7 @@ from typing import Any
 
 import numpy as np
 
-from . import reference, traffic, weights
+from . import reference, spec, traffic, weights
 
 _TOKEN = re.compile(r"<(\d+)>")
 
@@ -82,9 +82,10 @@ def logit_gaps(seed: int, config: dict, sample: list[dict]) -> dict:
     ids judged: the served ones, or the control's). Returns the widest gap
     by which a judged token's reference logit lies below the reference's
     best at its position."""
-    sz = weights.sizes_of(config, encoder=False)
+    family = spec.family_of(config)
+    sz = family.sizes(config)
     rows, at = _decoder_rows(sample)
-    ref = reference.decoder_logits(seed, sz, rows, at, sz["positions"])
+    ref = family.decoder_logits(seed, sz, rows, at, sz["positions"])
     gaps = []
     for lg, s in zip(ref, sample):
         judged = np.asarray(s["tokens"])
@@ -97,7 +98,7 @@ def logit_gaps(seed: int, config: dict, sample: list[dict]) -> dict:
 
 
 def _encoder_rows(config: dict, live: dict[int, str], sample: list[dict]):
-    sz = weights.sizes_of(config["encoder"], encoder=True)
+    sz = weights.encoder_sizes(config["encoder"])
     ids = sorted(live)
     rows = [traffic.tokenize(live[i], sz["vocab"], sz["positions"]) for i in ids]
     rows += [traffic.tokenize(s["query"], sz["vocab"], sz["positions"]) for s in sample]
@@ -149,9 +150,10 @@ def control_sample(seed: int, config: dict, live: dict[int, str],
         top = np.argsort(-sims[r])[:len(s["texts"])]
         out.append({**s, "texts": [live[ids[int(j)]] for j in top]})
     if "given" in sample[0]:
-        dsz = weights.sizes_of(config, encoder=False)
+        family = spec.family_of(config)
+        dsz = family.sizes(config)
         rows, at = _decoder_rows(sample)
-        ctl = reference.decoder_logits(
+        ctl = family.decoder_logits(
             seed, dsz, rows, at, dsz["positions"], fp8=True
         )
         for s, lg in zip(out, ctl):

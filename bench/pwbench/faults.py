@@ -9,46 +9,53 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import importlib
 from typing import Any, Callable, ContextManager, Iterator
+
+from . import spec
 
 
 @dataclasses.dataclass(frozen=True)
 class Fault:
-    """``program()`` is entered while the server is built (the programs it
-    jits are the broken ones); ``served(rag)`` is called after warm-up."""
-    program: Callable[[], ContextManager] = contextlib.nullcontext
+    """``program(config)`` is entered while the server of that
+    configuration is built (the programs it jits are the broken ones);
+    ``served(rag)`` is called after warm-up."""
+    program: Callable[[dict], ContextManager] = contextlib.nullcontext
     served: Callable[[Any], None] = lambda rag: None
 
 
-def _broken_step(wrap: Callable) -> Callable[[], ContextManager]:
-    """The batcher binds ``transformer.decode_step_slots`` when it is
-    built: while the context lasts, that is ``wrap(the real one)``."""
+def _broken_step(wrap: Callable) -> Callable[[dict], ContextManager]:
+    """The batcher binds the step when it is built, from the module and
+    attribute that the configuration's decoder family names (``STEP``):
+    while the context lasts, that is ``wrap(the real one, the decoder's
+    sizes)``. A step takes the parameters and the slot cache first and
+    returns (next tokens, cache)."""
     @contextlib.contextmanager
-    def planted() -> Iterator[None]:
-        from pathway_tpu.models import transformer
-
-        real = transformer.decode_step_slots
-        transformer.decode_step_slots = wrap(real)
+    def planted(config: dict) -> Iterator[None]:
+        family = spec.family_of(config)
+        module = importlib.import_module(family.STEP[0])
+        real = getattr(module, family.STEP[1])
+        setattr(module, family.STEP[1], wrap(real, family.sizes(config)))
         try:
             yield
         finally:
-            transformer.decode_step_slots = real
+            setattr(module, family.STEP[1], real)
 
     return planted
 
 
-def _token_altered(real: Callable) -> Callable:
-    def step(params, cache, token, pos, pad_len, cfg):
-        nxt, cache = real(params, cache, token, pos, pad_len, cfg)
-        return (nxt + 1) % cfg.vocab_size, cache
+def _token_altered(real: Callable, sizes: dict) -> Callable:
+    def step(params, cache, *rest, **kw):
+        nxt, cache = real(params, cache, *rest, **kw)
+        return (nxt + 1) % sizes["vocab"], cache
 
     return step
 
 
-def _state_unchanged(real: Callable) -> Callable:
-    def step(params, cache, token, pos, pad_len, cfg):
+def _state_unchanged(real: Callable, sizes: dict) -> Callable:
+    def step(params, cache, *rest, **kw):
         # (the real step rebinds the keys of the dict it is given)
-        nxt, _written = real(params, dict(cache), token, pos, pad_len, cfg)
+        nxt, _written = real(params, dict(cache), *rest, **kw)
         return nxt, cache  # the slot cache as it came: no key or value kept
 
     return step

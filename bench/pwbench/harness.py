@@ -9,11 +9,11 @@ import sys
 import threading
 import time
 from pathlib import Path
-from typing import Any
+from typing import Any, Callable
 
 import numpy as np
 
-from . import check, loadgen, spec, traffic, trace_reduce, weights
+from . import check, loadgen, spec, traffic, trace_reduce
 from .server import Client, Rag, wait_until_indexed
 
 # the traced part of a --trace 1 run: this long, from a quarter into the
@@ -39,8 +39,10 @@ def _device_check(chips: int, require_tpu: bool) -> Any:
 
 
 def _buckets(lo: int, hi: int, floor: int, cap: int) -> list[int]:
-    """The power-of-two buckets (as ``BucketPolicy`` rounds, capped) that
-    lengths from lo to hi can land in."""
+    """The power-of-two buckets (as ``BucketPolicy`` rounds rows and the
+    encoder's sequences, capped) that lengths from lo to hi can land in.
+    Not for prompts: their widths are the batcher's to say
+    (``_warm_prefill``)."""
     def bucket(n: int) -> int:
         b = floor
         while b < n:
@@ -56,9 +58,30 @@ def _buckets(lo: int, hi: int, floor: int, cap: int) -> list[int]:
         n = b + 1
 
 
+def _warm_prefill(widths_of: Callable[[list[int]], list[int]], lo: int,
+                  hi: int) -> list[int]:
+    """Loads every prompt width that lengths from lo to hi tokens can run
+    at, by asking the program and not a copy of its rule:
+    ``widths_of(lengths)`` runs prompts of that many tokens at once and
+    returns every width the program has run a prompt at so far. The
+    shortest and the longest prompt go first, together; where they ran at
+    two widths, the first length past the last width found is tried until
+    it runs at a width already met (the longest's), which finds every
+    width between so long as a longer prompt never runs narrower."""
+    found = widths_of(sorted({lo, hi}))
+    n = min(found) + 1
+    while n < hi:
+        more = widths_of([n])
+        if more == found:
+            break
+        n = max(min(set(more) - set(found)), n) + 1
+        found = more
+    return found
+
+
 def _warm_up(rag: Rag, cell: spec.Cell, corpus: traffic.Corpus) -> dict:
     """Every shape the window can use, through the program's own entries:
-    the batcher's ``submit`` for the prefill buckets and the step, the
+    the batcher's ``submit`` for the prompt widths and the step, the
     embedder for the query buckets, and concurrent bursts over HTTP for
     the search buckets."""
     cfg, mix = cell.config, cell.mix
@@ -84,16 +107,8 @@ def _warm_up(rag: Rag, cell: spec.Cell, corpus: traffic.Corpus) -> dict:
     mark("encode")
     k = srv["search_topk"]
     if mix["route"] == "/v2/answer":
-        budget = cfg["n_positions"] - srv["max_new_tokens"]
         lo, hi = corpus.prompt_token_range(k, mix["question_words"])
-        prefill = _buckets(lo, hi, 16, budget)
-        futures = [
-            rag.batcher.submit(" ".join(f"warm{j}" for j in range(b - 1)))
-            for b in prefill
-        ]
-        for f in futures:
-            f.result(timeout=1200)
-        warmed["prefill"] = prefill
+        warmed["prefill"] = _warm_prefill(rag.prompt_widths, lo, hi)
         mark("prefill_and_step")
     if float(mix.get("upserts_per_s", 0)) > 0:
         # the slab grows past its power of two with the first new
@@ -236,7 +251,7 @@ def run_cell(
         n_questions = len(due)
     questions = traffic.make_questions(seed, corpus, mix, n_questions)
     t = phase("traffic", t)
-    with fault.program() if fault is not None else contextlib.nullcontext():
+    with fault.program(cfg) if fault is not None else contextlib.nullcontext():
         rag = Rag(cfg, seed)
     t = phase("build", t)
     rag.start()
@@ -495,7 +510,7 @@ def _items(cell: spec.Cell, records: list, slot_of: dict) -> tuple[list, int]:
     cfg, mix = cell.config, cell.mix
     k = int(cfg["server"]["search_topk"])
     n_new = int(cfg["server"]["max_new_tokens"])
-    dec = weights.sizes_of(cfg, encoder=False)
+    dec = spec.family_of(cfg).sizes(cfg)
     budget = dec["positions"] - n_new
     items, malformed = [], 0
     for r in (r for r in records if r.status == 200):
@@ -511,7 +526,7 @@ def _items(cell: spec.Cell, records: list, slot_of: dict) -> tuple[list, int]:
         toks = check.served_tokens((r.reply or {}).get("response", ""))
         texts = [d["text"] for d in docs]
         if len(toks) != n_new or len(texts) != k or any(
-            not 0 <= t < cfg["vocab_size"] for t in toks
+            not 0 <= t < dec["vocab"] for t in toks
         ):
             malformed += 1
             continue

@@ -3,12 +3,17 @@ float32 at ``highest`` matmul precision, with no kernel, no cache and no
 batching tricks. It imports nothing of ``pathway_tpu``, and draws its own
 weights from the seed (weights.py), one layer at a time.
 
+Here are the encoder's embeddings and the helpers the references share:
+the fp8 rounding of the control, the matrix product, the norm, and the
+block that the encoder and the ``gpt2`` decoder family both run. A
+decoder's logits are its family's (bench/families/<family>.py).
+
 The block (the repository's, as the configurations' ``assumed`` say):
   x += attn(rms(x, ln1)) ; x += gelu_tanh(rms(x, ln2) @ ff_in) @ ff_out
   attn: qkv = h @ W_qkv, heads split in order, softmax(q k^T / sqrt(dh)) v,
-  then @ W_o. Decoder: causal, learned positions, logits = rms(x, ln_f) @
-  tok_embed^T. Encoder: every valid key, mean pool over valid tokens,
-  @ head, L2-normalised.
+  then @ W_o; learned positions. Decoder (gpt2): causal, logits =
+  rms(x, ln_f) @ tok_embed^T. Encoder: every valid key, mean pool over
+  valid tokens, @ head, L2-normalised.
 
 ``fp8`` puts the control in the reference's place: both operands of every
 matrix product are rounded to float8_e4m3fn (scaled per tensor), the
@@ -25,7 +30,7 @@ import numpy as np
 from . import weights
 
 
-def _q(x: Any, fp8: bool) -> Any:
+def quant(x: Any, fp8: bool) -> Any:
     import jax.numpy as jnp
 
     if not fp8:
@@ -34,16 +39,16 @@ def _q(x: Any, fp8: bool) -> Any:
     return (x / s).astype(jnp.float8_e4m3fn).astype(jnp.float32) * s
 
 
-def _mm(a: Any, b: Any, fp8: bool) -> Any:
+def mm(a: Any, b: Any, fp8: bool) -> Any:
     import jax
     import jax.numpy as jnp
 
     return jnp.matmul(
-        _q(a, fp8), _q(b, fp8), precision=jax.lax.Precision.HIGHEST
+        quant(a, fp8), quant(b, fp8), precision=jax.lax.Precision.HIGHEST
     )
 
 
-def _rms(x: Any, scale: Any) -> Any:
+def rms(x: Any, scale: Any) -> Any:
     import jax
     import jax.numpy as jnp
 
@@ -67,10 +72,10 @@ def _block_one(x: Any, mask: Any, w: dict, heads: int, causal: bool,
 
     s, d = x.shape
     dh = d // heads
-    qkv = _mm(_rms(x, w["ln1_scale"]), w["qkv"], fp8)
+    qkv = mm(rms(x, w["ln1_scale"]), w["qkv"], fp8)
     q, k, v = (qkv[:, i * d:(i + 1) * d].reshape(s, heads, dh) for i in range(3))
     scores = jnp.einsum(
-        "qhd,khd->hqk", _q(q, fp8), _q(k, fp8),
+        "qhd,khd->hqk", quant(q, fp8), quant(k, fp8),
         precision=jax.lax.Precision.HIGHEST,
     ) / math.sqrt(dh)
     attend = mask[None, None, :]
@@ -78,12 +83,12 @@ def _block_one(x: Any, mask: Any, w: dict, heads: int, causal: bool,
         attend = attend & jnp.tril(jnp.ones((s, s), bool))[None]
     probs = jax.nn.softmax(jnp.where(attend, scores, -1e30), axis=-1)
     ctx = jnp.einsum(
-        "hqk,khd->qhd", _q(probs, fp8), _q(v, fp8),
+        "hqk,khd->qhd", quant(probs, fp8), quant(v, fp8),
         precision=jax.lax.Precision.HIGHEST,
     ).reshape(s, d)
-    x = x + _mm(ctx, w["o"], fp8)
-    hidden = _gelu_tanh(_mm(_rms(x, w["ln2_scale"]), w["ff_in"], fp8))
-    return x + _mm(hidden, w["ff_out"], fp8)
+    x = x + mm(ctx, w["o"], fp8)
+    hidden = _gelu_tanh(mm(rms(x, w["ln2_scale"]), w["ff_in"], fp8))
+    return x + mm(hidden, w["ff_out"], fp8)
 
 
 @functools.lru_cache(maxsize=None)
@@ -127,7 +132,7 @@ def _top_fn(sz_items: tuple):
     return jax.jit(fn)
 
 
-def _hidden(seed: int, sz: dict, ids: np.ndarray, mask: np.ndarray,
+def hidden(seed: int, sz: dict, ids: np.ndarray, mask: np.ndarray,
             causal: bool, fp8: bool) -> tuple[Any, dict]:
     """Final hidden states [n, s, d] before the last norm, and the top
     leaves. Rows are right-padded; positions run from 0."""
@@ -143,30 +148,6 @@ def _hidden(seed: int, sz: dict, ids: np.ndarray, mask: np.ndarray,
     for li in range(sz["layers"]):
         x = layer(kd, jnp.asarray(li, jnp.int32), x, m)
     return x, top
-
-
-def decoder_logits(seed: int, sz: dict, rows: list[list[int]],
-                   at: list[range], width: int,
-                   fp8: bool = False) -> list[np.ndarray]:
-    """For each row of token ids, the logits [len(at[i]), vocab] at the
-    positions ``at[i]``. Rows are padded on the right to ``width``, one
-    shape for every call so that one program serves every seed."""
-    import jax
-    import jax.numpy as jnp
-
-    n = len(rows)
-    ids = np.zeros((n, width), np.int32)
-    mask = np.zeros((n, width), np.int32)
-    for i, r in enumerate(rows):
-        ids[i, :len(r)] = r
-        mask[i, :len(r)] = 1
-    x, top = _hidden(seed, sz, ids, mask, True, fp8)
-    out = []
-    for i in range(n):
-        h = _rms(x[i, at[i].start:at[i].stop, :], top["ln_f_scale"])
-        lg = _mm(h, top["tok_embed"].T, fp8)
-        out.append(np.asarray(jax.device_get(lg), np.float32))
-    return out
 
 
 def encoder_embed(seed: int, sz: dict, rows: list[list[int]],
@@ -188,11 +169,11 @@ def encoder_embed(seed: int, sz: dict, rows: list[list[int]],
             ids[i, :len(r)] = r
             mask[i, :len(r)] = 1
         mask[len(part):, 0] = 1  # rows of padding: one token, dropped below
-        x, top = _hidden(seed, sz, ids, mask, False, fp8)
-        h = _rms(x, top["ln_f_scale"])
+        x, top = hidden(seed, sz, ids, mask, False, fp8)
+        h = rms(x, top["ln_f_scale"])
         m = jnp.asarray(mask, jnp.float32)[:, :, None]
         pooled = jnp.sum(h * m, axis=1) / jnp.maximum(jnp.sum(m, axis=1), 1.0)
-        e = _mm(pooled, top["head"], fp8)
+        e = mm(pooled, top["head"], fp8)
         e = e / jnp.maximum(jnp.linalg.norm(e, axis=-1, keepdims=True), 1e-12)
         out[lo:lo + len(part)] = np.asarray(jax.device_get(e))[:len(part)]
     return out

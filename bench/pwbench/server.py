@@ -14,7 +14,7 @@ import threading
 import time
 from typing import Any
 
-from . import weights
+from . import spec, weights
 
 
 def free_port() -> int:
@@ -32,7 +32,7 @@ class Rag:
 
         import pathway_tpu as pw
         from pathway_tpu.engine.device_plane import get_device_plane
-        from pathway_tpu.models import embedder_config, lm_config
+        from pathway_tpu.models import embedder_config
         from pathway_tpu.stdlib.indexing import BruteForceKnnFactory
         from pathway_tpu.xpacks.llm.document_store import DocumentStore
         from pathway_tpu.xpacks.llm.embedders import JaxEmbedder
@@ -44,8 +44,9 @@ class Rag:
         self.config = config
         self.plane = get_device_plane()  # sets the compile cache first
         enc, srv = config["encoder"], config["server"]
-        self.enc_sizes = weights.sizes_of(enc, encoder=True)
-        self.dec_sizes = weights.sizes_of(config, encoder=False)
+        family = spec.family_of(config)  # the decoder's block is its file's
+        self.enc_sizes = weights.encoder_sizes(enc)
+        self.dec_sizes = family.sizes(config)
         dtype = {"bfloat16": jnp.bfloat16}[config["dtype"]]
         enc_cfg = embedder_config(
             vocab_size=enc["vocab_size"], d_model=enc["hidden_size"],
@@ -54,17 +55,12 @@ class Rag:
             max_len=enc["max_position_embeddings"],
             embed_dim=enc["embedding_size"], dtype=dtype,
         )
-        dec_cfg = lm_config(
-            vocab_size=config["vocab_size"], d_model=config["n_embd"],
-            n_heads=config["n_head"], n_layers=config["n_layer"],
-            d_ff=config["n_inner"], max_len=config["n_positions"],
-            dtype=dtype,
-        )
         self.embedder = JaxEmbedder(
             config=enc_cfg, params=weights.make_params(seed, self.enc_sizes)
         )
         self.chat = JaxLMChat(
-            config=dec_cfg, params=weights.make_params(seed, self.dec_sizes),
+            config=family.program_config(config, dtype),
+            params=family.make_params(seed, self.dec_sizes),
             max_new_tokens=srv["max_new_tokens"],
             decode_slots=srv["decode_slots"],
         )
@@ -119,6 +115,23 @@ class Rag:
         self.batcher.params = None
         self.chat.params = None
         self.embedder.params = None
+
+    def prompt_widths(self, lengths: list[int]) -> list[int]:
+        """Runs prompts of that many tokens (the leading class token and
+        made-up words) through the batcher's ``submit``, all at once, and
+        returns every width the program says it has run a prompt at: the
+        sequence lengths of the prefill program's buckets in the plane's
+        compile counts."""
+        futures = [
+            self.batcher.submit(" ".join(f"warm{j}" for j in range(n - 1)))
+            for n in lengths
+        ]
+        for f in futures:
+            f.result(timeout=1200)
+        return sorted(
+            int(bucket[-1]) for (name, bucket) in self.plane.compile_counts()
+            if name == f"{self.batcher.name}/prefill"
+        )
 
     # ---------------------------------------------------------- counters
 

@@ -2,6 +2,7 @@
 
 from __future__ import annotations
 
+import functools
 import importlib.util
 import json
 from pathlib import Path
@@ -9,11 +10,41 @@ from typing import Any
 
 BENCH = Path(__file__).resolve().parents[1]
 ROOT = BENCH.parent
+# where a decoder family's file is looked for: ``<directory>/<family>.py``
+FAMILY_DIRS = [BENCH / "families"]
 
 
 def _load(path: Path) -> Any:
     with open(path) as f:
         return json.load(f)
+
+
+@functools.lru_cache(maxsize=None)  # a file is run once, however often asked for
+def _module(name: str, path: Path) -> Any:
+    spec = importlib.util.spec_from_file_location(name, path)
+    if spec is None or spec.loader is None:
+        raise FileNotFoundError(path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def family(name: Any) -> Any:
+    """The module of a decoder family: everything the benchmark knows of
+    a decoder's block (bench/README.md says what the file gives)."""
+    for d in FAMILY_DIRS if isinstance(name, str) else ():
+        if (d / f"{name}.py").is_file():
+            return _module(f"_family_{name}", d / f"{name}.py")
+    there = sorted({p.stem for d in FAMILY_DIRS for p in d.glob("*.py")})
+    raise SystemExit(
+        f"unknown decoder family {name!r}: the configuration's file names "
+        f"its family under the key 'family', and the families are {there}"
+    )
+
+
+def family_of(config: dict) -> Any:
+    """The family a configuration's file names."""
+    return family(config.get("family"))
 
 
 class Cell:
@@ -55,13 +86,7 @@ class Cell:
         """The reader of a per-layer metric: the file named by the part of
         the metric's name before its first dot."""
         stem = metric_name.split(".", 1)[0]
-        path = self.readers_dir / f"{stem}.py"
-        spec = importlib.util.spec_from_file_location(f"_reader_{stem}", path)
-        if spec is None or spec.loader is None:
-            raise FileNotFoundError(path)
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        return mod.read
+        return _module(f"_reader_{stem}", self.readers_dir / f"{stem}.py").read
 
 
 def peaks(device_kind: str) -> dict:

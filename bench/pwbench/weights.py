@@ -1,8 +1,13 @@
 """Weights from ``--seed``, made by the benchmark and handed to the
 program as its model: one jitted call on the device, in bfloat16, in the
-tree layout ``pathway_tpu.models.transformer`` serves. The reference
-(reference.py) draws the same leaves again, layer by layer, from the same
-keys; it never reads the program's copy."""
+tree layout the program serves. The reference (reference.py) draws the
+same leaves again, layer by layer, from the same keys; it never reads the
+program's copy.
+
+Here are the encoder's sizes and the helpers every model shares: the key
+of a seed, a leaf, and the leaves of the block that the encoder and the
+``gpt2`` decoder family (bench/families/gpt2.py) both run. A decoder's own
+sizes, and a tree of another block, are its family's."""
 
 from __future__ import annotations
 
@@ -12,24 +17,14 @@ from typing import Any
 
 import numpy as np
 
-BLOCK_LEAVES = ("qkv", "o", "ff_in", "ff_out", "ln1_scale", "ln2_scale")
-TOP_LEAVES = ("tok_embed", "pos_embed", "ln_f_scale", "head")
-
-
-def sizes_of(model: dict, *, encoder: bool) -> dict:
-    """The sizes of one model, from its configuration group."""
-    if encoder:
-        return dict(
-            vocab=model["vocab_size"], d=model["hidden_size"],
-            heads=model["num_attention_heads"],
-            layers=model["num_hidden_layers"], ff=model["intermediate_size"],
-            positions=model["max_position_embeddings"],
-            embed=model["embedding_size"], tag=1,
-        )
+def encoder_sizes(model: dict) -> dict:
+    """The encoder's sizes, from a configuration's ``encoder`` group."""
     return dict(
-        vocab=model["vocab_size"], d=model["n_embd"], heads=model["n_head"],
-        layers=model["n_layer"], ff=model["n_inner"],
-        positions=model["n_positions"], embed=model["n_embd"], tag=2,
+        vocab=model["vocab_size"], d=model["hidden_size"],
+        heads=model["num_attention_heads"],
+        layers=model["num_hidden_layers"], ff=model["intermediate_size"],
+        positions=model["max_position_embeddings"],
+        embed=model["embedding_size"], tag=1,
     )
 
 
@@ -39,7 +34,7 @@ def key_data(seed: int, tag: int) -> np.ndarray:
     return np.asarray(state, np.uint32)
 
 
-def _leaf(key: Any, index: int, shape: tuple, scale: float, centre: float):
+def leaf(key: Any, index: int, shape: tuple, scale: float, centre: float):
     import jax
     import jax.numpy as jnp
 
@@ -55,22 +50,22 @@ def block_leaves(key: Any, layer: Any, sz: dict) -> dict:
     k = jax.random.fold_in(key, 1000 + layer)
     s = 1.0 / math.sqrt(d)
     return {
-        "qkv": _leaf(k, 0, (d, 3 * d), s, 0.0),
-        "o": _leaf(k, 1, (d, d), s, 0.0),
-        "ff_in": _leaf(k, 2, (d, f), s, 0.0),
-        "ff_out": _leaf(k, 3, (f, d), 1.0 / math.sqrt(f), 0.0),
-        "ln1_scale": _leaf(k, 4, (d,), 0.1, 1.0),
-        "ln2_scale": _leaf(k, 5, (d,), 0.1, 1.0),
+        "qkv": leaf(k, 0, (d, 3 * d), s, 0.0),
+        "o": leaf(k, 1, (d, d), s, 0.0),
+        "ff_in": leaf(k, 2, (d, f), s, 0.0),
+        "ff_out": leaf(k, 3, (f, d), 1.0 / math.sqrt(f), 0.0),
+        "ln1_scale": leaf(k, 4, (d,), 0.1, 1.0),
+        "ln2_scale": leaf(k, 5, (d,), 0.1, 1.0),
     }
 
 
 def top_leaves(key: Any, sz: dict) -> dict:
     d = sz["d"]
     return {
-        "tok_embed": _leaf(key, 0, (sz["vocab"], d), 0.02, 0.0),
-        "pos_embed": _leaf(key, 1, (sz["positions"], d), 0.02, 0.0),
-        "ln_f_scale": _leaf(key, 2, (d,), 0.1, 1.0),
-        "head": _leaf(key, 3, (d, sz["embed"]), 1.0 / math.sqrt(d), 0.0),
+        "tok_embed": leaf(key, 0, (sz["vocab"], d), 0.02, 0.0),
+        "pos_embed": leaf(key, 1, (sz["positions"], d), 0.02, 0.0),
+        "ln_f_scale": leaf(key, 2, (d,), 0.1, 1.0),
+        "head": leaf(key, 3, (d, sz["embed"]), 1.0 / math.sqrt(d), 0.0),
     }
 
 
@@ -97,12 +92,3 @@ def make_params(seed: int, sz: dict) -> dict:
 
     fn = _jitted_tree(tuple(sorted(sz.items())))
     return fn(jnp.asarray(key_data(seed, sz["tag"])))
-
-
-def n_params(sz: dict, *, embedding: bool) -> int:
-    """Parameters of the blocks, with or without the embedding tables."""
-    d, f = sz["d"], sz["ff"]
-    n = sz["layers"] * (4 * d * d + 2 * d * f + 2 * d) + d
-    if embedding:
-        n += (sz["vocab"] + sz["positions"]) * d
-    return n

@@ -26,7 +26,8 @@ ROOT = HERE.parent
 
 
 def rehearse(workload: str, seed: int, seconds: float, trace: bool,
-             control: bool = False) -> int:
+             control: bool = False,
+             bench_file: Path = HERE / "rehearsal" / "BENCHMARK.json") -> int:
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
     os.environ.setdefault(
         "JAX_COMPILATION_CACHE_DIR", str(ROOT / ".pathway-cache" / "xla-rehearsal")
@@ -36,7 +37,7 @@ def rehearse(workload: str, seed: int, seconds: float, trace: bool,
     from pwbench import harness
 
     result = harness.run_cell(
-        HERE / "rehearsal" / "BENCHMARK.json", workload, seed, seconds, trace,
+        bench_file, workload, seed, seconds, trace,
         t_start=T_START, require_tpu=False,
         out_dir=ROOT / ".bench-out" / "rehearsal", control=control,
     )
@@ -52,8 +53,9 @@ def rehearse(workload: str, seed: int, seconds: float, trace: bool,
 
 
 def compile_for_v5e(config_name: str) -> int:
-    """Lower and compile the three programs of a configuration at the real
-    shapes for a described v5e chip."""
+    """Lower and compile the programs of a configuration at the real
+    shapes for a described v5e chip: the decoder's (its family's
+    ``compile_jobs``) and the encoder's kernel."""
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     sys.path.insert(0, str(HERE))
@@ -65,9 +67,8 @@ def compile_for_v5e(config_name: str) -> int:
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
 
-    from pathway_tpu.models import lm_config, transformer
     from pathway_tpu.ops.attention import fused_qkv_attention
-    from pwbench import weights
+    from pwbench import spec, weights
 
     path = Path(config_name)
     if not path.is_file():  # a configuration's name, or any file of sizes
@@ -86,32 +87,8 @@ def compile_for_v5e(config_name: str) -> int:
     def i32(*shape):
         return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=chip)
 
-    enc, srv = cfg["encoder"], cfg["server"]
-    esz = weights.sizes_of(enc, encoder=True)
-    dsz = weights.sizes_of(cfg, encoder=False)
-    dec_cfg = lm_config(
-        vocab_size=dsz["vocab"], d_model=dsz["d"], n_heads=dsz["heads"],
-        n_layers=dsz["layers"], d_ff=dsz["ff"], max_len=dsz["positions"],
-    )
-    params = shaped(jax.eval_shape(
-        lambda: weights._tree(jnp.zeros(2, jnp.uint32), tuple(sorted(dsz.items())))
-    ))
-    cache = shaped(jax.eval_shape(
-        lambda: transformer.init_kv_cache(dec_cfg, srv["decode_slots"])
-    ))
-    n = srv["decode_slots"]
-    budget = dsz["positions"] - srv["max_new_tokens"]
-    jobs = {
-        f"step slots={n}": lambda: jax.jit(
-            functools.partial(transformer.decode_step_slots, cfg=dec_cfg),
-            donate_argnums=(1,),
-        ).lower(params, cache, i32(n), i32(n), i32(n)),
-    }
-    for p in sorted({min(1024, budget), budget}):
-        jobs[f"prefill p={p}"] = lambda p=p: jax.jit(
-            functools.partial(transformer.prefill_into_slot, cfg=dec_cfg),
-            donate_argnums=(3,),
-        ).lower(params, i32(1, p), i32(1, p), cache, i32())
+    esz = weights.encoder_sizes(cfg["encoder"])
+    jobs = spec.family_of(cfg).compile_jobs(cfg, shaped, i32)
     for rows, seq in ((4096, esz["positions"]), (16, 32)):
         qkv = jax.ShapeDtypeStruct((rows, seq, 3 * esz["d"]), jnp.bfloat16, sharding=chip)
         jobs[f"encoder kernel rows={rows} seq={seq}"] = (

@@ -14,7 +14,7 @@ from pathlib import Path
 HERE = Path(__file__).resolve().parent
 sys.path.insert(0, str(HERE))
 
-from pwbench import opsbytes, trace_reduce, weights  # noqa: E402
+from pwbench import opsbytes, spec, trace_reduce  # noqa: E402
 
 
 def check(name: str, ok: bool, detail: object = "") -> bool:
@@ -81,8 +81,10 @@ def worked_by_hand() -> list[bool]:
         n: json.load(open(HERE / "configs" / f"{n}.json"))
         for n in ("rag-gpt2-xl", "rag-cerebras-6b7")
     }
-    xl = weights.sizes_of(cfgs["rag-gpt2-xl"], encoder=False)
-    cb = weights.sizes_of(cfgs["rag-cerebras-6b7"], encoder=False)
+    # both through the family their files name, as the harness reads them
+    family = spec.family_of(cfgs["rag-gpt2-xl"])
+    xl = family.sizes(cfgs["rag-gpt2-xl"])
+    cb = spec.family_of(cfgs["rag-cerebras-6b7"]).sizes(cfgs["rag-cerebras-6b7"])
     return [
         # 48 x (4 x 1600^2 + 2 x 1600 x 6400) = 48 x 30,720,000
         check("gpt2-xl block matrices", opsbytes.n_block(xl) == 1_474_560_000),
@@ -95,8 +97,8 @@ def worked_by_hand() -> list[bool]:
         # 2 x (1,474,560,000 + 50257 x 1600) + 8 x 48 x 4 x 1600 x 500 + 8 x 48 x 4 x 1600
         check("gpt2-xl step bytes, 8 slots at 500 tokens",
               opsbytes.decode_step_bytes(xl, [500] * 8) == 3_109_942_400 + 1_228_800_000 + 2_457_600),
-        check("weights: parameters of gpt2-xl with its tables",
-              weights.n_params(xl, embedding=True)
+        check("parameters of gpt2-xl with its tables",
+              family.n_params(xl, embedding=True)
               == 1_474_560_000 + 48 * 2 * 1600 + 1600 + (50257 + 1024) * 1600),
     ]
 
