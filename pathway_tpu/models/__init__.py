@@ -9,6 +9,7 @@ decode path with a KV cache for on-TPU generation.
 """
 
 from pathway_tpu.models.transformer import (
+    LayerSpec,
     TransformerConfig,
     TransformerLM,
     count_params,
@@ -17,6 +18,7 @@ from pathway_tpu.models.transformer import (
 )
 
 __all__ = [
+    "LayerSpec",
     "TransformerConfig",
     "TransformerLM",
     "count_params",

@@ -20,6 +20,11 @@ Design notes (TPU-first):
   memory lever on TPU.
 - The causal decode path keeps a KV cache laid out [layers, B, S, H, Dh]
   sharded on heads, so generation is also tensor-parallel.
+- The decoder is a list of layers (`LayerSpec`): attention over every
+  earlier position or over a window whose cache rows are a ring, learned,
+  rotary or no positions, a dense GELU feed-forward or routed ReGLU
+  experts, with key/value heads shared by groups of query heads. The
+  default list is the plain block above; see the decoding section.
 """
 
 from __future__ import annotations
@@ -35,6 +40,17 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 Array = jax.Array
 Params = dict[str, Any]
+
+
+@dataclasses.dataclass(frozen=True)
+class LayerSpec:
+    """One decoder layer's kinds. The default is the block every layer
+    ran before there was a list: attention over every earlier position,
+    positions from the learned table, a dense GELU feed-forward."""
+
+    window: int | None = None  # None: every earlier position; W: the last W
+    pos: str = "learned"  # learned (the table, added to the embedding) | rotary | none
+    ff: str = "gelu"  # gelu (dense) | experts (routed ReGLU, top n_active of n_experts)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -63,16 +79,80 @@ class TransformerConfig:
     # streaming-softmax accumulation), and positions/pooling account for
     # the block offset. Long sequences scale with the ring size.
     seq_axis: str | None = None
+    # The decoder's per-layer list (None: n_layers of LayerSpec()), and what
+    # the kinds in it need. Key/value heads fewer than the query heads are
+    # shared by n_heads / n_kv_heads query heads each (None: one each);
+    # head_size is the width of a head where it is not d_model / n_heads;
+    # d_ff is one expert's width in an `experts` layer; an untied model has
+    # an output matrix `lm_head` of its own.
+    layers: tuple[LayerSpec, ...] | None = None
+    n_kv_heads: int | None = None
+    head_size: int | None = None
+    n_experts: int = 0
+    n_active: int = 0
+    rope_theta: float = 10000.0
+    tie_embeddings: bool = True
 
     @property
     def head_dim(self) -> int:
-        return self.d_model // self.n_heads
+        return self.head_size or self.d_model // self.n_heads
+
+    @property
+    def kv_heads(self) -> int:
+        return self.n_kv_heads or self.n_heads
+
+    @property
+    def layer_specs(self) -> tuple[LayerSpec, ...]:
+        return self.layers or (LayerSpec(),) * self.n_layers
+
+    @property
+    def learned_positions(self) -> bool:
+        return any(sp.pos == "learned" for sp in self.layer_specs)
+
+    @property
+    def window(self) -> int | None:
+        """Rows a window layer keeps of a sequence (its ring's length)."""
+        ws = {sp.window for sp in self.layer_specs if sp.window is not None}
+        return min(ws.pop(), self.max_len) if ws else None
+
+    @property
+    def n_expert_layers(self) -> int:
+        return sum(sp.ff == "experts" for sp in self.layer_specs)
+
+    @property
+    def plain(self) -> bool:
+        """The one block the encoder and the training step run."""
+        return (
+            all(sp == LayerSpec() for sp in self.layer_specs)
+            and self.kv_heads == self.n_heads
+            and self.head_size is None
+            and self.tie_embeddings
+        )
 
     def __post_init__(self) -> None:
         if self.pool not in ("mean", "cls", "last"):
             raise ValueError(f"pool must be mean|cls|last, got {self.pool!r}")
-        if self.d_model % self.n_heads != 0:
+        if self.head_size is None and self.d_model % self.n_heads != 0:
             raise ValueError("d_model must be divisible by n_heads")
+        if self.n_heads % self.kv_heads != 0:
+            raise ValueError("n_heads must be divisible by n_kv_heads")
+        specs = self.layer_specs
+        if len(specs) != self.n_layers:
+            raise ValueError(
+                f"layers lists {len(specs)} layers, n_layers is {self.n_layers}"
+            )
+        for sp in specs:
+            if sp.pos not in ("learned", "rotary", "none"):
+                raise ValueError(f"pos must be learned|rotary|none, got {sp.pos!r}")
+            if sp.ff not in ("gelu", "experts"):
+                raise ValueError(f"ff must be gelu|experts, got {sp.ff!r}")
+        if len({sp.window for sp in specs if sp.window is not None}) > 1:
+            # the window layers' rows are one stacked ring
+            raise ValueError("the window layers of one decoder share one window")
+        if self.n_expert_layers and not 0 < self.n_active <= self.n_experts:
+            raise ValueError("experts layers need 0 < n_active <= n_experts")
+        if not self.plain and not self.causal:
+            raise ValueError("the encoder runs the plain block only")
 
 
 def embedder_config(**kw) -> TransformerConfig:
@@ -90,21 +170,34 @@ def lm_config(**kw) -> TransformerConfig:
 
 
 def _init_block(
-    rng: Array, cfg: TransformerConfig, dtype: Any = jnp.float32
+    rng: Array, cfg: TransformerConfig, dtype: Any = jnp.float32,
+    spec: LayerSpec = LayerSpec(),
 ) -> Params:
     d, f = cfg.d_model, cfg.d_ff
+    hd = cfg.n_heads * cfg.head_dim  # the query heads' width: d in the plain block
+    kv = cfg.kv_heads * cfg.head_dim
     ks = jax.random.split(rng, 6)
     s = 1.0 / math.sqrt(d)
-    return {
-        "qkv": (jax.random.normal(ks[0], (d, 3 * d), jnp.float32) * s).astype(dtype),
-        "o": (jax.random.normal(ks[1], (d, d), jnp.float32) * s).astype(dtype),
-        "ff_in": (jax.random.normal(ks[2], (d, f), jnp.float32) * s).astype(dtype),
-        "ff_out": (
-            jax.random.normal(ks[3], (f, d), jnp.float32) * (1.0 / math.sqrt(f))
-        ).astype(dtype),
+
+    def leaf(key: Array, shape: tuple, scale: float) -> Array:
+        return (jax.random.normal(key, shape, jnp.float32) * scale).astype(dtype)
+
+    block = {
+        "qkv": leaf(ks[0], (d, hd + 2 * kv), s),
+        "o": leaf(ks[1], (hd, d), 1.0 / math.sqrt(hd)),
         "ln1_scale": jnp.ones((d,), dtype),
         "ln2_scale": jnp.ones((d,), dtype),
     }
+    if spec.ff == "experts":
+        e = cfg.n_experts
+        block["router"] = leaf(ks[4], (d, e), s)
+        block["expert_gate"] = leaf(ks[2], (e, d, f), s)
+        block["expert_up"] = leaf(ks[5], (e, d, f), s)
+        block["expert_down"] = leaf(ks[3], (e, f, d), 1.0 / math.sqrt(f))
+    else:
+        block["ff_in"] = leaf(ks[2], (d, f), s)
+        block["ff_out"] = leaf(ks[3], (f, d), 1.0 / math.sqrt(f))
+    return block
 
 
 def init_params(
@@ -121,19 +214,28 @@ def init_params(
             jax.random.normal(ks[0], (cfg.vocab_size, cfg.d_model), jnp.float32)
             * 0.02
         ).astype(dtype),
-        "pos_embed": (
-            jax.random.normal(ks[1], (cfg.max_len, cfg.d_model), jnp.float32)
-            * 0.02
-        ).astype(dtype),
         "ln_f_scale": jnp.ones((cfg.d_model,), dtype),
         "head": (
             jax.random.normal(ks[2], (cfg.d_model, e), jnp.float32)
             * (1.0 / math.sqrt(cfg.d_model))
         ).astype(dtype),
         "blocks": [
-            _init_block(ks[3 + i], cfg, dtype) for i in range(cfg.n_layers)
+            _init_block(ks[3 + i], cfg, dtype, spec)
+            for i, spec in enumerate(cfg.layer_specs)
         ],
     }
+    if cfg.learned_positions:
+        params["pos_embed"] = (
+            jax.random.normal(ks[1], (cfg.max_len, cfg.d_model), jnp.float32)
+            * 0.02
+        ).astype(dtype)
+    if not cfg.tie_embeddings:
+        params["lm_head"] = (
+            jax.random.normal(
+                jax.random.fold_in(ks[2], 1), (cfg.d_model, cfg.vocab_size),
+                jnp.float32,
+            ) * (1.0 / math.sqrt(cfg.d_model))
+        ).astype(dtype)
     return params
 
 
@@ -142,23 +244,36 @@ def param_specs(cfg: TransformerConfig) -> Params:
 
     qkv/ff_in are column-parallel (output dim sharded); o/ff_out are
     row-parallel (input dim sharded) so XLA places one psum per block half.
-    Embeddings shard the vocab/feature dim; norms are replicated.
+    Embeddings shard the vocab/feature dim; norms are replicated. An
+    experts layer is expert-parallel: the expert axis is the sharded one.
     """
-    block = {
-        "qkv": P(None, "model"),
-        "o": P("model", None),
-        "ff_in": P(None, "model"),
-        "ff_out": P("model", None),
-        "ln1_scale": P(None),
-        "ln2_scale": P(None),
-    }
-    return {
+    def block(spec: LayerSpec) -> Params:
+        out = {
+            "qkv": P(None, "model"),
+            "o": P("model", None),
+            "ln1_scale": P(None),
+            "ln2_scale": P(None),
+        }
+        if spec.ff == "experts":
+            out["router"] = P(None, None)
+            for name in ("expert_gate", "expert_up", "expert_down"):
+                out[name] = P("model", None, None)
+        else:
+            out["ff_in"] = P(None, "model")
+            out["ff_out"] = P("model", None)
+        return out
+
+    specs = {
         "tok_embed": P("model", None),
-        "pos_embed": P(None, None),
         "ln_f_scale": P(None),
         "head": P(None, "model"),
-        "blocks": [dict(block) for _ in range(cfg.n_layers)],
+        "blocks": [block(spec) for spec in cfg.layer_specs],
     }
+    if cfg.learned_positions:
+        specs["pos_embed"] = P(None, None)
+    if not cfg.tie_embeddings:
+        specs["lm_head"] = P(None, "model")
+    return specs
 
 
 def shard_params(params: Params, mesh: Mesh, cfg: TransformerConfig) -> Params:
@@ -313,6 +428,12 @@ def forward(
     params: Params, token_ids: Array, token_mask: Array, cfg: TransformerConfig
 ) -> Array:
     """Hidden states [b, s, d_model]."""
+    if not cfg.plain:
+        raise NotImplementedError(
+            "forward (encode, logits, the training step) runs the plain "
+            "block; a decoder of other kinds is served by prefill and "
+            "decode_step"
+        )
     b, s = token_ids.shape
     x = params["tok_embed"].astype(cfg.dtype)[token_ids]
     if cfg.seq_axis is not None:
@@ -422,14 +543,288 @@ def make_train_step(cfg: TransformerConfig, learning_rate: float = 1e-3):
 
 
 # ---------------------------------------------------------------- decoding
+#
+# One decoder, a list of layers. Each layer's kinds (`LayerSpec`) choose, at
+# trace time, which rows of the cache it writes and reads, whether rotary
+# turns q and k, and which feed-forward runs; the default list is the plain
+# block, whose two slot programs lower to what they always were. The
+# wave-aligned step (`decode_step`) is the slot step with every row at one
+# position.
+#
+# The cache is a dict of stacked leaves, a pair for each attention kind the
+# list holds: "k"/"v" [global layers, slots, max_len, kv heads, head] grow
+# with the sequence; "k_win"/"v_win" [window layers, slots, W, kv heads,
+# head] are rings: physical position t lives in row t mod W, so a window
+# layer's rows stop growing at W. Physical positions count the left pad
+# too; logical ones (physical less the pad) are what rotary turns by.
+
+# A prefill's float32 scores [heads, queries, keys] above this many bytes a
+# sequence are made a block of queries at a time (and a window layer's over
+# its band of keys only): a function of the shapes alone.
+_SCORE_BYTES_MAX = 1 << 30
+
+# what an experts decoder's two programs append to the tokens they return,
+# in this order (ContinuousBatcher adds them into its `stats`)
+PREFILL_COUNTERS = ("routed_pairs", "expert_load_max")
+STEP_COUNTERS = ("experts_touched", "moe_layers_run")
 
 
 def init_kv_cache(cfg: TransformerConfig, batch: int) -> Params:
-    shape = (cfg.n_layers, batch, cfg.max_len, cfg.n_heads, cfg.head_dim)
-    return {
+    specs = cfg.layer_specs
+    n_win = sum(sp.window is not None for sp in specs)
+    row = (cfg.kv_heads, cfg.head_dim)
+    shape = (len(specs) - n_win, batch, cfg.max_len, *row)
+    cache = {
         "k": jnp.zeros(shape, cfg.dtype),
         "v": jnp.zeros(shape, cfg.dtype),
     }
+    if n_win:
+        ring = (n_win, batch, cfg.window, *row)
+        cache["k_win"] = jnp.zeros(ring, cfg.dtype)
+        cache["v_win"] = jnp.zeros(ring, cfg.dtype)
+    return cache
+
+
+def _cache_rows(cfg: TransformerConfig) -> list[tuple[str, str, int]]:
+    """Per layer: its cache leaves and its index along their layer axis."""
+    out, n = [], {"k": 0, "k_win": 0}
+    for sp in cfg.layer_specs:
+        kname = "k" if sp.window is None else "k_win"
+        out.append((kname, "v" + kname[1:], n[kname]))
+        n[kname] += 1
+    return out
+
+
+def _qkv(xin: Array, block: Params, cfg: TransformerConfig):
+    """q [b, s, heads, dh] and k, v [b, s, kv heads, dh] of normed rows."""
+    b, s, _ = xin.shape
+    h, hk, dh = cfg.n_heads, cfg.kv_heads, cfg.head_dim
+    qkv = jnp.einsum(
+        "bsd,de->bse", xin, block["qkv"].astype(cfg.dtype),
+        preferred_element_type=jnp.float32,
+    ).astype(cfg.dtype)
+    q, k, v = jnp.split(qkv, [h * dh, (h + hk) * dh], axis=-1)
+    return (
+        q.reshape(b, s, h, dh), k.reshape(b, s, hk, dh), v.reshape(b, s, hk, dh)
+    )
+
+
+def _rope(x: Array, pos: Array, cfg: TransformerConfig) -> Array:
+    """Rotary positions, rotate-half over the head: x [b, s, heads, dh],
+    pos [b, s] logical positions."""
+    with jax.named_scope("rope"):
+        half = cfg.head_dim // 2
+        freq = cfg.rope_theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+        ang = pos.astype(jnp.float32)[:, :, None, None] * freq
+        cos, sin = jnp.cos(ang), jnp.sin(ang)
+        x32 = x.astype(jnp.float32)
+        x1, x2 = x32[..., :half], x32[..., half:]
+        return jnp.concatenate(
+            [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1
+        ).astype(x.dtype)
+
+
+def _attend(q: Array, keys: Array, vals: Array, ok: Array,
+            cfg: TransformerConfig) -> Array:
+    """softmax(q k^T / sqrt(dh)) v over the keys `ok` [b, 1, q, s] allows:
+    q [b, q, heads, dh], keys and vals [b, s, kv heads, dh] -> [b, q,
+    heads * dh]. Query heads that share a key head read it where it lies:
+    no key or value is repeated in memory."""
+    b, nq, h, dh = q.shape
+    hk = keys.shape[2]
+    if hk == h:
+        # the same mathematics as a group of one below, kept because the v5e
+        # compiler makes another program of that: compiled for
+        # rag-cerebras-6b7, prefill's temp goes 0.75 -> 0.62 GB at p = 1024
+        # and 2.01 -> 1.50 GB at 2016, and that cell's programs are to stay
+        # the parent's (ISSUE 30)
+        scores = jnp.einsum(
+            "bqhd,bkhd->bhqk", q, keys, preferred_element_type=jnp.float32
+        ) / math.sqrt(dh)
+        scores = jnp.where(ok, scores, -1e30)
+        probs = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)
+        ctx = jnp.einsum(
+            "bhqk,bkhd->bqhd", probs, vals, preferred_element_type=jnp.float32
+        )
+    else:
+        scores = jnp.einsum(
+            "bqkgd,bskd->bkgqs", q.reshape(b, nq, hk, h // hk, dh), keys,
+            preferred_element_type=jnp.float32,
+        ) / math.sqrt(dh)
+        scores = jnp.where(ok[:, :, None], scores, -1e30)
+        probs = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)
+        ctx = jnp.einsum(
+            "bkgqs,bskd->bqkgd", probs, vals, preferred_element_type=jnp.float32
+        )
+    return ctx.astype(cfg.dtype).reshape(b, nq, h * dh)
+
+
+def _attend_blocks(q: Array, k: Array, v: Array, valid: Array,
+                   window: int | None, cfg: TransformerConfig) -> Array:
+    """Causal attention of a whole prompt a block of queries at a time, so
+    that no [heads, p, p] array exists: `valid` [b, p] marks the real keys.
+    A global layer's block reads every key; a window layer's the band of
+    window + block keys that ends with it, so its work is p x W."""
+    b, p, h, dh = q.shape
+    blk = 1 << max(3, (_SCORE_BYTES_MAX // (4 * h * p)).bit_length() - 1)
+    n = -(-p // blk)
+    pad = n * blk - p
+    if pad:  # queries and keys past the end: never valid, cut off below
+        q, k, v = (jnp.pad(a, ((0, 0), (0, pad), (0, 0), (0, 0))) for a in (q, k, v))
+        valid = jnp.pad(valid, ((0, 0), (0, pad)))
+    banded = window is not None and window + blk < n * blk
+    span = window + blk if banded else n * blk
+
+    def one(i_qb):
+        i, qb = i_qb
+        q0 = i * blk
+        start = jnp.clip(q0 - window, 0, n * blk - span) if banded else 0
+        kb = jax.lax.dynamic_slice_in_dim(k, start, span, axis=1)
+        vb = jax.lax.dynamic_slice_in_dim(v, start, span, axis=1)
+        qp = q0 + jnp.arange(blk)[:, None]
+        kp = start + jnp.arange(span)[None, :]
+        ok = (kp <= qp) & jax.lax.dynamic_slice_in_dim(
+            valid, start, span, axis=1
+        )[:, None, None, :]
+        if window is not None:
+            ok = ok & (kp > qp - window)
+        return _attend(qb, kb, vb, ok, cfg)
+
+    qblocks = jnp.moveaxis(q.reshape(b, n, blk, h, dh), 1, 0)
+    ctx = jax.lax.map(one, (jnp.arange(n), qblocks))  # [n, b, blk, h * dh]
+    return jnp.moveaxis(ctx, 0, 1).reshape(b, n * blk, h * dh)[:, :p]
+
+
+def _route(x: Array, block: Params, cfg: TransformerConfig):
+    """The router, on the layer's input (before the attention's norm), in
+    float32: per token its n_active experts and their weights, the softmax
+    over the chosen logits."""
+    with jax.named_scope("router"):
+        logits = jnp.einsum(
+            "bsd,de->bse", x.astype(jnp.float32),
+            block["router"].astype(jnp.float32),
+            precision=jax.lax.Precision.HIGHEST,
+        )
+        top, idx = jax.lax.top_k(logits, cfg.n_active)
+        return idx, jax.nn.softmax(top, axis=-1)
+
+
+def _experts(u: Array, idx: Array, w: Array, live: Array, block: Params,
+             cfg: TransformerConfig):
+    """Routed ReGLU experts over normed rows u [b, s, d]: the token-expert
+    pairs are sorted by expert and each expert multiplies its own run of
+    rows (a grouped product), whatever the run's length, so no pair is ever
+    dropped. `live` [b, s] marks the rows that count. Returns the layer's
+    output and how many live pairs each expert got [n_experts]."""
+    with jax.named_scope("experts"):
+        b, s, d = u.shape
+        k, e = cfg.n_active, cfg.n_experts
+        flat = idx.reshape(-1)  # the pairs, token-major
+        order = jnp.argsort(flat, stable=True)
+        sizes = jnp.zeros((e,), jnp.int32).at[flat].add(1)
+        rows = u.reshape(-1, d)[order // k]  # each pair's token, by expert
+
+        def grouped(x: Array, name: str) -> Array:
+            return jax.lax.ragged_dot(
+                x, block[name].astype(cfg.dtype), sizes,
+                preferred_element_type=jnp.float32,
+            )
+
+        hidden = (
+            jax.nn.relu(grouped(rows, "expert_gate")) * grouped(rows, "expert_up")
+        ).astype(cfg.dtype)
+        y = grouped(hidden, "expert_down")  # [pairs, d], by expert
+        back = jnp.zeros_like(order).at[order].set(jnp.arange(order.size))
+        y = jnp.einsum("tkd,tk->td", y[back].reshape(b * s, k, d), w.reshape(-1, k))
+        counts = jnp.zeros((e,), jnp.int32).at[flat].add(
+            jnp.repeat(live.reshape(-1).astype(jnp.int32), k)
+        )
+        return y.astype(cfg.dtype).reshape(b, s, d), counts
+
+
+def _lm_logits(hline: Array, params: Params, cfg: TransformerConfig) -> Array:
+    with jax.named_scope("logits"):
+        if cfg.tie_embeddings:
+            return jnp.einsum(
+                "bsd,vd->bsv", hline, params["tok_embed"].astype(cfg.dtype),
+                preferred_element_type=jnp.float32,
+            )
+        return jnp.einsum(
+            "bsd,dv->bsv", hline, params["lm_head"].astype(cfg.dtype),
+            preferred_element_type=jnp.float32,
+        )
+
+
+def _layer(x, block, spec, cfg, pos, live, attend, counters):
+    """One decoder layer over rows x [b, s, d]. `attend(q, k, v)` writes the
+    layer's keys and values where they belong and returns the attention's
+    context; `pos` [b, s] are logical positions, `live` [b, s] the rows that
+    count (not padding, not a free slot). An experts layer appends its
+    per-expert counts of live pairs to `counters`."""
+    if spec.ff == "experts":
+        idx, w = _route(x, block, cfg)
+    xin = _rmsnorm(x, block["ln1_scale"])
+    with jax.named_scope("attn"):
+        q, k, v = _qkv(xin, block, cfg)
+        if spec.pos == "rotary":
+            q, k = _rope(q, pos, cfg), _rope(k, pos, cfg)
+    ctx = attend(q, k, v)
+    with jax.named_scope("attn"):
+        x = x + jnp.einsum(
+            "bsd,de->bse", ctx, block["o"].astype(cfg.dtype),
+            preferred_element_type=jnp.float32,
+        ).astype(cfg.dtype)
+    u = _rmsnorm(x, block["ln2_scale"])
+    if spec.ff == "experts":
+        y, counts = _experts(u, idx, w, live, block, cfg)
+        counters.append(counts)
+        return x + y
+    return x + _ffn(u, block, cfg)
+
+
+def _step_rows(
+    params: Params, cache: Params, token: Array, pos: Array, pad_len: Array,
+    cfg: TransformerConfig,
+):
+    """One token of every row, each row at its own physical position `pos`
+    [b] behind its own left pad `pad_len` [b]: writes the row's key and
+    value, attends over [pad_len, pos] (a window layer over its ring).
+    Returns (logits [b, vocab], cache, per-expert live-pair counts of each
+    experts layer)."""
+    b = token.shape[0]
+    x = params["tok_embed"].astype(cfg.dtype)[token][:, None, :]
+    logical = (pos - pad_len)[:, None]
+    if cfg.learned_positions:
+        x = x + params["pos_embed"].astype(cfg.dtype)[pos - pad_len][:, None, :]
+    at = jnp.arange(cfg.max_len)[None, :]
+    kmask = ((at <= pos[:, None]) & (at >= pad_len[:, None]))[:, None, None, :]
+    if cfg.window is not None:
+        # ring row j holds the newest physical position <= pos that is j
+        # modulo W: before the pad (or before the sequence) it is no key
+        ring = jnp.arange(cfg.window)[None, :]
+        held = pos[:, None] - (pos[:, None] - ring) % cfg.window
+        wmask = (held >= pad_len[:, None])[:, None, None, :]
+    rows = jnp.arange(b)
+    live = (pos > 0)[:, None]  # a free slot's vectors are zeros
+    counters: list[Array] = []
+    for (kname, vname, li), spec, block in zip(
+        _cache_rows(cfg), cfg.layer_specs, params["blocks"]
+    ):
+        def attend(q, k, v, kname=kname, vname=vname, li=li, spec=spec):
+            at_row = pos if spec.window is None else pos % cfg.window
+            with jax.named_scope("cache_write"):
+                cache[kname] = cache[kname].at[li, rows, at_row].set(k[:, 0])
+                cache[vname] = cache[vname].at[li, rows, at_row].set(v[:, 0])
+            kind = "attn_global" if spec.window is None else "attn_window"
+            with jax.named_scope("attn"), jax.named_scope(kind):
+                return _attend(
+                    q, cache[kname][li], cache[vname][li],
+                    kmask if spec.window is None else wmask, cfg,
+                )
+
+        x = _layer(x, block, spec, cfg, logical, live, attend, counters)
+    hline = _rmsnorm(x, params["ln_f_scale"])
+    return _lm_logits(hline, params, cfg)[:, 0, :], cache, counters
 
 
 def decode_step(
@@ -447,61 +842,71 @@ def decode_step(
     pad cache slots never enter attention — a row's tokens match what an
     unpadded single-prompt run would produce."""
     b = token.shape[0]
-    h, dh = cfg.n_heads, cfg.head_dim
-    x = params["tok_embed"].astype(cfg.dtype)[token][:, None, :]  # [b,1,d]
-    mask_len = cfg.max_len
-    if pad_len is None:
-        x = x + jax.lax.dynamic_slice_in_dim(
-            params["pos_embed"].astype(cfg.dtype), pos, 1, axis=0
-        )[None]
-        kmask = (jnp.arange(mask_len) <= pos)[None, None, None, :]
+    pad = jnp.zeros((b,), jnp.int32) if pad_len is None else pad_len
+    lg, cache, _ = _step_rows(
+        params, cache, token, jnp.full((b,), pos, jnp.int32), pad, cfg
+    )
+    return lg, cache
+
+
+def _prefill(
+    params: Params, prompt_ids: Array, cache: Params, cfg: TransformerConfig,
+    prompt_mask: Array | None,
+):
+    """`prefill`, and the per-expert live-pair counts of each experts
+    layer beside its results."""
+    b, p = prompt_ids.shape
+    x = params["tok_embed"].astype(cfg.dtype)[prompt_ids]
+    if prompt_mask is None:
+        valid = jnp.ones((b, p), jnp.int32)
+        pos_idx = jnp.broadcast_to(jnp.arange(p)[None, :], (b, p))
+        if cfg.learned_positions:
+            x = x + params["pos_embed"].astype(cfg.dtype)[None, :p, :]
     else:
-        x = x + params["pos_embed"].astype(cfg.dtype)[pos - pad_len][:, None, :]
-        kmask = (
-            (jnp.arange(mask_len)[None, :] <= pos)
-            & (jnp.arange(mask_len)[None, :] >= pad_len[:, None])
-        )[:, None, None, :]
-    for li, block in enumerate(params["blocks"]):
-        xin = _rmsnorm(x, block["ln1_scale"])
-        with jax.named_scope("attn"):
-            qkv = jnp.einsum(
-                "bsd,de->bse", xin, block["qkv"].astype(cfg.dtype),
-                preferred_element_type=jnp.float32,
-            ).astype(cfg.dtype)
-            q, k, v = jnp.split(qkv, 3, axis=-1)
-            q = q.reshape(b, 1, h, dh)
-            k = k.reshape(b, 1, h, dh)
-            v = v.reshape(b, 1, h, dh)
-        with jax.named_scope("cache_write"):
-            cache["k"] = jax.lax.dynamic_update_slice(
-                cache["k"], k[None], (li, 0, pos, 0, 0)
-            )
-            cache["v"] = jax.lax.dynamic_update_slice(
-                cache["v"], v[None], (li, 0, pos, 0, 0)
-            )
-        with jax.named_scope("attn"):
-            keys, vals = cache["k"][li], cache["v"][li]  # [b, S, h, dh]
-            scores = jnp.einsum(
-                "bqhd,bkhd->bhqk", q, keys, preferred_element_type=jnp.float32
-            ) / math.sqrt(dh)
-            scores = jnp.where(kmask, scores, -1e30)
-            probs = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)
-            ctx = jnp.einsum(
-                "bhqk,bkhd->bqhd", probs, vals, preferred_element_type=jnp.float32
-            ).astype(cfg.dtype).reshape(b, 1, cfg.d_model)
-            attn_out = jnp.einsum(
-                "bsd,de->bse", ctx, block["o"].astype(cfg.dtype),
-                preferred_element_type=jnp.float32,
-            ).astype(cfg.dtype)
-            x = x + attn_out
-        x = x + _ffn(_rmsnorm(x, block["ln2_scale"]), block, cfg)
-    hline = _rmsnorm(x, params["ln_f_scale"])
-    with jax.named_scope("logits"):
-        lg = jnp.einsum(
-            "bsd,vd->bsv", hline, params["tok_embed"].astype(cfg.dtype),
-            preferred_element_type=jnp.float32,
-        )
-    return lg[:, 0, :], cache
+        valid = prompt_mask
+        pos_idx = jnp.clip(jnp.cumsum(prompt_mask, axis=1) - 1, 0, None)
+        if cfg.learned_positions:
+            x = x + params["pos_embed"].astype(cfg.dtype)[pos_idx]
+    window = cfg.window
+    blocked = 4 * cfg.n_heads * p * p > _SCORE_BYTES_MAX
+    if not blocked:
+        mask = _build_mask(valid, causal=True)
+        if window is not None and window < p:
+            at = jnp.arange(p)
+            wmask = mask & (at[None, :] > at[:, None] - window)[None, None]
+        else:
+            wmask = mask
+    live = valid.astype(bool)
+    counters: list[Array] = []
+    for (kname, vname, li), spec, block in zip(
+        _cache_rows(cfg), cfg.layer_specs, params["blocks"]
+    ):
+        def attend(q, k, v, kname=kname, vname=vname, li=li, spec=spec):
+            kept_k, kept_v = k, v
+            if spec.window is not None and p > window:
+                # a prompt longer than the window leaves its last W keys,
+                # each in the ring's row of its physical position
+                turn = (p - window) % window
+                kept_k = jnp.roll(k[:, p - window:], turn, axis=1)
+                kept_v = jnp.roll(v[:, p - window:], turn, axis=1)
+            with jax.named_scope("cache_write"):
+                cache[kname] = jax.lax.dynamic_update_slice(
+                    cache[kname], kept_k[None], (li, 0, 0, 0, 0)
+                )
+                cache[vname] = jax.lax.dynamic_update_slice(
+                    cache[vname], kept_v[None], (li, 0, 0, 0, 0)
+                )
+            kind = "attn_global" if spec.window is None else "attn_window"
+            with jax.named_scope("attn"), jax.named_scope(kind):
+                if blocked:
+                    return _attend_blocks(q, k, v, live, spec.window, cfg)
+                return _attend(
+                    q, k, v, mask if spec.window is None else wmask, cfg
+                )
+
+        x = _layer(x, block, spec, cfg, pos_idx, live, attend, counters)
+    hlast = _rmsnorm(x[:, -1:, :], params["ln_f_scale"])
+    return _lm_logits(hlast, params, cfg)[:, 0, :], cache, counters
 
 
 def prefill(
@@ -522,56 +927,8 @@ def prefill(
     cumsum and pad keys are masked out, so a padded row's outputs equal
     an unpadded single-prompt run.
     """
-    b, p = prompt_ids.shape
-    h, dh = cfg.n_heads, cfg.head_dim
-    x = params["tok_embed"].astype(cfg.dtype)[prompt_ids]
-    if prompt_mask is None:
-        x = x + params["pos_embed"].astype(cfg.dtype)[None, :p, :]
-        mask = _build_mask(jnp.ones((b, p), jnp.int32), causal=True)
-    else:
-        pos_idx = jnp.clip(jnp.cumsum(prompt_mask, axis=1) - 1, 0, None)
-        x = x + params["pos_embed"].astype(cfg.dtype)[pos_idx]
-        mask = _build_mask(prompt_mask, causal=True)
-    for li, block in enumerate(params["blocks"]):
-        xin = _rmsnorm(x, block["ln1_scale"])
-        with jax.named_scope("attn"):
-            qkv = jnp.einsum(
-                "bsd,de->bse", xin, block["qkv"].astype(cfg.dtype),
-                preferred_element_type=jnp.float32,
-            ).astype(cfg.dtype)
-            q, k, v = jnp.split(qkv, 3, axis=-1)
-            q = q.reshape(b, p, h, dh)
-            k = k.reshape(b, p, h, dh)
-            v = v.reshape(b, p, h, dh)
-        with jax.named_scope("cache_write"):
-            cache["k"] = jax.lax.dynamic_update_slice(
-                cache["k"], k[None], (li, 0, 0, 0, 0)
-            )
-            cache["v"] = jax.lax.dynamic_update_slice(
-                cache["v"], v[None], (li, 0, 0, 0, 0)
-            )
-        with jax.named_scope("attn"):
-            scores = jnp.einsum(
-                "bqhd,bkhd->bhqk", q, k, preferred_element_type=jnp.float32
-            ) / math.sqrt(dh)
-            scores = jnp.where(mask, scores, -1e30)
-            probs = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)
-            ctx = jnp.einsum(
-                "bhqk,bkhd->bqhd", probs, v, preferred_element_type=jnp.float32
-            ).astype(cfg.dtype).reshape(b, p, cfg.d_model)
-            attn_out = jnp.einsum(
-                "bsd,de->bse", ctx, block["o"].astype(cfg.dtype),
-                preferred_element_type=jnp.float32,
-            ).astype(cfg.dtype)
-            x = x + attn_out
-        x = x + _ffn(_rmsnorm(x, block["ln2_scale"]), block, cfg)
-    hlast = _rmsnorm(x[:, -1:, :], params["ln_f_scale"])
-    with jax.named_scope("logits"):
-        lg = jnp.einsum(
-            "bsd,vd->bsv", hlast, params["tok_embed"].astype(cfg.dtype),
-            preferred_element_type=jnp.float32,
-        )
-    return lg[:, 0, :], cache
+    lg, cache, _ = _prefill(params, prompt_ids, cache, cfg, prompt_mask)
+    return lg, cache
 
 
 def generate(
@@ -651,6 +1008,12 @@ def generate_serving(
     return jnp.concatenate([prompt_ids, toks.T], axis=1), cache
 
 
+def _with_counters(tokens: Array, counters: list) -> Array:
+    """The tokens a program returns and, behind them, its counters: they
+    ride to the host in the one array the loop reads anyway."""
+    return jnp.concatenate([tokens, jnp.stack(counters).astype(jnp.int32)])
+
+
 def prefill_into_slot(
     params: Params,
     prompt_ids: Array,  # [1, P] LEFT-padded (pad_left_rows convention)
@@ -666,17 +1029,25 @@ def prefill_into_slot(
     slot of the bucket — a request joining an in-flight batch costs zero
     new XLA compilations once its prompt bucket is warm. Returns (first
     decoded token [1] int32, cache); argmax decoding, matching the
-    temperature-0 `generate_serving` path bit for bit per row."""
-    lg, mini = prefill(params, prompt_ids, init_kv_cache(cfg, 1), cfg, prompt_mask)
+    temperature-0 `generate_serving` path bit for bit per row. A decoder
+    with experts layers appends PREFILL_COUNTERS to the token: the
+    token-expert pairs of the real tokens summed over its layers, and the
+    fullest expert's pairs summed over its layers."""
+    lg, mini, counts = _prefill(
+        params, prompt_ids, init_kv_cache(cfg, 1), cfg, prompt_mask
+    )
     with jax.named_scope("cache_write"):
-        cache["k"] = jax.lax.dynamic_update_slice(
-            cache["k"], mini["k"], (0, slot, 0, 0, 0)
-        )
-        cache["v"] = jax.lax.dynamic_update_slice(
-            cache["v"], mini["v"], (0, slot, 0, 0, 0)
-        )
+        for name in mini:
+            cache[name] = jax.lax.dynamic_update_slice(
+                cache[name], mini[name], (0, slot, 0, 0, 0)
+            )
     with jax.named_scope("logits"):
-        return jnp.argmax(lg, -1).astype(jnp.int32), cache
+        first = jnp.argmax(lg, -1).astype(jnp.int32)
+    if not counts:
+        return first, cache
+    return _with_counters(first, [
+        sum(c.sum() for c in counts), sum(c.max() for c in counts),
+    ]), cache
 
 
 def decode_step_slots(
@@ -697,54 +1068,19 @@ def decode_step_slots(
     has decoded so far. Rows never read each other's slots, so a freshly
     prefilled request is correct from its first step even though its
     neighbours are mid-generation. Returns (next token [b] int32, cache);
-    argmax decoding, bit-identical per row to the wave-aligned path."""
-    b = token.shape[0]
-    h, dh = cfg.n_heads, cfg.head_dim
-    x = params["tok_embed"].astype(cfg.dtype)[token][:, None, :]
-    x = x + params["pos_embed"].astype(cfg.dtype)[pos - pad_len][:, None, :]
-    mask_len = cfg.max_len
-    kmask = (
-        (jnp.arange(mask_len)[None, :] <= pos[:, None])
-        & (jnp.arange(mask_len)[None, :] >= pad_len[:, None])
-    )[:, None, None, :]
-    rows = jnp.arange(b)
-    for li, block in enumerate(params["blocks"]):
-        xin = _rmsnorm(x, block["ln1_scale"])
-        with jax.named_scope("attn"):
-            qkv = jnp.einsum(
-                "bsd,de->bse", xin, block["qkv"].astype(cfg.dtype),
-                preferred_element_type=jnp.float32,
-            ).astype(cfg.dtype)
-            q, k, v = jnp.split(qkv, 3, axis=-1)
-            q = q.reshape(b, 1, h, dh)
-            k = k.reshape(b, h, dh)
-            v = v.reshape(b, h, dh)
-        with jax.named_scope("cache_write"):
-            cache["k"] = cache["k"].at[li, rows, pos].set(k)
-            cache["v"] = cache["v"].at[li, rows, pos].set(v)
-        with jax.named_scope("attn"):
-            keys, vals = cache["k"][li], cache["v"][li]  # [b, S, h, dh]
-            scores = jnp.einsum(
-                "bqhd,bkhd->bhqk", q, keys, preferred_element_type=jnp.float32
-            ) / math.sqrt(dh)
-            scores = jnp.where(kmask, scores, -1e30)
-            probs = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)
-            ctx = jnp.einsum(
-                "bhqk,bkhd->bqhd", probs, vals, preferred_element_type=jnp.float32
-            ).astype(cfg.dtype).reshape(b, 1, cfg.d_model)
-            attn_out = jnp.einsum(
-                "bsd,de->bse", ctx, block["o"].astype(cfg.dtype),
-                preferred_element_type=jnp.float32,
-            ).astype(cfg.dtype)
-            x = x + attn_out
-        x = x + _ffn(_rmsnorm(x, block["ln2_scale"]), block, cfg)
-    hline = _rmsnorm(x, params["ln_f_scale"])
+    argmax decoding, bit-identical per row to the wave-aligned path. A
+    decoder with experts layers appends STEP_COUNTERS to the tokens: the
+    distinct experts that the occupied rows (``pos`` > 0) hit, summed over
+    its layers, and the layers so counted."""
+    lg, cache, counts = _step_rows(params, cache, token, pos, pad_len, cfg)
     with jax.named_scope("logits"):
-        lg = jnp.einsum(
-            "bsd,vd->bsv", hline, params["tok_embed"].astype(cfg.dtype),
-            preferred_element_type=jnp.float32,
-        )
-        return jnp.argmax(lg[:, 0, :], -1).astype(jnp.int32), cache
+        nxt = jnp.argmax(lg, -1).astype(jnp.int32)
+    if not counts:
+        return nxt, cache
+    return _with_counters(nxt, [
+        sum((c > 0).sum() for c in counts),
+        len(counts) * jnp.any(pos > 0).astype(jnp.int32),
+    ]), cache
 
 
 class TransformerLM:

@@ -20,7 +20,16 @@ replaces that with a **slot scheduler**:
   single jitted program with per-row positions
   (`models/transformer.decode_step_slots`);
 * a request that finishes releases its slot at the step boundary, and
-  the same boundary re-fills the row from the admission queue.
+  the same boundary re-fills the row from the admission queue;
+* a boundary runs **one prefill at most**: with a queue behind several
+  free slots the loop goes prefill, step, prefill, step. A decoding
+  answer then waits for one prefill between two of its tokens (with
+  prompts of 10k tokens a prefill is 0.4 s, and eight in a row held
+  every answer for 3.4 s), and slots filled from one queue finish a step
+  apart and not in one burst that frees the whole pool at once. A burst
+  of k requests into an idle pool pays k - 1 steps more for its last
+  answer; a standing backlog pays nothing (a step with a free row costs
+  what a full one costs, and the free row is filled one step later).
 
 Both programs ride the device plane: the compile ledger proves a request
 joining mid-generation costs **zero new XLA compilations** (the step
@@ -205,6 +214,9 @@ class ContinuousBatcher:
             raise ValueError("n_steps must be >= 1")
         self.params = params
         self.cfg = cfg
+        # the decoder: the cache its configuration builds, its two programs
+        # and the names of the counters they send back
+        self._model = transformer
         self.tokenizer = tokenizer
         self.n_steps = n_steps
         # mesh-spanning pool: n_slots PER SHARD, the KV cache's slot axis
@@ -252,6 +264,11 @@ class ContinuousBatcher:
             # real tokens of the admitted prompts, and their widths
             "prompt_tokens": 0, "padded_tokens": 0,
             "preload_s": 0.0,  # the step program's load at construction
+            # what an experts decoder's programs count on the device and
+            # send back behind their tokens (0 for any other block)
+            **dict.fromkeys(
+                self._model.PREFILL_COUNTERS + self._model.STEP_COUNTERS, 0
+            ),
         }
         self.pool.scheduler_stats = self.stats
         self._loop_mark = 0.0  # perf_counter at the last `loop_s` tick
@@ -310,17 +327,18 @@ class ContinuousBatcher:
     def _init_cache(self):
         """Fresh multi-slot KV cache; with a mesh, the slot axis is
         sharded over `data` so the pool's rows live across every chip."""
-        from pathway_tpu.models import transformer
-
-        cache = transformer.init_kv_cache(self.cfg, self.n_slots)
+        cache = self._model.init_kv_cache(self.cfg, self.n_slots)
         if self.mesh is not None:
             import jax
             from jax.sharding import NamedSharding, PartitionSpec as P
 
-            spec = NamedSharding(
-                self.mesh, P(None, "data", None, None, None)
+            # whatever leaves the block's cache has: [layers, slots, ...]
+            cache = jax.tree.map(
+                lambda v: jax.device_put(v, NamedSharding(
+                    self.mesh, P(None, "data", *[None] * (v.ndim - 2))
+                )),
+                cache,
             )
-            cache = {k: jax.device_put(v, spec) for k, v in cache.items()}
         return cache
 
     def _step_vectors(self, tok, pos, pad):
@@ -351,6 +369,12 @@ class ContinuousBatcher:
         now = time.thread_time()
         self.stats["host_cpu_s"] += now - self._cpu_mark
         self._cpu_mark = now
+
+    def _count(self, names: tuple, tail: Any) -> None:
+        """Add the device counters a program sent back behind its tokens
+        (none, where the block has no experts) into `stats`."""
+        for name, value in zip(names, tail):
+            self.stats[name] += int(value)
 
     def _preload(self, cache: Any) -> Any:
         """Dispatch the step once with no slot occupied and wait for it:
@@ -392,18 +416,18 @@ class ContinuousBatcher:
             self._cpu_mark = time.thread_time()
             serving = True
             while True:
-                # ---- step boundary: re-fill freed slots from the queue
-                while True:
-                    with self._phase("admit_prep_s"), self._lock:
-                        if not self._queue:
-                            break
-                        slot = self.pool.acquire()
-                        if slot is None:
-                            break  # batch full; next boundary re-checks
+                # ---- step boundary: re-fill ONE freed slot from the
+                # queue. A decoding answer waits for one prefill between
+                # two of its tokens, never for a queue of them, and slots
+                # filled from a queue finish a step apart, not together
+                with self._phase("admit_prep_s"), self._lock:
+                    slot = self.pool.acquire() if self._queue else None
+                    if slot is not None:
                         req = self._queue.popleft()
                         self._active[slot] = req
                         req.slot = slot
                         req.t_admit = time.monotonic()
+                if slot is not None:
                     cache = self._admit(req, slot, cache)
                     self._loop_tick()
                 with self._phase("step_prep_s"):
@@ -436,6 +460,7 @@ class ContinuousBatcher:
                     nxt = np.asarray(nxt)
                 with self._phase("account_s"):
                     self.stats["decode_steps"] += 1
+                    self._count(self._model.STEP_COUNTERS, nxt[self.n_slots:])
                     if _obs.PLANE is not None:
                         _obs.PLANE.metrics.counter(
                             "pathway_serving_decode_steps_total",
@@ -510,6 +535,7 @@ class ContinuousBatcher:
             req.token = int(first[0])
             req.tokens.append(req.token)
             self.stats["prefills"] += 1
+            self._count(self._model.PREFILL_COUNTERS, first[1:])
             self.stats["prompt_tokens"] += req.length
             self.stats["padded_tokens"] += req.width
             self.stats["queue_wait_s"] += req.t_admit - req.t_submit

@@ -91,6 +91,8 @@ def test_stats_hold_every_key_from_construction():
     counts = {
         "submitted", "completed", "decode_steps", "prefills", "max_queue",
         "prompt_tokens", "padded_tokens",
+        # an experts decoder's device counters (0 for this block)
+        "routed_pairs", "expert_load_max", "experts_touched", "moe_layers_run",
     }
     clocks = (
         set(PHASES) | {"loop_s", "host_cpu_s", "preload_s"}
@@ -413,7 +415,7 @@ def _listed(names):
     entries = {m["name"]: m for m in bench["per_layer"]}
     for name in names:
         m = entries[f"{name}.tput"]
-        assert m["workloads"] == ["rag-cerebras-6b7.backlog"]
+        assert "rag-cerebras-6b7.backlog" in m["workloads"]
         assert m["moves"] == "answers_per_s"
         assert m["source"] == "program_counter" and m["better"] == "lower"
         assert (BENCH / "layer_metrics" / f"{name}.py").is_file()

@@ -412,3 +412,58 @@ def test_failed_step_load_is_logged_and_fails_the_first_request(monkeypatch):
     assert cb.submit("a b c").result(timeout=60)
     cb.drain()
     assert cb._step.compile_counts == {2: 1}
+
+
+# --------------------------------------------- one prefill a step boundary
+
+
+@pytest.mark.parametrize("slots,requests", [(2, 2), (3, 3), (2, 5), (4, 3)])
+def test_a_boundary_runs_one_prefill_and_slots_finish_a_step_apart(
+    slots, requests
+):
+    """A queue behind several free slots is admitted prefill, step,
+    prefill, step: no decoding answer waits for two prefills in a row,
+    slots filled from one queue finish at different steps, and the tokens
+    are those of the wave-aligned path."""
+    new = 6
+    chat = _chat(
+        continuous_batching=True, decode_slots=slots, max_new_tokens=new
+    )
+    cb = chat._cb
+    cb.drain()  # the construction's own pass
+    events: list[str] = []
+    done_at: list[int] = []
+    admit, step, finish = cb._admit, cb._step, cb._finish
+
+    def admit_logged(req, slot, cache):
+        events.append("P")
+        return admit(req, slot, cache)
+
+    def step_logged(*a, **kw):
+        events.append("S")
+        return step(*a, **kw)
+
+    def finish_logged(slot, req):
+        done_at.append(events.count("S"))
+        return finish(slot, req)
+
+    cb._admit, cb._step, cb._finish = admit_logged, step_logged, finish_logged
+    prompts = [f"question number {i} of the queue" for i in range(requests)]
+    with cb._lock:
+        cb._running = True  # hold the thread back until all are queued
+    futs = [cb.submit(p) for p in prompts]
+    with cb._lock:
+        cb._start_thread()
+    got = [f.result(timeout=120) for f in futs]
+    cb.drain()
+    wa = _chat(continuous_batching=False, max_new_tokens=new)
+    assert got == wa._generate_batch(prompts)
+    seq = "".join(events)
+    assert seq.count("P") == requests and "PP" not in seq, seq
+    first = min(slots, requests)
+    assert seq.startswith("PS" * first), seq
+    # no two answers of one filling finish at the same step
+    assert len(set(done_at)) == requests, (done_at, seq)
+    if requests <= slots:
+        # the last of a burst into an idle pool pays k - 1 steps more
+        assert cb.stats["decode_steps"] == (new - 1) + (requests - 1)
