@@ -557,11 +557,10 @@ def make_train_step(cfg: TransformerConfig, learning_rate: float = 1e-3):
 # head] are rings: physical position t lives in row t mod W, so a window
 # layer's rows stop growing at W. Physical positions count the left pad
 # too; logical ones (physical less the pad) are what rotary turns by.
-
-# A prefill's float32 scores [heads, queries, keys] above this many bytes a
-# sequence are made a block of queries at a time (and a window layer's over
-# its band of keys only): a function of the shapes alone.
-_SCORE_BYTES_MAX = 1 << 30
+#
+# A prefill's attention over the whole prompt runs ops/attention.py's kernel
+# where `prefill_uses_kernel` says so, and the plain `_attend` (the decode
+# step's attention) everywhere else.
 
 # what an experts decoder's two programs append to the tokens they return,
 # in this order (ContinuousBatcher adds them into its `stats`)
@@ -634,10 +633,11 @@ def _attend(q: Array, keys: Array, vals: Array, ok: Array,
     hk = keys.shape[2]
     if hk == h:
         # the same mathematics as a group of one below, kept because the v5e
-        # compiler makes another program of that: compiled for
-        # rag-cerebras-6b7, prefill's temp goes 0.75 -> 0.62 GB at p = 1024
-        # and 2.01 -> 1.50 GB at 2016, and that cell's programs are to stay
-        # the parent's (ISSUE 30)
+        # compiler makes another text of that: with it gone the step of
+        # rag-cerebras-6b7 differs from the parent's in 16 fusions (a
+        # layer's product of cache rows and weights takes its operands in
+        # the other order), and the step is held to the parent's HLO
+        # (ISSUE 32; a prefill on the chip no longer comes this way)
         scores = jnp.einsum(
             "bqhd,bkhd->bhqk", q, keys, preferred_element_type=jnp.float32
         ) / math.sqrt(dh)
@@ -657,42 +657,6 @@ def _attend(q: Array, keys: Array, vals: Array, ok: Array,
             "bkgqs,bskd->bqkgd", probs, vals, preferred_element_type=jnp.float32
         )
     return ctx.astype(cfg.dtype).reshape(b, nq, h * dh)
-
-
-def _attend_blocks(q: Array, k: Array, v: Array, valid: Array,
-                   window: int | None, cfg: TransformerConfig) -> Array:
-    """Causal attention of a whole prompt a block of queries at a time, so
-    that no [heads, p, p] array exists: `valid` [b, p] marks the real keys.
-    A global layer's block reads every key; a window layer's the band of
-    window + block keys that ends with it, so its work is p x W."""
-    b, p, h, dh = q.shape
-    blk = 1 << max(3, (_SCORE_BYTES_MAX // (4 * h * p)).bit_length() - 1)
-    n = -(-p // blk)
-    pad = n * blk - p
-    if pad:  # queries and keys past the end: never valid, cut off below
-        q, k, v = (jnp.pad(a, ((0, 0), (0, pad), (0, 0), (0, 0))) for a in (q, k, v))
-        valid = jnp.pad(valid, ((0, 0), (0, pad)))
-    banded = window is not None and window + blk < n * blk
-    span = window + blk if banded else n * blk
-
-    def one(i_qb):
-        i, qb = i_qb
-        q0 = i * blk
-        start = jnp.clip(q0 - window, 0, n * blk - span) if banded else 0
-        kb = jax.lax.dynamic_slice_in_dim(k, start, span, axis=1)
-        vb = jax.lax.dynamic_slice_in_dim(v, start, span, axis=1)
-        qp = q0 + jnp.arange(blk)[:, None]
-        kp = start + jnp.arange(span)[None, :]
-        ok = (kp <= qp) & jax.lax.dynamic_slice_in_dim(
-            valid, start, span, axis=1
-        )[:, None, None, :]
-        if window is not None:
-            ok = ok & (kp > qp - window)
-        return _attend(qb, kb, vb, ok, cfg)
-
-    qblocks = jnp.moveaxis(q.reshape(b, n, blk, h, dh), 1, 0)
-    ctx = jax.lax.map(one, (jnp.arange(n), qblocks))  # [n, b, blk, h * dh]
-    return jnp.moveaxis(ctx, 0, 1).reshape(b, n * blk, h * dh)[:, :p]
 
 
 def _route(x: Array, block: Params, cfg: TransformerConfig):
@@ -849,6 +813,23 @@ def decode_step(
     return lg, cache
 
 
+def prefill_uses_kernel(cfg: TransformerConfig, width: int) -> bool:
+    """Whether a prefill of prompts `width` wide runs ops/attention.py
+    `prefill_attention` (scores kept in VMEM) and not the plain `_attend`:
+    on a TPU, with heads of a multiple of 128 lanes and a width of 128 at
+    least (every rung of `BucketPolicy.seq_bucket` from there up; the
+    kernel pads the cap's rung inside). Read from the shapes and from
+    where the process runs; nothing sets it. Like the encoder's kernel it
+    has no partitioning rule: `fused_attention` off (`TransformerLM.shard`)
+    keeps tensor-parallel parameters on `_attend`."""
+    return (
+        cfg.fused_attention
+        and jax.default_backend() == "tpu"
+        and cfg.head_dim % 128 == 0
+        and width >= 128
+    )
+
+
 def _prefill(
     params: Params, prompt_ids: Array, cache: Params, cfg: TransformerConfig,
     prompt_mask: Array | None,
@@ -868,14 +849,13 @@ def _prefill(
         if cfg.learned_positions:
             x = x + params["pos_embed"].astype(cfg.dtype)[pos_idx]
     window = cfg.window
-    blocked = 4 * cfg.n_heads * p * p > _SCORE_BYTES_MAX
-    if not blocked:
-        mask = _build_mask(valid, causal=True)
-        if window is not None and window < p:
+    banded = window is not None and window < p  # else a window layer sees all
+    kernel = prefill_uses_kernel(cfg, p)
+    if not kernel:
+        mask = wmask = _build_mask(valid, causal=True)
+        if banded:
             at = jnp.arange(p)
             wmask = mask & (at[None, :] > at[:, None] - window)[None, None]
-        else:
-            wmask = mask
     live = valid.astype(bool)
     counters: list[Array] = []
     for (kname, vname, li), spec, block in zip(
@@ -898,8 +878,15 @@ def _prefill(
                 )
             kind = "attn_global" if spec.window is None else "attn_window"
             with jax.named_scope("attn"), jax.named_scope(kind):
-                if blocked:
-                    return _attend_blocks(q, k, v, live, spec.window, cfg)
+                if kernel:
+                    # imported where it is traced, as `_attention` does:
+                    # Pallas loads when a program first needs it
+                    from pathway_tpu.ops.attention import prefill_attention
+
+                    return prefill_attention(
+                        q, k, v, valid,
+                        window if banded and spec.window is not None else None,
+                    )
                 return _attend(
                     q, k, v, mask if spec.window is None else wmask, cfg
                 )

@@ -12,6 +12,13 @@ split, scores, masked softmax, and value contraction entirely in VMEM,
 and writes only ctx [b, s, d] back to HBM. Traffic per call is the
 read of qkv and the write of ctx — nothing else.
 
+`prefill_attention` is the decoder's: causal attention of a whole prompt
+to itself for every layer kind of `LayerSpec` (every earlier position or
+a window; any grouping of query heads over key heads), tiled with a
+streaming softmax so that no score reaches HBM, and skipping by whole
+tiles what the causal order, the window and the padding rule out.
+models/transformer.py `prefill_uses_kernel` says which prefills run it.
+
 Reference parity: replaces the torch SDPA used by the reference's local
 embedding models (`/root/reference/python/pathway/xpacks/llm/embedders.py:270`
 runs SentenceTransformer → torch attention); this is the TPU-native
@@ -27,6 +34,7 @@ import jax
 import jax.numpy as jnp
 
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _attn_kernel(qkv_ref, bias_ref, out_ref, *, n_heads: int, head_dim: int,
@@ -129,6 +137,235 @@ def reference_attention(
         "bhqk,bkhd->bqhd", probs, v, preferred_element_type=jnp.float32
     ).astype(qkv.dtype)
     return ctx.reshape(b, s, d)
+
+
+# ------------------------------------------------ causal prefill attention
+#
+# Written in XLA, a prefill's float32 scores [heads, p, p] cross HBM several
+# times: a third to a half of a prefill's device time on v5e (PERF.md
+# section 5). The kernel below never writes them.
+
+_MASKED = -1e30  # the score of a pair that is not allowed, as `_attend`'s
+_PREFILL_TILE_MAX = 896  # no tile is wider
+_PREFILL_VMEM = 16 << 20  # what `prefill_tile`'s sum may reach
+
+
+def _prefill_vmem(t: int, dh: int, group: int, itemsize: int) -> int:
+    """VMEM of one grid step at tile t, in bytes (`prefill_tile`)."""
+    qo = 2 * 2 * t * group * dh * itemsize  # query and context tiles, double-buffered
+    kv = 2 * 2 * t * dh * itemsize  # key and value tiles, double-buffered
+    acc = t * group * dh * 4  # float32 accumulator
+    ml = 2 * group * t * 128 * 4  # running maximum and sum, lane-replicated
+    live = 3 * t * t * 4  # one head's scores, their exponentials, the mask
+    return qo + kv + acc + ml + live
+
+
+def prefill_tile(p: int, dh: int, group: int, itemsize: int = 2) -> int:
+    """The tile of `prefill_attention`, queries and keys alike, for a width
+    p that 128 divides: the largest multiple of 128 that divides p, is at
+    most 896 and keeps the VMEM sum of a grid step (`_prefill_vmem`) under
+    16 MB. A function of the shapes alone: 640 at rag-cerebras-6b7's
+    (p 1280, a group of 1, bf16: 7.2 MB), 512 at
+    rag-smallthinker-21b-a3b's (p 10,240, 7 query heads a key head:
+    12.8 MB; 640 would be 17.0). 128 always divides, and is the tile of
+    a group so large that no tile fits: the kernel's VMEM limit follows
+    the sum.
+    A larger tile amortises the grid step's fixed cost (about 0.35 us) and
+    the key tile's fetch; a smaller one wastes less on the diagonal and
+    the band's edges. On the chip (PERF.md, PR 32) a tile's time is its
+    elements' whatever its shape: the softmax made a few rows at a time
+    to stay in registers was 2-10 times slower than the whole tile's."""
+    fits = [
+        t for t in range(128, min(p, _PREFILL_TILE_MAX) + 1, 128)
+        if p % t == 0 and _prefill_vmem(t, dh, group, itemsize) <= _PREFILL_VMEM
+    ]
+    return fits[-1] if fits else 128
+
+
+def _first_key_tile(qi, first, t: int, window: int | None):
+    """The first key tile that query tile `qi` needs: past the tiles that
+    hold no valid key before the first that does and, in a window layer,
+    those wholly left of the band of its first row."""
+    if window is None:
+        return first
+    return jnp.maximum(first, jax.lax.div(jnp.maximum(qi * t - window + 1, 0), t))
+
+
+def _prefill_kernel(first_ref, held_ref, q_ref, k_ref, v_ref, valid_ref, o_ref,
+                    m_ref, l_ref, acc_ref,
+                    *, t: int, group: int, dh: int, window: int | None,
+                    scale: float):
+    """One grid step (row, key head, query tile, k-th needed key tile): the
+    group's query tile [t, group * dh] against one key tile [t, dh]."""
+    bi, qi, kk = pl.program_id(0), pl.program_id(2), pl.program_id(3)
+    kt = _first_key_tile(qi, first_ref[bi], t, window) + kk  # the key tile
+    held = held_ref[bi * pl.num_programs(2) + jnp.minimum(kt, qi)]  # its valid keys
+    q0, k0 = qi * t, kt * t
+
+    @pl.when(kk == 0)
+    def _start():
+        m_ref[...] = jnp.full(m_ref.shape, _MASKED, jnp.float32)
+        l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+    def fold(ok):
+        k, v = k_ref[0], v_ref[0]
+        for g in range(group):
+            lanes = slice(g * dh, (g + 1) * dh)
+            s = jax.lax.dot_general(
+                q_ref[0, :, lanes], k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ) * scale  # [t, t]
+            if ok is not None:
+                s = jnp.where(ok, s, _MASKED)
+            m_prev = m_ref[g]  # [t, 128], every lane the same
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            e = jnp.exp(s - m_new[:, :1])
+            l_ref[g] = alpha * l_ref[g] + jnp.sum(e, axis=1, keepdims=True)
+            m_ref[g] = m_new
+            acc_ref[:, lanes] = acc_ref[:, lanes] * alpha[:, :1] + jnp.dot(
+                e.astype(v.dtype), v, preferred_element_type=jnp.float32
+            )
+
+    # a tile on the diagonal, on the band's edge or with a key that is not
+    # valid takes an element mask; an inner tile takes none
+    edge = (kt == qi) | (held < t)
+    if window is not None:
+        edge = edge | (k0 <= q0 + t - 1 - window)
+    # past the diagonal the clamped tile is not run again; a tile with no
+    # valid key is not run at all
+    needed = (kt <= qi) & (held > 0)
+
+    @pl.when(needed & edge)
+    def _edge():
+        qpos = q0 + jax.lax.broadcasted_iota(jnp.int32, (t, t), 0)
+        kpos = k0 + jax.lax.broadcasted_iota(jnp.int32, (t, t), 1)
+        ok = (kpos <= qpos) & (valid_ref[0] != 0)
+        if window is not None:
+            ok = ok & (kpos > qpos - window)
+        fold(ok)
+
+    @pl.when(needed & jnp.logical_not(edge))
+    def _inner():
+        fold(None)
+
+    @pl.when(kk == pl.num_programs(3) - 1)
+    def _finish():
+        for g in range(group):
+            lanes = slice(g * dh, (g + 1) * dh)
+            total = l_ref[g][:, :1]
+            # a row of a query tile that ran no key tile (all of it
+            # padding) has summed nothing: it returns zeros
+            o_ref[0, :, lanes] = (
+                acc_ref[:, lanes] / jnp.where(total == 0.0, 1.0, total)
+            ).astype(o_ref.dtype)
+
+
+# jitted so that the layers of one program share one trace of the kernel
+# and one lowering to Mosaic for each window they have: traced and lowered
+# a layer at a time, the kernel adds seconds to every load of a prefill
+# program, also one fetched from the persistent compile cache
+@functools.partial(jax.jit, static_argnames=("window", "interpret"))
+def prefill_attention(
+    q: jax.Array,  # [b, p, heads, dh]
+    k: jax.Array,  # [b, p, kv heads, dh]
+    v: jax.Array,  # [b, p, kv heads, dh]
+    valid: jax.Array,  # [b, p] 1/0: the keys that are real
+    window: int | None = None,
+    *,
+    interpret: bool = False,
+) -> jax.Array:
+    """Causal attention of whole prompts to themselves, the scores kept in
+    VMEM: query i of a row attends the valid keys j <= i and, in a window
+    layer, j > i - window. Returns the context [b, p, heads * dh]. Products
+    in the inputs' dtype with float32 accumulation, softmax in float32, as
+    models/transformer.py `_attend` states them; the order of the sums is
+    another. A query with no key to attend (a row of the padding) returns
+    some finite vector.
+
+    The inputs are read as `_qkv` leaves them: a block of dh lanes of the
+    flattened head axis is one head, so the grid's key-head index picks the
+    key head and the `heads / kv heads` query heads that share it, which
+    read one key tile. dh must be a multiple of 128 (a lane tile). A width
+    that is no multiple of 128 (of the prompt-length ladder only the cap
+    `max_len - n_steps` is none) is padded at the end to the next one:
+    keys never valid, queries cut off.
+
+    Grid (b, kv heads, p / t, key tiles a query tile can need), t from
+    `prefill_tile`. Key tile kk of query tile qi is tile
+    `_first_key_tile(qi) + kk`, clamped to the diagonal's: a grid step
+    past the diagonal names the tile already in VMEM, so it fetches nothing
+    and runs nothing. A window layer's last grid axis is as long as its
+    band, not as the prompt. The batcher left-pads a prompt to its rung:
+    the key tiles wholly inside that padding are neither fetched nor run."""
+    b, p0, h, dh = q.shape
+    hk = k.shape[2]
+    group = h // hk
+    if dh % 128 or h % hk:
+        raise ValueError(f"prefill_attention needs heads of a multiple of 128 "
+                         f"lanes in whole groups, got {h} x {dh} over {hk}")
+    extra = -p0 % 128
+    if extra:
+        q, k, v = (jnp.pad(a, ((0, 0), (0, extra), (0, 0), (0, 0))) for a in (q, k, v))
+        valid = jnp.pad(valid, ((0, 0), (0, extra)))
+    p = p0 + extra
+    t = prefill_tile(p, dh, group, q.dtype.itemsize)
+    n = p // t
+    n_keys = n if window is None else min(n, -(-(window - 1) // t) + 1)
+    valid = valid.astype(jnp.int32)
+    held = jnp.sum(valid.reshape(b, n, t), axis=2)  # valid keys of each key tile
+    first = jnp.argmax(held > 0, axis=1).astype(jnp.int32)
+
+    def q_block(bi, j, qi, kk, first_ref, held_ref):
+        return bi, qi, j
+
+    def key_tile(bi, qi, kk, first_ref):
+        return jnp.minimum(_first_key_tile(qi, first_ref[bi], t, window) + kk, qi)
+
+    def k_block(bi, j, qi, kk, first_ref, held_ref):
+        return bi, key_tile(bi, qi, kk, first_ref), j
+
+    def valid_block(bi, j, qi, kk, first_ref, held_ref):
+        return bi, 0, key_tile(bi, qi, kk, first_ref)
+
+    out = pl.pallas_call(
+        functools.partial(
+            _prefill_kernel, t=t, group=group, dh=dh, window=window,
+            scale=1.0 / math.sqrt(dh),
+        ),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(b, hk, n, n_keys),
+            in_specs=[
+                pl.BlockSpec((1, t, group * dh), q_block),
+                pl.BlockSpec((1, t, dh), k_block),
+                pl.BlockSpec((1, t, dh), k_block),
+                pl.BlockSpec((1, 1, t), valid_block),
+            ],
+            out_specs=pl.BlockSpec((1, t, group * dh), q_block),
+            scratch_shapes=[
+                pltpu.VMEM((group, t, 128), jnp.float32),  # running maximum
+                pltpu.VMEM((group, t, 128), jnp.float32),  # running sum
+                pltpu.VMEM((t, group * dh), jnp.float32),  # accumulator
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((b, p, h * dh), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
+            # the sum, and as much again for what the compiler adds
+            vmem_limit_bytes=2 * max(
+                _PREFILL_VMEM, _prefill_vmem(t, dh, group, q.dtype.itemsize)
+            ),
+        ),
+        name="prefill_attention",
+        interpret=interpret,
+    )(
+        first, held.reshape(b * n),
+        q.reshape(b, p, h * dh), k.reshape(b, p, hk * dh), v.reshape(b, p, hk * dh),
+        valid.reshape(b, 1, p),
+    )
+    return out[:, :p0] if extra else out
 
 
 # --------------------------------------------------------- ring attention

@@ -76,7 +76,9 @@ It counts as no decode step: its time goes to ``preload_s`` alone. A
 prefill's shape follows a prompt's length, which nothing knows before
 the prompt arrives: no prefill program is loaded ahead of its first
 prompt. ``prompt_tokens`` and ``padded_tokens`` count what the admitted
-prompts held and the widths they ran at.
+prompts held and the widths they ran at, ``kernel_prefills`` those whose
+attention kept its scores in VMEM (``transformer.prefill_uses_kernel`` of
+the width they ran at: the predicate the program itself branches on).
 
 Decoding is temperature-0 (argmax) here; sampled generation keeps the
 wave-aligned path (a per-request RNG stream inside a shared step program
@@ -263,6 +265,8 @@ class ContinuousBatcher:
             "queue_wait_s": 0.0, "first_token_s": 0.0, "residence_s": 0.0,
             # real tokens of the admitted prompts, and their widths
             "prompt_tokens": 0, "padded_tokens": 0,
+            # prefills whose attention ran ops/attention.py's kernel
+            "kernel_prefills": 0,
             "preload_s": 0.0,  # the step program's load at construction
             # what an experts decoder's programs count on the device and
             # send back behind their tokens (0 for any other block)
@@ -538,6 +542,9 @@ class ContinuousBatcher:
             self._count(self._model.PREFILL_COUNTERS, first[1:])
             self.stats["prompt_tokens"] += req.length
             self.stats["padded_tokens"] += req.width
+            self.stats["kernel_prefills"] += self._model.prefill_uses_kernel(
+                self.cfg, req.width
+            )
             self.stats["queue_wait_s"] += req.t_admit - req.t_submit
             self.stats["first_token_s"] += req.t_first - req.t_submit
             if len(req.tokens) >= self.n_steps:  # n_steps == 1

@@ -90,7 +90,7 @@ def test_stats_hold_every_key_from_construction():
     cb = _chat()._cb
     counts = {
         "submitted", "completed", "decode_steps", "prefills", "max_queue",
-        "prompt_tokens", "padded_tokens",
+        "prompt_tokens", "padded_tokens", "kernel_prefills",
         # an experts decoder's device counters (0 for this block)
         "routed_pairs", "expert_load_max", "experts_touched", "moe_layers_run",
     }
@@ -428,6 +428,29 @@ def test_benchmark_lists_the_four_metrics_for_the_backlog_cell():
     PR (PERF.md section 7)."""
     _listed(("host_per_dispatch_ms", "host_stall_pct", "queue_wait_ms",
              "outside_batcher_ms"))
+
+
+@pytest.mark.parametrize("engages", [True, False])
+def test_kernel_prefills_counts_what_the_predicate_says(monkeypatch, engages):
+    """`kernel_prefills` is the model module's own predicate of the width
+    a prompt ran at, the one `_prefill` branches on: with it patched true
+    (after the programs are traced, so that the CPU still runs them) the
+    count equals `prefills`; as it is off the TPU it stays 0."""
+    cb = _chat()._cb
+    _run(cb, ["warm up prompt"])
+    seen = []
+    if engages:
+        monkeypatch.setattr(
+            cb._model, "prefill_uses_kernel",
+            lambda cfg, width: seen.append((cfg, width)) or True,
+        )
+    before = dict(cb.stats)
+    _run(cb)
+    grown = cb.stats["prefills"] - before["prefills"]
+    assert grown == len(PROMPTS)
+    assert cb.stats["kernel_prefills"] == (grown if engages else 0)
+    if engages:
+        assert seen == [(cb.cfg, 16)] * len(PROMPTS)
 
 
 def test_benchmark_lists_prefill_pad_pct_in_the_batchers_layer():

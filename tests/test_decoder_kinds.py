@@ -23,6 +23,7 @@ import pytest
 
 from pathway_tpu.models import LayerSpec, TransformerConfig, lm_config
 from pathway_tpu.models import transformer as T
+from pathway_tpu.ops import attention as A
 
 BENCH = Path(__file__).resolve().parents[1] / "bench"
 sys.path.insert(0, str(BENCH))
@@ -41,12 +42,16 @@ KEYS = dict(
     tie_word_embeddings=False,
 )
 SIZES = FAMILY.sizes(KEYS)
+# the same decoder with heads of 128 lanes and room for a bucket of 256:
+# what the prefill's attention kernel (ops/attention.py) takes
+KERNEL_KEYS = {**KEYS, "head_dim": 128, "max_position_embeddings": 320}
+KERNEL_SIZES = FAMILY.sizes(KERNEL_KEYS)
 N_STEPS = 20  # from any of the prompts below, the ring wraps at least twice
 
 
 @functools.lru_cache(maxsize=None)
-def _params():
-    return FAMILY.make_params(SEED, SIZES)
+def _params(kernel: bool = False):
+    return FAMILY.make_params(SEED, KERNEL_SIZES if kernel else SIZES)
 
 
 def _prompt(length: int) -> list[int]:
@@ -58,7 +63,7 @@ def _served_logits(cfg, row: list[int], width: int, slot: int = 1, slots: int = 
     N_STEPS greedy steps, through a slot cache: the prompt left-padded to
     `width`, prefilled into a scratch row, scattered into `slot`, decoded
     with the neighbouring slots free. Returns (logits, the row decoded)."""
-    params = _params()
+    params = _params(kernel=cfg.head_dim == 128)
     ids = np.zeros((1, width), np.int32)
     mask = np.zeros((1, width), np.int32)
     ids[0, width - len(row):] = row
@@ -91,19 +96,38 @@ def _reference_logits(toks: list[int], n_prompt: int, sizes: dict = SIZES):
 # prompts shorter than, as long as and longer than the window, each
 # left-padded to a bucket; with 20 steps behind it the ring has wrapped
 PROMPTS = [(5, 8), (8, 8), (21, 32)]
+# and through the prefill's attention kernel: buckets of one tile and of
+# two, a prompt that fills its bucket, one whose padding is most of a tile
+KERNEL_PROMPTS = [(100, 128), (128, 128), (150, 256)]
 
 
-@pytest.mark.parametrize("blocked", [False, True], ids=["square", "blocked"])
 @pytest.mark.parametrize("length, width", PROMPTS)
-def test_slot_cache_matches_the_plain_reference_in_float32(
-    length, width, blocked, monkeypatch
-):
-    if blocked:  # a block of 8 queries at a time, the window's band of keys
-        monkeypatch.setattr(T, "_SCORE_BYTES_MAX", 1024)
+def test_slot_cache_matches_the_plain_reference_in_float32(length, width):
     cfg = FAMILY.program_config(KEYS, jnp.float32)
     got, toks = _served_logits(cfg, _prompt(length), width)
     assert width + N_STEPS > 2 * WINDOW  # the ring wrapped
     want = _reference_logits(toks, length)
+    assert np.abs(got - want).max() < 1e-4
+
+
+@pytest.mark.parametrize("length, width", KERNEL_PROMPTS)
+def test_slot_cache_through_the_prefill_kernel_matches_the_plain_reference(
+    length, width, monkeypatch
+):
+    """`_prefill` with the rule saying kernel (as on a TPU; interpreted
+    here, tiles of 128): every layer kind's attention, the ring's write
+    and the steps behind it against the family's reference, which has no
+    kernel, no cache and no ring."""
+    monkeypatch.setattr(T, "prefill_uses_kernel", lambda cfg, p: True)
+    monkeypatch.setattr(
+        A, "prefill_attention",
+        functools.partial(A.prefill_attention, interpret=True),
+    )
+    monkeypatch.setattr(A, "_PREFILL_TILE_MAX", 128)
+    cfg = FAMILY.program_config(KERNEL_KEYS, jnp.float32)
+    got, toks = _served_logits(cfg, _prompt(length), width)
+    at = range(length - 1, len(toks))
+    want = FAMILY.decoder_logits(SEED, KERNEL_SIZES, [toks], [at], 512)[0]
     assert np.abs(got - want).max() < 1e-4
 
 
