@@ -57,8 +57,8 @@ class Widths:
     kernel_rows: tuple[int, ...]
 
 
-# The largest widths the repository has (bench.py's embed and decode
-# rungs); no width and no depth is cut.
+# The largest widths the repository had when this check was written
+# (PR 21); no width and no depth is cut.
 FULL = Widths(
     encoder=dict(
         vocab_size=32768, d_model=384, n_heads=6, n_layers=6, d_ff=1536,

@@ -30,9 +30,9 @@ watermark:
 
 Out-of-order ACROSS operators, always in-order AT each operator: waves
 an operator cannot yet consume are stashed per-timestamp beside it and
-replayed the moment its frontier passes them. This is what retires the
-global BSP wave barrier (``Runtime.run_lockstep``): a straggler delays
-exactly the operators that causally consume its data.
+replayed the moment its frontier passes them. No wave waits on a global
+barrier: a straggler delays exactly the operators that causally consume
+its data.
 """
 
 from __future__ import annotations

@@ -604,8 +604,8 @@ class Runtime:
     def run_mesh(
         self, static_batches: list[tuple[int, InputNode, list[Entry]]] | None = None
     ) -> None:
-        """Multi-process frontier pump: replaces the lockstep BSP wave
-        barrier (``run_lockstep``) with asynchronous progress tracking.
+        """Multi-process frontier pump: asynchronous progress tracking
+        across the mesh, with no global wave barrier.
 
         Every process pumps its OWN sources at its own pace; exchange
         channels carry (time, batch) plus per-wire watermark
@@ -671,7 +671,6 @@ class Runtime:
         ]
         self._remote_tokens: dict[tuple[int, int], Any] = {}
         for x in xnodes:
-            x.frontier_mode = True
             for p in mesh.peers:
                 self._remote_tokens[(x.wire_id, p)] = sched.add_remote_source(
                     x, p
@@ -862,77 +861,6 @@ class Runtime:
         finally:
             mesh.frontier_inbox = False
 
-    def run_lockstep(
-        self, static_batches: list[tuple[int, InputNode, list[Entry]]] | None = None
-    ) -> None:
-        """DEPRECATED lockstep BSP pump (PATHWAY_MESH_BSP=1 fallback and
-        the measured baseline for docs/parallelism.md): every process
-        executes the same wave sequence in lockstep, so one slow worker
-        bounds the whole mesh's wave rate. Superseded by ``run_mesh``'s
-        frontier-based progress tracking. A per-round control exchange
-        gives each process the identical (any_data, all_done) view so
-        wave times and termination agree everywhere."""
-        mesh = self.mesh
-        assert mesh is not None
-        for c in self.connectors:
-            c.start()
-        statics = sorted(static_batches or [], key=lambda b: b[0])
-        # checkpoint cadence must be a deterministic function of the
-        # SHARED round count — per-process wall clocks would snapshot at
-        # different waves, leaving exchange rounds (and therefore resume)
-        # mutually inconsistent
-        ckpt_every = 1
-        if self.checkpointer is not None:
-            interval = self.checkpointer.config.snapshot_interval_ms
-            ckpt_every = max(1, interval // max(self.autocommit_ms, 1))
-        rnd = 0
-        waves = 0
-        while True:
-            has_data = False
-            t_hint = 0
-            if statics:  # feed one scripted timestamp per wave
-                t_hint = statics[0][0]
-                while statics and statics[0][0] == t_hint:
-                    _t, node, entries = statics.pop(0)
-                    node.push(
-                        list(entries) if type(entries) is list else entries
-                    )
-                    has_data = True
-            for c in self.connectors:
-                entries = c.poll()
-                if entries:
-                    c.session.node.push(entries)
-                    has_data = True
-            stopped = self.stop_event is not None and self.stop_event.is_set()
-            local_done = (
-                not statics
-                and (stopped or all(c.done for c in self.connectors))
-            )
-            any_data, all_done, t_max = mesh.control_round(
-                rnd, has_data, local_done, t_hint
-            )
-            rnd += 1
-            if any_data:
-                # scripted timestamps win (identical everywhere via the
-                # control exchange); live waves use the even-ms counter
-                self.time = max(self.time + 2, t_max)
-                t = self.time
-                self.graph.step(t)
-                waves += 1
-                for m in self.monitors:
-                    m(t)
-                if self.checkpointer is not None and waves % ckpt_every == 0:
-                    self.checkpointer.checkpoint(t)
-            elif not all_done:
-                _time.sleep(self.autocommit_ms / 1000.0)
-            if all_done and not any_data:
-                t = self.next_time()
-                self.graph.end(t)
-                if self.checkpointer is not None:
-                    self.checkpointer.checkpoint(t)
-                    self.checkpointer.close()
-                break
-
     def run_static(self, batches: list[tuple[int, InputNode, list[Entry]]]) -> None:
         """Batch mode: feed pre-timed batches, run each wave, then end.
 
@@ -940,7 +868,7 @@ class Runtime:
         domain. Pipelines with deferrable device stages (async-apply
         under stage overlap) run through the frontier scheduler so waves
         at distinct timestamps pipeline across operators; everything
-        else keeps the exact deterministic lockstep pump.
+        else runs one whole-graph wave per timestamp, in time order.
         """
         if self._wants_stage_overlap():
             return self._run_static_frontier(batches)
@@ -988,10 +916,8 @@ class Runtime:
                     raise RuntimeError(f"{what} stalled with undrained waves")
 
     def _wants_stage_overlap(self) -> bool:
-        if os.environ.get("PATHWAY_STAGE_OVERLAP", "1") == "0":
-            return False
         return any(
-            isinstance(n, AsyncApplyNode) and n.is_async and n.overlap
+            isinstance(n, AsyncApplyNode) and n.is_async
             for n in self.graph.nodes
         )
 
@@ -1003,8 +929,8 @@ class Runtime:
         timestamp, so a deferred device dispatch of wave t (embed,
         generate) overlaps the staging and compute of wave t+1 — the
         serving pipeline the device plane is built around. Results are
-        identical to the lockstep pump (same per-operator time order);
-        only the interleaving differs.
+        identical to one whole-graph wave per timestamp (same per-operator
+        time order); only the interleaving differs.
         """
         sched = self._make_scheduler()
         sched.allow_async = True
@@ -1676,7 +1602,8 @@ class AsyncApplyNode(Node):
     admissible work (including this node's own later waves: that is the
     double buffer — wave t+1 stages/tokenizes while wave t computes on
     the device), and when the batch resolves the node fires again at the
-    held time to emit. Opt out with PATHWAY_STAGE_OVERLAP=0.
+    held time to emit. Under a scheduler without ``allow_async`` the wave
+    runs to its end inside the fire (the synchronous branch below).
     """
 
     _state_routing = {"memo": "keytup"}  # memo keys are (key.value, row)
@@ -1695,7 +1622,6 @@ class AsyncApplyNode(Node):
         self.is_async = is_async
         self.deterministic = deterministic
         self.memo: dict[tuple, Any] = {}
-        self.overlap = os.environ.get("PATHWAY_STAGE_OVERLAP", "1") != "0"
         # time -> (entries, concurrent Future[results dict]) for deferred
         # waves; never persisted — checkpoints cut at the global frontier,
         # which a hold keeps below any half-done wave
@@ -1723,7 +1649,6 @@ class AsyncApplyNode(Node):
         sched = self.graph.scheduler
         if (
             self.is_async
-            and self.overlap
             and sched is not None
             and getattr(sched, "allow_async", False)
             # a retraction-only wave behind an in-flight one must chain

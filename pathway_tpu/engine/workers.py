@@ -420,17 +420,13 @@ class ProcessExchangeNode(Node):
     downstream operator (optionally thread-sharded on top) owns its
     shard exclusively: every key lives on exactly one process.
 
-    Two delivery protocols share the split logic:
-
-      * frontier mode (default, ``Runtime.run_mesh``): ``finish_time``
-        only SENDS — buckets cross the wire tagged with their
-        timestamp, and the receiving pump injects them below the peer's
-        replica of this node (``inject_remote``) once its input
-        frontier passes that time. No blocking, no per-wave barrier: a
-        slow peer delays only the operators consuming its wire.
-      * lockstep BSP (deprecated fallback, ``run_lockstep``): the node
-        BLOCKS until every peer's bucket for this (node, round)
-        arrives — the old global wave barrier.
+    ``finish_time`` only SENDS (``Runtime.run_mesh``): buckets cross the
+    wire tagged with their timestamp, and the receiving pump injects them
+    below the peer's replica of this node (``inject_remote``) once its
+    input frontier passes that time. No blocking, no per-wave barrier: a
+    slow peer delays only the operators consuming its wire. The one
+    blocking exchange is the end barrier, at the negotiated end time
+    every process steps together.
 
     `route` maps (key, row) -> shard token; None routes everything to
     process 0 (operators with global state: buffers, gradual broadcast,
@@ -460,19 +456,11 @@ class ProcessExchangeNode(Node):
         # creation order) and be unique across sessions sharing one
         # process-wide mesh — the lowering allocates it
         self.wire_id = wire_id
-        self.round = 0
-        # frontier protocol switches (set by Runtime.run_mesh)
-        self.frontier_mode = False
+        # set by Runtime.run_mesh once every process has announced done
         self.end_barrier = False
 
     def persist_signature(self) -> str:
         return f"ProcessExchange/{self.mesh.n}/{int(self.route is None)}"
-
-    def persist_state(self) -> dict:
-        return {"round": self.round}
-
-    def restore_state(self, st: dict) -> None:
-        self.round = st["round"]
 
     def _split_native(self, batch: Any, n: int):
         """Per-process sub-batches of a NativeBatch, or None (no plan /
@@ -560,8 +548,8 @@ class ProcessExchangeNode(Node):
         return buckets, nb_buckets
 
     def inject_remote(self, time: int, payload: Any) -> None:
-        """Deliver a peer's bucket below this node (frontier mode): the
-        pump calls this once the wire's watermark admits `time`."""
+        """Deliver a peer's bucket below this node: the pump calls this
+        once the wire's watermark admits `time`."""
         if isinstance(payload, tuple):
             ents, wires = payload
             if wires:
@@ -576,8 +564,8 @@ class ProcessExchangeNode(Node):
 
     def finish_time(self, time: int) -> None:
         batches, entries = self.take_segments()
-        if self.frontier_mode and not self.end_barrier:
-            # frontier protocol: no blocking. Peer buckets cross the
+        if not self.end_barrier:
+            # no blocking: peer buckets cross the
             # mesh tagged with their time and are injected below the
             # peer's replica of this node once its operators' frontiers
             # admit them; the local bucket emits downstream directly —
@@ -600,9 +588,9 @@ class ProcessExchangeNode(Node):
             return
         buckets, nb_buckets = self._split_wave(batches, entries)
         me = self.mesh.process_id
-        # end barrier (frontier mode) reuses the blocking exchange once,
-        # at the negotiated end time every process steps together
-        rnd = ("end", time) if self.end_barrier else self.round
+        # the end barrier: one blocking exchange, at the negotiated end
+        # time every process steps together
+        rnd = ("end", time)
         for p in self.mesh.peers:
             wires = [b.to_wire() for b in nb_buckets[p]]
             self.mesh.send_bucket(
@@ -623,7 +611,6 @@ class ProcessExchangeNode(Node):
                     )
             else:  # legacy plain-entry frame
                 merged.extend(payload)
-        self.round += 1
         for b in local_batches:
             self.emit(time, b)
         if merged:

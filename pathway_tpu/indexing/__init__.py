@@ -9,8 +9,7 @@ index (`ann.py`), built on the kernels in `pathway_tpu/ops/ivf.py`.
 Kill switch: ``PATHWAY_ANN=0`` forces every ANN-configured retriever
 back to the exact slab search (byte-identical ranking semantics —
 same (score, key) tie-break), the same discipline as
-``PATHWAY_STAGE_OVERLAP`` / ``PATHWAY_ITERATE_NATIVE`` /
-``PATHWAY_CONTINUOUS_BATCH``. ``PATHWAY_ANN=1`` additionally flips
+``PATHWAY_ITERATE_NATIVE``. ``PATHWAY_ANN=1`` additionally flips
 opt-in call sites (``make_knn_searcher``) whose default is exact.
 """
 

@@ -3,9 +3,8 @@ src/engine/dataflow/config.rs env-first config).
 
 Env vars mirror the reference's: PATHWAY_THREADS, PATHWAY_PROCESSES,
 PATHWAY_PROCESS_ID, PATHWAY_FIRST_PORT, PATHWAY_PERSISTENT_STORAGE,
-PATHWAY_RUN_ID. TPU addition: PATHWAY_MESH (e.g. "dp=2,tp=4" for the
-device mesh used by the numeric plane). Which device the numeric plane
-runs on is JAX's choice (JAX_PLATFORMS), not a setting here.
+PATHWAY_RUN_ID. Which device the numeric plane runs on is JAX's choice
+(JAX_PLATFORMS), not a setting here.
 """
 
 from __future__ import annotations
@@ -26,7 +25,6 @@ class PathwayConfig:
     license_key: str | None = None
     monitoring_server: str | None = None
     ignore_asserts: bool = False
-    mesh_spec: str | None = None
     terminate_on_error: bool = False
 
     @property
@@ -57,9 +55,7 @@ def get_config(refresh: bool = False) -> PathwayConfig:
             process_id=_int_env("PATHWAY_PROCESS_ID", 0),
             first_port=_int_env("PATHWAY_FIRST_PORT", 10000),
             persistent_storage_path=os.environ.get("PATHWAY_PERSISTENT_STORAGE"),
-            license_key=os.environ.get("PATHWAY_LICENSE_KEY"),
             monitoring_server=os.environ.get("PATHWAY_MONITORING_SERVER"),
-            mesh_spec=os.environ.get("PATHWAY_MESH"),
         )
     return _config
 

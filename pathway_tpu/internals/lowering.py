@@ -2001,19 +2001,12 @@ class Session:
         runtime.mesh = self.mesh
         runtime.session_seq = self._session_seq
         if self.mesh is not None:
-            import os as _os
-
             for c in self.connectors:
                 runtime.add_connector(c)
-            if _os.environ.get("PATHWAY_MESH_BSP") == "1":
-                # deprecated lockstep fallback: every process steps every
-                # wave together (kept as the measured straggler baseline)
-                runtime.run_lockstep(self.static_batches)
-            else:
-                # frontier-based progress tracking: each process pumps at
-                # its own pace; exchange wires carry (time, batch) +
-                # watermarks (engine/frontier.py)
-                runtime.run_mesh(self.static_batches)
+            # frontier-based progress tracking: each process pumps at its
+            # own pace; exchange wires carry (time, batch) + watermarks
+            # (engine/frontier.py)
+            runtime.run_mesh(self.static_batches)
             return
         if not self.connectors:
             runtime.run_static(self.static_batches)

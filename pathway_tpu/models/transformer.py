@@ -315,23 +315,6 @@ def _rmsnorm(x: Array, scale: Array) -> Array:
         return (x32 * jax.lax.rsqrt(var + 1e-6) * scale).astype(x.dtype)
 
 
-_FUSED_ATTN_ENV: bool | None = None
-
-
-def _use_fused_attention() -> bool:
-    # the kill switch is read ONCE per process: _attention runs inside
-    # jit traces, and an env read per trace is the hot-path bug class
-    # the repo lint bans (PR 9(h))
-    global _FUSED_ATTN_ENV
-    if _FUSED_ATTN_ENV is None:
-        import os
-
-        _FUSED_ATTN_ENV = (
-            os.environ.get("PATHWAY_TPU_FUSED_ATTN", "1") != "0"
-        )
-    return _FUSED_ATTN_ENV and jax.default_backend() == "tpu"
-
-
 def _attention(
     x: Array,
     block: Params,
@@ -364,7 +347,10 @@ def _attention(
             causal=cfg.causal,
             kv_mask=token_mask,
         ).reshape(b, s, d)
-    elif not cfg.causal and cfg.fused_attention and _use_fused_attention():
+    elif (
+        not cfg.causal and cfg.fused_attention
+        and jax.default_backend() == "tpu"
+    ):
         from pathway_tpu.ops.attention import fused_qkv_attention
 
         ctx = fused_qkv_attention(qkv, token_mask, h)
@@ -631,31 +617,15 @@ def _attend(q: Array, keys: Array, vals: Array, ok: Array,
     no key or value is repeated in memory."""
     b, nq, h, dh = q.shape
     hk = keys.shape[2]
-    if hk == h:
-        # the same mathematics as a group of one below, kept because the v5e
-        # compiler makes another text of that: with it gone the step of
-        # rag-cerebras-6b7 differs from the parent's in 16 fusions (a
-        # layer's product of cache rows and weights takes its operands in
-        # the other order), and the step is held to the parent's HLO
-        # (ISSUE 32; a prefill on the chip no longer comes this way)
-        scores = jnp.einsum(
-            "bqhd,bkhd->bhqk", q, keys, preferred_element_type=jnp.float32
-        ) / math.sqrt(dh)
-        scores = jnp.where(ok, scores, -1e30)
-        probs = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)
-        ctx = jnp.einsum(
-            "bhqk,bkhd->bqhd", probs, vals, preferred_element_type=jnp.float32
-        )
-    else:
-        scores = jnp.einsum(
-            "bqkgd,bskd->bkgqs", q.reshape(b, nq, hk, h // hk, dh), keys,
-            preferred_element_type=jnp.float32,
-        ) / math.sqrt(dh)
-        scores = jnp.where(ok[:, :, None], scores, -1e30)
-        probs = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)
-        ctx = jnp.einsum(
-            "bkgqs,bskd->bqkgd", probs, vals, preferred_element_type=jnp.float32
-        )
+    scores = jnp.einsum(
+        "bqkgd,bskd->bkgqs", q.reshape(b, nq, hk, h // hk, dh), keys,
+        preferred_element_type=jnp.float32,
+    ) / math.sqrt(dh)
+    scores = jnp.where(ok[:, :, None], scores, -1e30)
+    probs = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)
+    ctx = jnp.einsum(
+        "bkgqs,bskd->bqkgd", probs, vals, preferred_element_type=jnp.float32
+    )
     return ctx.astype(cfg.dtype).reshape(b, nq, h * dh)
 
 

@@ -474,12 +474,9 @@ def _rebalance_roots(
         ]
         cat = _category(node, ProcessExchangeNode)
         if cat == "exchange":
-            # per-process round counters: monotone, restart-consistent
-            merged_round = max(int(st.get("round", 0)) for _, st in rend)
-            parts: list[dict | None] = [
-                {"round": merged_round} for _ in range(new_n)
-            ]
-        elif cat == "global":
+            continue  # holds none (an older snapshot's wave counter: unread)
+        parts: list[dict | None]
+        if cat == "global":
             # route=None exchanges deliver every record to process 0:
             # peers hold the state's initial (empty) value by construction
             st0 = next((st for p, st in rend if p == 0), None)

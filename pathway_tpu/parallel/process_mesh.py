@@ -10,9 +10,10 @@ protocol. Here the equivalents are:
     and exchanges length-prefixed pickle frames;
   * data frames — (node_id, round, entries) buckets routed by each
     exchange operator's shard key (engine/workers.py ProcessExchangeNode);
-  * control frames — per-round (has_data, done) flags, giving every
-    process the same global view to decide lockstep waves and
-    termination (the progress-protocol stand-in).
+  * control frames — per-wire watermarks, monotone flags (done, fence,
+    quiesce) and one-shot all-gathers, from which every process derives
+    the same view of progress and termination (the progress-protocol
+    stand-in; engine/runtime.py run_mesh).
 
 The host control plane carries arbitrary Python rows; bulk numeric
 columns ride the ICI all_to_all in parallel/exchange.py instead.
@@ -83,7 +84,6 @@ class ProcessMesh:
         self._send_locks: dict[int, threading.Lock] = {}
         self._cv = threading.Condition()
         self._data: dict[tuple[int, int, int], list] = {}  # (node, round, proc)
-        self._ctl: dict[tuple[int, int], tuple[bool, bool, int]] = {}  # (round, proc)
         self._nego: dict[tuple[str, int], Any] = {}  # (tag, proc) -> value
         # frontier-mode state (engine/runtime.py run_mesh):
         #   _inbox  — arrival-ordered (wire, time, peer) keys of buckets
@@ -209,15 +209,12 @@ class ProcessMesh:
                         key = (wire, peer)
                         if value > self._wm.get(key, -1):
                             self._wm[key] = value
-                    elif kind == "flag":
+                    else:  # flag
                         tag, value = payload
                         key = (tag, peer)
                         old = self._flags.get(key)
                         if old is None or value > old:
                             self._flags[key] = value
-                    else:  # ctl
-                        rnd, has_data, done, t_hint = payload
-                        self._ctl[(rnd, peer)] = (has_data, done, t_hint)
                     self._cv.notify_all()
         finally:
             if not self._closed:
@@ -321,32 +318,6 @@ class ProcessMesh:
             return self._data.pop(key)
 
     # ------------------------------------------------------------- control
-
-    def control_round(
-        self, rnd: int, has_data: bool, done: bool, t_hint: int = 0
-    ) -> tuple[bool, bool, int]:
-        """Broadcast this process's round flags and gather every peer's.
-        Returns (any_has_data, all_done, max_t_hint) — identical on every
-        process. `t_hint` carries scripted static timestamps so wave
-        times agree across processes even though only process 0 holds the
-        scripted batches. Dead peers raise; slow peers are waited for."""
-        for p in self.peers:
-            self._send(p, "ctl", (rnd, has_data, done, t_hint))
-        any_data, all_done, t_max = has_data, done, t_hint
-        with self._cv:
-            for p in self.peers:
-                while (rnd, p) not in self._ctl:
-                    if p in self._dead:
-                        raise WorkerLost(
-                            f"process {self.process_id}: peer {p} died "
-                            f"(control round {rnd})"
-                        )
-                    self._cv.wait(60.0)
-                p_data, p_done, p_hint = self._ctl.pop((rnd, p))
-                any_data = any_data or p_data
-                all_done = all_done and p_done
-                t_max = max(t_max, p_hint)
-        return any_data, all_done, t_max
 
     def allgather(self, tag: str, value: Any) -> dict[int, Any]:
         """One-shot all-gather of a small value under a unique tag (e.g.

@@ -12,8 +12,8 @@ ingress and the frontier runtime sit three subsystems —
 * **continuous batching** (:mod:`.continuous_batching`) — LLM decode
   runs as a slot scheduler over one persistent KV cache: new requests
   join in-flight batches at step boundaries instead of waiting for the
-  wave to drain (``PATHWAY_CONTINUOUS_BATCH=0`` restores wave-aligned
-  dispatch byte-identically).
+  wave to drain. ``JaxLMChat`` builds it at temperature 0; sampled
+  generation keeps the wave-aligned coalescer.
 
 Entry point: ``ServingGateway`` passed to ``rest_connector(gateway=...)``
 (or to the ``xpacks.llm.servers`` REST servers). Docs: docs/serving.md §6.
@@ -25,10 +25,7 @@ from pathway_tpu.serving.admission import (
     TokenBucket,
 )
 from pathway_tpu.serving.backpressure import WatermarkBackpressure
-from pathway_tpu.serving.continuous_batching import (
-    ContinuousBatcher,
-    continuous_batching_on,
-)
+from pathway_tpu.serving.continuous_batching import ContinuousBatcher
 from pathway_tpu.serving.gateway import ServingGateway
 
 __all__ = [
@@ -38,5 +35,4 @@ __all__ = [
     "ServingGateway",
     "TokenBucket",
     "WatermarkBackpressure",
-    "continuous_batching_on",
 ]

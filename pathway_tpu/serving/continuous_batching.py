@@ -39,23 +39,24 @@ counters flow into the metrics registry
 ``pathway_serving_joined_inflight_total``,
 ``pathway_serving_decode_steps_total``, ``pathway_serving_slots_active``).
 
-**Kill switch**: ``PATHWAY_CONTINUOUS_BATCH=0`` makes `JaxLMChat` fall
-back to the wave-aligned coalescer path. The fallback is byte-identical
-per request — `decode_step_slots` is the same math as the scanned
+**One rule chooses the scheduler**: `JaxLMChat` builds this batcher at
+temperature 0 and the wave-aligned coalescer above it; nothing else
+selects. `JaxLMChat._generate_batch` stays callable on any chat as the
+oracle: `decode_step_slots` is the same math as the scanned
 `decode_step` with the shared scalar position replaced by a per-row
-vector, pinned by ``tests/test_continuous_batching.py``.
+vector, so a request's tokens are byte-identical on both, pinned by
+``tests/test_continuous_batching.py``.
 
-**Mesh-spanning slot pools** (``PATHWAY_MESH_SLOTS=1``, or
-``mesh_span=True``): on a multi-device mesh the persistent KV cache's
-slot axis is sharded over the mesh's ``data`` axis and the pool grows to
-``n_slots x shards`` — one slot scheduler drives decode slots spread
-across every chip, so serving concurrency scales with the pod instead of
-one chip's HBM. The decode step stays ONE program (jit partitions the
-per-row vectors along the same axis); scheduling, admission, and the
-step-boundary protocol are unchanged, and per-request tokens are
-byte-identical to the single-device pool (the slot axis is batch — rows
-never read each other's slots). Off by default: behavior without the
-flag is exactly the pre-mesh pool.
+**Mesh-spanning slot pools** (``mesh_span=True``): on a multi-device
+mesh the persistent KV cache's slot axis is sharded over the mesh's
+``data`` axis and the pool grows to ``n_slots x shards`` — one slot
+scheduler drives decode slots spread across every chip, so serving
+concurrency scales with the pod instead of one chip's HBM. The decode
+step stays ONE program (jit partitions the per-row vectors along the
+same axis); scheduling, admission, and the step-boundary protocol are
+unchanged, and per-request tokens are byte-identical to the
+single-device pool (the slot axis is batch — rows never read each
+other's slots).
 
 **The scheduler times itself.** The decode loop is cut into contiguous
 leaf phases (:data:`PHASES`): each opens a profiler span (visible when a
@@ -87,7 +88,6 @@ is future work and the chat constructor routes accordingly).
 
 from __future__ import annotations
 
-import os
 import threading
 import time
 from collections import deque
@@ -97,21 +97,7 @@ from typing import Any
 from pathway_tpu.internals import observability as _obs
 from pathway_tpu.analysis import lockgraph as _lockgraph
 
-__all__ = ["ContinuousBatcher", "continuous_batching_on", "mesh_slots_on"]
-
-
-def continuous_batching_on() -> bool:
-    """The kill switch: PATHWAY_CONTINUOUS_BATCH=0 restores wave-aligned
-    dispatch (default on)."""
-    return os.environ.get("PATHWAY_CONTINUOUS_BATCH", "1") not in (
-        "0", "false", "no",
-    )
-
-
-def mesh_slots_on() -> bool:
-    """PATHWAY_MESH_SLOTS=1 spans the slot pool across the device mesh
-    (default off: single-device pools, pre-mesh behavior)."""
-    return os.environ.get("PATHWAY_MESH_SLOTS", "0") == "1"
+__all__ = ["ContinuousBatcher"]
 
 
 # `stats` key (seconds, cumulative) -> the profiler span of that phase of
@@ -205,7 +191,7 @@ class ContinuousBatcher:
         n_slots: int = 8,
         plane: Any = None,
         name: str | None = None,
-        mesh_span: bool | None = None,
+        mesh_span: bool = False,
     ):
         import functools
 
@@ -224,7 +210,7 @@ class ContinuousBatcher:
         # mesh-spanning pool: n_slots PER SHARD, the KV cache's slot axis
         # sharded over the mesh `data` axis (module docstring)
         self.mesh = None
-        if mesh_span if mesh_span is not None else mesh_slots_on():
+        if mesh_span:
             import jax
 
             if len(jax.devices()) > 1:
@@ -517,7 +503,7 @@ class ContinuousBatcher:
         import jax.numpy as jnp
         import numpy as np
 
-        from pathway_tpu.xpacks.llm.embedders import pad_left_rows
+        from pathway_tpu.engine.device_plane import pad_left_rows
 
         with self._phase("admit_prep_s", req=req.id, slot=slot):
             ids, mask = pad_left_rows([req.row], self.budget, n_rows=1)

@@ -7,9 +7,9 @@ TPU redesign: both `BruteForceKnn` and `UsearchKnn` run on the same
 HBM-resident bf16 vector slab (`host_indexes.VectorSlabIndex`); the
 difference is the top-k phase — exact `lax.top_k` vs TPU-optimized
 `lax.approx_max_k`. There is no HNSW graph: on the MXU a fused
-matmul+top-k over 1M docs takes single-digit milliseconds, so the
+matmul+top-k over the whole slab is one dispatch, so the
 graph-traversal accuracy/latency trade the reference buys with usearch
-does not pay for itself on this hardware (see bench.py).
+is not taken (what a search costs on the chip: `PERF.md`).
 """
 
 from __future__ import annotations

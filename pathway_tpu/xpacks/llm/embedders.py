@@ -154,47 +154,14 @@ class SentenceTransformerEmbedder(BaseEmbedder):
 # The wave batcher moved into the device plane: coalescing is a serving
 # concern shared by every XLA-backed stage (embed, generate, batched
 # UDFs). Kept under its historical name — callers (and the bench's phase
-# probes) patch `<udf>._batcher.flush_fn`.
+# probes) patch `<udf>._batcher.flush_fn`. The padding rules live beside
+# the ladder they pad to (BucketPolicy) and are re-exported here.
 from pathway_tpu.engine.device_plane import (  # noqa: E402
     WaveCoalescer as _MicroBatcher,
+    bucket_len,
     get_device_plane,
+    pad_left_rows,
 )
-
-
-def bucket_len(longest: int, cap: int) -> int:
-    """Sequence bucket (>=16: a power of two up to 512, four rungs an
-    octave above it, never over `cap`) so the jit cache sees few distinct
-    shapes as lengths vary — shared by the embedder's right-pad and the
-    chat's left-pad batching (the device plane's BucketPolicy)."""
-    return get_device_plane().buckets.seq_bucket(longest, cap)
-
-
-def pad_left_rows(
-    rows: list, cap: int, pad_rows_to: int | None = None,
-    n_rows: int | None = None,
-):
-    """Left-pad variable-length token rows into (ids, mask) int32 arrays
-    at a bucketed width (`bucket_len` of the longest row; generation
-    convention — real tokens end at the last column, so last-position
-    logits are every row's next token).
-    The batch dimension pads with all-masked rows so arbitrary wave
-    sizes hit few jit shapes: to exactly `n_rows` (callers pass the
-    device plane's row bucket), to a multiple of `pad_rows_to`, or to
-    the plane's power-of-two bucket by default."""
-    bucket = bucket_len(max((len(r) for r in rows), default=1) or 1, cap)
-    if n_rows is not None:
-        n = n_rows
-    elif pad_rows_to is not None:
-        n = ((len(rows) + pad_rows_to - 1) // pad_rows_to) * pad_rows_to
-    else:
-        n = get_device_plane().buckets.rows_bucket(len(rows))
-    ids = np.zeros((n, bucket), np.int32)
-    mask = np.zeros((n, bucket), np.int32)
-    for i, r in enumerate(rows):
-        r = r[-bucket:]
-        ids[i, bucket - len(r):] = r
-        mask[i, bucket - len(r):] = 1
-    return ids, mask
 
 
 class JaxEmbedder(BaseEmbedder):

@@ -185,13 +185,16 @@ class JaxLMChat(BaseChat):
     decode with a KV cache (models/transformer.py), jit-compiled once.
     Pass trained `params`, or leave None for random weights (testing).
 
-    Dispatch model: **continuous batching** by default (temperature 0) —
-    requests join an in-flight decode batch at step boundaries through
-    the slot scheduler (serving/continuous_batching.py), so a request
-    arriving mid-generation never waits for the whole wave to drain.
-    ``PATHWAY_CONTINUOUS_BATCH=0`` (or ``continuous_batching=False``, or
-    any ``temperature > 0``) falls back to the wave-aligned coalescer:
-    one left-padded generate dispatch per wave, byte-identical output.
+    Dispatch model, chosen by ``temperature`` alone: at 0, **continuous
+    batching** — requests join an in-flight decode batch at step
+    boundaries through the slot scheduler
+    (serving/continuous_batching.py), so a request arriving
+    mid-generation never waits for the whole wave to drain; above 0, the
+    wave-aligned coalescer: one left-padded generate dispatch per wave
+    (the batcher does not sample). Only the chosen scheduler is built.
+    ``_generate_batch`` is callable on any chat and makes its program on
+    first use: at temperature 0 its output is byte-identical to the
+    batcher's, which is what the tests hold the batcher to.
     """
 
     def __init__(
@@ -202,18 +205,16 @@ class JaxLMChat(BaseChat):
         max_new_tokens: int = 64,
         temperature: float = 0.0,
         max_batch: int = 64,
-        continuous_batching: bool | None = None,
         decode_slots: int = 8,
         **kwargs: Any,
     ):
         super().__init__(**kwargs)
-        import functools
-
         import jax
 
         from pathway_tpu.engine.device_plane import get_device_plane
         from pathway_tpu.models import lm_config, transformer
         from pathway_tpu.models.tokenizer import HashTokenizer
+        from pathway_tpu.serving.continuous_batching import ContinuousBatcher
 
         self.config = config or lm_config(
             vocab_size=32768, d_model=256, n_heads=8, n_layers=4, d_ff=1024,
@@ -233,38 +234,15 @@ class JaxLMChat(BaseChat):
         self.max_new_tokens = max_new_tokens
         self.temperature = temperature
         self.max_batch = max_batch
-        # serving batcher: a wave of concurrent chat calls left-pads into
-        # ONE generate dispatch (prompt_mask keeps per-row outputs equal
-        # to unpadded runs); per-question dispatch would serialize on
-        # host->device submission latency. The KV cache is a PERSISTENT
-        # donated buffer per row bucket (device_plane lease): XLA reuses
-        # the allocation across dispatches instead of re-allocating the
-        # cache every call.
         self._plane = get_device_plane()
-        self._gen = self._plane.program(
-            self._plane.unique_name("lm_generate"),
-            functools.partial(
-                transformer.generate_serving,
-                n_steps=self.max_new_tokens,
-                cfg=self.config,
-                temperature=self.temperature,
-            ),
-            donate_argnums=(2,),  # the KV cache rides the lease cycle
-        )
-        self._batcher = self._plane.coalescer(
-            self._generate_batch, max_batch=max_batch
-        )
-        # continuous batching: slot-scheduled decode (joins at step
-        # boundaries) unless killed by env/arg or sampled generation
-        from pathway_tpu.serving.continuous_batching import (
-            ContinuousBatcher,
-            continuous_batching_on,
-        )
-
-        if continuous_batching is None:
-            continuous_batching = continuous_batching_on()
+        # the wave-aligned program: its name is reserved now, for the
+        # finalizer, and the first `_generate_batch` registers it
+        self._gen_name = self._plane.unique_name("lm_generate")
+        self._gen: Any = None
         self._cb: ContinuousBatcher | None = None
-        if continuous_batching and self.temperature == 0.0:
+        self._batcher: Any = None
+        if self.temperature == 0.0:
+            # slot-scheduled decode: requests join at step boundaries
             self._cb = ContinuousBatcher(
                 params=self.params,
                 cfg=self.config,
@@ -273,21 +251,53 @@ class JaxLMChat(BaseChat):
                 n_slots=decode_slots,
                 plane=self._plane,
             )
+        else:
+            # a wave of concurrent chat calls left-pads into ONE generate
+            # dispatch (prompt_mask keeps per-row outputs equal to
+            # unpadded runs); per-question dispatch would serialize on
+            # host->device submission latency
+            self._batcher = self._plane.coalescer(
+                self._generate_batch, max_batch=max_batch
+            )
         # the plane is process-global: without this, every dead chat
         # instance would pin its compiled program + KV-cache pools forever
         self._finalizer = weakref.finalize(
-            self, _release_chat_programs, self._plane, self._gen.name,
+            self, _release_chat_programs, self._plane, self._gen_name,
             self._cb.name if self._cb is not None else None,
         )
+
+    def _generate_program(self) -> Any:
+        """The wave-aligned `generate_serving` program, registered by the
+        first `_generate_batch` (register-or-get: racing first calls are
+        handed one program). Its KV cache is a PERSISTENT donated buffer
+        per row bucket (device_plane lease): XLA reuses the allocation
+        across dispatches instead of re-allocating the cache every call."""
+        import functools
+
+        from pathway_tpu.models import transformer
+
+        if self._gen is None:
+            self._gen = self._plane.program(
+                self._gen_name,
+                functools.partial(
+                    transformer.generate_serving,
+                    n_steps=self.max_new_tokens,
+                    cfg=self.config,
+                    temperature=self.temperature,
+                ),
+                donate_argnums=(2,),  # the KV cache rides the lease cycle
+            )
+        return self._gen
 
     def _generate_batch(self, prompts: list[str]) -> list[str]:
         import jax
         import jax.numpy as jnp
         import numpy as np
 
+        from pathway_tpu.engine.device_plane import pad_left_rows
         from pathway_tpu.models import transformer
-        from pathway_tpu.xpacks.llm.embedders import pad_left_rows
 
+        gen = self._generate_program()
         budget = self.config.max_len - self.max_new_tokens
         rows = [self.tokenizer.tokenize(p)[-budget:] for p in prompts]
         n = min(self._plane.buckets.rows_bucket(len(rows)), self.max_batch)
@@ -297,11 +307,11 @@ class JaxLMChat(BaseChat):
         kwargs = {}
         if self.temperature > 0.0:
             kwargs["rng"] = jax.random.PRNGKey(abs(hash(tuple(prompts))) % (1 << 31))
-        cache_key = ("lm_kv_cache", self._gen.name, n)
+        cache_key = ("lm_kv_cache", gen.name, n)
         cache = self._plane.lease(
             cache_key, lambda: transformer.init_kv_cache(self.config, n)
         )
-        out, cache = self._gen(
+        out, cache = gen(
             self.params, jnp.asarray(ids), cache,
             prompt_mask=jnp.asarray(mask),
             bucket=(n, bucket), **kwargs,

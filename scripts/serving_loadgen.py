@@ -2,7 +2,7 @@
 """Closed-loop serving load bench: N concurrent clients against a live
 gateway-fronted RAG pipeline, measuring p50/p99 latency and goodput.
 
-One process hosts both sides (bench.py runs one invocation per rung, the
+One process hosts both sides (one invocation per measurement, the
 established one-pw.run-per-process discipline):
 
 * **server** — `rest_connector` (+ optional `ServingGateway`) feeding a

@@ -22,12 +22,6 @@ Leg 6 (observability): the engine suites with full instrumentation on
 recorder must be result-invariant (docs/observability.md); the A/B
 byte-identical pipeline check itself lives in
 tests/test_observability_plane.py::test_instrumentation_is_result_invariant.
-Leg 7 (serving-gateway): the serving edge suites with the
-continuous-batching kill switch thrown (PATHWAY_CONTINUOUS_BATCH=0) —
-wave-aligned fallback must stay byte-identical and the gateway /
-rest-connector contract must hold on both dispatch models; the CB-on
-side of the same suites already runs inside legs 1-2
-(docs/serving.md §6).
 Leg 8 (ann): the indexing suites with the ANN kill switch thrown
 (PATHWAY_ANN=0) — every IVF-PQ-configured retriever must drop back to
 the exact slab search with byte-identical ranking semantics
@@ -255,18 +249,6 @@ def main() -> int:
                 "tests/test_observability_plane.py",
                 "tests/test_frontier.py",
                 "tests/test_workers.py",
-            ],
-        ),
-        # serving edge with continuous batching killed: the wave-aligned
-        # fallback must stay byte-identical and the gateway contract
-        # (admission/backpressure/rest statuses) must hold either way
-        run_leg(
-            "serving-gateway", {"PATHWAY_CONTINUOUS_BATCH": "0"}, extra,
-            [
-                "tests/test_serving_gateway.py",
-                "tests/test_continuous_batching.py",
-                "tests/test_device_plane.py",
-                "tests/test_llm_xpack.py",
             ],
         ),
         # ANN kill switch thrown: IVF-PQ retrievers must reproduce the

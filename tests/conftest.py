@@ -28,7 +28,48 @@ import jax  # noqa: E402
 jax.config.update("jax_platforms", "cpu")
 assert jax.devices()[0].platform == "cpu", "tests must run on the CPU mesh"
 
+import socket  # noqa: E402
+
 import pytest  # noqa: E402
+
+# ---------------------------------------------------------------- ports
+#
+# A test that asks the kernel for a free port (bind to 0), closes the
+# probe and hands the number to a server or a child process has lost the
+# port by the time that one binds: the other xdist workers bind and
+# connect in the same ephemeral range meanwhile. So ports come from below
+# that range (32768 up on Linux), where the kernel hands out nothing by
+# itself, each xdist worker from a slice of its own, and no block twice.
+_PORT_FLOOR, _PORT_SLICE, _PORT_SLICES = 20000, 1000, 12
+_port_offset = 0  # into this worker's slice: where the next block starts
+
+
+def free_port_base(n: int = 1) -> int:
+    """The first of `n` consecutive ports that are free now and that no
+    other call, in this worker or another, is handed."""
+    global _port_offset
+    worker = os.environ.get("PYTEST_XDIST_WORKER", "gw0")
+    lo = _PORT_FLOOR + (int(worker[2:] or 0) % _PORT_SLICES) * _PORT_SLICE
+    for _ in range(_PORT_SLICE // n):
+        if _port_offset + n > _PORT_SLICE:
+            _port_offset = 0
+        base = lo + _port_offset
+        _port_offset += n
+        probes = []
+        try:
+            for port in range(base, base + n):
+                probe = socket.socket()
+                probes.append(probe)
+                probe.bind(("127.0.0.1", port))
+            return base
+        except OSError:
+            continue  # held by another program, or still closing
+        finally:
+            for probe in probes:
+                probe.close()
+    raise RuntimeError(
+        f"no {n} consecutive free ports in {lo}..{lo + _PORT_SLICE}"
+    )
 
 
 @pytest.fixture(autouse=True)

@@ -112,7 +112,7 @@ def test_phases_sum_to_loop_and_tokens_are_unchanged():
     _run(cb, ["warm up prompt"])  # compiles land in the dispatch phases
     got = _run(cb)
     # the clock changes no token: the wave-aligned path is the reference
-    assert got == _chat(continuous_batching=False)._generate_batch(PROMPTS)
+    assert got == chat._generate_batch(PROMPTS)
     s = cb.stats
     phases = sum(s[k] for k in PHASES)
     assert all(s[k] > 0 for k in PHASES)
@@ -253,16 +253,14 @@ def test_statistics_and_metrics_show_the_batcher_beside_its_pool():
 
 
 def test_rest_route_sums_the_residence_of_its_200s():
-    import socket
     import threading
 
     import requests
+    from conftest import free_port_base
 
     from pathway_tpu.internals import run as run_mod
 
-    with socket.socket() as sock:
-        sock.bind(("127.0.0.1", 0))
-        port = sock.getsockname()[1]
+    port = free_port_base()
     ws = pw.io.http.PathwayWebserver(host="127.0.0.1", port=port)
     queries, writer = pw.io.http.rest_connector(
         webserver=ws, route="/clock",

@@ -8,7 +8,6 @@ from __future__ import annotations
 
 import json
 import os
-import socket
 import sys
 import textwrap
 import time
@@ -17,6 +16,8 @@ import pytest
 
 import pathway_tpu as pw
 from pathway_tpu.engine import faults
+
+from conftest import free_port_base
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.join(REPO, "scripts"))
@@ -357,18 +358,6 @@ MESH_SCRIPT = textwrap.dedent(
 )
 
 
-def _free_port_base(n: int) -> int:
-    socks, ports = [], []
-    for _ in range(n + 4):
-        s = socket.socket()
-        s.bind(("127.0.0.1", 0))
-        socks.append(s)
-        ports.append(s.getsockname()[1])
-    for s in socks:
-        s.close()
-    return max(ports) + 1
-
-
 def _consolidate_mesh(out_base: str, n: int) -> dict:
     combined: dict = {}
     for pid in range(n):
@@ -401,7 +390,7 @@ def test_supervised_mesh_restarts_after_worker_crash(tmp_path):
 
     out = str(tmp_path / "mesh-out")
     pdir = str(tmp_path / "mesh-pdir")
-    base = _free_port_base(2)
+    base = free_port_base(2)
     result = run_supervised(
         [sys.executable, "-c", MESH_SCRIPT.format(repo=REPO), out, pdir],
         n_processes=2,

@@ -465,7 +465,7 @@ def test_ivf_pq_index_sharded_search_parity():
 
 
 def test_mesh_spanning_slot_pool_byte_identical():
-    """PATHWAY_MESH_SLOTS: the slot pool spans the mesh (n_slots x
+    """`mesh_span=True`: the slot pool spans the mesh (n_slots x
     shards) and per-request tokens are byte-identical to the
     single-device pool."""
     import jax

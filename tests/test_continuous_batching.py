@@ -6,9 +6,9 @@ Pins the acceptance contract of the slot scheduler:
     a step boundary — the device-plane compile ledger shows zero new XLA
     compilations for the join, and the slot counters (pool + metrics
     registry) prove the freed-slot re-fill happened;
-  * `PATHWAY_CONTINUOUS_BATCH=0` (and `continuous_batching=False`) fall
-    back to wave-aligned dispatch BYTE-identically — the slot path's
-    per-row math is the same as the scanned `generate_serving` path;
+  * the scheduler is a function of `temperature` alone, and the slot
+    path's per-row math equals the scanned `generate_serving` path BYTE
+    for byte — `chat._generate_batch` is the oracle on any chat;
   * slot-pool bookkeeping: acquire/release, refill + joined-in-flight
     counters, exhaustion, namespace cleanup.
 """
@@ -83,34 +83,64 @@ def test_plane_slot_pool_registry_and_namespace_drop():
     )
 
 
-# ---------------------------------------------------- kill-switch equality
+# ------------------------------------------- one selector: the temperature
 
 
 def test_continuous_batching_matches_wave_aligned_byte_identically():
     """The central equivalence: the slot scheduler's output equals the
     wave-aligned generate dispatch byte for byte, per request."""
-    cb = _chat(continuous_batching=True, decode_slots=4)
-    wa = _chat(continuous_batching=False)
+    chat = _chat(decode_slots=4)
     prompts = ["a b c", "d", "hello world longer prompt", "x y", "q", "z z z"]
-    futs = [cb._cb.submit(p) for p in prompts]
+    futs = [chat._cb.submit(p) for p in prompts]
     got_cb = [f.result(timeout=60) for f in futs]
-    got_wa = wa._generate_batch(prompts)
-    assert got_cb == got_wa
-    cb._cb.drain()
+    assert got_cb == chat._generate_batch(prompts)
+    chat._cb.drain()
 
 
-def test_kill_switch_env_restores_wave_aligned_path(monkeypatch):
-    monkeypatch.setenv("PATHWAY_CONTINUOUS_BATCH", "0")
-    chat = _chat()
-    assert chat._cb is None  # wave-aligned coalescer only
-    monkeypatch.setenv("PATHWAY_CONTINUOUS_BATCH", "1")
-    chat_on = _chat()
-    assert chat_on._cb is not None
+def _lm_generate_programs(plane) -> set:
+    return {n for n in plane.programs if n.startswith("lm_generate")}
+
+
+def test_greedy_chat_makes_the_oracle_program_on_first_use_only():
+    """At temperature 0 only the batcher is built: no `lm_generate`
+    program and no coalescer until `_generate_batch` is called, and the
+    finalizer then drops the batcher's namespace and that program."""
+    plane = _chat()._plane
+    before = _lm_generate_programs(plane)
+    chat = _chat(decode_slots=2)
+    assert chat._cb is not None and chat._batcher is None
+    assert chat._gen is None and _lm_generate_programs(plane) == before
+    chat._cb.submit("a b").result(timeout=60)  # served without it, too
+    chat._cb.drain()
+    assert chat._gen is None and _lm_generate_programs(plane) == before
+    chat._generate_batch(["a b"])
+    name, cb_name = chat._gen.name, chat._cb.name
+    assert _lm_generate_programs(plane) == before | {name}
+    assert chat._generate_batch(["c"]) and chat._gen.name == name  # made once
+    assert any(isinstance(k, tuple) and name in k for k in plane._leases)
+    chat._finalizer()  # what gc runs when the instance dies
+    assert name not in plane.programs
+    assert f"{cb_name}/step" not in plane.programs
+    assert f"{cb_name}/slots" not in plane._slot_pools
+    assert not any(
+        isinstance(k, tuple) and (name in k or cb_name in k)
+        for k in plane._leases
+    )
 
 
 def test_sampled_generation_keeps_wave_aligned_path():
+    plane = _chat()._plane
+    programs, pools = set(plane.programs), set(plane._slot_pools)
     chat = _chat(temperature=0.7)
     assert chat._cb is None  # per-request rng in a shared step: future work
+    assert chat._batcher is not None  # the coalescer is its scheduler
+    # no batcher, so none of its programs and no slot pool
+    assert set(plane.programs) == programs
+    assert set(plane._slot_pools) == pools
+    out = chat._generate_batch(["a b c", "d"])
+    assert [len(o.split()) for o in out] == [4, 4]
+    assert set(plane._slot_pools) == pools
+    assert set(plane.programs) - programs == {chat._gen.name}
 
 
 # ------------------------------------------- mid-generation join acceptance
@@ -122,7 +152,7 @@ def test_mid_generation_join_refills_slot_without_new_compile():
     program and the prompt bucket are warm) and the slot counters — on
     the pool and in the metrics registry — record the join/re-fill."""
     obs.enable()
-    chat = _chat(max_new_tokens=24, continuous_batching=True, decode_slots=2)
+    chat = _chat(max_new_tokens=24, decode_slots=2)
     cb = chat._cb
     assert cb is not None
     # warm both programs and the prompt bucket with one full generation
@@ -142,8 +172,7 @@ def test_mid_generation_join_refills_slot_without_new_compile():
     r2 = second.result(timeout=60)
     cb.drain()
     # outputs still equal the wave-aligned path (no cross-slot bleed)
-    wa = _chat(continuous_batching=False, max_new_tokens=24)
-    assert [r1, r2] == wa._generate_batch(
+    assert [r1, r2] == chat._generate_batch(
         ["first long running request", "second joins the flight"]
     )
     # zero new compiles for the join
@@ -169,21 +198,20 @@ def test_mid_generation_join_refills_slot_without_new_compile():
 def test_queue_overflow_waits_for_free_slot():
     """More requests than slots: the excess queues and lands in freed
     slots (refills), every result still byte-equal to wave-aligned."""
-    chat = _chat(continuous_batching=True, decode_slots=2)
+    chat = _chat(decode_slots=2)
     cb = chat._cb
     prompts = [f"prompt number {i}" for i in range(7)]
     futs = [cb.submit(p) for p in prompts]
     got = [f.result(timeout=120) for f in futs]
     cb.drain()
-    wa = _chat(continuous_batching=False)
-    assert got == wa._generate_batch(prompts)
+    assert got == chat._generate_batch(prompts)
     snap = cb.pool.snapshot()
     assert snap["refills"] >= 5  # 7 requests over 2 slots
     assert snap["active"] == 0  # fully drained
 
 
 def test_chat_finalizer_releases_cb_namespace():
-    chat = _chat(continuous_batching=True, decode_slots=2)
+    chat = _chat(decode_slots=2)
     cb = chat._cb
     cb.submit("a b").result(timeout=60)
     cb.drain()
@@ -202,31 +230,30 @@ def test_chat_finalizer_releases_cb_namespace():
 
 def test_cb_chat_through_a_pipeline():
     """JaxLMChat rides the UDF machinery with continuous batching on:
-    a table of questions answers identically to the wave-aligned run."""
+    a table of questions answers identically to the wave-aligned oracle."""
     import pathway_tpu as pw
     from pathway_tpu.xpacks.llm.llms import prompt_chat_single_qa
 
-    def run_once(cb_on: bool) -> dict:
-        chat = _chat(continuous_batching=cb_on, decode_slots=2)
-        t = pw.debug.table_from_rows(
-            pw.schema_from_types(q=str),
-            [("what is a", ), ("what is b", ), ("what is c", )],
-        )
-        r = t.select(
-            q=pw.this.q, a=chat(pw.apply(prompt_chat_single_qa, pw.this.q))
-        )
-        rows = {}
-        pw.io.subscribe(
-            r,
-            on_change=lambda key, row, time, is_addition: rows.__setitem__(
-                row["q"], row["a"]
-            ),
-        )
-        pw.run()
-        pw.internals.parse_graph.G.clear()
-        return rows
-
-    assert run_once(True) == run_once(False)
+    chat = _chat(decode_slots=2)
+    questions = ["what is a", "what is b", "what is c"]
+    t = pw.debug.table_from_rows(
+        pw.schema_from_types(q=str), [(q,) for q in questions]
+    )
+    r = t.select(
+        q=pw.this.q, a=chat(pw.apply(prompt_chat_single_qa, pw.this.q))
+    )
+    rows = {}
+    pw.io.subscribe(
+        r,
+        on_change=lambda key, row, time, is_addition: rows.__setitem__(
+            row["q"], row["a"]
+        ),
+    )
+    pw.run()
+    pw.internals.parse_graph.G.clear()
+    assert chat._cb.stats["completed"] == len(questions)  # the batcher served
+    # a single-turn message's prompt is its content: the question itself
+    assert [rows[q] for q in questions] == chat._generate_batch(questions)
 
 
 # ------------------------------------------------- the prompt-length ladder
@@ -238,7 +265,7 @@ LONG = dict(
 
 
 def _cb_chat(**kw):
-    return _chat(continuous_batching=True, decode_slots=2, **kw)
+    return _chat(decode_slots=2, **kw)
 
 
 def _words(n_tokens: int, salt: str = "w") -> str:
@@ -250,7 +277,7 @@ def test_long_prompt_runs_at_its_rung_and_matches_the_cap_width(monkeypatch):
     """A 600-token prompt runs at width 640, not at the cap's 1,096, and
     gives the tokens the cap's width gives: the left padding and
     `pad_len` are carried through the prefill and every step."""
-    from pathway_tpu.xpacks.llm import embedders
+    from pathway_tpu.engine import device_plane
 
     prompt = _words(600)
     widths = []
@@ -274,7 +301,7 @@ def test_long_prompt_runs_at_its_rung_and_matches_the_cap_width(monkeypatch):
     assert cb._prefill.compile_counts == {(1, 640): 1}
     assert cb.stats["prompt_tokens"] == 600
     assert cb.stats["padded_tokens"] == 640
-    monkeypatch.setattr(embedders, "bucket_len", lambda longest, cap: cap)
+    monkeypatch.setattr(device_plane, "bucket_len", lambda longest, cap: cap)
     at_cap, cb = run()
     assert widths[1:] == [(600, 1096, 496)]
     assert cb._prefill.compile_counts == {(1, 1096): 1}
@@ -324,7 +351,7 @@ def test_construction_loads_the_step_program_and_counts_no_step():
 def test_submit_racing_the_construction_is_answered_by_the_same_thread():
     """No second thread and no second lease, wherever the construction's
     pass stands when the request comes in."""
-    wa = _chat(continuous_batching=False)._generate_batch(["a b c"])
+    wa = _chat()._generate_batch(["a b c"])
     for _ in range(4):
         chat = _cb_chat()
         cb = chat._cb
@@ -426,9 +453,7 @@ def test_a_boundary_runs_one_prefill_and_slots_finish_a_step_apart(
     slots filled from one queue finish at different steps, and the tokens
     are those of the wave-aligned path."""
     new = 6
-    chat = _chat(
-        continuous_batching=True, decode_slots=slots, max_new_tokens=new
-    )
+    chat = _chat(decode_slots=slots, max_new_tokens=new)
     cb = chat._cb
     cb.drain()  # the construction's own pass
     events: list[str] = []
@@ -456,8 +481,7 @@ def test_a_boundary_runs_one_prefill_and_slots_finish_a_step_apart(
         cb._start_thread()
     got = [f.result(timeout=120) for f in futs]
     cb.drain()
-    wa = _chat(continuous_batching=False, max_new_tokens=new)
-    assert got == wa._generate_batch(prompts)
+    assert got == chat._generate_batch(prompts)
     seq = "".join(events)
     assert seq.count("P") == requests and "PP" not in seq, seq
     first = min(slots, requests)

@@ -311,8 +311,8 @@ def test_an_experts_decoder_sends_its_counters_behind_the_tokens():
 
 
 def test_the_wave_aligned_path_serves_the_same_tokens():
-    """`generate_serving` (the fallback of the kill switch) runs the same
-    kinds: its tokens are the slot path's."""
+    """`generate_serving` (sampled generation's path and the slot path's
+    oracle) runs the same kinds: its tokens are the slot path's."""
     cfg = FAMILY.program_config(KEYS, jnp.float32)
     row = _prompt(21)
     _, toks = _served_logits(cfg, row, 32)

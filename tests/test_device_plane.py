@@ -383,18 +383,6 @@ def test_retraction_behind_inflight_wave_stays_consistent():
     assert sorted(live.values()) == [80]  # 7 inserted AND cleanly retracted
 
 
-def test_overlap_off_is_bit_identical(monkeypatch):
-    monkeypatch.setenv("PATHWAY_STAGE_OVERLAP", "0")
-    events: list = []
-    res = _overlap_pipeline(events)
-    seen: list = []
-    pw.io.subscribe(
-        res, on_change=lambda key, row, time, is_addition: seen.append(row["g"])
-    )
-    pw.run()
-    assert sorted(seen) == sorted(i * 10 + 1 for i in range(16))
-
-
 # ---------------------------------------------------------- batched UDFs
 
 

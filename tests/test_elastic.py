@@ -24,7 +24,6 @@ from __future__ import annotations
 
 import json
 import os
-import socket
 import subprocess
 import sys
 import textwrap
@@ -32,6 +31,8 @@ import threading
 import time
 
 import pytest
+
+from conftest import free_port_base
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -83,24 +84,6 @@ requires_elastic = pytest.mark.skipif(
 )
 
 
-def _free_port_base(n: int) -> int:
-    for _ in range(60):
-        with socket.socket() as s:
-            s.bind(("127.0.0.1", 0))
-            p = s.getsockname()[1]
-        ok = True
-        for i in range(n * n):
-            try:
-                with socket.socket() as s2:
-                    s2.bind(("127.0.0.1", p + i))
-            except OSError:
-                ok = False
-                break
-        if ok:
-            return p
-    raise RuntimeError("no contiguous port range free")
-
-
 def _consolidate(out_prefix: str, max_pids: int) -> dict:
     """Final table from the delivered add/remove stream, replayed in
     GLOBAL delivery order across all worker files."""
@@ -141,7 +124,7 @@ def _run_elastic(tmp_path, start_n: int, announce):
     pdir = str(tmp_path / "pstate")
     out = str(tmp_path / "deliveries")
     ready = str(tmp_path / "ready")
-    base = _free_port_base(max(start_n, start_n + 1))
+    base = free_port_base(max(start_n, start_n + 1))
     argv = [sys.executable, "-c", MESH_WORKER, pdir, out, ready,
             str(N_EVENTS)]
 
@@ -264,7 +247,7 @@ def test_elastic_join_matches_static_mesh(tmp_path):
     # static control: same workload, same width it STARTED at, no join
     sdir = tmp_path / "static"
     os.makedirs(sdir)
-    base = _free_port_base(2)
+    base = free_port_base(2)
     argv = [sys.executable, "-c", MESH_WORKER, str(sdir / "pstate"),
             str(sdir / "deliveries"), str(sdir / "ready"), str(N_EVENTS)]
     sres = run_supervised(

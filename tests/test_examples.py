@@ -3,11 +3,12 @@ would (subprocess, --once / live server) and its output checked."""
 
 import json
 import os
-import socket
 import subprocess
 import sys
 import time
 import urllib.request
+
+from conftest import free_port_base
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ENV = {**os.environ, "JAX_PLATFORMS": "cpu", "PYTHONPATH": REPO}
@@ -89,9 +90,7 @@ def test_adaptive_rag_example(tmp_path):
     (corpus / "shipping.txt").write_text(
         "Shipping: orders ship within 2 business days."
     )
-    with socket.socket() as s:
-        s.bind(("127.0.0.1", 0))
-        port = s.getsockname()[1]
+    port = free_port_base()
     # never PIPE a long-running server without draining: a filled pipe
     # buffer would block its writes and stall serving
     errlog = open(tmp_path / "server.err", "w+")

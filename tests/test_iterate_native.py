@@ -320,17 +320,9 @@ def test_pagerank_mesh_two_process_invariance(tmp_path):
     """PATHWAY_PROCESSES=2: the iterate scope runs whole on process 0
     behind exchange wires (protocol-5 zero-copy frames); the final state
     is byte-identical to the single-process run."""
-    import socket
+    from conftest import free_port_base
 
-    socks, ports = [], []
-    for _ in range(6):
-        s = socket.socket()
-        s.bind(("127.0.0.1", 0))
-        socks.append(s)
-        ports.append(s.getsockname()[1])
-    for s in socks:
-        s.close()
-    base = max(ports) + 1
+    base = free_port_base(2)
 
     single = _subprocess_form("pagerank", {})[1]
     out = str(tmp_path / "mesh_state.json")

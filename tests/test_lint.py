@@ -11,6 +11,8 @@ import os
 import subprocess
 import sys
 
+import pytest
+
 from pathway_tpu.analysis import lint
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -67,6 +69,30 @@ def lowering_helper():
     return os.environ.get("PATHWAY_FUSE", "1")
 """
     assert "env-hot-path" not in _rules(src)
+
+
+# switches whose code paths the chip's records decided and PR 33 deleted:
+# nothing may read them again, and no document may send a reader to them
+_DELETED_ENV = (
+    "PATHWAY_CONTINUOUS_BATCH", "PATHWAY_TPU_FUSED_ATTN",
+    "PATHWAY_STAGE_OVERLAP", "PATHWAY_MESH_SLOTS", "PATHWAY_MESH_BSP",
+)
+
+
+@pytest.mark.parametrize("name", _DELETED_ENV)
+def test_deleted_env_name_is_named_nowhere(name):
+    hits = []
+    for top in ("pathway_tpu", "scripts", "docs"):
+        for folder, dirs, files in os.walk(os.path.join(REPO, top)):
+            dirs[:] = [d for d in dirs if d != "__pycache__"]
+            for f in files:
+                if f.endswith((".so", ".o", ".pyc")):
+                    continue
+                path = os.path.join(folder, f)
+                with open(path, encoding="utf-8", errors="replace") as fh:
+                    if name in fh.read():
+                        hits.append(os.path.relpath(path, REPO))
+    assert not hits, f"{name} is back in {hits}"
 
 
 # ------------------------------------------------- swallowed-io-error

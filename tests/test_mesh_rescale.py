@@ -5,11 +5,12 @@ aggregates. (tests/test_multiprocess.py covers each alone.)"""
 
 import json
 import os
-import socket
 import subprocess
 import sys
 import textwrap
 import time
+
+from conftest import free_port_base
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -48,29 +49,11 @@ SCRIPT = textwrap.dedent(
 )
 
 
-def _free_port_base(n: int) -> int:
-    for _ in range(60):
-        with socket.socket() as s:
-            s.bind(("127.0.0.1", 0))
-            p = s.getsockname()[1]
-        ok = True
-        for i in range(n * n):
-            try:
-                with socket.socket() as s2:
-                    s2.bind(("127.0.0.1", p + i))
-            except OSError:
-                ok = False
-                break
-        if ok:
-            return p
-    raise RuntimeError("no contiguous port range free")
-
-
 def test_mesh_crash_resume_with_different_thread_count(tmp_path):
     pdir = str(tmp_path / "pstate")
     out = str(tmp_path / "deliveries")
     ready = str(tmp_path / "ready")
-    base = _free_port_base(2)
+    base = free_port_base(2)
 
     def launch(threads: int):
         procs = []

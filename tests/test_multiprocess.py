@@ -13,25 +13,13 @@ from __future__ import annotations
 
 import json
 import os
-import socket
 import subprocess
 import sys
 import textwrap
 
+from conftest import free_port_base
+
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-
-def _free_port_base(n: int) -> int:
-    socks = []
-    ports = []
-    for _ in range(n + 4):
-        s = socket.socket()
-        s.bind(("127.0.0.1", 0))
-        socks.append(s)
-        ports.append(s.getsockname()[1])
-    for s in socks:
-        s.close()
-    return max(ports) + 1  # a fresh contiguous-ish range
 
 
 SCRIPT = textwrap.dedent(
@@ -79,7 +67,7 @@ SCRIPT = textwrap.dedent(
 
 def test_two_processes_cooperate_exact_results(tmp_path):
     out = str(tmp_path / "out.json")
-    base = _free_port_base(2)
+    base = free_port_base(2)
     procs = []
     for pid in range(2):
         env = {
@@ -129,7 +117,7 @@ def test_processes_times_threads(tmp_path):
     """2 processes x 2 thread shards: the exchanges compose — exact
     results with state partitioned at both levels."""
     out = str(tmp_path / "out.json")
-    base = _free_port_base(2)
+    base = free_port_base(2)
     procs = []
     for pid in range(2):
         env = {
@@ -161,7 +149,7 @@ def test_processes_times_threads(tmp_path):
 def test_spawn_cli_contract(tmp_path):
     """`python -m pathway_tpu spawn -n 2` launches cooperating processes."""
     out = str(tmp_path / "out.json")
-    base = _free_port_base(2)
+    base = free_port_base(2)
     script = tmp_path / "pipeline.py"
     script.write_text(SCRIPT.format(repo=REPO).replace("sys.argv[1]", repr(out)))
     r = subprocess.run(
@@ -211,7 +199,7 @@ def test_iterate_under_two_processes(tmp_path):
     """pw.iterate pins its body to process 0; the other process must not
     deadlock on phantom exchange barriers inside the loop."""
     out = str(tmp_path / "it.json")
-    base = _free_port_base(2)
+    base = free_port_base(2)
     procs = []
     for pid in range(2):
         env = {
@@ -263,7 +251,7 @@ def test_worker_failure_detected_not_hung(tmp_path):
     """Killing one process mid-run must surface a clear peer-death error
     on the survivor (failure detection), never an indefinite hang."""
     ready = str(tmp_path / "ready")
-    base = _free_port_base(2)
+    base = free_port_base(2)
     procs = []
     for pid in range(2):
         env = {
@@ -339,7 +327,7 @@ def test_multiprocess_kill_both_and_resume_exact(tmp_path):
     pdir = str(tmp_path / "pstate")
     out = str(tmp_path / "deliveries")
     ready = str(tmp_path / "ready")
-    base = _free_port_base(2)
+    base = free_port_base(2)
 
     def launch():
         procs = []
@@ -489,7 +477,7 @@ def test_mesh_kill9_coordinated_recovery(tmp_path):
     — coordinated min-epoch recovery yields EXACT aggregates."""
     out = str(tmp_path / "deliv")
     pdir = str(tmp_path / "pstorage")
-    base = _free_port_base(2)
+    base = free_port_base(2)
 
     procs = _spawn_mesh(out, pdir, "crash", base)
     # process 1 self-kills (os._exit(9)) after both epochs commit
@@ -508,7 +496,7 @@ def test_mesh_kill9_coordinated_recovery(tmp_path):
         procs[0].wait()
 
     # restart the whole mesh on fresh ports, same persistence roots
-    base2 = _free_port_base(2)
+    base2 = free_port_base(2)
     procs2 = _spawn_mesh(out, pdir, "finish", base2)
     for p in procs2:
         try:
@@ -572,7 +560,7 @@ def test_native_batches_cross_process_wire(tmp_path):
         for i in range(900):
             f.write('{"word": "w%d"}\n' % (i % 6))
     out = str(tmp_path / "out")
-    base = _free_port_base(2)
+    base = free_port_base(2)
     procs = []
     for pid in range(2):
         env = {

@@ -56,11 +56,9 @@ def test_metrics_server_openmetrics_format():
     session = Session()
     t = pw.debug.table_from_rows(pw.schema_from_types(v=int), [(1,), (2,)])
     cap = session.capture(t.reduce(n=pw.reducers.count()))
-    import socket
+    from conftest import free_port_base
 
-    with socket.socket() as sock:
-        sock.bind(("127.0.0.1", 0))
-        port = sock.getsockname()[1]
+    port = free_port_base()
     start_metrics_server(session, port=port)  # daemon thread
     session.execute()
     deadline = 20

@@ -9,7 +9,6 @@ from __future__ import annotations
 import json
 import os
 import re
-import socket
 import subprocess
 import sys
 import textwrap
@@ -23,6 +22,8 @@ from pathway_tpu.engine import faults
 from pathway_tpu.internals import observability as obs
 from pathway_tpu.internals.parse_graph import G
 
+from conftest import free_port_base
+
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
@@ -33,24 +34,6 @@ def _fresh_graph_and_plane():
     obs.disable()
     faults.reset()
     G.clear()
-
-
-def _free_port() -> int:
-    with socket.socket() as sock:
-        sock.bind(("127.0.0.1", 0))
-        return sock.getsockname()[1]
-
-
-def _free_port_base(n: int) -> int:
-    socks, ports = [], []
-    for _ in range(n + 4):
-        s = socket.socket()
-        s.bind(("127.0.0.1", 0))
-        socks.append(s)
-        ports.append(s.getsockname()[1])
-    for s in socks:
-        s.close()
-    return max(ports) + 1
 
 
 def _run_small_pipeline() -> list[dict]:
@@ -196,7 +179,7 @@ def test_metrics_endpoint_full_scrape_parses_against_grammar():
         pw.schema_from_types(g=str, v=int), [("a", 1), ("b", 2)]
     )
     session.capture(t.groupby(t.g).reduce(t.g, n=pw.reducers.count()))
-    port = _free_port()
+    port = free_port_base()
     start_metrics_server(session, port=port)
     session.execute()
     body = ""
@@ -251,7 +234,7 @@ def test_statistics_json_route_and_404():
         pw.schema_from_types(g=str, v=int), [("a", 1), ("a", 2), ("b", 3)]
     )
     session.capture(t.groupby(t.g).reduce(t.g, n=pw.reducers.count()))
-    port = _free_port()
+    port = free_port_base()
     start_metrics_server(session, port=port)
     session.execute()
     stats = None
@@ -509,7 +492,7 @@ def test_mesh_frames_carry_trace_context(tmp_path):
     """Data frames crossing the process mesh are tagged with trace
     context; joining both workers' dumps on (run, seq) reconstructs the
     cross-worker wave path."""
-    base = _free_port_base(2)
+    base = free_port_base(2)
     flight = {p: str(tmp_path / f"flight{p}") for p in range(2)}
     procs = []
     for pid in range(2):

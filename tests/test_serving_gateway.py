@@ -28,6 +28,8 @@ import requests
 import pathway_tpu as pw
 from pathway_tpu.internals import observability as obs
 from pathway_tpu.internals import run as run_mod
+
+from conftest import free_port_base
 from pathway_tpu.serving import (
     AdmissionController,
     ServingGateway,
@@ -40,12 +42,6 @@ from pathway_tpu.serving import (
 def _teardown_plane():
     yield
     obs.disable()
-
-
-def _free_port() -> int:
-    with socket.socket() as sock:
-        sock.bind(("127.0.0.1", 0))
-        return sock.getsockname()[1]
 
 
 # -------------------------------------------------------- admission units
@@ -185,7 +181,7 @@ def test_gateway_backpressure_sheds_with_reason():
 def _serving(writer_fn, gateway=None, timeout_s: float = 20.0, **rest_kw):
     """rest_connector + pipeline on a background pw.run; yields the port.
     Stops the run and the webserver on exit."""
-    port = _free_port()
+    port = free_port_base()
     ws = pw.io.http.PathwayWebserver(host="127.0.0.1", port=port)
     queries, writer = pw.io.http.rest_connector(
         webserver=ws,
@@ -297,7 +293,7 @@ def test_rest_gateway_sheds_with_429_and_retry_after():
 
 
 def test_rest_503_before_pipeline_runs():
-    port = _free_port()
+    port = free_port_base()
     ws = pw.io.http.PathwayWebserver(host="127.0.0.1", port=port)
     pw.io.http.rest_connector(
         webserver=ws, route="/q",
@@ -332,7 +328,7 @@ def test_rest_504_when_the_pipeline_never_answers():
 
 
 def test_webserver_bind_error_surfaces_to_the_caller():
-    port = _free_port()
+    port = free_port_base()
     blocker = socket.socket()
     blocker.bind(("127.0.0.1", port))
     blocker.listen(1)
@@ -347,7 +343,7 @@ def test_webserver_bind_error_surfaces_to_the_caller():
 
 
 def test_webserver_stop_releases_the_port():
-    port = _free_port()
+    port = free_port_base()
     ws = pw.io.http.PathwayWebserver(host="127.0.0.1", port=port)
     ws.start()
     ws.stop()
@@ -422,7 +418,7 @@ def test_http_read_failures_ride_the_retry_policy():
     from pathway_tpu.io._retry import RetryPolicy
     from tests.utils import run_capture
 
-    dead_port = _free_port()  # nothing listens here
+    dead_port = free_port_base()  # nothing listens here
     policy = RetryPolicy(
         "http.read:test", max_attempts=3, initial_delay_ms=1,
         jitter_ms=0, breaker_threshold=None,
@@ -443,7 +439,7 @@ def test_http_read_failures_ride_the_retry_policy():
 def test_http_read_breaker_opens_under_streaming_failures():
     from pathway_tpu.io._retry import RetryPolicy
 
-    dead_port = _free_port()
+    dead_port = free_port_base()
     policy = RetryPolicy(
         "http.read:breaker", max_attempts=1, initial_delay_ms=1,
         jitter_ms=0, breaker_threshold=2, breaker_reset_ms=60_000,

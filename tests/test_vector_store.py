@@ -152,13 +152,12 @@ def test_slides_redaction_does_not_mutate_store():
 def test_slides_redaction_served_over_rest():
     """run_server must register the SUBCLASS endpoints — the redacted
     inputs listing is what REST clients get."""
-    import socket
     import threading
     import time
 
-    with socket.socket() as s:
-        s.bind(("127.0.0.1", 0))
-        port = s.getsockname()[1]
+    from conftest import free_port_base
+
+    port = free_port_base()
     server = SlidesVectorStoreServer(
         _docs(), embedder=lambda x: fake_embeddings_model(x, DIM)
     )

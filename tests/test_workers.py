@@ -258,7 +258,7 @@ def test_async_udf_memo_and_invariance():
 def test_groupby_invariance_parallel_shards_large_stream():
     """Sharded native aggregation stays correct under a bigger stream
     (worker-count INVARIANCE at volume — engine throughput itself is
-    measured by bench.py's wordcount/join configs, not asserted here)."""
+    not asserted here)."""
     import random
 
     rng = random.Random(7)
