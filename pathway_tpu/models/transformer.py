@@ -18,8 +18,8 @@ Design notes (TPU-first):
 - `remat` wraps each block for the train step: activations are
   rematerialized in backward, trading MXU flops for HBM — the standard
   memory lever on TPU.
-- The causal decode path keeps a KV cache laid out [layers, B, S, H, Dh]
-  sharded on heads, so generation is also tensor-parallel.
+- The causal decode path keeps a KV cache laid out head-major,
+  [layers, B, kv heads, S, Dh] (the decoding section says why).
 - The decoder is a list of layers (`LayerSpec`): attention over every
   earlier position or over a window whose cache rows are a ring, learned,
   rotary or no positions, a dense GELU feed-forward or routed ReGLU
@@ -538,15 +538,24 @@ def make_train_step(cfg: TransformerConfig, learning_rate: float = 1e-3):
 # position.
 #
 # The cache is a dict of stacked leaves, a pair for each attention kind the
-# list holds: "k"/"v" [global layers, slots, max_len, kv heads, head] grow
-# with the sequence; "k_win"/"v_win" [window layers, slots, W, kv heads,
-# head] are rings: physical position t lives in row t mod W, so a window
-# layer's rows stop growing at W. Physical positions count the left pad
-# too; logical ones (physical less the pad) are what rotary turns by.
+# list holds, laid out head-major: "k"/"v" [global layers, slots, kv heads,
+# max_len, head] grow with the sequence; "k_win"/"v_win" [window layers,
+# slots, kv heads, W, head] are rings: physical position t lives in row
+# t mod W, so a window layer's rows stop growing at W. Physical positions
+# count the left pad too; logical ones (physical less the pad) are what
+# rotary turns by. Head-major, because a head's rows are then whole
+# (rows, head) tiles whatever the number of heads: the step's kernel
+# fetches blocks of them out of the leaf itself, and where the heads are
+# narrower than a lane tile (64) the rows are what fills the other axis,
+# not 25 heads padded to 32.
 #
-# A prefill's attention over the whole prompt runs ops/attention.py's kernel
-# where `prefill_uses_kernel` says so, and the plain `_attend` (the decode
-# step's attention) everywhere else.
+# Two attentions read it, each where a rule on what the code can see says
+# so and nothing else does. A prefill's, over the whole prompt:
+# ops/attention.py `prefill_attention` where `prefill_uses_kernel` holds.
+# A step's, one query a slot: ops/attention.py `decode_attention` where
+# `step_uses_kernel` holds; it takes the stacked leaf as its operand and
+# fetches only the tiles that hold a live row of the slot. Everywhere else
+# (the CPU, heads of 64, tensor-parallel parameters) the plain `_attend`.
 
 # what an experts decoder's two programs append to the tokens they return,
 # in this order (ContinuousBatcher adds them into its `stats`)
@@ -557,14 +566,13 @@ STEP_COUNTERS = ("experts_touched", "moe_layers_run")
 def init_kv_cache(cfg: TransformerConfig, batch: int) -> Params:
     specs = cfg.layer_specs
     n_win = sum(sp.window is not None for sp in specs)
-    row = (cfg.kv_heads, cfg.head_dim)
-    shape = (len(specs) - n_win, batch, cfg.max_len, *row)
+    shape = (len(specs) - n_win, batch, cfg.kv_heads, cfg.max_len, cfg.head_dim)
     cache = {
         "k": jnp.zeros(shape, cfg.dtype),
         "v": jnp.zeros(shape, cfg.dtype),
     }
     if n_win:
-        ring = (n_win, batch, cfg.window, *row)
+        ring = (n_win, batch, cfg.kv_heads, cfg.window, cfg.head_dim)
         cache["k_win"] = jnp.zeros(ring, cfg.dtype)
         cache["v_win"] = jnp.zeros(ring, cfg.dtype)
     return cache
@@ -612,21 +620,21 @@ def _rope(x: Array, pos: Array, cfg: TransformerConfig) -> Array:
 def _attend(q: Array, keys: Array, vals: Array, ok: Array,
             cfg: TransformerConfig) -> Array:
     """softmax(q k^T / sqrt(dh)) v over the keys `ok` [b, 1, q, s] allows:
-    q [b, q, heads, dh], keys and vals [b, s, kv heads, dh] -> [b, q,
-    heads * dh]. Query heads that share a key head read it where it lies:
-    no key or value is repeated in memory."""
+    q [b, q, heads, dh], keys and vals [b, kv heads, s, dh] (the cache's
+    layout) -> [b, q, heads * dh]. Query heads that share a key head read
+    it where it lies: no key or value is repeated in memory."""
     b, nq, h, dh = q.shape
-    hk = keys.shape[2]
+    hk = keys.shape[1]
     scores = jnp.einsum(
-        "bqkgd,bskd->bkgqs", q.reshape(b, nq, hk, h // hk, dh), keys,
+        "bqkgd,bksd->bkgqs", q.reshape(b, nq, hk, h // hk, dh), keys,
         preferred_element_type=jnp.float32,
     ) / math.sqrt(dh)
     scores = jnp.where(ok[:, :, None], scores, -1e30)
     probs = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)
     ctx = jnp.einsum(
-        "bkgqs,bskd->bqkgd", probs, vals, preferred_element_type=jnp.float32
+        "bkgqs,bksd->bkgqd", probs, vals, preferred_element_type=jnp.float32
     )
-    return ctx.astype(cfg.dtype).reshape(b, nq, h * dh)
+    return ctx.astype(cfg.dtype).transpose(0, 3, 1, 2, 4).reshape(b, nq, h * dh)
 
 
 def _route(x: Array, block: Params, cfg: TransformerConfig):
@@ -730,26 +738,40 @@ def _step_rows(
     logical = (pos - pad_len)[:, None]
     if cfg.learned_positions:
         x = x + params["pos_embed"].astype(cfg.dtype)[pos - pad_len][:, None, :]
-    at = jnp.arange(cfg.max_len)[None, :]
-    kmask = ((at <= pos[:, None]) & (at >= pad_len[:, None]))[:, None, None, :]
-    if cfg.window is not None:
-        # ring row j holds the newest physical position <= pos that is j
-        # modulo W: before the pad (or before the sequence) it is no key
-        ring = jnp.arange(cfg.window)[None, :]
-        held = pos[:, None] - (pos[:, None] - ring) % cfg.window
-        wmask = (held >= pad_len[:, None])[:, None, None, :]
-    rows = jnp.arange(b)
+    kernel = step_uses_kernel(cfg)
+    if not kernel:
+        at = jnp.arange(cfg.max_len)[None, :]
+        kmask = ((at <= pos[:, None]) & (at >= pad_len[:, None]))[:, None, None, :]
+        if cfg.window is not None:
+            # ring row j holds the newest physical position <= pos that is
+            # j modulo W: before the pad (or before the sequence) it is no key
+            ring = jnp.arange(cfg.window)[None, :]
+            held = pos[:, None] - (pos[:, None] - ring) % cfg.window
+            wmask = (held >= pad_len[:, None])[:, None, None, :]
+        rows, heads = jnp.arange(b)[:, None], jnp.arange(cfg.kv_heads)[None, :]
     live = (pos > 0)[:, None]  # a free slot's vectors are zeros
     counters: list[Array] = []
     for (kname, vname, li), spec, block in zip(
         _cache_rows(cfg), cfg.layer_specs, params["blocks"]
     ):
         def attend(q, k, v, kname=kname, vname=vname, li=li, spec=spec):
-            at_row = pos if spec.window is None else pos % cfg.window
-            with jax.named_scope("cache_write"):
-                cache[kname] = cache[kname].at[li, rows, at_row].set(k[:, 0])
-                cache[vname] = cache[vname].at[li, rows, at_row].set(v[:, 0])
             kind = "attn_global" if spec.window is None else "attn_window"
+            if kernel:
+                # imported where it is traced: Pallas loads when a
+                # program first needs it
+                from pathway_tpu.ops.attention import decode_attention
+
+                with jax.named_scope("attn"), jax.named_scope(kind):
+                    ctx, cache[kname], cache[vname] = decode_attention(
+                        q[:, 0], k[:, 0], v[:, 0], cache[kname], cache[vname],
+                        li, pos, pad_len,
+                    )
+                return ctx[:, None]
+            at_row = (pos if spec.window is None else pos % cfg.window)[:, None]
+            with jax.named_scope("cache_write"):
+                # a head's row at a time, which is what lies together
+                cache[kname] = cache[kname].at[li, rows, heads, at_row].set(k[:, 0])
+                cache[vname] = cache[vname].at[li, rows, heads, at_row].set(v[:, 0])
             with jax.named_scope("attn"), jax.named_scope(kind):
                 return _attend(
                     q, cache[kname][li], cache[vname][li],
@@ -781,6 +803,22 @@ def decode_step(
         params, cache, token, jnp.full((b,), pos, jnp.int32), pad, cfg
     )
     return lg, cache
+
+
+def step_uses_kernel(cfg: TransformerConfig) -> bool:
+    """Whether a step's attention, one query a slot, runs ops/attention.py
+    `decode_attention` (the stacked cache leaf read in place, only the
+    tiles that hold a live row fetched) and not the plain `_attend` over
+    every row the cache has room for: on a TPU, with heads of a multiple
+    of 128 lanes. Read from the shapes and from where the process runs,
+    as `prefill_uses_kernel`; nothing sets it, and `fused_attention` off
+    keeps tensor-parallel parameters and a slot axis sharded over a mesh
+    (the kernel has no partitioning rule) on `_attend`."""
+    return (
+        cfg.fused_attention
+        and jax.default_backend() == "tpu"
+        and cfg.head_dim % 128 == 0
+    )
 
 
 def prefill_uses_kernel(cfg: TransformerConfig, width: int) -> bool:
@@ -832,14 +870,16 @@ def _prefill(
         _cache_rows(cfg), cfg.layer_specs, params["blocks"]
     ):
         def attend(q, k, v, kname=kname, vname=vname, li=li, spec=spec):
-            kept_k, kept_v = k, v
-            if spec.window is not None and p > window:
-                # a prompt longer than the window leaves its last W keys,
-                # each in the ring's row of its physical position
-                turn = (p - window) % window
-                kept_k = jnp.roll(k[:, p - window:], turn, axis=1)
-                kept_v = jnp.roll(v[:, p - window:], turn, axis=1)
             with jax.named_scope("cache_write"):
+                # head-major, as the cache lies
+                kept_k = kt = k.transpose(0, 2, 1, 3)
+                kept_v = vt = v.transpose(0, 2, 1, 3)
+                if spec.window is not None and p > window:
+                    # a prompt longer than the window leaves its last W
+                    # keys, each in the ring's row of its physical position
+                    turn = (p - window) % window
+                    kept_k = jnp.roll(kt[:, :, p - window:], turn, axis=2)
+                    kept_v = jnp.roll(vt[:, :, p - window:], turn, axis=2)
                 cache[kname] = jax.lax.dynamic_update_slice(
                     cache[kname], kept_k[None], (li, 0, 0, 0, 0)
                 )
@@ -858,7 +898,7 @@ def _prefill(
                         window if banded and spec.window is not None else None,
                     )
                 return _attend(
-                    q, k, v, mask if spec.window is None else wmask, cfg
+                    q, kt, vt, mask if spec.window is None else wmask, cfg
                 )
 
         x = _layer(x, block, spec, cfg, pos_idx, live, attend, counters)

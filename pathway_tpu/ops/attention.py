@@ -19,6 +19,13 @@ streaming softmax so that no score reaches HBM, and skipping by whole
 tiles what the causal order, the window and the padding rule out.
 models/transformer.py `prefill_uses_kernel` says which prefills run it.
 
+`decode_attention` is the decode step's: one query a slot over the slot
+cache, whose stacked leaf ([layers, slots, kv heads, rows, head]) is the
+kernel's operand and result as it lies. It writes the step's own row and
+fetches only the tiles that hold a live row of the slot, so a step reads
+what its slots have decoded, not what the cache has room for.
+models/transformer.py `step_uses_kernel` says which steps run it.
+
 Reference parity: replaces the torch SDPA used by the reference's local
 embedding models (`/root/reference/python/pathway/xpacks/llm/embedders.py:270`
 runs SentenceTransformer → torch attention); this is the TPU-native
@@ -366,6 +373,242 @@ def prefill_attention(
         valid.reshape(b, 1, p),
     )
     return out[:, :p0] if extra else out
+
+
+# ------------------------------------------------- decode-step attention
+#
+# A step's query is one token a slot, so its attention is a read of the
+# slot cache and little else. Written in XLA it reads every row the cache
+# has room for, and copies a layer's rows out of the stacked leaf first
+# where the heads are grouped (PERF.md section 5). The kernel below takes
+# the stacked leaf as it lies, fetches the tiles that hold a live row, and
+# writes the step's own row into the leaf on its way.
+
+_DECODE_BLOCK = 1 << 20  # bytes of keys (and of values) a grid step fetches
+
+
+def decode_block(rows: int, kv_heads: int, dh: int, itemsize: int = 2
+                 ) -> tuple[int, int]:
+    """The block of `decode_attention`: (key heads, rows) a grid step
+    fetches of a leaf that keeps `rows` rows a slot and head. A function
+    of the shapes alone: about a megabyte of keys, so that the step's
+    fixed cost (about 0.35 us) is small beside the fetch, made of as many
+    heads and as few rows as that allows, because rows are what the tile
+    of the last live row wastes: (32, 128) at rag-cerebras-6b7's (32 key
+    heads of 128, 2,048 rows), (4, 1024) at rag-smallthinker-21b-a3b's
+    (4 key heads; 16,384 rows and the ring's 4,096 alike). On the chip
+    (PERF.md, PR 34) these fetch at 85% and 82-84% of the HBM bandwidth;
+    (16, 256), (8, 512) and (4, 512), (4, 2048) were no faster."""
+    t = min(rows, 128)
+    hb = kv_heads
+    while hb > 1 and (kv_heads % hb or hb * t * dh * itemsize > _DECODE_BLOCK):
+        hb -= 1
+    if hb == kv_heads:
+        while 2 * t <= rows and 2 * hb * t * dh * itemsize <= _DECODE_BLOCK:
+            t *= 2
+    return hb, t
+
+
+def _written_rows(t: int, itemsize: int) -> int:
+    """Rows of the block the step's own row is written back in: the rows
+    that share a packed sublane tile (16 of bfloat16, 8 of float32), or
+    the whole tile where those do not divide it."""
+    packed = 8 * max(1, 4 // itemsize)
+    return packed if t % packed == 0 else t
+
+
+def _decode_tiles(pos, pad, t: int, rows: int):
+    """The first and the last tile of `t` rows that hold a live row of a
+    slot at physical position `pos` behind a left pad of `pad`: rows
+    pad .. pos until the ring has wrapped, every tile after."""
+    wrapped = pos >= rows
+    last = jax.lax.div(jnp.where(wrapped, rows - 1, pos), t)
+    first = jnp.where(wrapped, 0, jnp.minimum(jax.lax.div(pad, t), last))
+    return first, last
+
+
+def _decode_kernel(layer_ref, pos_ref, pad_ref, q_ref, kn_ref, vn_ref, k_ref,
+                   v_ref, o_ref, ko_ref, vo_ref, m_ref, l_ref, acc_ref,
+                   *, t: int, sub: int, rows: int, scale: float):
+    """One grid step (slot, block of key heads, kk-th needed key tile): the
+    slot's query heads [hb, group, dh] against a tile [hb, t, dh] of the
+    keys and values of the key heads they share."""
+    del layer_ref  # the index maps' business
+    si, kk = pl.program_id(0), pl.program_id(2)
+    pos, pad = pos_ref[si], pad_ref[si]
+    first, last = _decode_tiles(pos, pad, t, rows)
+    kt = first + kk
+    turn = jax.lax.rem(pos, rows)  # the row of the step's own key
+
+    @pl.when(kk == 0)
+    def _start():
+        # the step's own key is the first the softmax meets: it comes
+        # from the operands, for it is in no tile of the leaf yet
+        own = jnp.sum(
+            q_ref[...].astype(jnp.float32) * kn_ref[...].astype(jnp.float32),
+            axis=2, keepdims=True,
+        ) * scale  # [hb, group, 1]
+        m_ref[...] = jnp.broadcast_to(own, m_ref.shape)
+        l_ref[...] = jnp.ones(l_ref.shape, jnp.float32)
+        acc_ref[...] = jnp.broadcast_to(
+            vn_ref[...].astype(jnp.float32), acc_ref.shape
+        )
+
+    # past the last live tile the clamped tile is not run again
+    @pl.when(kt <= last)
+    def _fold():
+        k, v = k_ref[...], v_ref[...]
+        s = jax.lax.dot_general(
+            q_ref[...], k, (((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32,
+        ) * scale  # [hb, group, t]
+        # row j of the ring holds the newest position <= pos that is j
+        # modulo its length (`_step_rows`' own rule, without a vector
+        # remainder): a key unless that lies before the pad. The step's
+        # own row still holds what it held a ring ago
+        row = kt * t + jax.lax.broadcasted_iota(jnp.int32, (1, 1, t), 2)
+        held = pos - turn + row - jnp.where(row > turn, rows, 0)
+        ok = (held >= pad) & (row != turn)
+        if rows % t:
+            # the last tile hangs over the leaf's end: what was fetched
+            # from there is no key, and anything: 0 x NaN is no 0
+            ok = ok & (row < rows)
+            inside = kt * t + jax.lax.broadcasted_iota(
+                jnp.int32, (1, t, 1), 1
+            ) < rows
+            v = jnp.where(inside, v, jnp.zeros_like(v))
+        s = jnp.where(ok, s, _MASKED)
+        m_prev = m_ref[...]  # [hb, group, 128], every lane the same
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        e = jnp.exp(s - m_new[:, :, :1])
+        l_ref[...] = alpha * l_ref[...] + jnp.sum(e, axis=2, keepdims=True)
+        m_ref[...] = m_new
+        acc_ref[...] = acc_ref[...] * alpha[:, :, :1] + jax.lax.dot_general(
+            e.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32,
+        )
+
+    # the tile of the step's own row is always among the live ones: the
+    # few rows around it go back to the leaf with the new row among them
+    @pl.when(kt == jax.lax.div(turn, t))
+    def _write():
+        at = jax.lax.div(turn - kt * t, sub) * sub
+        mine = kt * t + at + jax.lax.broadcasted_iota(
+            jnp.int32, (1, sub, 1), 1
+        ) == turn
+        for new_ref, old_ref, out_ref in (
+            (kn_ref, k_ref, ko_ref), (vn_ref, v_ref, vo_ref)
+        ):
+            old = old_ref[:, pl.ds(pl.multiple_of(at, sub), sub), :]
+            out_ref[...] = jnp.where(mine, new_ref[...], old)
+
+    @pl.when(kk == pl.num_programs(2) - 1)
+    def _finish():
+        o_ref[...] = (acc_ref[...] / l_ref[...][:, :, :1]).astype(o_ref.dtype)
+
+
+# jitted for `prefill_attention`'s reason; the layer is an operand, not a
+# constant of the kernel, so that one lowering serves every layer of a leaf
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def decode_attention(
+    q: jax.Array,  # [slots, heads, dh]: one token a slot
+    k_new: jax.Array,  # [slots, kv heads, dh]: the token's key
+    v_new: jax.Array,  # [slots, kv heads, dh]: and value
+    k_cache: jax.Array,  # [layers, slots, kv heads, rows, dh]: a whole leaf
+    v_cache: jax.Array,  # the same
+    layer: jax.Array,  # scalar int32: the layer's index along the leaf
+    pos: jax.Array,  # [slots] int32: each slot's physical position
+    pad_len: jax.Array,  # [slots] int32: each slot's left pad
+    *,
+    interpret: bool = False,
+) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """One step of a layer's attention over the slot cache, read and
+    written where it lies: each slot's key and value go into row
+    `pos mod rows` of its slot, and its query attends the slot's live rows,
+    that one among them. The leaves are operands and results of one
+    buffer: the layer and the slot are picked by the index maps, nothing
+    is copied out of a leaf and nothing but the new rows goes in. Returns
+    (context [slots, heads * dh], k_cache, v_cache).
+
+    A leaf's rows are a ring of their own number: row j holds the newest
+    position <= pos that is j modulo `rows`, and is a key iff that
+    position is not before `pad_len`. A global layer's leaf has `max_len`
+    rows and never wraps, so its keys are rows pad_len .. pos; a window
+    layer's has W. Until a ring has wrapped only the tiles
+    pad_len // t .. pos // t are fetched: a grid step past the last names
+    the tile already in VMEM, so it fetches nothing and runs nothing (as
+    `prefill_attention` past the diagonal). A free slot (pos 0) writes and
+    attends its row 0.
+
+    Products in the cache's dtype with float32 accumulation, softmax in
+    float32, the weights cast to the cache's dtype before the second
+    product, as models/transformer.py `_attend` states them; the order of
+    the sums is another. The `heads / kv heads` query heads of a group
+    share each key tile. dh must be a multiple of 128 (a lane tile).
+    Grid (slots, kv heads / hb, tiles of t rows), (hb, t) from
+    `decode_block`."""
+    n, h, dh = q.shape
+    _, _, hk, rows, _ = k_cache.shape
+    group = h // hk
+    if dh % 128 or h % hk:
+        raise ValueError(f"decode_attention needs heads of a multiple of 128 "
+                         f"lanes in whole groups, got {h} x {dh} over {hk}")
+    hb, t = decode_block(rows, hk, dh, k_cache.dtype.itemsize)
+    sub = _written_rows(t, k_cache.dtype.itemsize)
+
+    def q_block(si, hi, kk, layer_ref, pos_ref, pad_ref):
+        return si, hi, 0, 0
+
+    def k_block(si, hi, kk, layer_ref, pos_ref, pad_ref):
+        first, last = _decode_tiles(pos_ref[si], pad_ref[si], t, rows)
+        return layer_ref[0], si, hi, jnp.minimum(first + kk, last), 0
+
+    def written_block(si, hi, kk, layer_ref, pos_ref, pad_ref):
+        return layer_ref[0], si, hi, jax.lax.div(jax.lax.rem(pos_ref[si], rows), sub), 0
+
+    leaf = jax.ShapeDtypeStruct(k_cache.shape, k_cache.dtype)
+    out, k_cache, v_cache = pl.pallas_call(
+        functools.partial(
+            _decode_kernel, t=t, sub=sub, rows=rows, scale=1.0 / math.sqrt(dh)
+        ),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(n, hk // hb, pl.cdiv(rows, t)),
+            in_specs=[
+                pl.BlockSpec((None, hb, group, dh), q_block),
+                pl.BlockSpec((None, hb, 1, dh), q_block),
+                pl.BlockSpec((None, hb, 1, dh), q_block),
+                pl.BlockSpec((None, None, hb, t, dh), k_block),
+                pl.BlockSpec((None, None, hb, t, dh), k_block),
+            ],
+            out_specs=[
+                pl.BlockSpec((None, hb, group, dh), q_block),
+                pl.BlockSpec((None, None, hb, sub, dh), written_block),
+                pl.BlockSpec((None, None, hb, sub, dh), written_block),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((hb, group, 128), jnp.float32),  # running maximum
+                pltpu.VMEM((hb, group, 128), jnp.float32),  # running sum
+                pltpu.VMEM((hb, group, dh), jnp.float32),  # accumulator
+            ],
+        ),
+        out_shape=[jax.ShapeDtypeStruct((n, hk, group, dh), q.dtype), leaf, leaf],
+        # the leaves are written in place (operands count the three
+        # prefetched scalars too)
+        input_output_aliases={6: 1, 7: 2},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+        ),
+        name="decode_attention",
+        interpret=interpret,
+    )(
+        jnp.asarray(layer, jnp.int32).reshape(1), pos.astype(jnp.int32),
+        pad_len.astype(jnp.int32), q.reshape(n, hk, group, dh),
+        k_new.reshape(n, hk, 1, dh), v_new.reshape(n, hk, 1, dh),
+        k_cache, v_cache,
+    )
+    return out.reshape(n, h * dh), k_cache, v_cache
 
 
 # --------------------------------------------------------- ring attention

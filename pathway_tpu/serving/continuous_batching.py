@@ -56,7 +56,9 @@ step stays ONE program (jit partitions the per-row vectors along the
 same axis); scheduling, admission, and the step-boundary protocol are
 unchanged, and per-request tokens are byte-identical to the
 single-device pool (the slot axis is batch — rows never read each
-other's slots).
+other's slots). The attention kernels have no partitioning rule, so a
+pool that spans a mesh runs its programs with ``fused_attention`` off
+(``TransformerConfig``: the plain attention, which jit partitions).
 
 **The scheduler times itself.** The decode loop is cut into contiguous
 leaf phases (:data:`PHASES`): each opens a profiler span (visible when a
@@ -79,7 +81,9 @@ the prompt arrives: no prefill program is loaded ahead of its first
 prompt. ``prompt_tokens`` and ``padded_tokens`` count what the admitted
 prompts held and the widths they ran at, ``kernel_prefills`` those whose
 attention kept its scores in VMEM (``transformer.prefill_uses_kernel`` of
-the width they ran at: the predicate the program itself branches on).
+the width they ran at: the predicate the program itself branches on),
+and ``kernel_steps`` the decode steps whose attention read the slot cache
+in place (``transformer.step_uses_kernel``, likewise).
 
 Decoding is temperature-0 (argmax) here; sampled generation keeps the
 wave-aligned path (a per-request RNG stream inside a shared step program
@@ -193,6 +197,7 @@ class ContinuousBatcher:
         name: str | None = None,
         mesh_span: bool = False,
     ):
+        import dataclasses
         import functools
 
         from pathway_tpu.engine.device_plane import get_device_plane
@@ -218,6 +223,8 @@ class ContinuousBatcher:
 
                 self.mesh = default_mesh(("data",))
                 n_slots = n_slots * self.mesh.shape["data"]
+                # a kernel cannot be partitioned along the slot axis
+                cfg = self.cfg = dataclasses.replace(cfg, fused_attention=False)
         self.n_slots = n_slots
         self.budget = cfg.max_len - n_steps
         self._plane = plane or get_device_plane()
@@ -253,6 +260,8 @@ class ContinuousBatcher:
             "prompt_tokens": 0, "padded_tokens": 0,
             # prefills whose attention ran ops/attention.py's kernel
             "kernel_prefills": 0,
+            # decode steps whose attention ran ops/attention.py's kernel
+            "kernel_steps": 0,
             "preload_s": 0.0,  # the step program's load at construction
             # what an experts decoder's programs count on the device and
             # send back behind their tokens (0 for any other block)
@@ -450,6 +459,9 @@ class ContinuousBatcher:
                     nxt = np.asarray(nxt)
                 with self._phase("account_s"):
                     self.stats["decode_steps"] += 1
+                    self.stats["kernel_steps"] += self._model.step_uses_kernel(
+                        self.cfg
+                    )
                     self._count(self._model.STEP_COUNTERS, nxt[self.n_slots:])
                     if _obs.PLANE is not None:
                         _obs.PLANE.metrics.counter(

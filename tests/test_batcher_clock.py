@@ -90,7 +90,7 @@ def test_stats_hold_every_key_from_construction():
     cb = _chat()._cb
     counts = {
         "submitted", "completed", "decode_steps", "prefills", "max_queue",
-        "prompt_tokens", "padded_tokens", "kernel_prefills",
+        "prompt_tokens", "padded_tokens", "kernel_prefills", "kernel_steps",
         # an experts decoder's device counters (0 for this block)
         "routed_pairs", "expert_load_max", "experts_touched", "moe_layers_run",
     }
@@ -449,6 +449,30 @@ def test_kernel_prefills_counts_what_the_predicate_says(monkeypatch, engages):
     assert cb.stats["kernel_prefills"] == (grown if engages else 0)
     if engages:
         assert seen == [(cb.cfg, 16)] * len(PROMPTS)
+
+
+@pytest.mark.parametrize("engages", [True, False])
+def test_kernel_steps_counts_what_the_predicate_says(monkeypatch, engages):
+    """`kernel_steps` is the model module's own predicate, the one
+    `_step_rows` branches on: with it patched true (after the step program
+    is traced, so that the CPU still runs it) the count equals
+    `decode_steps`; as it is off the TPU it stays 0."""
+    cb = _chat()._cb
+    _run(cb, ["warm up prompt"])
+    seen = []
+    if engages:
+        monkeypatch.setattr(
+            cb._model, "step_uses_kernel", lambda cfg: seen.append(cfg) or True
+        )
+    before = dict(cb.stats)
+    _run(cb)
+    grown = cb.stats["decode_steps"] - before["decode_steps"]
+    assert grown > 0
+    assert cb.stats["kernel_steps"] - before["kernel_steps"] == (
+        grown if engages else 0
+    )
+    if engages:
+        assert seen == [cb.cfg] * grown
 
 
 def test_benchmark_lists_prefill_pad_pct_in_the_batchers_layer():

@@ -58,16 +58,21 @@ def _prompt(length: int) -> list[int]:
     return np.random.default_rng(length).integers(2, 256, length).tolist()
 
 
+def _left_padded(row: list[int], width: int):
+    """The prompt at the end of a row `width` wide, and its mask."""
+    ids = np.zeros((1, width), np.int32)
+    mask = np.zeros((1, width), np.int32)
+    ids[0, width - len(row):], mask[0, width - len(row):] = row, 1
+    return ids, mask
+
+
 def _served_logits(cfg, row: list[int], width: int, slot: int = 1, slots: int = 3):
     """The program's logits at the prompt's last position and after each of
     N_STEPS greedy steps, through a slot cache: the prompt left-padded to
     `width`, prefilled into a scratch row, scattered into `slot`, decoded
     with the neighbouring slots free. Returns (logits, the row decoded)."""
     params = _params(kernel=cfg.head_dim == 128)
-    ids = np.zeros((1, width), np.int32)
-    mask = np.zeros((1, width), np.int32)
-    ids[0, width - len(row):] = row
-    mask[0, width - len(row):] = 1
+    ids, mask = _left_padded(row, width)
     lg, mini, _ = T._prefill(
         params, jnp.asarray(ids), T.init_kv_cache(cfg, 1), cfg, jnp.asarray(mask)
     )
@@ -131,6 +136,53 @@ def test_slot_cache_through_the_prefill_kernel_matches_the_plain_reference(
     assert np.abs(got - want).max() < 1e-4
 
 
+def _slot_tokens(cfg, rows: list[list[int]], width: int, n_steps: int):
+    """`prefill_into_slot` of each prompt into every other slot of a pool
+    (a free slot between two live ones), then `n_steps` `decode_step_slots`
+    over the pool: the tokens each live slot decoded."""
+    params = _params(kernel=True)
+    slots = 2 * len(rows) - 1
+    cache = T.init_kv_cache(cfg, slots)
+    tok, pos, pad = (np.zeros(slots, np.int32) for _ in range(3))
+    for i, row in enumerate(rows):
+        ids, mask = _left_padded(row, width)
+        first, cache = T.prefill_into_slot(
+            params, jnp.asarray(ids), jnp.asarray(mask), cache,
+            jnp.asarray(2 * i), cfg,
+        )
+        tok[2 * i], pos[2 * i], pad[2 * i] = int(first[0]), width, width - len(row)
+    step = jax.jit(functools.partial(T.decode_step_slots, cfg=cfg))
+    out = [tok.copy()]
+    for _ in range(n_steps):
+        nxt, cache = step(
+            params, cache, jnp.asarray(tok), jnp.asarray(pos), jnp.asarray(pad)
+        )
+        tok = np.where(pos > 0, np.asarray(nxt)[:slots], 0).astype(np.int32)
+        pos = np.where(pos > 0, pos + 1, 0).astype(np.int32)
+        out.append(tok.copy())
+    return np.stack(out)[:, ::2]
+
+
+def test_the_steps_through_the_decode_kernel_serve_the_plain_paths_tokens(
+    monkeypatch,
+):
+    """`decode_step_slots` with the rule saying kernel (as on a TPU;
+    interpreted here): both layer kinds' attention and row write through
+    ops/attention.py `decode_attention`, 32 steps behind two prefills, the
+    ring of 8 wrapping four times: the tokens of the plain path."""
+    cfg = FAMILY.program_config(KERNEL_KEYS, jnp.float32)
+    rows = [_prompt(100), _prompt(21)]
+    want = _slot_tokens(cfg, rows, 128, 32)
+    monkeypatch.setattr(T, "step_uses_kernel", lambda cfg: True)
+    monkeypatch.setattr(
+        A, "decode_attention",
+        functools.partial(A.decode_attention, interpret=True),
+    )
+    got = _slot_tokens(cfg, rows, 128, 32)
+    assert want.shape == (33, 2) and len(set(want[:, 0].tolist())) > 4
+    assert got.tolist() == want.tolist()
+
+
 @pytest.mark.parametrize("length, width", PROMPTS)
 def test_slot_cache_matches_the_plain_reference_in_bfloat16(length, width):
     """bf16 activations against the float32 reference. The tolerance of
@@ -143,10 +195,7 @@ def test_slot_cache_matches_the_plain_reference_in_bfloat16(length, width):
     # the reference is conditioned on the tokens the float32 program emits
     _, toks = _served_logits(FAMILY.program_config(KEYS, jnp.float32), row, width)
     params = _params()
-    ids = np.zeros((1, width), np.int32)
-    mask = np.zeros((1, width), np.int32)
-    ids[0, width - length:] = row
-    mask[0, width - length:] = 1
+    ids, mask = _left_padded(row, width)
     lg, cache, _ = T._prefill(
         params, jnp.asarray(ids), T.init_kv_cache(cfg, 1), cfg, jnp.asarray(mask)
     )
@@ -287,7 +336,7 @@ def test_a_block_without_experts_counts_nothing_and_returns_tokens_alone():
     zeros = jnp.zeros((2,), jnp.int32)
     nxt, cache = T.decode_step_slots(params, cache, zeros, zeros + 8, zeros, cfg)
     assert first.shape == (1,) and nxt.shape == (2,)
-    assert sorted(cache) == ["k", "v"] and cache["k"].shape == (1, 2, 64, 2, 8)
+    assert sorted(cache) == ["k", "v"] and cache["k"].shape == (1, 2, 2, 64, 8)
 
 
 def test_an_experts_decoder_sends_its_counters_behind_the_tokens():
@@ -299,8 +348,8 @@ def test_an_experts_decoder_sends_its_counters_behind_the_tokens():
     )
     assert first.shape == (1 + len(T.PREFILL_COUNTERS),)
     assert int(first[1]) == 8 * 2 * 8
-    assert cache["k"].shape == (2, 2, 64, 2, 16)  # global layers: every row
-    assert cache["k_win"].shape == (6, 2, WINDOW, 2, 16)  # window layers: a ring
+    assert cache["k"].shape == (2, 2, 2, 64, 16)  # global layers: every row
+    assert cache["k_win"].shape == (6, 2, 2, WINDOW, 16)  # window layers: a ring
     tok = jnp.asarray([0, int(first[0])], jnp.int32)
     nxt, _ = T.decode_step_slots(
         _params(), cache, tok, jnp.asarray([0, 8], jnp.int32),
@@ -316,9 +365,7 @@ def test_the_wave_aligned_path_serves_the_same_tokens():
     cfg = FAMILY.program_config(KEYS, jnp.float32)
     row = _prompt(21)
     _, toks = _served_logits(cfg, row, 32)
-    ids = np.zeros((1, 32), np.int32)
-    mask = np.zeros((1, 32), np.int32)
-    ids[0, 11:], mask[0, 11:] = row, 1
+    ids, mask = _left_padded(row, 32)
     out = T.generate(
         _params(), jnp.asarray(ids), N_STEPS, cfg, prompt_mask=jnp.asarray(mask)
     )

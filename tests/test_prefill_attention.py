@@ -45,6 +45,8 @@ def _plain(q, k, v, valid, window):
     if window is not None:
         at = jnp.arange(p)
         ok = ok & (at[None, :] > at[:, None] - window)[None, None]
+    # `_attend` reads keys as the cache lies: [b, kv heads, p, dh]
+    k, v = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
     return T._attend(q, k, v, ok, lm_config(dtype=q.dtype))
 
 

@@ -1,14 +1,17 @@
-"""The prefill's attention kernel (ops/attention.py `prefill_attention`)
-compiled for a described TPU v5e by the chip's own compiler, from this CPU
-host: Mosaic refuses what the interpreter lets pass (a slice off the
-tiling, too much VMEM). Compiles, never runs: no time or result comes from
-here. The topology is described inside a fixture, and in this file only:
-one process at a time may load the TPU's library.
+"""The decoder's attention kernels (ops/attention.py `prefill_attention`,
+`decode_attention`) and the programs that hold them, compiled for a
+described TPU v5e by the chip's own compiler, from this CPU host: Mosaic
+refuses what the interpreter lets pass (a slice off the tiling, too much
+VMEM), and the compiled text shows whether the slot cache stays where it
+lies. Compiles, never runs: no time or result comes from here. The
+topology is described inside a fixture, and in this file only: one process
+at a time may load the TPU's library.
 """
 
 from __future__ import annotations
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -17,7 +20,7 @@ from jax.sharding import SingleDeviceSharding
 
 from pathway_tpu.models import LayerSpec, lm_config
 from pathway_tpu.models import transformer as T
-from pathway_tpu.ops.attention import prefill_attention
+from pathway_tpu.ops.attention import decode_attention, prefill_attention
 
 
 @pytest.fixture(scope="module")
@@ -97,3 +100,95 @@ def test_a_prefill_the_rule_sends_to_the_kernel_holds_it_once_a_kind(
 def test_a_width_under_128_takes_the_plain_attention(chip, monkeypatch):
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     assert "tpu_custom_call" not in _prefill_lowered(64, chip)
+
+
+# the benchmark's two cells (a global leaf of rag-cerebras-6b7; a global
+# leaf and the ring of rag-smallthinker-21b-a3b, seven query heads a key
+# head), heads of 256 (chip_smoke.py's decoder), two slots in float32
+@pytest.mark.parametrize("layers, slots, heads, kv_heads, rows, dh, dtype", [
+    (16, 8, 32, 32, 2048, 128, jnp.bfloat16),
+    (2, 8, 28, 4, 16384, 128, jnp.bfloat16),
+    (6, 8, 28, 4, 4096, 128, jnp.bfloat16),
+    (18, 2, 8, 8, 1024, 256, jnp.bfloat16),
+    (1, 2, 4, 4, 320, 128, jnp.float32),
+])
+def test_the_decode_kernel_compiles_for_v5e(
+    layers, slots, heads, kv_heads, rows, dh, dtype, chip
+):
+    def arg(*shape, dt=dtype):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=chip)
+
+    leaf = arg(layers, slots, kv_heads, rows, dh)
+    compiled = jax.jit(decode_attention, donate_argnums=(3, 4)).lower(
+        arg(slots, heads, dh), arg(slots, kv_heads, dh), arg(slots, kv_heads, dh),
+        leaf, leaf, arg(dt=jnp.int32), arg(slots, dt=jnp.int32),
+        arg(slots, dt=jnp.int32),
+    ).compile()
+    text = compiled.as_text()
+    assert 'custom_call_target="tpu_custom_call"' in text
+    # the name a device trace shows (`decode_attention[tpu_custom_call]`)
+    assert "%decode_attention" in text
+    # the leaves are written in place: nothing as large as one is kept
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+# the two cells' decoders with the slot caches they serve ([16, 8, 32, 2048,
+# 128] x 2; [2, 8, 4, 16384, 128] x 2 with [6, 8, 4, 4096, 128] x 2), their
+# feed-forwards, experts and vocabularies cut small: the attention and the
+# cache are what is looked at
+CELLS = {
+    "rag-cerebras-6b7": dict(
+        vocab_size=512, d_model=4096, n_heads=32, n_layers=16, d_ff=256,
+        max_len=2048,
+    ),
+    "rag-smallthinker-21b-a3b": dict(
+        vocab_size=512, d_model=2560, n_heads=28, n_kv_heads=4, head_size=128,
+        n_layers=8, d_ff=128, max_len=16384, n_experts=8, n_active=2,
+        tie_embeddings=False, rope_theta=1.5e6,
+        layers=(
+            LayerSpec(pos="none", ff="experts"),
+            *(LayerSpec(window=4096, pos="rotary", ff="experts"),) * 3,
+        ) * 2,
+    ),
+}
+_RESULT = re.compile(r"= (\w+)\[([\d,]+)\]\S* ([\w\-]+)\(")
+
+
+@pytest.mark.parametrize("cell", sorted(CELLS))
+def test_a_cells_step_reads_and_writes_the_cache_where_it_lies(
+    cell, chip, monkeypatch
+):
+    """The step program at a cell's cache shapes, the cache donated: every
+    layer's attention is the kernel, and besides the kernel no operation
+    has a result as large as one layer of one leaf (no copy, no slice, no
+    transpose, no scatter over the leaf): the leaves go from the argument
+    through the kernels to the result."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = lm_config(dtype=jnp.bfloat16, **CELLS[cell])
+    slots = 8
+    shaped = lambda tree: jax.tree.map(  # noqa: E731
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip), tree
+    )
+    params = shaped(jax.eval_shape(
+        lambda: T.init_params(jax.random.PRNGKey(0), cfg, jnp.bfloat16)
+    ))
+    cache = shaped(jax.eval_shape(lambda: T.init_kv_cache(cfg, slots)))
+    vec = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=chip)
+    compiled = jax.jit(
+        functools.partial(T.decode_step_slots, cfg=cfg), donate_argnums=(1,)
+    ).lower(params, cache, vec, vec, vec).compile()
+    text = compiled.as_text()
+    assert text.count("%decode_attention") >= cfg.n_layers
+    layer_of_a_leaf = slots * cfg.kv_heads * (cfg.window or cfg.max_len) * cfg.head_dim
+    passed_on = {"parameter", "get-tuple-element", "bitcast"}
+    large = [
+        (op, dims) for _, dims, op in _RESULT.findall(text)
+        if op not in passed_on
+        and functools.reduce(int.__mul__, map(int, dims.split(","))) >= layer_of_a_leaf
+    ]
+    assert large == []
+    stats = compiled.memory_analysis()
+    assert stats.alias_size_in_bytes == sum(
+        x.size * x.dtype.itemsize for x in jax.tree.leaves(cache)
+    )
+    assert stats.temp_size_in_bytes < layer_of_a_leaf * 2  # bytes of one in bf16
