@@ -1,5 +1,7 @@
 """Model step: device time of the decode-step program per execution (ms),
-from the traced window's ``XLA Modules`` line."""
+from the traced window's ``XLA Modules`` line: the mean over the
+executions that lie whole inside the trace (one cut by the trace's first
+or last instant is left out by the reduction, ``trace_reduce``, ``cut``)."""
 
 
 def read(ctx):

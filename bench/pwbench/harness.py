@@ -13,7 +13,7 @@ from typing import Any, Callable
 
 import numpy as np
 
-from . import check, loadgen, spec, traffic, trace_reduce
+from . import check, compiles, loadgen, spec, traffic, trace_reduce
 from .server import Client, Rag, wait_until_indexed
 
 # the traced part of a --trace 1 run: this long, from a quarter into the
@@ -114,7 +114,6 @@ def _warm_up(rag: Rag, cell: spec.Cell, corpus: traffic.Corpus) -> dict:
         # the slab grows past its power of two with the first new
         # document, and its update program has a bucket per batch of
         # changed rows: both happen here, not in the window
-        client = Client(rag.port)
         # up to 256 rows at once: a stalled engine takes that many upserts
         # in one wave, and a wave's size picks the encode and update buckets
         bursts = [1, 8, 16, 32, 64, 128, 256]
@@ -126,12 +125,13 @@ def _warm_up(rag: Rag, cell: spec.Cell, corpus: traffic.Corpus) -> dict:
                 else:
                     docs.append((j, corpus.replace(j)))
             rag.source.put(docs)
-            wait_until_indexed(rag.port, len(corpus.texts), time.monotonic() + 120)
-            # a search applies the pending rows to the device slab
-            status, hits = client.post("/v1/retrieve", {"query": docs[-1][1], "k": k})
-            if status != 200:
-                raise RunFailed("warm-up retrieve after upserts failed")
-        client.close()
+            # the burst's last document comes back first: it is embedded,
+            # and the search that found it applied the pending rows to the
+            # device slab
+            wait_until_indexed(
+                rag, len(corpus.texts), time.monotonic() + 120,
+                probe=docs[-1][1], k=k,
+            )
         warmed["upsert_bursts"] = bursts
         mark("upserts")
     # the search program's buckets follow how many queries share an engine
@@ -167,6 +167,26 @@ def _burst(port: int, route: str, questions: list[dict], k: int) -> None:
     for rec in recs:
         if rec.status != 200:
             raise RunFailed(f"warm-up {route} failed: {rec.reply!r}")
+
+
+def _ingest(rag: Rag, corpus: traffic.Corpus,
+            wave_rows: int | None = None) -> list[int]:
+    """Feeds the corpus in waves of one fixed size, the encoder's largest
+    row bucket, each put whole (``CorpusSource``) and the next only when
+    the index holds the last: every encode call of every run is then the
+    same program, the last wave as the first, where one ``put`` of the
+    whole corpus was cut by timing into waves whose last had 77 to 2,833
+    rows and a row bucket, and so an encoder program, of its own (PERF.md,
+    Open question 14). Returns the rows of each encode call it caused."""
+    initial = sorted(corpus.texts.items())
+    wave = wave_rows or rag.wave_rows
+    seen = len(rag.encode_calls)
+    for i in range(0, len(initial), wave):
+        rag.source.put(initial[i:i + wave])
+        wait_until_indexed(
+            rag, min(i + wave, len(initial)), time.monotonic() + 900
+        )
+    return [call[0] for call in rag.encode_calls[seen:]]
 
 
 def _grown(before: dict, after: dict) -> dict:
@@ -222,6 +242,7 @@ def run_cell(
     both are for bench/tests and bench/control.py, and each has to come
     out with ``correct`` false."""
     cell = spec.Cell(bench_file, workload)
+    compile_log = compiles.CompileLog().install()  # before anything compiles
     devices = _device_check(cell.chips, require_tpu)
     dev = devices[0]
     peaks = spec.peaks(dev.device_kind) if dev.platform == "tpu" else None
@@ -254,14 +275,14 @@ def run_cell(
     with fault.program(cfg) if fault is not None else contextlib.nullcontext():
         rag = Rag(cfg, seed)
     t = phase("build", t)
+    rag.watch_encodes(True)
     rag.start()
-    initial = sorted(corpus.texts.items())
-    rag.source.put(initial)
-    wait_until_indexed(rag.port, len(initial), time.monotonic() + 900)
-    t = phase("ingest", t)
     try:
+        encode_waves = _ingest(rag, corpus)
+        t = phase("ingest", t)
         warmed = _warm_up(rag, cell, corpus)
         t = phase("warm_up", t)
+        rag.watch_encodes(False)  # the window runs the program's own flush
         if fault is not None:
             fault.served(rag)
         trace_dir = None
@@ -422,8 +443,22 @@ def run_cell(
     result["phases_s"] = phases
     result["counters"] = counters
     result["warmed"] = warmed
+    # the rows of each encode call of the ingest: the same list in every run
+    result["encode_waves"] = encode_waves
+    result["encode_wave_s"] = [  # the ingest's calls are the embedder's first
+        end - start for _rows, start, end, _thread in rag.encode_calls[:len(encode_waves)]
+    ]
+    # what XLA compiled, and did not load from the checkout's cache, between
+    # process start and the window: everything in a checkout's first run,
+    # nothing after it. It fails no run; it tells a draw from a regression
+    in_setup = compile_log.between(t_start, t0, phases, rag.encode_calls)
+    result["compiled_in_setup"] = in_setup["compiled"]
+    result["loaded_in_setup"] = in_setup["loaded"]
     if trace:
         result["end_to_end_in_traced_run"] = values
+        # executions the trace's edges cut, [program, seconds left of it]:
+        # in no program's or operation's sum (trace_reduce)
+        result["trace_cut"] = (traced or {}).get("cut_modules", [])
     if program_numbers is not None:
         # control mode: ``compared`` and ``correct`` are the control's;
         # what the program itself served, judged the same way, is here
@@ -473,7 +508,7 @@ def _check_guarantees(rag: Rag, corpus: traffic.Corpus, log: list, seed: int,
     back for its own text."""
     out = {"not_found": 0, "zombies": 0, "checked": 0}
     if log:
-        wait_until_indexed(rag.port, len(corpus.texts), time.monotonic() + 60)
+        wait_until_indexed(rag, len(corpus.texts), time.monotonic() + 60)
     rng = np.random.default_rng([int(seed), 61])
     live_ids = sorted({d for _t, d, _o, _n in log})
     picks = [live_ids[int(i)] for i in rng.permutation(len(live_ids))[:16]]

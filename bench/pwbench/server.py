@@ -76,9 +76,10 @@ class Rag:
         table = pw.io.python.read(
             self.source.subject, schema=DocSchema, name="bench-corpus"
         )
+        self.indexes: list[Any] = []  # the store's index, once the run builds it
         store = DocumentStore(
             table,
-            retriever_factory=BruteForceKnnFactory(
+            retriever_factory=_watched(BruteForceKnnFactory, self.indexes)(
                 dimensions=enc["embedding_size"], embedder=self.embedder
             ),
         )
@@ -87,6 +88,10 @@ class Rag:
         )
         self.port = free_port()
         self.thread: threading.Thread | None = None
+        # the encoder's largest row bucket: a wave of the ingest
+        self.wave_rows = int(self.embedder._batcher.max_batch)
+        # (rows, start, end, thread) of every encode call while set-up watches
+        self.encode_calls: list[tuple[int, float, float, int]] = []
 
     def start(self) -> None:
         self.thread = self.qa.run_server(
@@ -115,6 +120,30 @@ class Rag:
         self.batcher.params = None
         self.chat.params = None
         self.embedder.params = None
+
+    def indexed(self) -> int:
+        """Documents the store's index holds now: embedded, added, and
+        seen by the next search (``/v1/statistics`` counts the parsed)."""
+        return len(self.indexes[-1]) if self.indexes else 0
+
+    def watch_encodes(self, on: bool) -> None:
+        """While on, every call of the embedder's flush (the engine's
+        waves and the queries alike) is noted in ``encode_calls``. Set-up
+        watches; the window runs the program's own function."""
+        batcher = self.embedder._batcher
+        if not on:
+            batcher.flush_fn = self.embedder._encode_batch
+            return
+
+        def noted(texts: list[str]) -> list:
+            t = time.monotonic()
+            out = self.embedder._encode_batch(texts)
+            self.encode_calls.append(
+                (len(texts), t, time.monotonic(), threading.get_ident())
+            )
+            return out
+
+        batcher.flush_fn = noted
 
     def prompt_widths(self, lengths: list[int]) -> list[int]:
         """Runs prompts of that many tokens (the leading class token and
@@ -154,6 +183,33 @@ class Rag:
         }
 
 
+def _watched(factory_cls: Any, found: list) -> Any:
+    """The index factory, with the index it builds noted in ``found``:
+    the engine makes the index when the run starts and shows it nowhere,
+    and the harness waits for its count. The index is the program's own,
+    built by the program's own code."""
+
+    class WatchedFactory(factory_cls):
+        def build_inner_index(self, *args: Any, **kwargs: Any) -> Any:
+            inner = super().build_inner_index(*args, **kwargs)
+            make = inner._host_index_factory
+
+            def noting() -> Any:
+                build = make()
+
+                def built() -> Any:
+                    found.append(build())
+                    return found[-1]
+
+                return built
+
+            # a frozen dataclass: set as it sets its own cached column
+            object.__setattr__(inner, "_host_index_factory", noting)
+            return inner
+
+    return WatchedFactory
+
+
 def _log_slots(batcher: Any) -> dict[tuple, int]:
     """Prompt (its token ids) -> the slot the batcher admitted it into:
     the program keeps the slot on its request and shows it nowhere, so the
@@ -176,7 +232,13 @@ def _log_slots(batcher: Any) -> dict[tuple, int]:
 
 class CorpusSource:
     """Feeds documents to the engine; built before ``pw.io.python.read``
-    wraps it. ``put`` may be called from any thread at any time."""
+    wraps it. ``put`` may be called from any thread at any time, and what
+    one ``put`` holds reaches the engine whole: the engine's pump takes
+    whatever the session has staged each time it looks, and the program's
+    ``commit`` is a yield, so rows staged one by one were cut into waves
+    by timing (PERF.md, Open question 14). The pump's ``drain`` of this
+    session therefore waits while a ``put`` is being staged; the rows
+    still go in through the program's own ``next``."""
 
     def __init__(self) -> None:
         import pathway_tpu as pw
@@ -187,16 +249,26 @@ class CorpusSource:
 
         class Subject(pw.io.python.ConnectorSubject):
             def run(self) -> None:
+                session = self._session
+                whole = threading.Lock()
+                drain = session.drain
+
+                def drain_whole_puts() -> list:
+                    with whole:
+                        return drain()
+
+                session.drain = drain_whole_puts
                 while not outer._stop.is_set():
                     try:
                         batch = outer._queue.get(timeout=0.05)
                     except queue.Empty:
                         continue
-                    for doc_id, text in batch:
-                        self.next(
-                            doc_id=doc_id, data=text.encode(),
-                            _metadata={"path": f"p{doc_id}"},
-                        )
+                    with whole:
+                        for doc_id, text in batch:
+                            self.next(
+                                doc_id=doc_id, data=text.encode(),
+                                _metadata={"path": f"p{doc_id}"},
+                            )
                     self.commit()
 
         self.subject = Subject()
@@ -249,20 +321,34 @@ class Client:
             self.conn = None
 
 
-def wait_until_indexed(port: int, n_docs: int, deadline: float) -> None:
-    """Poll /v1/statistics until the store reports every document."""
-    client = Client(port, timeout=60)
+def wait_until_indexed(rag: Rag, n_docs: int, deadline: float,
+                       probe: str | None = None, k: int = 1) -> None:
+    """Returns when the store's index holds ``n_docs`` documents: each
+    embedded and added, so that the next search sees it. (``/v1/statistics``
+    counts parsed documents and says so seconds before the first is
+    embedded.) The count does not move when a live document is replaced:
+    ``probe``, the text of the last document put, is then retrieved until
+    it comes back first. Raises ``TimeoutError`` at the deadline."""
+    while rag.indexed() != n_docs:
+        if time.monotonic() >= deadline:
+            raise TimeoutError(
+                f"the index holds {rag.indexed()} documents, never {n_docs}"
+            )
+        time.sleep(0.01)
+    if probe is None:
+        return
+    client = Client(rag.port, timeout=60)
     last: Any = None
     try:
         while time.monotonic() < deadline:
             try:
-                status, last = client.post("/v1/statistics", {})
-                if status == 200 and last.get("file_count") == n_docs:
+                status, last = client.post("/v1/retrieve", {"query": probe, "k": k})
+                if status == 200 and last and last[0]["text"] == probe:
                     return
             except OSError as e:  # the server is still starting
                 last = e
                 client.close()
-            time.sleep(0.25)
+            time.sleep(0.05)
     finally:
         client.close()
-    raise TimeoutError(f"index never reported {n_docs} documents: {last!r}")
+    raise TimeoutError(f"the last document put never came back first: {last!r}")

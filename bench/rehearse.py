@@ -44,7 +44,9 @@ def rehearse(workload: str, seed: int, seconds: float, trace: bool,
     counts = {
         "correct": result["correct"], "attempted": result["attempted"],
         "failed": result["failed"], "counters": result["counters"],
-        "warmed": result["warmed"], "compared": result["compared"],
+        "warmed": result["warmed"], "encode_waves": result["encode_waves"],
+        "compiled_in_setup": [c["program"] for c in result["compiled_in_setup"]],
+        "compared": result["compared"],
         "metric_names": sorted(result["metrics"]),
         "device": result["device"]["platform"],
     }

@@ -23,27 +23,41 @@ def check(name: str, ok: bool, detail: object = "") -> bool:
 
 
 def hand_made() -> list[bool]:
-    """Two programs, one request span; every number worked by hand (ns)."""
+    """Two programs, one request span; every number worked by hand (ns).
+    The first and the last module touch their device's first and last
+    instant: the trace's edges cut them, and they are in no sum."""
     planes = [
         {"name": "/device:TPU:0", "lines": [
             {"name": "XLA Modules", "events": [
+                ("jit__unknown(1)", 40.0, 20.0),  # what the trace kept of a step
                 ("jit__unknown(1)", 100.0, 50.0), ("jit__unknown(2)", 200.0, 20.0),
-                ("jit__unknown(1)", 300.0, 50.0)]},
+                ("jit__unknown(1)", 300.0, 50.0),
+                ("jit__unknown(2)", 985.0, 15.0)]},  # a prefill, cut at its end
             {"name": "XLA Ops", "events": [
+                ("%fusion.1 = x fusion(%params__blocks___0___qkv__.1)", 40.0, 20.0),
                 ("%fusion.1 = x fusion(%params__blocks___0___qkv__.1)", 100.0, 30.0),
                 ("%fusion.2 = y", 120.0, 30.0),  # overlaps the first by 10
-                ("%fusion.1 = x", 205.0, 10.0), ("%fusion.1 = x", 300.0, 50.0)]},
+                ("%fusion.1 = x", 205.0, 10.0), ("%fusion.1 = x", 300.0, 50.0),
+                ("%fusion.1 = x", 985.0, 15.0)]},
             {"name": "Async XLA Ops", "events": [("%copy-start = z", 0.0, 1000.0)]},
         ]},
         {"name": "/host:CPU", "lines": [{"name": "", "events": [
+            ("PjitFunction(step)", 30.0, 5.0),
             ("PjitFunction(step)", 90.0, 5.0), ("PjitFunction(prefill)", 190.0, 5.0),
-            ("PjitFunction(step)", 290.0, 5.0), ("bench.req 1", 80.0, 300.0)]}]},
+            ("PjitFunction(step)", 290.0, 5.0), ("PjitFunction(prefill)", 975.0, 5.0),
+            ("bench.req 1", 80.0, 300.0)]}]},
     ]
     r = trace_reduce.reduce_planes(planes)
     busy = (150 - 100) + 10 + 50  # union: [100,150) [205,215) [300,350)
+    cut_busy = 20 + 15  # the two cut executions: busy all the same
     return [
         check("busy is the union of XLA Ops, async copies left out",
-              abs(r["busy_s"] - busy * 1e-9) < 1e-15, r["busy_s"]),
+              abs(r["busy_s"] - (busy + cut_busy) * 1e-9) < 1e-15, r["busy_s"]),
+        check("an execution that touches its device's first or last instant is cut",
+              {k: v["cut"] for k, v in r["programs"].items()} == {"step": 1, "prefill": 1}
+              and [(k, round(v * 1e9)) for k, v in sorted(r["cut_modules"])]
+              == [("prefill", 15), ("step", 20)],
+              r["cut_modules"]),
         check("window runs from the first event to the last",
               abs(r["window_s"] - 1000e-9) < 1e-15, r["window_s"]),
         check("modules are named by the host's launches",
@@ -65,8 +79,11 @@ def recorded() -> list[bool]:
     counts = {k: v["count"] for k, v in r["programs"].items()}
     return [
         check("recorded trace: one TPU plane", r["n_device_planes"] == 1),
-        check("recorded trace: 12 steps and 3 prefills, named from the host",
-              counts.get("small_step") == 12 and counts.get("small_prefill") == 3, counts),
+        check("recorded trace: 12 steps and 3 prefills, named from the host, the "
+              "first prefill and the last step at the trace's edges",
+              counts.get("small_step") == 11 and counts.get("small_prefill") == 2
+              and r["programs"]["small_step"]["cut"] == 1
+              and r["programs"]["small_prefill"]["cut"] == 1, r["programs"]),
         check("recorded trace: 0 < busy < window",
               0 < r["busy_s"] < r["window_s"], (r["busy_s"], r["window_s"])),
         check("recorded trace: three request spans, busy inside each below its length",

@@ -36,7 +36,7 @@ def main() -> int:
     sys.path.insert(0, str(HERE))
     sys.path.insert(0, str(ROOT))
     from pwbench import harness, loadgen, spec, traffic
-    from pwbench.server import Rag, wait_until_indexed
+    from pwbench.server import Rag
 
     cell = spec.Cell(ROOT / "BENCHMARK.json", a.workload)
     devices = harness._device_check(cell.chips, True)
@@ -47,9 +47,7 @@ def main() -> int:
     rag.start()
     rows = []
     try:
-        initial = sorted(corpus.texts.items())
-        rag.source.put(initial)
-        wait_until_indexed(rag.port, len(initial), time.monotonic() + 900)
+        harness._ingest(rag, corpus)
         harness._warm_up(rag, cell, corpus)
         for i, rate in enumerate(float(r) for r in a.rates.split(",")):
             due = list(traffic.arrival_times(a.seed + i, rate, a.seconds))
