@@ -657,30 +657,62 @@ def _experts(u: Array, idx: Array, w: Array, live: Array, block: Params,
     pairs are sorted by expert and each expert multiplies its own run of
     rows (a grouped product), whatever the run's length, so no pair is ever
     dropped. `live` [b, s] marks the rows that count. Returns the layer's
-    output and how many live pairs each expert got [n_experts]."""
+    output and how many live pairs each expert got [n_experts].
+
+    Where `experts_use_kernel` holds the products are ops/experts.py
+    `grouped_experts` (gate and up in one kernel with the ReLU product, the
+    pair's weight in the down kernel) and the combine is its
+    `combine_experts` (a token's rows fetched by index and summed);
+    elsewhere three `ragged_dot` and the weighted sum. Products accumulate
+    in float32 and a token's pairs are summed in float32 on both."""
     with jax.named_scope("experts"):
         b, s, d = u.shape
         k, e = cfg.n_active, cfg.n_experts
         flat = idx.reshape(-1)  # the pairs, token-major
-        order = jnp.argsort(flat, stable=True)
-        sizes = jnp.zeros((e,), jnp.int32).at[flat].add(1)
-        rows = u.reshape(-1, d)[order // k]  # each pair's token, by expert
-
-        def grouped(x: Array, name: str) -> Array:
-            return jax.lax.ragged_dot(
-                x, block[name].astype(cfg.dtype), sizes,
-                preferred_element_type=jnp.float32,
-            )
-
-        hidden = (
-            jax.nn.relu(grouped(rows, "expert_gate")) * grouped(rows, "expert_up")
-        ).astype(cfg.dtype)
-        y = grouped(hidden, "expert_down")  # [pairs, d], by expert
-        back = jnp.zeros_like(order).at[order].set(jnp.arange(order.size))
-        y = jnp.einsum("tkd,tk->td", y[back].reshape(b * s, k, d), w.reshape(-1, k))
-        counts = jnp.zeros((e,), jnp.int32).at[flat].add(
-            jnp.repeat(live.reshape(-1).astype(jnp.int32), k)
+        # the pairs by expert, and their weights carried along by the sort
+        _, order, by_expert_w = jax.lax.sort(
+            (flat, jnp.arange(flat.size, dtype=jnp.int32), w.reshape(-1)),
+            num_keys=1, is_stable=True,
         )
+        # each expert's pairs, and those of live rows: one comparison and
+        # two sums, not two scatters of every pair into the bins
+        hit = flat[:, None] == jnp.arange(e, dtype=flat.dtype)
+        sizes = jnp.sum(hit, axis=0, dtype=jnp.int32)
+        counts = jnp.sum(
+            hit & jnp.repeat(live.reshape(-1), k)[:, None], axis=0,
+            dtype=jnp.int32,
+        )
+        rows = u.reshape(-1, d)[order // k]  # each pair's token, by expert
+        # each pair's place there, a token's k side by side in [k, tokens]:
+        # rows gathered by it are k slabs of whole [tokens, d] tiles, where
+        # [tokens, k, d] pads k to a sublane tile in a pass of its own
+        back = jnp.argsort(order).reshape(b * s, k).T
+        if experts_use_kernel(cfg, b * s * k):
+            # imported where it is traced: Pallas loads when a program
+            # first needs it
+            from pathway_tpu.ops.experts import combine_experts, grouped_experts
+
+            # leaves of the activations' dtype (a served decoder's) are the
+            # kernels' own operands, read where they lie: the cast is none
+            y = grouped_experts(
+                rows, by_expert_w, sizes,
+                *(block[name].astype(cfg.dtype)
+                  for name in ("expert_gate", "expert_up", "expert_down")),
+            )  # [pairs, d / 128, 128] float32, weighted, by expert
+            y = combine_experts(y, back, cfg.dtype)
+        else:
+            def grouped(x: Array, name: str) -> Array:
+                return jax.lax.ragged_dot(
+                    x, block[name].astype(cfg.dtype), sizes,
+                    preferred_element_type=jnp.float32,
+                )
+
+            hidden = (
+                jax.nn.relu(grouped(rows, "expert_gate"))
+                * grouped(rows, "expert_up")
+            ).astype(cfg.dtype)
+            y = grouped(hidden, "expert_down")  # [pairs, d], by expert
+            y = jnp.einsum("ktd,tk->td", y[back], w.reshape(-1, k))
         return y.astype(cfg.dtype).reshape(b, s, d), counts
 
 
@@ -835,6 +867,45 @@ def prefill_uses_kernel(cfg: TransformerConfig, width: int) -> bool:
         and jax.default_backend() == "tpu"
         and cfg.head_dim % 128 == 0
         and width >= 128
+    )
+
+
+# a visit of ops/experts.py's kernels fetches an expert's matrices and runs
+# whole blocks of 128 rows: with fewer pairs an expert than that most of a
+# block is masked. Measured at a prefill's 960 an expert (twice as fast as
+# `ragged_dot`); a decode step's 6 a slot stay on `ragged_dot`, which reads
+# each touched expert once (PERF.md section 6, PR 36)
+_EXPERT_KERNEL_PAIRS = 128
+# the combine kernel's row indices, one int32 a pair, ride in the chip's
+# scalar memory (1 MiB on a v5e; the compiler refuses 65,536 x 6)
+_EXPERT_KERNEL_MAX_PAIRS = 196_608
+
+
+def experts_use_kernel(cfg: TransformerConfig, pairs: int) -> bool:
+    """Whether an experts layer over `pairs` token-expert pairs runs
+    ops/experts.py `grouped_experts` and `combine_experts` and not three
+    `ragged_dot` and a weighted sum: on a TPU, with model and expert widths
+    of a multiple of 128 lanes, `_EXPERT_KERNEL_PAIRS` pairs an expert at
+    least, which a prefill has and a decode step has not, and no more than
+    `_EXPERT_KERNEL_MAX_PAIRS` in all. Read from the shapes and from where
+    the process runs, as `prefill_uses_kernel`; nothing sets it, and
+    `fused_attention` off keeps tensor-parallel parameters and a pool that
+    spans a mesh on `ragged_dot` (the kernels have no partitioning rule)."""
+    return (
+        cfg.fused_attention
+        and jax.default_backend() == "tpu"
+        and cfg.d_model % 128 == 0
+        and cfg.d_ff % 128 == 0
+        and _EXPERT_KERNEL_PAIRS * cfg.n_experts <= pairs <= _EXPERT_KERNEL_MAX_PAIRS
+    )
+
+
+def prefill_experts_use_kernel(cfg: TransformerConfig, width: int) -> bool:
+    """Whether the experts layers of a prefill of prompts `width` wide, one
+    row, run the kernel: `experts_use_kernel` of its pairs, for a decoder
+    that has such layers."""
+    return any(sp.ff == "experts" for sp in cfg.layer_specs) and (
+        experts_use_kernel(cfg, width * cfg.n_active)
     )
 
 

@@ -82,8 +82,11 @@ prompt. ``prompt_tokens`` and ``padded_tokens`` count what the admitted
 prompts held and the widths they ran at, ``kernel_prefills`` those whose
 attention kept its scores in VMEM (``transformer.prefill_uses_kernel`` of
 the width they ran at: the predicate the program itself branches on),
-and ``kernel_steps`` the decode steps whose attention read the slot cache
-in place (``transformer.step_uses_kernel``, likewise).
+``kernel_expert_prefills`` those whose routed expert layers ran
+ops/experts.py's grouped kernels (``transformer.prefill_experts_use_kernel``,
+likewise: a run that fell back to ``ragged_dot`` says so), and
+``kernel_steps`` the decode steps whose attention read the slot cache in
+place (``transformer.step_uses_kernel``, likewise).
 
 Decoding is temperature-0 (argmax) here; sampled generation keeps the
 wave-aligned path (a per-request RNG stream inside a shared step program
@@ -260,6 +263,8 @@ class ContinuousBatcher:
             "prompt_tokens": 0, "padded_tokens": 0,
             # prefills whose attention ran ops/attention.py's kernel
             "kernel_prefills": 0,
+            # prefills whose expert layers ran ops/experts.py's kernels
+            "kernel_expert_prefills": 0,
             # decode steps whose attention ran ops/attention.py's kernel
             "kernel_steps": 0,
             "preload_s": 0.0,  # the step program's load at construction
@@ -542,6 +547,9 @@ class ContinuousBatcher:
             self.stats["padded_tokens"] += req.width
             self.stats["kernel_prefills"] += self._model.prefill_uses_kernel(
                 self.cfg, req.width
+            )
+            self.stats["kernel_expert_prefills"] += (
+                self._model.prefill_experts_use_kernel(self.cfg, req.width)
             )
             self.stats["queue_wait_s"] += req.t_admit - req.t_submit
             self.stats["first_token_s"] += req.t_first - req.t_submit

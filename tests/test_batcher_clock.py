@@ -91,6 +91,7 @@ def test_stats_hold_every_key_from_construction():
     counts = {
         "submitted", "completed", "decode_steps", "prefills", "max_queue",
         "prompt_tokens", "padded_tokens", "kernel_prefills", "kernel_steps",
+        "kernel_expert_prefills",
         # an experts decoder's device counters (0 for this block)
         "routed_pairs", "expert_load_max", "experts_touched", "moe_layers_run",
     }
@@ -429,24 +430,31 @@ def test_benchmark_lists_the_four_metrics_for_the_backlog_cell():
 
 
 @pytest.mark.parametrize("engages", [True, False])
-def test_kernel_prefills_counts_what_the_predicate_says(monkeypatch, engages):
-    """`kernel_prefills` is the model module's own predicate of the width
-    a prompt ran at, the one `_prefill` branches on: with it patched true
-    (after the programs are traced, so that the CPU still runs them) the
-    count equals `prefills`; as it is off the TPU it stays 0."""
+@pytest.mark.parametrize("stat, predicate", [
+    ("kernel_prefills", "prefill_uses_kernel"),
+    ("kernel_expert_prefills", "prefill_experts_use_kernel"),
+])
+def test_kernel_prefills_counts_what_the_predicate_says(
+    monkeypatch, engages, stat, predicate
+):
+    """`kernel_prefills` and `kernel_expert_prefills` are the model module's
+    own predicates of the width a prompt ran at, the ones `_prefill` and
+    (through `experts_use_kernel`) `_experts` branch on: with one patched
+    true (after the programs are traced, so that the CPU still runs them)
+    its count equals `prefills`; as it is off the TPU it stays 0."""
     cb = _chat()._cb
     _run(cb, ["warm up prompt"])
     seen = []
     if engages:
         monkeypatch.setattr(
-            cb._model, "prefill_uses_kernel",
+            cb._model, predicate,
             lambda cfg, width: seen.append((cfg, width)) or True,
         )
     before = dict(cb.stats)
     _run(cb)
     grown = cb.stats["prefills"] - before["prefills"]
     assert grown == len(PROMPTS)
-    assert cb.stats["kernel_prefills"] == (grown if engages else 0)
+    assert cb.stats[stat] == (grown if engages else 0)
     if engages:
         assert seen == [(cb.cfg, 16)] * len(PROMPTS)
 

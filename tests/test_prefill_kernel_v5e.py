@@ -1,5 +1,6 @@
 """The decoder's attention kernels (ops/attention.py `prefill_attention`,
-`decode_attention`) and the programs that hold them, compiled for a
+`decode_attention`), its expert layer's (ops/experts.py `grouped_experts`)
+and the programs that hold them, compiled for a
 described TPU v5e by the chip's own compiler, from this CPU host: Mosaic
 refuses what the interpreter lets pass (a slice off the tiling, too much
 VMEM), and the compiled text shows whether the slot cache stays where it
@@ -21,6 +22,7 @@ from jax.sharding import SingleDeviceSharding
 from pathway_tpu.models import LayerSpec, lm_config
 from pathway_tpu.models import transformer as T
 from pathway_tpu.ops.attention import decode_attention, prefill_attention
+from pathway_tpu.ops.experts import combine_experts, grouped_experts
 
 
 @pytest.fixture(scope="module")
@@ -63,6 +65,14 @@ def test_the_kernel_compiles_for_v5e(b, p, heads, kv_heads, window, dtype, chip)
     assert "%prefill_attention" in text
 
 
+def _shaped(chip, make):
+    """The shapes of what `make()` would build, placed on the chip."""
+    return jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip),
+        jax.eval_shape(make),
+    )
+
+
 def _prefill_lowered(width: int, chip) -> str:
     """A prefill of two global and two window layers with heads of 128,
     lowered for the chip."""
@@ -71,13 +81,10 @@ def _prefill_lowered(width: int, chip) -> str:
         n_layers=4, d_ff=512, max_len=2048, dtype=jnp.bfloat16,
         layers=(LayerSpec(pos="none"), LayerSpec(window=512, pos="rotary")) * 2,
     )
-    shaped = lambda tree: jax.tree.map(  # noqa: E731
-        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip), tree
+    params = _shaped(
+        chip, lambda: T.init_params(jax.random.PRNGKey(0), cfg, jnp.bfloat16)
     )
-    params = shaped(jax.eval_shape(
-        lambda: T.init_params(jax.random.PRNGKey(0), cfg, jnp.bfloat16)
-    ))
-    cache = shaped(jax.eval_shape(lambda: T.init_kv_cache(cfg, 2)))
+    cache = _shaped(chip, lambda: T.init_kv_cache(cfg, 2))
     ids = jax.ShapeDtypeStruct((1, width), jnp.int32, sharding=chip)
     slot = jax.ShapeDtypeStruct((), jnp.int32, sharding=chip)
     return jax.jit(
@@ -166,13 +173,10 @@ def test_a_cells_step_reads_and_writes_the_cache_where_it_lies(
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     cfg = lm_config(dtype=jnp.bfloat16, **CELLS[cell])
     slots = 8
-    shaped = lambda tree: jax.tree.map(  # noqa: E731
-        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip), tree
+    params = _shaped(
+        chip, lambda: T.init_params(jax.random.PRNGKey(0), cfg, jnp.bfloat16)
     )
-    params = shaped(jax.eval_shape(
-        lambda: T.init_params(jax.random.PRNGKey(0), cfg, jnp.bfloat16)
-    ))
-    cache = shaped(jax.eval_shape(lambda: T.init_kv_cache(cfg, slots)))
+    cache = _shaped(chip, lambda: T.init_kv_cache(cfg, slots))
     vec = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=chip)
     compiled = jax.jit(
         functools.partial(T.decode_step_slots, cfg=cfg), donate_argnums=(1,)
@@ -192,3 +196,84 @@ def test_a_cells_step_reads_and_writes_the_cache_where_it_lies(
         x.size * x.dtype.itemsize for x in jax.tree.leaves(cache)
     )
     assert stats.temp_size_in_bytes < layer_of_a_leaf * 2  # bytes of one in bf16
+
+
+# the second cell's experts layer (10,240 tokens x 6 of 64 experts of width
+# 768 at d 2560), the narrowest prompt the rule sends here, and float32
+@pytest.mark.parametrize("pairs, d, ff, experts, dtype", [
+    (61440, 2560, 768, 64, jnp.bfloat16),
+    (8448, 2560, 768, 64, jnp.bfloat16),
+    (1000, 256, 128, 8, jnp.float32),
+])
+def test_the_expert_kernels_compile_for_v5e(pairs, d, ff, experts, dtype, chip):
+    def arg(*shape, dt=dtype):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=chip)
+
+    compiled = jax.jit(grouped_experts).lower(
+        arg(pairs, d), arg(pairs, dt=jnp.float32), arg(experts, dt=jnp.int32),
+        arg(experts, d, ff), arg(experts, d, ff), arg(experts, ff, d),
+    ).compile()
+    text = compiled.as_text()
+    # the names a device trace shows
+    assert "%expert_gate_up" in text and "%expert_down" in text
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    # a pair's row leaves as a slab of lane tiles, for the combine's copies
+    assert re.search(rf"%expert_down\S* = f32\[{pairs},{d // 128},128\]", text)
+
+
+@pytest.mark.parametrize("tokens, k, d", [(10240, 6, 2560), (32768, 6, 2560)])
+def test_the_combine_kernel_compiles_for_v5e(tokens, k, d, chip):
+    """The second cell's prefill, and the most pairs `experts_use_kernel`
+    sends here: their row indices fit the chip's scalar memory."""
+    assert tokens * k <= T._EXPERT_KERNEL_MAX_PAIRS
+    compiled = jax.jit(combine_experts, static_argnames=("dtype",)).lower(
+        jax.ShapeDtypeStruct((tokens * k, d // 128, 128), jnp.float32, sharding=chip),
+        jax.ShapeDtypeStruct((k, tokens), jnp.int32, sharding=chip),
+        dtype=jnp.bfloat16,
+    ).compile()
+    assert "%expert_combine" in compiled.as_text()
+
+
+def test_a_prefills_expert_kernels_read_the_leaves_where_they_lie(
+    chip, monkeypatch
+):
+    """A prefill of two experts layers at the second cell's widths (two
+    experts' worth of them), the rule saying TPU: each layer's two kernels
+    take the `expert_` leaves of `params` as their own operands, with no
+    cast, slice or copy between the parameter and the call, which is what
+    the benchmark's `expert_prefill_roofline` finds them by
+    (`trace_reduce.op_key`: an operation is named by the leaves it reads)."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = lm_config(
+        vocab_size=512, d_model=2560, n_heads=28, n_kv_heads=4, head_size=128,
+        n_layers=2, d_ff=768, max_len=2048, n_experts=2, n_active=1,
+        tie_embeddings=False, dtype=jnp.bfloat16,
+        layers=(LayerSpec(pos="none", ff="experts"),
+                LayerSpec(window=512, pos="rotary", ff="experts")),
+    )
+    assert T.prefill_experts_use_kernel(cfg, 1280)
+    params = _shaped(
+        chip, lambda: T.init_params(jax.random.PRNGKey(0), cfg, jnp.bfloat16)
+    )
+    cache = _shaped(chip, lambda: T.init_kv_cache(cfg, 2))
+    ids = jax.ShapeDtypeStruct((1, 1280), jnp.int32, sharding=chip)
+    slot = jax.ShapeDtypeStruct((), jnp.int32, sharding=chip)
+
+    def prefill_into_slot(params, prompt_ids, prompt_mask, cache, slot):
+        return T.prefill_into_slot(params, prompt_ids, prompt_mask, cache, slot, cfg)
+
+    text = jax.jit(prefill_into_slot, donate_argnums=(3,)).lower(
+        params, ids, ids, cache, slot
+    ).compile().as_text()
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    for layer in (0, 1):
+        leaf = f"%params__blocks___{layer}___expert_"
+        gate_up = [ln for ln in calls if "%expert_gate_up" in ln.split("=")[0]
+                   and leaf + "gate__" in ln]
+        down = [ln for ln in calls if "%expert_down" in ln.split("=")[0]
+                and leaf + "down__" in ln]
+        assert len(gate_up) == 1 and leaf + "up__" in gate_up[0], layer
+        assert len(down) == 1, layer
+    # the combine reads the down kernel's rows as they were written
+    assert sum("%expert_combine" in ln.split("=")[0] for ln in calls) == 2
+    assert "ragged-dot" not in text
