@@ -19,8 +19,10 @@ replaces that with a **slot scheduler**:
 * every decode step advances ALL occupied slots by one token through a
   single jitted program with per-row positions
   (`models/transformer.decode_step_slots`);
-* a request that finishes releases its slot at the step boundary, and
-  the same boundary re-fills the row from the admission queue;
+* a request releases its slot at the boundary after its **last step was
+  dispatched**, and the same boundary re-fills the row from the admission
+  queue (the cache is donated from program to program, so the device runs
+  the prefill after the step that last used the row);
 * a boundary runs **one prefill at most**: with a queue behind several
   free slots the loop goes prefill, step, prefill, step. A decoding
   answer then waits for one prefill between two of its tokens (with
@@ -30,6 +32,39 @@ replaces that with a **slot scheduler**:
   of k requests into an idle pool pays k - 1 steps more for its last
   answer; a standing backlog pays nothing (a step with a free row costs
   what a full one costs, and the free row is filled one step later).
+
+**The loop dispatches ahead of what it reads.** Decoding is argmax with a
+fixed number of steps, so the host knows every slot's position, pad length
+and last step without reading a token. The order of one turn of the loop:
+at the boundary, dispatch the prefill of one queued request if a slot is
+free, then *read the oldest program out, if more than* ``_AHEAD`` *are*;
+prepare ``pos`` and ``pad_len``, dispatch the step, give back the slots
+whose last step that was, then read again in the same way. A result is read
+``_AHEAD`` programs late: ``np.asarray`` on program n returns when n has run,
+by which time n + 1 and n + 2 are queued behind it and the device goes from
+one to the next without the host (the thread's wake-up, the accounting, the
+lock, the next boundary's preparation and dispatch all run while n + 1
+does, and a burst in which the thread does not get the interpreter for
+longer than a step is covered by n + 2). The host still follows the device
+program for program, since every read blocks until its program has run: a
+reply leaves when its last step ends, not later. ``_AHEAD`` is two, fixed in
+the code. What lags with the read: a request's tokens, its device
+counters, ``prefills`` / ``decode_steps`` / ``completed`` and its clocks,
+and its reply, which never leaves before its last token is on the host;
+``queue_depth()`` counts a request until then.
+What does not lag: the slot. **The token vector stays on the device**: the
+step program is the model's ``decode_step_slots`` behind one line that
+makes its ``token`` operand from the last step's result and, in the slot a
+prefill has filled since, that prefill's first token (a free slot's token
+is whatever was left there: it runs at position 0, and the next prefill
+overwrites its row). When nothing is left to dispatch the loop reads what
+is still out, and only then exits or hands the cache lease back. A dispatch
+or a read that raises fails every waiter (queued, holding a slot, or with
+its last step out and unread) and gives every held slot back.
+``stats["dispatched_ahead"]`` counts the dispatches that found the program
+before them still running (``jax.Array.is_ready()`` false on its result
+once the new one was out): over ``decode_steps + prefills`` it is near 1 in
+a backlog, and where it is low the host is the pace.
 
 Both programs ride the device plane: the compile ledger proves a request
 joining mid-generation costs **zero new XLA compilations** (the step
@@ -63,7 +98,9 @@ pool that spans a mesh runs its programs with ``fused_attention`` off
 **The scheduler times itself.** The decode loop is cut into contiguous
 leaf phases (:data:`PHASES`): each opens a profiler span (visible when a
 JAX profiler session is active) and adds its wall time to a cumulative
-``stats`` key, always on like the counts beside it. ``loop_s`` is the
+``stats`` key, always on like the counts beside it. The two waits are the
+reads above, the only places the thread blocks on the device: each waits
+for a program ``_AHEAD`` *before* the one just dispatched. ``loop_s`` is the
 loop's own wall time, ``host_cpu_s`` the thread's CPU time outside the
 two waits for the device, and ``queue_wait_s`` / ``first_token_s`` /
 ``residence_s`` sum each request's life from ``submit`` (counted by
@@ -120,6 +157,12 @@ PHASES = {
     "account_s": _obs.SPAN_CB_ACCOUNT,
 }
 _WAITS = ("admit_wait_s", "step_wait_s")  # blocked on the device
+# programs the loop keeps dispatched and unread. One is enough where the
+# host's time a dispatch is always under the running program's; where it is
+# so only on average (a step of 8.75 ms, a host of 6 that waits for the
+# interpreter in bursts), a second carries the device over the bursts
+# (PERF.md section 6, PR 37: 0.75 -> 0.88 of the dispatches ahead, +2.7%)
+_AHEAD = 2
 
 
 class _Phase:
@@ -150,7 +193,7 @@ class _Phase:
 
 class _Request:
     __slots__ = (
-        "row", "length", "future", "tokens", "token", "steps_done", "slot",
+        "row", "length", "future", "tokens", "steps_out", "slot",
         "pad_len", "width", "id", "t_submit", "t_admit", "t_first",
     )
 
@@ -164,12 +207,28 @@ class _Request:
         self.row = row  # token ids (already budget-truncated)
         self.length = len(row)
         self.future = future
-        self.tokens: list[int] = []  # emitted output tokens
-        self.token = 0  # the token the next decode step consumes
-        self.steps_done = 0
+        self.tokens: list[int] = []  # output tokens read back so far
+        self.steps_out = 0  # decode steps dispatched for it, read or not
         self.slot: int | None = None
         self.pad_len = 0  # left-pad of the prompt bucket
         self.width = 0  # physical prompt width (the seq bucket)
+
+
+class _Dispatched:
+    """A program that is out and not read yet: its result, still on the
+    device, and the requests whose tokens it holds: the one a prefill
+    admitted (`batch` is None), or a step's rows, slot -> request."""
+
+    __slots__ = ("out", "req", "slot", "batch")
+
+    def __init__(
+        self, out: Any, req: "_Request | None" = None, slot: int = -1,
+        batch: "dict[int, _Request] | None" = None,
+    ):
+        self.out = out
+        self.req = req
+        self.slot = slot
+        self.batch = batch
 
 
 class ContinuousBatcher:
@@ -238,10 +297,25 @@ class ContinuousBatcher:
             functools.partial(transformer.prefill_into_slot, cfg=cfg),
             donate_argnums=(3,),  # the shared cache rides the lease cycle
         )
+        # the model's step, looked up now (what stands under that name
+        # while the batcher is built is what it serves), under a wrapper of
+        # the same name, which is the name of the XLA module
+        step = functools.partial(transformer.decode_step_slots, cfg=cfg)
+        rows = n_slots
+
+        def decode_step_slots(params, cache, last, first, fresh, pos, pad_len):
+            """The model's step on a token vector that never left the
+            device: the last step's tokens and, in the slot a prefill has
+            filled since (`fresh`; -1 for none), that prefill's first."""
+            import jax.numpy as jnp
+
+            token = jnp.where(
+                jnp.arange(rows) == fresh, first[0], last[:rows]
+            )
+            return step(params, cache, token, pos, pad_len)
+
         self._step = self._plane.program(
-            f"{self.name}/step",
-            functools.partial(transformer.decode_step_slots, cfg=cfg),
-            donate_argnums=(1,),
+            f"{self.name}/step", decode_step_slots, donate_argnums=(1,),
         )
         self._cache_key = ("cb_kv_cache", self.name, n_slots)
         self._lock = _lockgraph.register_lock(
@@ -249,6 +323,19 @@ class ContinuousBatcher:
         )
         self._queue: deque[_Request] = deque()
         self._active: dict[int, _Request] = {}  # slot -> request
+        # requests whose last step is out and whose slot is back in the
+        # pool, until that step is read and their future resolved
+        self._leaving: set[_Request] = set()
+        # programs dispatched and not read yet, oldest first: the loop
+        # reads one when more than _AHEAD are out
+        self._out: deque[_Dispatched] = deque()
+        # what the next step's tokens are made of, all of it on the
+        # device: the last step's result, the last prefill's, and the slot
+        # that prefill filled if no step has run since (else -1)
+        self._last: Any = None
+        self._first: Any = None
+        self._fresh = -1
+        self._slot_ids: list | None = None  # -1 .. n_slots - 1, on the device
         self._running = False
         self._thread: threading.Thread | None = None
         # every key from the start: readers copy the dict from other
@@ -256,6 +343,8 @@ class ContinuousBatcher:
         self.stats: dict[str, float] = {
             "submitted": 0, "completed": 0, "decode_steps": 0,
             "prefills": 0, "max_queue": 0,
+            # dispatches that found the program before them still running
+            "dispatched_ahead": 0,
             **dict.fromkeys(PHASES, 0.0),
             "loop_s": 0.0, "host_cpu_s": 0.0,
             "queue_wait_s": 0.0, "first_token_s": 0.0, "residence_s": 0.0,
@@ -312,7 +401,9 @@ class ContinuousBatcher:
 
     def queue_depth(self) -> int:
         with self._lock:
-            return len(self._queue) + len(self._active)
+            return (
+                len(self._queue) + len(self._active) + len(self._leaving)
+            )
 
     def drain(self, timeout: float | None = 30.0) -> None:
         """Block until the in-flight work finishes (tests/teardown)."""
@@ -345,20 +436,48 @@ class ContinuousBatcher:
             )
         return cache
 
-    def _step_vectors(self, tok, pos, pad):
-        """The per-slot step vectors as device arrays — sharded along the
-        same `data` axis as the cache rows when the pool spans the mesh
-        (jit then partitions the step program instead of replicating)."""
+    def _placed(self, arr: Any, *axes: Any) -> Any:
+        """`arr` on the device; when the pool spans the mesh, laid out
+        along `axes` of it (none: a copy on every chip)."""
         import jax.numpy as jnp
 
-        arrs = [jnp.asarray(a) for a in (tok, pos, pad)]
+        arr = jnp.asarray(arr)
         if self.mesh is not None:
             import jax
             from jax.sharding import NamedSharding, PartitionSpec as P
 
-            row = NamedSharding(self.mesh, P("data"))
-            arrs = [jax.device_put(a, row) for a in arrs]
-        return arrs
+            arr = jax.device_put(arr, NamedSharding(self.mesh, P(*axes)))
+        return arr
+
+    def _step_vectors(self, pos, pad):
+        """The per-slot positions and pad lengths as device arrays, which
+        the host knows without reading a token back — sharded along the
+        same `data` axis as the cache rows when the pool spans the mesh
+        (jit then partitions the step program instead of replicating)."""
+        return self._placed(pos, "data"), self._placed(pad, "data")
+
+    def _device_state(self) -> None:
+        """What a step takes besides the cache, before any program has
+        made it: a token vector and a prefill's result of the shapes the
+        two programs return (their counters ride behind the tokens), and
+        the slot numbers, which never change."""
+        import numpy as np
+
+        experts = bool(self.cfg.n_expert_layers)
+        if self._last is None:
+            tail = len(self._model.STEP_COUNTERS) * experts
+            self._last = self._placed(np.zeros(self.n_slots + tail, np.int32))
+        if self._first is None:
+            tail = len(self._model.PREFILL_COUNTERS) * experts
+            self._first = self._placed(np.zeros(1 + tail, np.int32))
+        if self._slot_ids is None:
+            self._slot_ids = [
+                self._placed(np.int32(i)) for i in range(-1, self.n_slots)
+            ]
+
+    def _slot_id(self, slot: int) -> Any:
+        """Slot number `slot` (-1: none) as a scalar on the device."""
+        return self._slot_ids[slot + 1]
 
     def _phase(self, key: str, **meta: Any) -> _Phase:
         """Context manager of one phase of the loop (a key of PHASES)."""
@@ -380,6 +499,16 @@ class ContinuousBatcher:
         for name, value in zip(names, tail):
             self.stats[name] += int(value)
 
+    def _dispatch_step(self, cache: Any, pos: Any, pad: Any) -> tuple:
+        """One step over every slot, its tokens taken from what the
+        device holds; its result is the next step's token vector."""
+        nxt, cache = self._step(
+            self.params, cache, self._last, self._first,
+            self._slot_id(self._fresh), pos, pad, bucket=self.n_slots,
+        )
+        self._last, self._fresh = nxt, -1
+        return nxt, cache
+
     def _preload(self, cache: Any) -> Any:
         """Dispatch the step once with no slot occupied and wait for it:
         the program is traced, compiled or fetched, and loaded. The rows
@@ -388,9 +517,8 @@ class ContinuousBatcher:
         import numpy as np
 
         zeros = np.zeros(self.n_slots, np.int32)
-        nxt, cache = self._step(
-            self.params, cache, *self._step_vectors(zeros, zeros, zeros),
-            bucket=self.n_slots,
+        nxt, cache = self._dispatch_step(
+            cache, *self._step_vectors(zeros, zeros)
         )
         np.asarray(nxt)
         return cache
@@ -409,6 +537,7 @@ class ContinuousBatcher:
         try:
             t0 = time.perf_counter()
             cache = self._plane.lease(self._cache_key, self._init_cache)
+            self._device_state()
             if preload:
                 cache = self._preload(cache)
                 self.stats["preload_s"] += time.perf_counter() - t0
@@ -433,61 +562,61 @@ class ContinuousBatcher:
                         req.t_admit = time.monotonic()
                 if slot is not None:
                     cache = self._admit(req, slot, cache)
+                    self._read_behind()
                     self._loop_tick()
                 with self._phase("step_prep_s"):
                     with self._lock:
-                        if not self._active:
-                            # nothing left; exit under the lock so a
-                            # submit racing this check either sees
-                            # _running=True (we loop again) or starts a
-                            # fresh thread
-                            if self._queue:
-                                continue
+                        batch = dict(self._active)
+                        queued = bool(self._queue)
+                        if not batch and not queued and not self._out:
+                            # nothing left, nothing out; exit under the
+                            # lock so a submit racing this check either
+                            # sees _running=True (we loop again) or
+                            # starts a fresh thread
                             self._running = False
                             return
-                        batch = dict(self._active)
-                    # ---- one decode step over every occupied slot
-                    tok = np.zeros(self.n_slots, np.int32)
-                    pos = np.zeros(self.n_slots, np.int32)
-                    pad = np.zeros(self.n_slots, np.int32)
-                    for slot, req in batch.items():
-                        tok[slot] = req.token
-                        pos[slot] = req.width + req.steps_done
-                        pad[slot] = req.pad_len
-                    tok_d, pos_d, pad_d = self._step_vectors(tok, pos, pad)
+                    if batch:
+                        # ---- one decode step over every occupied slot
+                        pos = np.zeros(self.n_slots, np.int32)
+                        pad = np.zeros(self.n_slots, np.int32)
+                        for slot, req in batch.items():
+                            pos[slot] = req.width + req.steps_out
+                            pad[slot] = req.pad_len
+                        pos_d, pad_d = self._step_vectors(pos, pad)
+                if not batch:
+                    # no slot to step. With a queue the next boundary's
+                    # prefill goes out first; without one the program
+                    # still out is read, and the check above is made again
+                    if not queued:
+                        self._read_behind(keep=0)
+                        self._loop_tick()
+                    continue
                 with self._phase("step_dispatch_s"):
-                    nxt, cache = self._step(
-                        self.params, cache, tok_d, pos_d, pad_d,
-                        bucket=self.n_slots,
-                    )
-                with self._phase("step_wait_s"):
-                    nxt = np.asarray(nxt)
-                with self._phase("account_s"):
-                    self.stats["decode_steps"] += 1
-                    self.stats["kernel_steps"] += self._model.step_uses_kernel(
-                        self.cfg
-                    )
-                    self._count(self._model.STEP_COUNTERS, nxt[self.n_slots:])
-                    if _obs.PLANE is not None:
-                        _obs.PLANE.metrics.counter(
-                            "pathway_serving_decode_steps_total",
-                            {"pool": self.pool.name},
-                            help="continuous-batching decode steps dispatched",
-                        )
+                    nxt, cache = self._dispatch_step(cache, pos_d, pad_d)
+                    self._sent(_Dispatched(nxt, batch=batch))
                     for slot, req in batch.items():
-                        req.steps_done += 1
-                        req.tokens.append(int(nxt[slot]))
-                        req.token = int(nxt[slot])
-                        if len(req.tokens) >= self.n_steps:
-                            self._finish(slot, req)
+                        req.steps_out += 1
+                        if req.steps_out >= self.n_steps - 1:
+                            self._release(slot, req)
+                self._read_behind()
                 self._loop_tick()
         except BaseException as e:  # noqa: BLE001 — fail every waiter loudly
             with self._lock:
                 self._running = False
                 held = list(self._active.keys())
-                waiting = list(self._active.values()) + list(self._queue)
+                waiting = (
+                    list(self._active.values()) + list(self._leaving)
+                    + list(self._queue)
+                )
                 self._active.clear()
+                self._leaving.clear()
                 self._queue.clear()
+            # what is out is not read: its requests have just failed. The
+            # next thread starts from a blank token vector (a result that
+            # failed on the device would fail every step fed with it)
+            self._out.clear()
+            self._last = self._first = None
+            self._fresh = -1
             for slot in held:
                 # slots must go back to the pool: leaking them would
                 # shrink the batch forever and leave a later submit
@@ -516,9 +645,9 @@ class ContinuousBatcher:
 
     def _admit(self, req: _Request, slot: int, cache: Any):
         """Prefill one queued request into its freshly acquired slot (the
-        join-at-step-boundary event)."""
+        join-at-step-boundary event). The prefill is dispatched and not
+        waited for: the loop reads it when `_AHEAD` more programs are out."""
         import jax.numpy as jnp
-        import numpy as np
 
         from pathway_tpu.engine.device_plane import pad_left_rows
 
@@ -527,43 +656,103 @@ class ContinuousBatcher:
             req.width = ids.shape[1]
             req.pad_len = req.width - req.length
             ids_d, mask_d = jnp.asarray(ids), jnp.asarray(mask)
-            slot_d = jnp.asarray(slot, jnp.int32)
         # who is being admitted rides as span metadata, never in the name
         meta = {"req": req.id, "slot": slot, "width": req.width}
         with self._phase("admit_dispatch_s", **meta):
             first, cache = self._prefill(
-                self.params, ids_d, mask_d, cache, slot_d,
+                self.params, ids_d, mask_d, cache, self._slot_id(slot),
                 bucket=(1, req.width),
             )
-        with self._phase("admit_wait_s", **meta):
-            first = np.asarray(first)
-        with self._phase("account_s"):
-            req.t_first = time.monotonic()
-            req.token = int(first[0])
-            req.tokens.append(req.token)
-            self.stats["prefills"] += 1
-            self._count(self._model.PREFILL_COUNTERS, first[1:])
-            self.stats["prompt_tokens"] += req.length
-            self.stats["padded_tokens"] += req.width
-            self.stats["kernel_prefills"] += self._model.prefill_uses_kernel(
-                self.cfg, req.width
-            )
-            self.stats["kernel_expert_prefills"] += (
-                self._model.prefill_experts_use_kernel(self.cfg, req.width)
-            )
-            self.stats["queue_wait_s"] += req.t_admit - req.t_submit
-            self.stats["first_token_s"] += req.t_first - req.t_submit
-            if len(req.tokens) >= self.n_steps:  # n_steps == 1
-                self._finish(slot, req)
+            self._sent(_Dispatched(first, req=req, slot=slot))
+            if self.n_steps > 1:
+                # the slot's next step takes its token from `first`
+                self._first, self._fresh = first, slot
+            else:
+                self._release(slot, req)
         return cache
 
-    def _finish(self, slot: int, req: _Request) -> None:
-        total = time.monotonic() - req.t_submit
+    def _sent(self, out: _Dispatched) -> None:
+        """A program has just been dispatched. Counted as dispatched ahead
+        if the one before it is still running: the device then goes from
+        one to the other without the host."""
+        if self._out and not self._out[-1].out.is_ready():
+            self.stats["dispatched_ahead"] += 1
+        # its way to the host starts when it ends, not when it is asked for
+        out.out.copy_to_host_async()
+        self._out.append(out)
+
+    def _release(self, slot: int, req: _Request) -> None:
+        """The request's last program is out: its slot is free for the
+        next boundary's prefill, which the device runs after that program
+        (the cache is donated from one to the next). Its reply leaves
+        when the program is read."""
         with self._lock:
             self._active.pop(slot, None)
+            self._leaving.add(req)
+        self.pool.release(slot)
+
+    def _read_behind(self, keep: int = _AHEAD) -> None:
+        """Read the programs behind the newest `keep`, oldest first: each
+        has run, or is running with the newer ones queued behind it."""
+        while len(self._out) > keep:
+            self._read(self._out.popleft())
+
+    def _read(self, done: _Dispatched) -> None:
+        """Wait for a program's result and account for it: the tokens into
+        their requests, the counters behind them, the replies of the
+        requests it finished. With `_preload`'s, the only places the
+        thread blocks on the device."""
+        import numpy as np
+
+        if done.batch is None:
+            req, slot = done.req, done.slot
+            meta = {"req": req.id, "slot": slot, "width": req.width}
+            with self._phase("admit_wait_s", **meta):
+                first = np.asarray(done.out)
+            with self._phase("account_s"):
+                req.t_first = time.monotonic()
+                req.tokens.append(int(first[0]))
+                self.stats["prefills"] += 1
+                self._count(self._model.PREFILL_COUNTERS, first[1:])
+                self.stats["prompt_tokens"] += req.length
+                self.stats["padded_tokens"] += req.width
+                self.stats["kernel_prefills"] += (
+                    self._model.prefill_uses_kernel(self.cfg, req.width)
+                )
+                self.stats["kernel_expert_prefills"] += (
+                    self._model.prefill_experts_use_kernel(self.cfg, req.width)
+                )
+                self.stats["queue_wait_s"] += req.t_admit - req.t_submit
+                self.stats["first_token_s"] += req.t_first - req.t_submit
+                if len(req.tokens) >= self.n_steps:  # n_steps == 1
+                    self._finish(slot, req)
+            return
+        with self._phase("step_wait_s"):
+            nxt = np.asarray(done.out)
+        with self._phase("account_s"):
+            self.stats["decode_steps"] += 1
+            self.stats["kernel_steps"] += self._model.step_uses_kernel(
+                self.cfg
+            )
+            self._count(self._model.STEP_COUNTERS, nxt[self.n_slots:])
+            if _obs.PLANE is not None:
+                _obs.PLANE.metrics.counter(
+                    "pathway_serving_decode_steps_total",
+                    {"pool": self.pool.name},
+                    help="continuous-batching decode steps dispatched",
+                )
+            for slot, req in done.batch.items():
+                req.tokens.append(int(nxt[slot]))
+                if len(req.tokens) >= self.n_steps:
+                    self._finish(slot, req)
+
+    def _finish(self, slot: int, req: _Request) -> None:
+        """The request's last token is on the host: its reply leaves."""
+        total = time.monotonic() - req.t_submit
+        with self._lock:
+            self._leaving.discard(req)
             self.stats["completed"] += 1
             self.stats["residence_s"] += total
-        self.pool.release(slot)
         if not req.future.done():
             req.future.set_result(
                 " ".join(f"<{int(t)}>" for t in req.tokens)

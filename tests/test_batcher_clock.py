@@ -90,6 +90,7 @@ def test_stats_hold_every_key_from_construction():
     cb = _chat()._cb
     counts = {
         "submitted", "completed", "decode_steps", "prefills", "max_queue",
+        "dispatched_ahead",
         "prompt_tokens", "padded_tokens", "kernel_prefills", "kernel_steps",
         "kernel_expert_prefills",
         # an experts decoder's device counters (0 for this block)
@@ -123,6 +124,62 @@ def test_phases_sum_to_loop_and_tokens_are_unchanged():
     assert 0 < s["host_cpu_s"] <= s["loop_s"] - waits + 0.05
     assert s["prefills"] == s["completed"] == len(PROMPTS) + 1
     assert 0 < s["queue_wait_s"] <= s["first_token_s"] <= s["residence_s"]
+
+
+def test_dispatched_ahead_counts_dispatches_behind_a_running_program():
+    """The count is taken where a program goes out, of the result before
+    it: with every result ready it stays 0, with none ready it is every
+    dispatch but the first after an idle device. No token depends on it."""
+    chat = _chat()
+    cb = chat._cb
+    _run(cb, ["warm up prompt"])
+    assert 0 <= cb.stats["dispatched_ahead"] <= (
+        cb.stats["decode_steps"] + cb.stats["prefills"] - 1
+    )
+    sent = cb._sent
+
+    class Running:
+        def __init__(self, out):
+            self.out = out
+
+        def is_ready(self):
+            return False
+
+    def sent_never_ready(done):
+        # what the count looks at is the program before: make it look busy
+        if cb._out:
+            behind = cb._out[-1]
+            real = behind.out
+            behind.out = Running(real)
+            sent(done)
+            behind.out = real
+        else:
+            sent(done)
+
+    cb._sent = sent_never_ready
+    before = dict(cb.stats)
+    assert _run(cb) == chat._generate_batch(PROMPTS)
+    grown = {k: cb.stats[k] - before[k] for k in before}
+    assert grown["dispatched_ahead"] == (
+        grown["decode_steps"] + grown["prefills"] - 1
+    )
+
+
+def test_the_benchmarks_wrapper_of_admit_sees_every_admitted_request():
+    """`bench/pwbench/server.py _log_slots` replaces `_admit` with
+    `logged(req, slot, cache)` and hands on what it returns: the loop calls
+    it once a request with those three, whatever else travels."""
+    sys.path.insert(0, str(BENCH))
+    try:
+        from pwbench.server import _log_slots
+    finally:
+        sys.path.remove(str(BENCH))
+    chat = _chat()
+    cb = chat._cb
+    slot_of = _log_slots(cb)
+    assert _run(cb) == chat._generate_batch(PROMPTS)
+    rows = {tuple(cb.tokenizer.tokenize(p)) for p in PROMPTS}
+    assert set(slot_of) == rows and set(slot_of.values()) == {0, 1}
 
 
 def test_request_ids_count_submissions():

@@ -22,6 +22,7 @@ import pytest
 from pathway_tpu.engine.device_plane import DevicePlane, SlotPool
 from pathway_tpu.internals import observability as obs
 from pathway_tpu.models import lm_config
+from pathway_tpu.serving.continuous_batching import _AHEAD
 
 
 @pytest.fixture(autouse=True)
@@ -444,6 +445,46 @@ def test_failed_step_load_is_logged_and_fails_the_first_request(monkeypatch):
 # --------------------------------------------- one prefill a step boundary
 
 
+def _held_back(cb, prompts):
+    """Queue every prompt before the thread looks: what the loop does with
+    a standing queue is then the same in every run."""
+    with cb._lock:
+        cb._running = True
+    futs = [cb.submit(p) for p in prompts]
+    with cb._lock:
+        cb._start_thread()
+    return futs
+
+
+def _logged(cb):
+    """The loop's programs in the order the host handled them: `P<slot>`
+    and `S` where one is dispatched, `p` and `s` where its result is read,
+    and for each request finished the number of steps read by then."""
+    events: list[str] = []
+    done_at: list[int] = []
+    admit, step, read, finish = cb._admit, cb._step, cb._read, cb._finish
+
+    def admit_logged(req, slot, cache):
+        events.append(f"P{slot}")
+        return admit(req, slot, cache)
+
+    def step_logged(*a, **kw):
+        events.append("S")
+        return step(*a, **kw)
+
+    def read_logged(done):
+        events.append("p" if done.batch is None else "s")
+        return read(done)
+
+    def finish_logged(slot, req):
+        done_at.append(events.count("s"))
+        return finish(slot, req)
+
+    cb._admit, cb._step = admit_logged, step_logged
+    cb._read, cb._finish = read_logged, finish_logged
+    return events, done_at
+
+
 @pytest.mark.parametrize("slots,requests", [(2, 2), (3, 3), (2, 5), (4, 3)])
 def test_a_boundary_runs_one_prefill_and_slots_finish_a_step_apart(
     slots, requests
@@ -456,38 +497,169 @@ def test_a_boundary_runs_one_prefill_and_slots_finish_a_step_apart(
     chat = _chat(decode_slots=slots, max_new_tokens=new)
     cb = chat._cb
     cb.drain()  # the construction's own pass
-    events: list[str] = []
-    done_at: list[int] = []
-    admit, step, finish = cb._admit, cb._step, cb._finish
-
-    def admit_logged(req, slot, cache):
-        events.append("P")
-        return admit(req, slot, cache)
-
-    def step_logged(*a, **kw):
-        events.append("S")
-        return step(*a, **kw)
-
-    def finish_logged(slot, req):
-        done_at.append(events.count("S"))
-        return finish(slot, req)
-
-    cb._admit, cb._step, cb._finish = admit_logged, step_logged, finish_logged
+    events, done_at = _logged(cb)
     prompts = [f"question number {i} of the queue" for i in range(requests)]
-    with cb._lock:
-        cb._running = True  # hold the thread back until all are queued
-    futs = [cb.submit(p) for p in prompts]
-    with cb._lock:
-        cb._start_thread()
-    got = [f.result(timeout=120) for f in futs]
+    got = [f.result(timeout=120) for f in _held_back(cb, prompts)]
     cb.drain()
     assert got == chat._generate_batch(prompts)
-    seq = "".join(events)
+    seq = "".join(e[0] for e in events if e[0] in "PS")
     assert seq.count("P") == requests and "PP" not in seq, seq
     first = min(slots, requests)
     assert seq.startswith("PS" * first), seq
     # no two answers of one filling finish at the same step
-    assert len(set(done_at)) == requests, (done_at, seq)
+    assert len(set(done_at)) == requests, (done_at, events)
     if requests <= slots:
         # the last of a burst into an idle pool pays k - 1 steps more
         assert cb.stats["decode_steps"] == (new - 1) + (requests - 1)
+
+
+# ------------------------------------------ programs dispatched ahead of the read
+
+
+def test_programs_are_dispatched_ahead_of_the_read():
+    """Three requests of three tokens over two slots, the whole order: each
+    result is read after the next two programs went out; the slot whose
+    last step is out (0, after the second step) takes the third prefill at
+    that very boundary, before that step is read; the last programs are
+    read with nothing behind them, and only then does the thread leave."""
+    assert _AHEAD == 2  # the order below is that depth's
+    chat = _chat(decode_slots=2, max_new_tokens=3)
+    cb = chat._cb
+    cb.drain()
+    events, done_at = _logged(cb)
+    prompts = ["first of three", "second", "the third one"]
+    got = [f.result(timeout=120) for f in _held_back(cb, prompts)]
+    cb.drain()
+    assert got == chat._generate_batch(prompts)
+    assert " ".join(events) == "P0 S P1 p S s P0 p S s S p s s"
+    assert done_at == [2, 3, 4]
+    s = cb.stats
+    assert (s["prefills"], s["decode_steps"], s["completed"]) == (3, 4, 3)
+    assert 0 <= s["dispatched_ahead"] <= 6  # all but the first may be
+    assert not cb._out and not cb._leaving | set(cb._active)
+    assert cb.pool.snapshot()["active"] == 0 and cb.queue_depth() == 0
+
+
+@pytest.mark.parametrize("new", [1, 2, 5])
+@pytest.mark.parametrize("slots", [1, 2])
+def test_reading_late_keeps_the_tokens_at_the_edges(new, slots):
+    """A queue deeper than the pool, and the edges of reading `_AHEAD`
+    programs late: an answer that is its prefill's token alone (no step is
+    ever dispatched, and its slot is free as soon as the prefill is out),
+    and one whose only step is its last."""
+    chat = _chat(decode_slots=slots, max_new_tokens=new)
+    cb = chat._cb
+    cb.drain()
+    events, _ = _logged(cb)
+    prompts = [f"prompt {i} of a queue deeper than the pool" for i in range(5)]
+    got = [f.result(timeout=120) for f in _held_back(cb, prompts)]
+    cb.drain()
+    assert got == chat._generate_batch(prompts)
+    assert all(len(g.split()) == new for g in got)
+    if new == 1:
+        # prefills alone, each ahead of the reads
+        assert "".join(e[0] for e in events) == "PPPpPpPppp"
+        assert cb.stats["decode_steps"] == 0
+    # results are read in the order their programs went out, each after
+    # `_AHEAD` more dispatches (the last ones have none behind them)
+    sent_at = [i for i, e in enumerate(events) if e[0] in "PS"]
+    read_at = [i for i, e in enumerate(events) if e in "ps"]
+    assert [events[i] for i in read_at] == [events[i][0].lower() for i in sent_at]
+    assert all(n < read for n, read in zip(sent_at[_AHEAD:], read_at)), events
+    assert cb.stats["completed"] == cb.stats["prefills"] == 5
+    assert cb.pool.snapshot()["active"] == 0 and cb.queue_depth() == 0
+
+
+def test_queue_depth_counts_a_request_until_its_reply_leaves():
+    """The slot goes back when the last step is dispatched; the request is
+    still the batcher's until that step is read."""
+    chat = _chat(decode_slots=1, max_new_tokens=2)
+    cb = chat._cb
+    cb.drain()
+    seen = []
+    read = cb._read
+
+    def read_logged(done):
+        seen.append(
+            (cb.queue_depth(), cb.pool.snapshot()["active"], len(cb._leaving))
+        )
+        return read(done)
+
+    cb._read = read_logged
+    assert cb.submit("a b c").result(timeout=60)
+    cb.drain()
+    # the prefill is read with the only step out and nothing more to
+    # dispatch: no slot held, one request leaving; then that step, the same
+    assert seen == [(1, 0, 1), (1, 0, 1)]
+    assert cb.queue_depth() == 0
+
+
+@pytest.mark.parametrize("fails_at", [1, 2, 3])
+def test_a_read_that_raises_fails_every_future_and_returns_every_slot(fails_at):
+    """The read of a prefill (1, 3) or of a step (2) raises: every request
+    fails with it — queued, holding a slot, or with its last step out and
+    not read — every slot is back, the lease is back, and the same batcher
+    serves again."""
+    chat = _chat(decode_slots=2, max_new_tokens=3)
+    cb = chat._cb
+    cb.drain()
+    read, reads = cb._read, []
+
+    def read_failing(done):
+        reads.append(done)
+        if len(reads) == fails_at:
+            raise RuntimeError("result lost")
+        return read(done)
+
+    cb._read = read_failing
+    futs = _held_back(cb, [f"request {i}" for i in range(4)])
+    for fut in futs:
+        with pytest.raises(RuntimeError, match="result lost"):
+            fut.result(timeout=60)
+    cb.drain()
+    assert len(reads) == fails_at
+    assert cb.pool.snapshot()["active"] == 0 and cb.queue_depth() == 0
+    assert not cb._out and not cb._leaving | set(cb._active)
+    assert cb.stats["completed"] == 0
+    assert len(chat._plane._leases[cb._cache_key]) == 1
+    cb._read = read
+    prompts = ["served after the failure", "and a second"]
+    got = [f.result(timeout=60) for f in [cb.submit(p) for p in prompts]]
+    cb.drain()
+    assert got == chat._generate_batch(prompts)
+
+
+def test_submitters_racing_the_loop_lose_no_request():
+    """More submitting threads than cores and a short switch interval: the
+    loop takes from the queue, frees slots at a dispatch and resolves at
+    a read while they add to it; every request is answered with the
+    oracle's tokens and nothing is left held."""
+    import sys
+    import threading
+
+    chat = _chat(decode_slots=2, max_new_tokens=3)
+    cb = chat._cb
+    prompts = [f"racing prompt {i}" for i in range(6)]
+    want = dict(zip(prompts, chat._generate_batch(prompts)))
+    got: list = []
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        def client(k):
+            for p in prompts[k % 3:] + prompts[:k % 3]:
+                got.append((p, cb.submit(p).result(timeout=120)))
+
+        threads = [threading.Thread(target=client, args=(k,)) for k in range(12)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(180)
+        assert not any(t.is_alive() for t in threads)
+    finally:
+        sys.setswitchinterval(interval)
+    cb.drain()
+    assert len(got) == 12 * len(prompts)
+    assert all(out == want[p] for p, out in got)
+    assert cb.stats["completed"] == cb.stats["prefills"] == len(got)
+    assert cb.pool.snapshot()["active"] == 0 and cb.queue_depth() == 0
+    assert not cb._out and not cb._leaving | set(cb._active)
