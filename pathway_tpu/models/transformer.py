@@ -22,8 +22,11 @@ Design notes (TPU-first):
   [layers, B, kv heads, S, Dh] (the decoding section says why).
 - The decoder is a list of layers (`LayerSpec`): attention over every
   earlier position or over a window whose cache rows are a ring, learned,
-  rotary or no positions, a dense GELU feed-forward or routed ReGLU
-  experts, with key/value heads shared by groups of query heads. The
+  rotary or no positions, a dense GELU or SwiGLU feed-forward or routed
+  ReGLU experts, with key/value heads shared by groups of query heads; a
+  layer's mixer is softmax attention, softmax attention over blocks of keys
+  it chooses by a score over pooled keys (`sparse`), or a linear recurrence
+  with a decay a head and a state instead of rows of keys (`linear`). The
   default list is the plain block above; see the decoding section.
 """
 
@@ -50,7 +53,37 @@ class LayerSpec:
 
     window: int | None = None  # None: every earlier position; W: the last W
     pos: str = "learned"  # learned (the table, added to the embedding) | rotary | none
-    ff: str = "gelu"  # gelu (dense) | experts (routed ReGLU, top n_active of n_experts)
+    # gelu (dense) | swiglu (dense, silu(gate) * up) | experts (routed ReGLU,
+    # top n_active of n_experts)
+    ff: str = "gelu"
+    # softmax (attention over the keys `window` allows) | sparse (over the
+    # blocks of keys `SparseSpec` chooses for each query) | linear (no
+    # softmax: a decayed sum of k^T v, kept as a state)
+    mixer: str = "softmax"
+
+
+@dataclasses.dataclass(frozen=True)
+class SparseSpec:
+    """What a `sparse` layer chooses its keys by (InfLLM v2's sizes). Keys
+    are pooled by their mean over `kernel` positions every `stride`; a
+    query scores each pooled key it can see whole, a block of `block`
+    positions scores the best of the pooled keys that overlap it, and the
+    query attends block 0 .. `init_blocks` - 1, the blocks that hold its
+    last `window` positions and the best others up to `topk` blocks, one
+    set for the query heads that share a key head. A row of `dense_len`
+    positions or fewer attends every earlier position."""
+
+    topk: int = 64
+    block: int = 64
+    kernel: int = 32
+    stride: int = 16
+    init_blocks: int = 1
+    window: int = 2048
+    dense_len: int = 8192
+
+    @property
+    def local_blocks(self) -> int:
+        return self.window // self.block
 
 
 @dataclasses.dataclass(frozen=True)
@@ -92,6 +125,25 @@ class TransformerConfig:
     n_active: int = 0
     rope_theta: float = 10000.0
     tie_embeddings: bool = True
+    # What the `sparse` and `linear` mixers read, and the parts a block may
+    # have around any mixer. qk_norm: q and k are RMS-normed over each
+    # head's width with a learned scale; out_gate: every mixer's output
+    # times sigmoid(h W_gate), element by element, before W_o;
+    # linear_out_norm: a linear layer's output RMS-normed over each
+    # head's width first. A linear layer has `linear_heads` heads (None:
+    # n_heads) of head_dim, keys and values as many, and head h decays its
+    # state by exp(-linear_slopes[h]) a position. The three scales are
+    # MiniCPM's: the embedding times embed_scale, every residual branch
+    # times residual_scale, the last norm's output times logit_scale.
+    sparse: SparseSpec | None = None
+    qk_norm: bool = False
+    out_gate: bool = False
+    linear_out_norm: bool = False
+    linear_heads: int | None = None
+    linear_slopes: tuple[float, ...] | None = None
+    embed_scale: float = 1.0
+    residual_scale: float = 1.0
+    logit_scale: float = 1.0
 
     @property
     def head_dim(self) -> int:
@@ -119,6 +171,13 @@ class TransformerConfig:
     def n_expert_layers(self) -> int:
         return sum(sp.ff == "experts" for sp in self.layer_specs)
 
+    def n_mixer_layers(self, mixer: str) -> int:
+        return sum(sp.mixer == mixer for sp in self.layer_specs)
+
+    @property
+    def lin_heads(self) -> int:
+        return self.linear_heads or self.n_heads
+
     @property
     def plain(self) -> bool:
         """The one block the encoder and the training step run."""
@@ -127,6 +186,8 @@ class TransformerConfig:
             and self.kv_heads == self.n_heads
             and self.head_size is None
             and self.tie_embeddings
+            and not (self.qk_norm or self.out_gate)
+            and self.embed_scale == self.residual_scale == self.logit_scale == 1.0
         )
 
     def __post_init__(self) -> None:
@@ -144,13 +205,42 @@ class TransformerConfig:
         for sp in specs:
             if sp.pos not in ("learned", "rotary", "none"):
                 raise ValueError(f"pos must be learned|rotary|none, got {sp.pos!r}")
-            if sp.ff not in ("gelu", "experts"):
-                raise ValueError(f"ff must be gelu|experts, got {sp.ff!r}")
+            if sp.ff not in ("gelu", "swiglu", "experts"):
+                raise ValueError(f"ff must be gelu|swiglu|experts, got {sp.ff!r}")
+            if sp.mixer not in ("softmax", "sparse", "linear"):
+                raise ValueError(
+                    f"mixer must be softmax|sparse|linear, got {sp.mixer!r}"
+                )
+            if sp.mixer != "softmax" and sp.window is not None:
+                raise ValueError("a window is a softmax layer's")
+            if sp.mixer == "linear" and sp.pos == "learned":
+                raise ValueError("a linear layer's positions are rotary or none")
         if len({sp.window for sp in specs if sp.window is not None}) > 1:
             # the window layers' rows are one stacked ring
             raise ValueError("the window layers of one decoder share one window")
         if self.n_expert_layers and not 0 < self.n_active <= self.n_experts:
             raise ValueError("experts layers need 0 < n_active <= n_experts")
+        if self.n_mixer_layers("sparse"):
+            sq = self.sparse
+            if sq is None:
+                raise ValueError("sparse layers need `sparse` (a SparseSpec)")
+            if (
+                sq.block % sq.stride or sq.kernel % sq.stride
+                or sq.window % sq.block
+                or self.max_len % sq.block
+                or -(-sq.kernel // sq.stride) - 1 > sq.block // sq.stride
+                or sq.init_blocks + sq.local_blocks > sq.topk
+            ):
+                raise ValueError(
+                    "sparse: stride divides kernel and block, block divides window and "
+                    "max_len, a pooled key overlaps two blocks at most, and "
+                    "topk holds the init and local blocks"
+                )
+        if self.n_mixer_layers("linear") and (
+            self.linear_slopes is None
+            or len(self.linear_slopes) != self.lin_heads
+        ):
+            raise ValueError("linear layers need a slope for each linear head")
         if not self.plain and not self.causal:
             raise ValueError("the encoder runs the plain block only")
 
@@ -169,13 +259,21 @@ def lm_config(**kw) -> TransformerConfig:
 # ------------------------------------------------------------------ params
 
 
+def _mixer_heads(cfg: TransformerConfig, spec: LayerSpec) -> tuple[int, int]:
+    """A layer's query heads and its key/value heads."""
+    if spec.mixer == "linear":
+        return cfg.lin_heads, cfg.lin_heads
+    return cfg.n_heads, cfg.kv_heads
+
+
 def _init_block(
     rng: Array, cfg: TransformerConfig, dtype: Any = jnp.float32,
     spec: LayerSpec = LayerSpec(),
 ) -> Params:
     d, f = cfg.d_model, cfg.d_ff
-    hd = cfg.n_heads * cfg.head_dim  # the query heads' width: d in the plain block
-    kv = cfg.kv_heads * cfg.head_dim
+    h, hk = _mixer_heads(cfg, spec)
+    hd = h * cfg.head_dim  # the query heads' width: d in the plain block
+    kv = hk * cfg.head_dim
     ks = jax.random.split(rng, 6)
     s = 1.0 / math.sqrt(d)
 
@@ -188,12 +286,23 @@ def _init_block(
         "ln1_scale": jnp.ones((d,), dtype),
         "ln2_scale": jnp.ones((d,), dtype),
     }
+    if cfg.qk_norm:
+        block["q_norm"] = jnp.ones((cfg.head_dim,), dtype)
+        block["k_norm"] = jnp.ones((cfg.head_dim,), dtype)
+    if cfg.out_gate:
+        block["gate"] = leaf(jax.random.fold_in(ks[0], 1), (d, hd), s)
+    if spec.mixer == "linear" and cfg.linear_out_norm:
+        block["o_norm"] = jnp.ones((cfg.head_dim,), dtype)
     if spec.ff == "experts":
         e = cfg.n_experts
         block["router"] = leaf(ks[4], (d, e), s)
         block["expert_gate"] = leaf(ks[2], (e, d, f), s)
         block["expert_up"] = leaf(ks[5], (e, d, f), s)
         block["expert_down"] = leaf(ks[3], (e, f, d), 1.0 / math.sqrt(f))
+    elif spec.ff == "swiglu":
+        block["ff_gate"] = leaf(ks[2], (d, f), s)
+        block["ff_up"] = leaf(ks[5], (d, f), s)
+        block["ff_out"] = leaf(ks[3], (f, d), 1.0 / math.sqrt(f))
     else:
         block["ff_in"] = leaf(ks[2], (d, f), s)
         block["ff_out"] = leaf(ks[3], (f, d), 1.0 / math.sqrt(f))
@@ -254,10 +363,19 @@ def param_specs(cfg: TransformerConfig) -> Params:
             "ln1_scale": P(None),
             "ln2_scale": P(None),
         }
+        if cfg.qk_norm:
+            out["q_norm"] = out["k_norm"] = P(None)
+        if cfg.out_gate:
+            out["gate"] = P(None, "model")
+        if spec.mixer == "linear" and cfg.linear_out_norm:
+            out["o_norm"] = P(None)
         if spec.ff == "experts":
             out["router"] = P(None, None)
             for name in ("expert_gate", "expert_up", "expert_down"):
                 out[name] = P("model", None, None)
+        elif spec.ff == "swiglu":
+            out["ff_gate"] = out["ff_up"] = P(None, "model")
+            out["ff_out"] = P("model", None)
         else:
             out["ff_in"] = P(None, "model")
             out["ff_out"] = P("model", None)
@@ -379,6 +497,19 @@ def _attention(
 
 def _ffn(x: Array, block: Params, cfg: TransformerConfig) -> Array:
     with jax.named_scope("ff"):
+        if "ff_gate" in block:  # swiglu: silu(x W_gate) * (x W_up), then W_out
+            gate = jax.nn.silu(jnp.einsum(
+                "bsd,df->bsf", x, block["ff_gate"].astype(cfg.dtype),
+                preferred_element_type=jnp.float32,
+            )).astype(cfg.dtype)
+            hline = (jnp.einsum(
+                "bsd,df->bsf", x, block["ff_up"].astype(cfg.dtype),
+                preferred_element_type=jnp.float32,
+            ) * gate).astype(cfg.dtype)
+            return jnp.einsum(
+                "bsf,fd->bsd", hline, block["ff_out"].astype(cfg.dtype),
+                preferred_element_type=jnp.float32,
+            ).astype(cfg.dtype)
         hline = jnp.einsum(
             "bsd,df->bsf", x, block["ff_in"].astype(cfg.dtype),
             preferred_element_type=jnp.float32,
@@ -556,42 +687,121 @@ def make_train_step(cfg: TransformerConfig, learning_rate: float = 1e-3):
 # `step_uses_kernel` holds; it takes the stacked leaf as its operand and
 # fetches only the tiles that hold a live row of the slot. Everywhere else
 # (the CPU, heads of 64, tensor-parallel parameters) the plain `_attend`.
+#
+# A layer's mixer need not be that attention. A `sparse` layer keeps rows
+# of keys too, but at LOGICAL positions ("k_sparse"/"v_sparse": the prefill
+# turns the left pad behind the prompt), and a pooled key every `stride`
+# positions ("k_pool"); each query scores the pooled keys it sees whole,
+# chooses blocks of rows by them and attends those (`select_blocks`;
+# ops/sparse_attention.py where `sparse_prefill_uses_kernel` /
+# `sparse_step_uses_kernel` hold, `_attend` under the blocks' mask
+# elsewhere; up to `dense_len` positions a row attends every earlier key
+# through the attentions above). A `linear` layer keeps no rows at all: its
+# leaf "state" is a float32 [heads, dh, dh] sum a slot, which a prefill's
+# chunked scan leaves after the last token (`linear_scan`;
+# ops/linear_attention.py where `linear_prefill_uses_kernel` holds) and a
+# step decays and adds to.
 
 # what an experts decoder's two programs append to the tokens they return,
 # in this order (ContinuousBatcher adds them into its `stats`)
 PREFILL_COUNTERS = ("routed_pairs", "expert_load_max")
 STEP_COUNTERS = ("experts_touched", "moe_layers_run")
+# and what a decoder with sparse or linear layers appends behind those, to
+# both programs: summed over real queries, key heads and sparse layers the
+# blocks a query attended and the blocks at or before it, and the real
+# tokens a prefill's linear layers scanned, summed over those layers (a
+# step sends 0 there)
+MIXER_COUNTERS = ("sparse_blocks_read", "sparse_blocks_visible", "linear_tokens")
+
+
+def _has_mixers(cfg: TransformerConfig) -> bool:
+    return any(sp.mixer != "softmax" for sp in cfg.layer_specs)
+
+
+def prefill_counters(cfg: TransformerConfig) -> tuple[str, ...]:
+    """The counters `prefill_into_slot` appends to its token, in order."""
+    return (
+        PREFILL_COUNTERS * bool(cfg.n_expert_layers)
+        + MIXER_COUNTERS * _has_mixers(cfg)
+    )
+
+
+def step_counters(cfg: TransformerConfig) -> tuple[str, ...]:
+    """The counters `decode_step_slots` appends to its tokens, in order."""
+    return (
+        STEP_COUNTERS * bool(cfg.n_expert_layers)
+        + MIXER_COUNTERS * _has_mixers(cfg)
+    )
+
+
+# The slot cache's leaves, by the kind of layer that keeps them. Every leaf
+# is stacked over the layers of its kind and has the slot second:
+# [layers of the kind, slots, ...]. `k`/`v` (softmax over every earlier
+# position) and `k_win`/`v_win` (a window's ring) hold rows at physical
+# positions; `k_sparse`/`v_sparse` hold a sparse layer's rows at LOGICAL
+# positions (the left pad taken off, so that a block of the selection is a
+# block of rows) with `k_pool`, the pooled keys, one every `stride`
+# positions; `state` is a linear layer's float32 sum, [heads, dh, dh].
+_SLOT_AXIS = 1
+
+
+def _layer_kind(spec: LayerSpec) -> str:
+    if spec.mixer != "softmax":
+        return spec.mixer
+    return "global" if spec.window is None else "window"
+
+
+_KIND_LEAVES = {
+    "global": {"k": "k", "v": "v"},
+    "window": {"k": "k_win", "v": "v_win"},
+    "sparse": {"k": "k_sparse", "v": "v_sparse", "pool": "k_pool"},
+    "linear": {"state": "state"},
+}
 
 
 def init_kv_cache(cfg: TransformerConfig, batch: int) -> Params:
-    specs = cfg.layer_specs
-    n_win = sum(sp.window is not None for sp in specs)
-    shape = (len(specs) - n_win, batch, cfg.kv_heads, cfg.max_len, cfg.head_dim)
-    cache = {
-        "k": jnp.zeros(shape, cfg.dtype),
-        "v": jnp.zeros(shape, cfg.dtype),
-    }
-    if n_win:
-        ring = (n_win, batch, cfg.kv_heads, cfg.window, cfg.head_dim)
+    kinds = [_layer_kind(sp) for sp in cfg.layer_specs]
+    n = {kind: kinds.count(kind) for kind in _KIND_LEAVES}
+    hk, dh = cfg.kv_heads, cfg.head_dim
+    cache = {}
+    if n["global"]:
+        shape = (n["global"], batch, hk, cfg.max_len, dh)
+        cache["k"] = jnp.zeros(shape, cfg.dtype)
+        cache["v"] = jnp.zeros(shape, cfg.dtype)
+    if n["window"]:
+        ring = (n["window"], batch, hk, cfg.window, dh)
         cache["k_win"] = jnp.zeros(ring, cfg.dtype)
         cache["v_win"] = jnp.zeros(ring, cfg.dtype)
+    if n["sparse"]:
+        rows = (n["sparse"], batch, hk, cfg.max_len, dh)
+        cache["k_sparse"] = jnp.zeros(rows, cfg.dtype)
+        cache["v_sparse"] = jnp.zeros(rows, cfg.dtype)
+        cache["k_pool"] = jnp.zeros(
+            (n["sparse"], batch, hk, cfg.max_len // cfg.sparse.stride, dh),
+            cfg.dtype,
+        )
+    if n["linear"]:
+        cache["state"] = jnp.zeros(
+            (n["linear"], batch, cfg.lin_heads, dh, dh), jnp.float32
+        )
     return cache
 
 
-def _cache_rows(cfg: TransformerConfig) -> list[tuple[str, str, int]]:
-    """Per layer: its cache leaves and its index along their layer axis."""
-    out, n = [], {"k": 0, "k_win": 0}
+def _cache_rows(cfg: TransformerConfig) -> list[tuple[dict[str, str], int]]:
+    """Per layer: its cache leaves by what they hold (`_KIND_LEAVES` of the
+    layer's kind) and its index along their layer axis."""
+    out, n = [], dict.fromkeys(_KIND_LEAVES, 0)
     for sp in cfg.layer_specs:
-        kname = "k" if sp.window is None else "k_win"
-        out.append((kname, "v" + kname[1:], n[kname]))
-        n[kname] += 1
+        kind = _layer_kind(sp)
+        out.append((_KIND_LEAVES[kind], n[kind]))
+        n[kind] += 1
     return out
 
 
-def _qkv(xin: Array, block: Params, cfg: TransformerConfig):
+def _qkv(xin: Array, block: Params, cfg: TransformerConfig, spec: LayerSpec):
     """q [b, s, heads, dh] and k, v [b, s, kv heads, dh] of normed rows."""
     b, s, _ = xin.shape
-    h, hk, dh = cfg.n_heads, cfg.kv_heads, cfg.head_dim
+    (h, hk), dh = _mixer_heads(cfg, spec), cfg.head_dim
     qkv = jnp.einsum(
         "bsd,de->bse", xin, block["qkv"].astype(cfg.dtype),
         preferred_element_type=jnp.float32,
@@ -718,6 +928,8 @@ def _experts(u: Array, idx: Array, w: Array, live: Array, block: Params,
 
 def _lm_logits(hline: Array, params: Params, cfg: TransformerConfig) -> Array:
     with jax.named_scope("logits"):
+        if cfg.logit_scale != 1.0:
+            hline = (hline * cfg.logit_scale).astype(hline.dtype)
         if cfg.tie_embeddings:
             return jnp.einsum(
                 "bsd,vd->bsv", hline, params["tok_embed"].astype(cfg.dtype),
@@ -729,31 +941,248 @@ def _lm_logits(hline: Array, params: Params, cfg: TransformerConfig) -> Array:
         )
 
 
+def _embed(params: Params, token: Array, cfg: TransformerConfig) -> Array:
+    x = params["tok_embed"].astype(cfg.dtype)[token]
+    if cfg.embed_scale != 1.0:
+        x = (x * cfg.embed_scale).astype(cfg.dtype)
+    return x
+
+
+def _branch(x: Array, y: Array, cfg: TransformerConfig) -> Array:
+    """The residual stream plus a branch's output."""
+    if cfg.residual_scale != 1.0:
+        y = (y * cfg.residual_scale).astype(y.dtype)
+    return x + y
+
+
 def _layer(x, block, spec, cfg, pos, live, attend, counters):
     """One decoder layer over rows x [b, s, d]. `attend(q, k, v)` writes the
-    layer's keys and values where they belong and returns the attention's
-    context; `pos` [b, s] are logical positions, `live` [b, s] the rows that
-    count (not padding, not a free slot). An experts layer appends its
-    per-expert counts of live pairs to `counters`."""
+    layer's keys and values (or its state) where they belong and returns
+    the mixer's output; `pos` [b, s] are logical positions, `live` [b, s]
+    the rows that count (not padding, not a free slot). An experts layer
+    appends its per-expert counts of live pairs to `counters["experts"]`."""
     if spec.ff == "experts":
         idx, w = _route(x, block, cfg)
     xin = _rmsnorm(x, block["ln1_scale"])
     with jax.named_scope("attn"):
-        q, k, v = _qkv(xin, block, cfg)
+        q, k, v = _qkv(xin, block, cfg, spec)
+        if cfg.qk_norm:
+            q, k = _rmsnorm(q, block["q_norm"]), _rmsnorm(k, block["k_norm"])
         if spec.pos == "rotary":
             q, k = _rope(q, pos, cfg), _rope(k, pos, cfg)
     ctx = attend(q, k, v)
     with jax.named_scope("attn"):
-        x = x + jnp.einsum(
+        if cfg.out_gate:
+            with jax.named_scope("gate"):
+                gate = jax.nn.sigmoid(jnp.einsum(
+                    "bsd,de->bse", xin, block["gate"].astype(cfg.dtype),
+                    preferred_element_type=jnp.float32,
+                ))
+                ctx = (ctx * gate).astype(cfg.dtype)
+        x = _branch(x, jnp.einsum(
             "bsd,de->bse", ctx, block["o"].astype(cfg.dtype),
             preferred_element_type=jnp.float32,
-        ).astype(cfg.dtype)
+        ).astype(cfg.dtype), cfg)
     u = _rmsnorm(x, block["ln2_scale"])
     if spec.ff == "experts":
         y, counts = _experts(u, idx, w, live, block, cfg)
-        counters.append(counts)
-        return x + y
-    return x + _ffn(u, block, cfg)
+        counters["experts"].append(counts)
+        return _branch(x, y, cfg)
+    return _branch(x, _ffn(u, block, cfg), cfg)
+
+
+def _new_counters() -> dict[str, list]:
+    """What a program's layers append to as they are traced: an experts
+    layer its per-expert counts, a sparse or linear layer its share of each
+    of MIXER_COUNTERS."""
+    return {"experts": [], **{name: [] for name in MIXER_COUNTERS}}
+
+
+def _mixer_counts(counters: dict[str, list]) -> list:
+    """MIXER_COUNTERS of one program, from what its layers appended."""
+    return [
+        sum(counters[name], jnp.zeros((), jnp.int32)) for name in MIXER_COUNTERS
+    ]
+
+
+# ------------------------------------------------- the linear mixer
+#
+# o_t = (q_t / sqrt(dh)) S_t with S_t = lambda S_{t-1} + k_t^T v_t, lambda =
+# exp(-slope) a head: no softmax and no normaliser, and instead of rows of
+# keys a float32 state [dh, dh] a head. A prefill scans its prompt in
+# chunks: inside a chunk the decay-masked product (q k^T * D) v with D_ij =
+# lambda^(i-j) for j <= i, between chunks the carried state. Prompts are
+# left-padded: a pad's key is zeroed before the scan, so it adds nothing,
+# and a state of zeros decays to zeros, so the pads before the first real
+# token do not count.
+
+_LINEAR_CHUNK = 256  # ops/linear_attention.py's chunk, and the scan's below
+
+
+def _slopes(cfg: TransformerConfig) -> Array:
+    return jnp.asarray(cfg.linear_slopes, jnp.float32)
+
+
+def linear_scan(q: Array, k: Array, v: Array, slopes: Array,
+                chunk: int = _LINEAR_CHUNK):
+    """The chunked scan in `jax.numpy`: q, k, v [b, p, heads, dh] (a pad's
+    key zeroed) -> (o [b, p, heads, dh] float32, the state after the last
+    position [b, heads, dh, dh] float32). Products of the inputs' dtype
+    accumulate in float32; the state and what multiplies it stay float32."""
+    b, p, h, dh = q.shape
+    chunk = min(chunk, p)
+    extra = -p % chunk
+    if extra:  # zeros in front add nothing and decay nothing
+        q, k, v = (jnp.pad(a, ((0, 0), (extra, 0), (0, 0), (0, 0))) for a in (q, k, v))
+    n = (p + extra) // chunk
+    # [chunks, b, heads, chunk, dh]
+    qc, kc, vc = (
+        a.reshape(b, n, chunk, h, dh).transpose(1, 0, 3, 2, 4) for a in (q, k, v)
+    )
+    at = jnp.arange(chunk, dtype=jnp.float32)
+    ago = at[:, None] - at[None, :]
+    rate = slopes[:, None, None]
+    decay = jnp.where(ago >= 0, jnp.exp(-rate * jnp.maximum(ago, 0.0)), 0.0)
+    into = jnp.exp(-slopes[:, None] * (at + 1.0))[..., None]  # the old state's share
+    left = jnp.exp(-slopes[:, None] * (chunk - 1.0 - at))[..., None]  # a key's, at the end
+    whole = jnp.exp(-slopes * chunk)[:, None, None]
+    high = jax.lax.Precision.HIGHEST
+
+    def one(state, qkv):
+        qi, ki, vi = qkv
+        pairs = jnp.einsum(
+            "bhid,bhjd->bhij", qi, ki, preferred_element_type=jnp.float32
+        ) * decay
+        inner = jnp.einsum(
+            "bhij,bhjd->bhid", pairs.astype(vi.dtype), vi,
+            preferred_element_type=jnp.float32,
+        )
+        carried = jnp.einsum(
+            "bhid,bhde->bhie", qi.astype(jnp.float32) * into, state, precision=high
+        )
+        state = whole * state + jnp.einsum(
+            "bhjd,bhje->bhde", ki.astype(jnp.float32) * left,
+            vi.astype(jnp.float32), precision=high,
+        )
+        return state, inner + carried
+
+    state, out = jax.lax.scan(
+        one, jnp.zeros((b, h, dh, dh), jnp.float32), (qc, kc, vc)
+    )
+    out = out.transpose(1, 0, 3, 2, 4).reshape(b, n * chunk, h, dh)
+    return out[:, extra:] / math.sqrt(dh), state
+
+
+def linear_step(q: Array, k: Array, v: Array, state: Array, slopes: Array,
+                live: Array | None = None):
+    """One more position of the scan: q, k, v [b, heads, dh], state
+    [b, heads, dh, dh] float32 -> (o [b, heads, dh] float32, state). A row
+    that is not `live` [b] keeps its state: it decays by 1 and adds 0, so
+    that what is written back is one plain update of the leaf."""
+    decay = jnp.exp(-slopes)[None, :, None, None]
+    added = k.astype(jnp.float32)[..., :, None] * v.astype(jnp.float32)[..., None, :]
+    if live is not None:
+        on = live[:, None, None, None]
+        decay, added = jnp.where(on, decay, 1.0), jnp.where(on, added, 0.0)
+    state = decay * state + added
+    out = jnp.einsum(
+        "bhd,bhde->bhe", q.astype(jnp.float32), state,
+        precision=jax.lax.Precision.HIGHEST,
+    )
+    return out / math.sqrt(q.shape[-1]), state
+
+
+def _linear_out(out: Array, block: Params, cfg: TransformerConfig) -> Array:
+    """[b, s, heads, dh] float32 -> the layer's context [b, s, heads * dh]."""
+    b, s, h, dh = out.shape
+    if cfg.linear_out_norm:
+        out = _rmsnorm(out, block["o_norm"].astype(jnp.float32))
+    return out.astype(cfg.dtype).reshape(b, s, h * dh)
+
+
+# ------------------------------------------------- the sparse mixer
+#
+# Which blocks of keys a query attends (`SparseSpec`). Everything here is
+# in logical positions, counted from a row's first real token.
+
+
+def pool_keys(k: Array, sq: SparseSpec) -> Array:
+    """The pooled keys of rows k [b, kv heads, s, dh] that start at logical
+    position 0: [b, kv heads, s / stride, dh] (s rounded up to whole
+    blocks), pooled key i the mean of
+    positions stride i .. stride i + kernel - 1 (a window that runs past
+    the end takes zeros there: no query can see it yet). Summed in float32,
+    kept in k's dtype."""
+    b, hk, s, dh = k.shape
+    k = jnp.pad(k, ((0, 0), (0, 0), (0, -s % sq.block), (0, 0)))
+    n = k.shape[2] // sq.stride
+    part = k.astype(jnp.float32).reshape(
+        b, hk, n, sq.stride, dh
+    ).sum(axis=3)
+    whole = -(-sq.kernel // sq.stride)  # strides a window spans
+    part = jnp.pad(part, ((0, 0), (0, 0), (0, whole - 1), (0, 0)))
+    total = sum(part[:, :, j:j + n] for j in range(whole))
+    return (total / sq.kernel).astype(k.dtype)
+
+
+def select_blocks(q: Array, pooled: Array, t: Array, dense: Array,
+                  sq: SparseSpec) -> Array:
+    """The blocks each query attends: q [b, nq, kv heads, group, dh], pooled
+    [b, kv heads, n_pool, dh], t [b, nq] the queries' logical positions,
+    dense [b] or [b, nq] the rows that attend every earlier position ->
+    [b, kv heads, nq, n_pool * stride / block] bool, one set for a group.
+
+    A query sees pooled key i when the whole window lies at or before it;
+    its relevance is the softmax over the pooled keys it sees, summed over
+    the group's heads; a block's score is the largest relevance among the
+    pooled keys that overlap it. The first `init_blocks` blocks and the
+    `local_blocks` that end in the query's own are always taken, then the
+    best others up to `topk`, or every block at or before the query where
+    those are fewer. Scores from the inputs' dtype with float32
+    accumulation, the softmax in float32."""
+    b, nq, hk, g, dh = q.shape
+    n_pool = pooled.shape[2]
+    m = sq.block // sq.stride  # pooled keys that start in a block
+    before = -(-sq.kernel // sq.stride) - 1  # and those that reach in from the last
+    nb = n_pool // m
+    scores = jnp.einsum(
+        "bqkgd,bkid->bkgqi", q, pooled, preferred_element_type=jnp.float32
+    ) / math.sqrt(dh)
+    ends = sq.stride * jnp.arange(n_pool) + sq.kernel - 1
+    seen = (ends[None, None, :] <= t[:, :, None])[:, None, None]  # [b, 1, 1, nq, n_pool]
+    probs = jax.nn.softmax(jnp.where(seen, scores, -1e30), axis=-1)
+    relevance = jnp.sum(jnp.where(seen, probs, 0.0), axis=2)  # [b, kv heads, nq, n_pool]
+    by_block = relevance.reshape(b, hk, nq, nb, m)
+    score = by_block.max(axis=-1)
+    if before:
+        reach = by_block[..., m - before:].max(axis=-1)
+        score = jnp.maximum(
+            score, jnp.pad(reach[..., :-1], ((0, 0), (0, 0), (0, 0), (1, 0)))
+        )
+    own = (t // sq.block)[:, None, :, None]  # [b, 1, nq, 1]
+    blk = jnp.arange(nb)
+    visible = blk <= own
+    forced = (blk < sq.init_blocks) | (blk > own - sq.local_blocks)
+    score = jnp.where(forced, jnp.inf, score)
+    score = jnp.where(visible, score, -jnp.inf)
+    # a block's rank among the scores, counted and not sorted (a `top_k` of
+    # 384 scores a query was a sort of 80 ms a 24k-token prefill on a v5e);
+    # of equal scores, which neighbouring blocks share with the pooled key
+    # that reaches from one into the next, the lower block goes first
+    mine, theirs = score[..., :, None], score[..., None, :]
+    ahead = (theirs > mine) | ((theirs == mine) & (blk[None, :] < blk[:, None]))
+    chosen = (jnp.sum(ahead, axis=-1, dtype=jnp.int32) < sq.topk) & visible
+    every = jnp.reshape(dense, (b, 1, -1, 1))
+    return jnp.where(every, visible, chosen)
+
+
+def _keys_of_blocks(blocks: Array, at: Array, sq: SparseSpec) -> Array:
+    """blocks [b, kv heads, nq, nb] -> whether each query may read the key at
+    logical position at [b, s] (negative: no key): [b, kv heads, nq, s]."""
+    nb = blocks.shape[-1]
+    idx = jnp.clip(at // sq.block, 0, nb - 1)[:, None, None, :]
+    idx = jnp.broadcast_to(idx, blocks.shape[:3] + idx.shape[-1:])
+    return jnp.take_along_axis(blocks, idx, axis=-1) & (at >= 0)[:, None, None, :]
 
 
 def _step_rows(
@@ -762,11 +1191,12 @@ def _step_rows(
 ):
     """One token of every row, each row at its own physical position `pos`
     [b] behind its own left pad `pad_len` [b]: writes the row's key and
-    value, attends over [pad_len, pos] (a window layer over its ring).
-    Returns (logits [b, vocab], cache, per-expert live-pair counts of each
-    experts layer)."""
+    value, attends over [pad_len, pos] (a window layer over its ring, a
+    sparse layer over the blocks it chooses; a linear layer moves its state
+    on by one position). Returns (logits [b, vocab], cache, what the layers
+    counted: `_new_counters`)."""
     b = token.shape[0]
-    x = params["tok_embed"].astype(cfg.dtype)[token][:, None, :]
+    x = _embed(params, token, cfg)[:, None, :]
     logical = (pos - pad_len)[:, None]
     if cfg.learned_positions:
         x = x + params["pos_embed"].astype(cfg.dtype)[pos - pad_len][:, None, :]
@@ -782,11 +1212,18 @@ def _step_rows(
             wmask = (held >= pad_len[:, None])[:, None, None, :]
         rows, heads = jnp.arange(b)[:, None], jnp.arange(cfg.kv_heads)[None, :]
     live = (pos > 0)[:, None]  # a free slot's vectors are zeros
-    counters: list[Array] = []
-    for (kname, vname, li), spec, block in zip(
+    counters = _new_counters()
+    for (names, li), spec, block in zip(
         _cache_rows(cfg), cfg.layer_specs, params["blocks"]
     ):
-        def attend(q, k, v, kname=kname, vname=vname, li=li, spec=spec):
+        def attend(q, k, v, names=names, li=li, spec=spec, block=block):
+            if spec.mixer == "linear":
+                return _step_linear(q, k, v, cache, names, li, live, block, cfg)
+            if spec.mixer == "sparse":
+                return _step_sparse(
+                    q, k, v, cache, names, li, logical[:, 0], live, cfg, counters
+                )
+            kname, vname = names["k"], names["v"]
             kind = "attn_global" if spec.window is None else "attn_window"
             if kernel:
                 # imported where it is traced: Pallas loads when a
@@ -813,6 +1250,83 @@ def _step_rows(
         x = _layer(x, block, spec, cfg, logical, live, attend, counters)
     hline = _rmsnorm(x, params["ln_f_scale"])
     return _lm_logits(hline, params, cfg)[:, 0, :], cache, counters
+
+
+def _step_linear(q, k, v, cache, names, li, live, block, cfg):
+    """A linear layer's step: the state of every occupied row moves on by
+    one position, in its leaf; a free row's stays as it is."""
+    with jax.named_scope("attn"), jax.named_scope("attn_linear"):
+        leaf = names["state"]
+        out, new = linear_step(
+            q[:, 0], k[:, 0], v[:, 0], cache[leaf][li], _slopes(cfg), live[:, 0]
+        )
+        with jax.named_scope("state_write"):
+            cache[leaf] = jax.lax.dynamic_update_slice(
+                cache[leaf], new[None], (li, 0, 0, 0, 0)
+            )
+        return _linear_out(out[:, None], block, cfg)
+
+
+def _step_sparse(q, k, v, cache, names, li, t, live, cfg, counters):
+    """A sparse layer's step, every row at its logical position t [b]: the
+    key and value go into row t of the slot, the pooled key whose window
+    the row completes (or has completed, up to stride - 1 steps ago: the
+    same rows, the same mean) is written again, and the query attends the
+    blocks it chooses among those at or before t."""
+    sq = cfg.sparse
+    b, hk = q.shape[0], cfg.kv_heads
+    rows, heads = jnp.arange(b)[:, None], jnp.arange(hk)[None, :]
+    kname, vname, pname = names["k"], names["v"], names["pool"]
+    with jax.named_scope("attn"), jax.named_scope("attn_sparse"):
+        with jax.named_scope("pool"):
+            # the newest pooled key whose window ends at or before t; before
+            # the first has ended this writes one nobody sees yet. The
+            # window's rows from the leaf, the step's own key among them
+            newest = jnp.maximum(t - (sq.kernel - 1), 0) // sq.stride
+            first = newest * sq.stride
+            # (a slice a slot out of the stacked leaf itself: batched, the
+            # slices become a gather for which the compiler relays the leaf)
+            window = jnp.concatenate([
+                jax.lax.dynamic_slice(
+                    cache[kname], (li, slot, 0, first[slot], 0),
+                    (1, 1, hk, sq.kernel, cfg.head_dim),
+                )[0] for slot in range(b)
+            ])  # [b, kv heads, kernel, dh]
+            own = (first[:, None] + jnp.arange(sq.kernel) == t[:, None])
+            window = jnp.where(own[:, None, :, None], k[:, 0][:, :, None, :], window)
+            mean = jnp.mean(window.astype(jnp.float32), axis=2).astype(cfg.dtype)
+            cache[pname] = cache[pname].at[li, rows, heads, newest[:, None]].set(mean)
+        with jax.named_scope("select"):
+            blocks = select_blocks(
+                q.reshape(b, 1, hk, -1, cfg.head_dim), cache[pname][li],
+                t[:, None], t < sq.dense_len, sq,
+            )
+            seen = live[:, :, None, None]
+            counters["sparse_blocks_read"].append(jnp.sum(blocks & seen, dtype=jnp.int32))
+            counters["sparse_blocks_visible"].append(
+                hk * jnp.sum(jnp.where(live[:, 0], t // sq.block + 1, 0), dtype=jnp.int32)
+            )
+        if sparse_step_uses_kernel(cfg):
+            # imported where it is traced: Pallas loads when a program
+            # first needs it
+            from pathway_tpu.ops.sparse_attention import (
+                sparse_decode_attention, sparse_decode_tile,
+            )
+
+            ctx, cache[kname], cache[vname] = sparse_decode_attention(
+                q[:, 0], k[:, 0], v[:, 0], cache[kname], cache[vname], li, t,
+                blocks[:, :, 0], block=sq.block,
+                tile=sparse_decode_tile(sq.block, sq.topk, sq.dense_len),
+                steps=sq.topk,
+            )
+            return ctx[:, None]
+    with jax.named_scope("cache_write"):
+        cache[kname] = cache[kname].at[li, rows, heads, t[:, None]].set(k[:, 0])
+        cache[vname] = cache[vname].at[li, rows, heads, t[:, None]].set(v[:, 0])
+    with jax.named_scope("attn"), jax.named_scope("attn_sparse"):
+        at = jnp.broadcast_to(jnp.arange(cfg.max_len)[None, :], (b, cfg.max_len))
+        ok = _keys_of_blocks(blocks, at, sq) & (at <= t[:, None])[:, None, None, :]
+        return _attend(q, cache[kname][li], cache[vname][li], ok, cfg)
 
 
 def decode_step(
@@ -870,6 +1384,46 @@ def prefill_uses_kernel(cfg: TransformerConfig, width: int) -> bool:
     )
 
 
+def linear_prefill_uses_kernel(cfg: TransformerConfig, width: int) -> bool:
+    """Whether the linear layers of a prefill `width` wide run
+    ops/linear_attention.py `linear_prefill_attention` (a chunk's pairs and
+    the state kept in VMEM) and not the `linear_scan` above: where
+    `prefill_uses_kernel` would hold of such a width, for a decoder that has
+    such layers. Read from the shapes and from where the process runs."""
+    return bool(cfg.n_mixer_layers("linear")) and prefill_uses_kernel(cfg, width)
+
+
+def sparse_prefill_uses_kernel(cfg: TransformerConfig, width: int) -> bool:
+    """Whether the sparse layers of a prefill `width` wide run
+    ops/sparse_attention.py `sparse_prefill_attention` over the blocks each
+    query chose: where `prefill_uses_kernel` holds, at a width past
+    `dense_len` (up to it they run `prefill_attention` as every softmax
+    layer does), for a decoder that has such layers."""
+    return (
+        bool(cfg.n_mixer_layers("sparse"))
+        and width > cfg.sparse.dense_len
+        and prefill_uses_kernel(cfg, width)
+    )
+
+
+def sparse_step_uses_kernel(cfg: TransformerConfig) -> bool:
+    """Whether a step's sparse layers run ops/sparse_attention.py
+    `sparse_decode_attention`, which fetches only the tiles that hold a
+    block the query chose and writes the step's row on its way, and not the
+    plain `_attend` over all of the slot's rows under the selection's mask:
+    where `step_uses_kernel` holds, for a decoder with such layers whose
+    rows are whole tiles of `sparse_decode_tile` (a tile's rows a multiple
+    of a packed sublane tile). Read from the shapes and from where the
+    process runs; nothing sets it."""
+    if not cfg.n_mixer_layers("sparse") or not step_uses_kernel(cfg):
+        return False
+    from pathway_tpu.ops.sparse_attention import sparse_decode_tile
+
+    sq = cfg.sparse
+    tile = sparse_decode_tile(sq.block, sq.topk, sq.dense_len)
+    return cfg.max_len % tile == 0 and tile % 16 == 0
+
+
 # a visit of ops/experts.py's kernels fetches an expert's matrices and runs
 # whole blocks of 128 rows: with fewer pairs an expert than that most of a
 # block is masked. Measured at a prefill's 960 an expert (twice as fast as
@@ -913,10 +1467,10 @@ def _prefill(
     params: Params, prompt_ids: Array, cache: Params, cfg: TransformerConfig,
     prompt_mask: Array | None,
 ):
-    """`prefill`, and the per-expert live-pair counts of each experts
-    layer beside its results."""
+    """`prefill`, and what the layers counted (`_new_counters`) beside its
+    results."""
     b, p = prompt_ids.shape
-    x = params["tok_embed"].astype(cfg.dtype)[prompt_ids]
+    x = _embed(params, prompt_ids, cfg)
     if prompt_mask is None:
         valid = jnp.ones((b, p), jnp.int32)
         pos_idx = jnp.broadcast_to(jnp.arange(p)[None, :], (b, p))
@@ -936,11 +1490,21 @@ def _prefill(
             at = jnp.arange(p)
             wmask = mask & (at[None, :] > at[:, None] - window)[None, None]
     live = valid.astype(bool)
-    counters: list[Array] = []
-    for (kname, vname, li), spec, block in zip(
+    counters = _new_counters()
+    for (names, li), spec, block in zip(
         _cache_rows(cfg), cfg.layer_specs, params["blocks"]
     ):
-        def attend(q, k, v, kname=kname, vname=vname, li=li, spec=spec):
+        def attend(q, k, v, names=names, li=li, spec=spec, block=block):
+            if spec.mixer == "linear":
+                return _prefill_linear(
+                    q, k, v, cache, names, li, live, block, cfg, counters
+                )
+            if spec.mixer == "sparse":
+                return _prefill_sparse(
+                    q, k, v, cache, names, li, valid, pos_idx, cfg, counters,
+                    None if kernel else mask,
+                )
+            kname, vname = names["k"], names["v"]
             with jax.named_scope("cache_write"):
                 # head-major, as the cache lies
                 kept_k = kt = k.transpose(0, 2, 1, 3)
@@ -975,6 +1539,103 @@ def _prefill(
         x = _layer(x, block, spec, cfg, pos_idx, live, attend, counters)
     hlast = _rmsnorm(x[:, -1:, :], params["ln_f_scale"])
     return _lm_logits(hlast, params, cfg)[:, 0, :], cache, counters
+
+
+def _prefill_linear(q, k, v, cache, names, li, live, block, cfg, counters):
+    """A linear layer over whole prompts: the chunked scan, and the state
+    it leaves after the last token into the layer's leaf."""
+    p = q.shape[1]
+    with jax.named_scope("attn"), jax.named_scope("attn_linear"):
+        k = jnp.where(live[:, :, None, None], k, jnp.zeros_like(k))  # a pad adds nothing
+        with jax.named_scope("scan"):
+            if linear_prefill_uses_kernel(cfg, p):
+                # imported where it is traced: Pallas loads when a program
+                # first needs it
+                from pathway_tpu.ops.linear_attention import linear_prefill_attention
+
+                out, state = linear_prefill_attention(
+                    q, k, v, _slopes(cfg), _LINEAR_CHUNK
+                )
+            else:
+                out, state = linear_scan(q, k, v, _slopes(cfg))
+        with jax.named_scope("state_write"):
+            cache[names["state"]] = jax.lax.dynamic_update_slice(
+                cache[names["state"]], state[None], (li, 0, 0, 0, 0)
+            )
+        counters["linear_tokens"].append(jnp.sum(live, dtype=jnp.int32))
+        return _linear_out(out, block, cfg)
+
+
+# a prefill's selection scores [heads, queries, pooled keys] are float32:
+# at 24,576 tokens 4.8 GB for the whole prompt. The queries go through in
+# chunks whose scores stay under this
+_SELECT_SCORE_BYTES = 256 << 20
+
+
+def _prefill_sparse(q, k, v, cache, names, li, valid, pos_idx, cfg, counters,
+                    mask):
+    """A sparse layer over whole prompts [b, p]: keys, values and pooled
+    keys into the layer's leaves at their logical positions, and each query
+    over the blocks it chooses (every earlier key where the prompt is no
+    longer than `dense_len`, which a width under it settles when traced).
+    `mask` is the causal mask of the plain path, None where the kernels
+    run."""
+    sq = cfg.sparse
+    b, p, h, dh = q.shape
+    hk = k.shape[2]
+    kname, vname, pname = names["k"], names["v"], names["pool"]
+    n = jnp.sum(valid, axis=1).astype(jnp.int32)  # real tokens of each row
+    with jax.named_scope("cache_write"):
+        # head-major, and each row's first real token in row 0: the pad
+        # goes behind the prompt, where every step writes over it
+        turn = jax.vmap(lambda a, by: jnp.roll(a, by, axis=1))
+        kt = turn(k.transpose(0, 2, 1, 3), n - p)
+        vt = turn(v.transpose(0, 2, 1, 3), n - p)
+        cache[kname] = jax.lax.dynamic_update_slice(
+            cache[kname], kt[None], (li, 0, 0, 0, 0)
+        )
+        cache[vname] = jax.lax.dynamic_update_slice(
+            cache[vname], vt[None], (li, 0, 0, 0, 0)
+        )
+    real = valid.astype(bool)
+    own = jnp.where(real, pos_idx // sq.block + 1, 0)  # blocks at or before each query
+    with jax.named_scope("attn"), jax.named_scope("attn_sparse"):
+        with jax.named_scope("pool"):
+            pooled = pool_keys(kt, sq)
+            cache[pname] = jax.lax.dynamic_update_slice(
+                cache[pname], pooled[None], (li, 0, 0, 0, 0)
+            )
+        counters["sparse_blocks_visible"].append(hk * jnp.sum(own, dtype=jnp.int32))
+        if p <= sq.dense_len:
+            counters["sparse_blocks_read"].append(hk * jnp.sum(own, dtype=jnp.int32))
+            if mask is None:
+                from pathway_tpu.ops.attention import prefill_attention
+
+                return prefill_attention(q, k, v, valid, None)
+            return _attend(q, k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3), mask, cfg)
+        with jax.named_scope("select"):
+            chunk = p
+            while chunk > 128 and chunk % 2 == 0 and (
+                4 * b * h * chunk * pooled.shape[2] > _SELECT_SCORE_BYTES
+            ):
+                chunk //= 2
+            qg = q.reshape(b, p // chunk, chunk, hk, h // hk, dh)
+            tq = jnp.where(real, pos_idx, -1).reshape(b, p // chunk, chunk)
+            blocks = jax.lax.map(
+                lambda qt: select_blocks(qt[0], pooled, qt[1], n <= sq.dense_len, sq),
+                (qg.transpose(1, 0, 2, 3, 4, 5), tq.transpose(1, 0, 2)),
+            )  # [chunks, b, kv heads, chunk, blocks]
+            blocks = blocks.transpose(1, 2, 0, 3, 4).reshape(b, hk, p, -1)
+            counters["sparse_blocks_read"].append(
+                jnp.sum(blocks & real[:, None, :, None], dtype=jnp.int32)
+            )
+        if mask is None:
+            from pathway_tpu.ops.sparse_attention import sparse_prefill_attention
+
+            return sparse_prefill_attention(q, k, v, valid, blocks, sq.block)
+        at = jnp.where(real, pos_idx, -1)  # a key's logical position
+        ok = _keys_of_blocks(blocks, at, sq) & mask
+        return _attend(q, k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3), ok, cfg)
 
 
 def prefill(
@@ -1105,17 +1766,23 @@ def prefill_into_slot(
         params, prompt_ids, init_kv_cache(cfg, 1), cfg, prompt_mask
     )
     with jax.named_scope("cache_write"):
-        for name in mini:
-            cache[name] = jax.lax.dynamic_update_slice(
-                cache[name], mini[name], (0, slot, 0, 0, 0)
-            )
+        for name, row in mini.items():
+            # the slot's whole row of every leaf, whatever its axes: nothing
+            # of the slot's last request stays
+            at = [0] * row.ndim
+            at[_SLOT_AXIS] = slot
+            cache[name] = jax.lax.dynamic_update_slice(cache[name], row, at)
     with jax.named_scope("logits"):
         first = jnp.argmax(lg, -1).astype(jnp.int32)
-    if not counts:
-        return first, cache
-    return _with_counters(first, [
-        sum(c.sum() for c in counts), sum(c.max() for c in counts),
-    ]), cache
+    tail = []
+    if counts["experts"]:
+        tail += [
+            sum(c.sum() for c in counts["experts"]),
+            sum(c.max() for c in counts["experts"]),
+        ]
+    if _has_mixers(cfg):
+        tail += _mixer_counts(counts)
+    return (_with_counters(first, tail) if tail else first), cache
 
 
 def decode_step_slots(
@@ -1143,12 +1810,15 @@ def decode_step_slots(
     lg, cache, counts = _step_rows(params, cache, token, pos, pad_len, cfg)
     with jax.named_scope("logits"):
         nxt = jnp.argmax(lg, -1).astype(jnp.int32)
-    if not counts:
-        return nxt, cache
-    return _with_counters(nxt, [
-        sum((c > 0).sum() for c in counts),
-        len(counts) * jnp.any(pos > 0).astype(jnp.int32),
-    ]), cache
+    tail = []
+    if counts["experts"]:
+        tail += [
+            sum((c > 0).sum() for c in counts["experts"]),
+            len(counts["experts"]) * jnp.any(pos > 0).astype(jnp.int32),
+        ]
+    if _has_mixers(cfg):
+        tail += _mixer_counts(counts)
+    return (_with_counters(nxt, tail) if tail else nxt), cache
 
 
 class TransformerLM:

@@ -121,9 +121,22 @@ attention kept its scores in VMEM (``transformer.prefill_uses_kernel`` of
 the width they ran at: the predicate the program itself branches on),
 ``kernel_expert_prefills`` those whose routed expert layers ran
 ops/experts.py's grouped kernels (``transformer.prefill_experts_use_kernel``,
-likewise: a run that fell back to ``ragged_dot`` says so), and
+likewise: a run that fell back to ``ragged_dot`` says so),
+``kernel_linear_prefills`` and ``kernel_sparse_prefills`` those whose linear
+layers ran ops/linear_attention.py's scan and whose sparse layers ran
+ops/sparse_attention.py's kernel over the blocks each query chose
+(``transformer.linear_prefill_uses_kernel``, ``sparse_prefill_uses_kernel``),
 ``kernel_steps`` the decode steps whose attention read the slot cache in
-place (``transformer.step_uses_kernel``, likewise).
+place (``transformer.step_uses_kernel``, likewise), and
+``kernel_sparse_steps`` those whose sparse layers read only the rows of the
+blocks they chose (``transformer.sparse_step_uses_kernel``).
+
+**A slot's whole row is the request's.** A prefill writes every leaf of the
+slot cache at its slot, whatever the leaf holds (rows of keys, pooled keys,
+a linear layer's state) and however long the prompt: nothing of the slot's
+last request survives it, and the cache is donated from program to program,
+so the prefill runs after the last step that used the row even with two
+programs dispatched ahead.
 
 Decoding is temperature-0 (argmax) here; sampled generation keeps the
 wave-aligned path (a per-request RNG stream inside a shared step program
@@ -354,15 +367,23 @@ class ContinuousBatcher:
             "kernel_prefills": 0,
             # prefills whose expert layers ran ops/experts.py's kernels
             "kernel_expert_prefills": 0,
+            # prefills whose linear and sparse layers ran their kernels
+            "kernel_linear_prefills": 0, "kernel_sparse_prefills": 0,
             # decode steps whose attention ran ops/attention.py's kernel
             "kernel_steps": 0,
+            # and those whose sparse layers read only their chosen blocks
+            "kernel_sparse_steps": 0,
             "preload_s": 0.0,  # the step program's load at construction
-            # what an experts decoder's programs count on the device and
-            # send back behind their tokens (0 for any other block)
+            # what a decoder's programs count on the device and send back
+            # behind their tokens (0 where the block has no such layer)
             **dict.fromkeys(
-                self._model.PREFILL_COUNTERS + self._model.STEP_COUNTERS, 0
+                self._model.PREFILL_COUNTERS + self._model.STEP_COUNTERS
+                + self._model.MIXER_COUNTERS, 0
             ),
         }
+        # the counters this decoder's two programs append, in their order
+        self._prefill_tail = transformer.prefill_counters(cfg)
+        self._step_tail = transformer.step_counters(cfg)
         self.pool.scheduler_stats = self.stats
         self._loop_mark = 0.0  # perf_counter at the last `loop_s` tick
         self._cpu_mark = 0.0  # thread_time at the last `host_cpu_s` tick
@@ -463,13 +484,14 @@ class ContinuousBatcher:
         the slot numbers, which never change."""
         import numpy as np
 
-        experts = bool(self.cfg.n_expert_layers)
         if self._last is None:
-            tail = len(self._model.STEP_COUNTERS) * experts
-            self._last = self._placed(np.zeros(self.n_slots + tail, np.int32))
+            self._last = self._placed(
+                np.zeros(self.n_slots + len(self._step_tail), np.int32)
+            )
         if self._first is None:
-            tail = len(self._model.PREFILL_COUNTERS) * experts
-            self._first = self._placed(np.zeros(1 + tail, np.int32))
+            self._first = self._placed(
+                np.zeros(1 + len(self._prefill_tail), np.int32)
+            )
         if self._slot_ids is None:
             self._slot_ids = [
                 self._placed(np.int32(i)) for i in range(-1, self.n_slots)
@@ -495,7 +517,7 @@ class ContinuousBatcher:
 
     def _count(self, names: tuple, tail: Any) -> None:
         """Add the device counters a program sent back behind its tokens
-        (none, where the block has no experts) into `stats`."""
+        (none, where the block counts nothing) into `stats`."""
         for name, value in zip(names, tail):
             self.stats[name] += int(value)
 
@@ -713,7 +735,7 @@ class ContinuousBatcher:
                 req.t_first = time.monotonic()
                 req.tokens.append(int(first[0]))
                 self.stats["prefills"] += 1
-                self._count(self._model.PREFILL_COUNTERS, first[1:])
+                self._count(self._prefill_tail, first[1:])
                 self.stats["prompt_tokens"] += req.length
                 self.stats["padded_tokens"] += req.width
                 self.stats["kernel_prefills"] += (
@@ -721,6 +743,12 @@ class ContinuousBatcher:
                 )
                 self.stats["kernel_expert_prefills"] += (
                     self._model.prefill_experts_use_kernel(self.cfg, req.width)
+                )
+                self.stats["kernel_linear_prefills"] += (
+                    self._model.linear_prefill_uses_kernel(self.cfg, req.width)
+                )
+                self.stats["kernel_sparse_prefills"] += (
+                    self._model.sparse_prefill_uses_kernel(self.cfg, req.width)
                 )
                 self.stats["queue_wait_s"] += req.t_admit - req.t_submit
                 self.stats["first_token_s"] += req.t_first - req.t_submit
@@ -734,7 +762,10 @@ class ContinuousBatcher:
             self.stats["kernel_steps"] += self._model.step_uses_kernel(
                 self.cfg
             )
-            self._count(self._model.STEP_COUNTERS, nxt[self.n_slots:])
+            self.stats["kernel_sparse_steps"] += (
+                self._model.sparse_step_uses_kernel(self.cfg)
+            )
+            self._count(self._step_tail, nxt[self.n_slots:])
             if _obs.PLANE is not None:
                 _obs.PLANE.metrics.counter(
                     "pathway_serving_decode_steps_total",

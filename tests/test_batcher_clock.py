@@ -92,9 +92,12 @@ def test_stats_hold_every_key_from_construction():
         "submitted", "completed", "decode_steps", "prefills", "max_queue",
         "dispatched_ahead",
         "prompt_tokens", "padded_tokens", "kernel_prefills", "kernel_steps",
-        "kernel_expert_prefills",
+        "kernel_expert_prefills", "kernel_linear_prefills",
+        "kernel_sparse_prefills", "kernel_sparse_steps",
         # an experts decoder's device counters (0 for this block)
         "routed_pairs", "expert_load_max", "experts_touched", "moe_layers_run",
+        # and those of a decoder with sparse or linear layers
+        "sparse_blocks_read", "sparse_blocks_visible", "linear_tokens",
     }
     clocks = (
         set(PHASES) | {"loop_s", "host_cpu_s", "preload_s"}

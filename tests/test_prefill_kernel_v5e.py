@@ -277,3 +277,145 @@ def test_a_prefills_expert_kernels_read_the_leaves_where_they_lie(
     # the combine reads the down kernel's rows as they were written
     assert sum("%expert_combine" in ln.split("=")[0] for ln in calls) == 2
     assert "ragged-dot" not in text
+
+
+# ---------------------------------------------- the sparse and linear mixers
+# (ops/linear_attention.py, ops/sparse_attention.py): the third cell's
+# shapes (32 linear heads of 128; 32 query heads over 2 key heads, blocks of
+# 64 positions, p 24,576 and the cap of 32,768 positions), a narrow rung,
+# and float32.
+
+
+@pytest.mark.parametrize("b, p, heads, dtype", [
+    (1, 24576, 32, jnp.bfloat16),
+    (1, 32736, 32, jnp.bfloat16),
+    (1, 128, 32, jnp.bfloat16),
+    (2, 640, 4, jnp.float32),
+])
+def test_the_scan_kernel_compiles_for_v5e(b, p, heads, dtype, chip):
+    from pathway_tpu.ops.linear_attention import linear_prefill_attention
+
+    def arg(*shape, dt=dtype):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=chip)
+
+    compiled = jax.jit(linear_prefill_attention).lower(
+        arg(b, p, heads, 128), arg(b, p, heads, 128), arg(b, p, heads, 128),
+        arg(heads, dt=jnp.float32),
+    ).compile()
+    text = compiled.as_text()
+    # the name a device trace shows (`linear_prefill_attention[tpu_custom_call]`)
+    assert "%linear_prefill_attention" in text
+    assert 'custom_call_target="tpu_custom_call"' in text
+
+
+@pytest.mark.parametrize("b, p, heads, kv_heads, block, dtype", [
+    (1, 24576, 32, 2, 64, jnp.bfloat16),
+    (1, 32736, 32, 2, 64, jnp.bfloat16),
+    (1, 10240, 28, 4, 64, jnp.bfloat16),
+    (2, 896, 4, 4, 16, jnp.float32),
+])
+def test_the_selected_block_kernel_compiles_for_v5e(
+    b, p, heads, kv_heads, block, dtype, chip
+):
+    from pathway_tpu.ops.sparse_attention import sparse_prefill_attention
+
+    def arg(*shape, dt=dtype):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=chip)
+
+    compiled = jax.jit(
+        functools.partial(sparse_prefill_attention, block=block)
+    ).lower(
+        arg(b, p, heads, 128), arg(b, p, kv_heads, 128), arg(b, p, kv_heads, 128),
+        arg(b, p, dt=jnp.int32), arg(b, kv_heads, p, -(-p // block), dt=jnp.bool_),
+    ).compile()
+    text = compiled.as_text()
+    assert "%sparse_prefill_attention" in text
+    assert 'custom_call_target="tpu_custom_call"' in text
+
+
+def _sala(**kw):
+    """The third cell's decoder with its slot cache ([1, 8, 2, 32768, 128] x
+    2 of rows, pooled keys, [3, 8, 32, 128, 128] float32 of states), its
+    feed-forward and vocabulary cut small."""
+    base = dict(
+        vocab_size=512, d_model=4096, n_heads=32, n_kv_heads=2, head_size=128,
+        n_layers=4, d_ff=256, max_len=32768, tie_embeddings=False,
+        dtype=jnp.bfloat16,
+        layers=(
+            LayerSpec(pos="none", ff="swiglu", mixer="sparse"),
+            *(LayerSpec(pos="rotary", ff="swiglu", mixer="linear"),) * 3,
+        ),
+        sparse=T.SparseSpec(), qk_norm=True, out_gate=True,
+        linear_out_norm=True, linear_heads=32,
+        linear_slopes=tuple(2.0 ** (-8 * h / 32) for h in range(1, 33)),
+        embed_scale=12.0, residual_scale=1.4 / 32 ** 0.5, logit_scale=1 / 16,
+    )
+    return lm_config(**{**base, **kw})
+
+
+def test_the_third_cells_step_reads_and_writes_the_cache_where_it_lies(
+    chip, monkeypatch
+):
+    """The step program at the cell's cache shapes, the cache donated: the
+    sparse layer's attention and row write are the kernel over the chosen
+    blocks, the states are updated in their leaf, and no operation has a
+    result as large as the layer's rows (no copy, no relayout, no scatter
+    over the leaf, no slice of it for the pooled key's window)."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = _sala()
+    assert T.sparse_step_uses_kernel(cfg)
+    slots = 8
+    params = _shaped(
+        chip, lambda: T.init_params(jax.random.PRNGKey(0), cfg, jnp.bfloat16)
+    )
+    cache = _shaped(chip, lambda: T.init_kv_cache(cfg, slots))
+    vec = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=chip)
+    compiled = jax.jit(
+        functools.partial(T.decode_step_slots, cfg=cfg), donate_argnums=(1,)
+    ).lower(params, cache, vec, vec, vec).compile()
+    text = compiled.as_text()
+    assert "%sparse_decode_attention" in text
+    rows_of_the_layer = slots * cfg.kv_heads * cfg.max_len * cfg.head_dim
+    passed_on = {"parameter", "get-tuple-element", "bitcast"}
+    large = [
+        (op, dims) for _, dims, op in _RESULT.findall(text)
+        if op not in passed_on
+        and functools.reduce(int.__mul__, map(int, dims.split(","))) >= rows_of_the_layer
+    ]
+    assert large == []
+    stats = compiled.memory_analysis()
+    assert stats.alias_size_in_bytes == sum(
+        x.size * x.dtype.itemsize for x in jax.tree.leaves(cache)
+    )
+    assert stats.temp_size_in_bytes < 16 << 20
+
+
+def test_the_third_cells_prefill_holds_its_three_kernels(chip, monkeypatch):
+    """A prefill past `dense_len` (lowered, not compiled: the feed-forward
+    is cut small, the rest is the cell's) calls the scan's kernel once for
+    its three linear layers' one lowering and the selected-block kernel, and
+    no `prefill_attention`; a prefill up to `dense_len` calls
+    `prefill_attention` in the sparse layer's place."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = _sala(max_len=16384)
+    params = _shaped(
+        chip, lambda: T.init_params(jax.random.PRNGKey(0), cfg, jnp.bfloat16)
+    )
+    cache = _shaped(chip, lambda: T.init_kv_cache(cfg, 2))
+
+    def lowered(width):
+        ids = jax.ShapeDtypeStruct((1, width), jnp.int32, sharding=chip)
+        return jax.jit(
+            functools.partial(T.prefill_into_slot, cfg=cfg), donate_argnums=(3,)
+        ).lower(params, ids, ids, cache, jax.ShapeDtypeStruct((), jnp.int32, sharding=chip)).as_text()
+
+    def calls(text, kernel):
+        """How often a lowering names `kernel` and not a longer name."""
+        return len(re.findall(rf"(?<![a-z_]){kernel}(?![a-z_])", text))
+
+    long = lowered(10240)
+    assert calls(long, "linear_prefill_attention") and calls(long, "sparse_prefill_attention")
+    assert not calls(long, "prefill_attention")
+    short = lowered(1024)
+    assert calls(short, "linear_prefill_attention") and calls(short, "prefill_attention")
+    assert not calls(short, "sparse_prefill_attention")
