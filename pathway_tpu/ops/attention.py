@@ -168,20 +168,29 @@ def _prefill_vmem(t: int, dh: int, group: int, itemsize: int) -> int:
 
 
 def prefill_tile(p: int, dh: int, group: int, itemsize: int = 2) -> int:
-    """The tile of `prefill_attention`, queries and keys alike, for a width
-    p that 128 divides: the largest multiple of 128 that divides p, is at
-    most 896 and keeps the VMEM sum of a grid step (`_prefill_vmem`) under
+    """The tile of `prefill_attention` and of ops/sparse_attention.py
+    `sparse_prefill_attention`, queries and keys alike, for a width p that
+    128 divides: the largest multiple of 128 that divides p, is at most
+    896 and keeps the VMEM sum of a grid step (`_prefill_vmem`) under
     16 MB. A function of the shapes alone: 640 at rag-cerebras-6b7's
     (p 1280, a group of 1, bf16: 7.2 MB), 512 at
     rag-smallthinker-21b-a3b's (p 10,240, 7 query heads a key head:
-    12.8 MB; 640 would be 17.0). 128 always divides, and is the tile of
-    a group so large that no tile fits: the kernel's VMEM limit follows
-    the sum.
-    A larger tile amortises the grid step's fixed cost (about 0.35 us) and
-    the key tile's fetch; a smaller one wastes less on the diagonal and
-    the band's edges. On the chip (PERF.md, PR 32) a tile's time is its
-    elements' whatever its shape: the softmax made a few rows at a time
-    to stay in registers was 2-10 times slower than the whole tile's."""
+    12.8 MB; 640 would be 17.0), 256 at rag-minicpm-sala's (p 24,576, 16
+    query heads a key head). 128 always divides, and is the tile of a
+    group so large that no tile fits: the kernel's VMEM limit follows the
+    sum.
+    A larger tile amortises the grid step's fixed cost (about 0.35 us),
+    the key tile's fetch and the MXU's weight loads; a smaller one wastes
+    less on the diagonal and the band's edges. On the chip (PERF.md
+    section 6, PR 39) the tile body runs at the rate of its two products
+    and a tile's time is its elements' at any shape of one area: at
+    p 10,240 x 7 heads 5.26 ms at 512 x 512 and 5.12 to 5.63 at 384 x 512,
+    1024 x 256, 512 x 1024, 256 x 1024 and 640 x 640 (queries x keys),
+    6.97 at 256 x 256; at p 24,576 x 16 heads 33.4 ms at 256 x 256 and
+    31.1 to 34.4 at 512 x 256, 512 x 512 and 256 x 1024: under a hundredth
+    of a prefill either way, so the tile stays one number. The softmax
+    made a few rows at a time to stay in registers was 1.3-10 times
+    slower than the whole tile's (PR 32)."""
     fits = [
         t for t in range(128, min(p, _PREFILL_TILE_MAX) + 1, 128)
         if p % t == 0 and _prefill_vmem(t, dh, group, itemsize) <= _PREFILL_VMEM
@@ -198,6 +207,72 @@ def _first_key_tile(qi, first, t: int, window: int | None):
     return jnp.maximum(first, jax.lax.div(jnp.maximum(qi * t - window + 1, 0), t))
 
 
+def _along_lanes(x, width: int):
+    """A lane-replicated [rows, 128] array at `width` lanes, as it lies:
+    whole vregs named again, where a slice of lane 0 broadcast back
+    (`x[:, :1]`) is a permute through the XLU for every row group."""
+    return x if width == x.shape[1] else jnp.tile(x, (1, width // x.shape[1]))
+
+
+def _fold_key_tile(q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref, ok,
+                   *, group: int, dh: int, scale: float):
+    """The tile body of the streaming softmax, `prefill_attention`'s and
+    ops/sparse_attention.py `sparse_prefill_attention`'s: the group's query
+    tile [t, group * dh] against one key tile [t, dh], a head at a time,
+    into the head's running maximum and sum ([t, 128], every lane the
+    same) and its float32 accumulator. `ok` [t, t] says which pairs are
+    allowed, None that all are.
+
+    The running maximum and the rescale meet the scores and the accumulator
+    as the lane-replicated arrays they are kept as (`_along_lanes`). That
+    leaves the XLU to the two row reductions, and the softmax hides behind
+    the two products: on a v5e a global layer of 10,240 tokens and 28 heads
+    over 4 takes 5.3 ms so and took 10.7 with the slices, softmax and
+    products in turn (PERF.md section 6, PR 39, which also has what was
+    tried on top and dropped: the scale inside `exp2`, the row sum on the
+    MXU, the next head's product issued first)."""
+    k, v = k_ref[0], v_ref[0]
+    for g in range(group):
+        lanes = slice(g * dh, (g + 1) * dh)
+        s = jax.lax.dot_general(
+            q_ref[0, :, lanes], k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) * scale  # [t, t]
+        if ok is not None:
+            s = jnp.where(ok, s, _MASKED)
+        m_prev = m_ref[g]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        # a row none of whose allowed keys has come yet keeps its maximum
+        # at the mask's value and sums exponentials of 0; its first allowed
+        # key moves the maximum and alpha wipes them
+        e = jnp.exp(s - _along_lanes(m_new, s.shape[1]))
+        l_ref[g] = alpha * l_ref[g] + jnp.sum(e, axis=1, keepdims=True)
+        m_ref[g] = m_new
+        acc_ref[:, lanes] = acc_ref[:, lanes] * _along_lanes(alpha, dh) + jnp.dot(
+            e.astype(v.dtype), v, preferred_element_type=jnp.float32
+        )
+
+
+def _start_softmax(m_ref, l_ref, acc_ref):
+    """A query tile's first grid step: nothing seen yet."""
+    m_ref[...] = jnp.full(m_ref.shape, _MASKED, jnp.float32)
+    l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+    acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+
+def _finish_softmax(o_ref, l_ref, acc_ref, *, group: int, dh: int):
+    """A query tile's last grid step: the accumulators over the sums."""
+    for g in range(group):
+        lanes = slice(g * dh, (g + 1) * dh)
+        total = _along_lanes(l_ref[g], dh)
+        # a row of a query tile that ran no key tile (all of it padding)
+        # has summed nothing: it returns zeros
+        o_ref[0, :, lanes] = (
+            acc_ref[:, lanes] / jnp.where(total == 0.0, 1.0, total)
+        ).astype(o_ref.dtype)
+
+
 def _prefill_kernel(first_ref, held_ref, q_ref, k_ref, v_ref, valid_ref, o_ref,
                     m_ref, l_ref, acc_ref,
                     *, t: int, group: int, dh: int, window: int | None,
@@ -211,30 +286,12 @@ def _prefill_kernel(first_ref, held_ref, q_ref, k_ref, v_ref, valid_ref, o_ref,
 
     @pl.when(kk == 0)
     def _start():
-        m_ref[...] = jnp.full(m_ref.shape, _MASKED, jnp.float32)
-        l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
-        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+        _start_softmax(m_ref, l_ref, acc_ref)
 
-    def fold(ok):
-        k, v = k_ref[0], v_ref[0]
-        for g in range(group):
-            lanes = slice(g * dh, (g + 1) * dh)
-            s = jax.lax.dot_general(
-                q_ref[0, :, lanes], k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            ) * scale  # [t, t]
-            if ok is not None:
-                s = jnp.where(ok, s, _MASKED)
-            m_prev = m_ref[g]  # [t, 128], every lane the same
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-            alpha = jnp.exp(m_prev - m_new)
-            e = jnp.exp(s - m_new[:, :1])
-            l_ref[g] = alpha * l_ref[g] + jnp.sum(e, axis=1, keepdims=True)
-            m_ref[g] = m_new
-            acc_ref[:, lanes] = acc_ref[:, lanes] * alpha[:, :1] + jnp.dot(
-                e.astype(v.dtype), v, preferred_element_type=jnp.float32
-            )
-
+    fold = functools.partial(
+        _fold_key_tile, q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref,
+        group=group, dh=dh, scale=scale,
+    )
     # a tile on the diagonal, on the band's edge or with a key that is not
     # valid takes an element mask; an inner tile takes none
     edge = (kt == qi) | (held < t)
@@ -259,14 +316,7 @@ def _prefill_kernel(first_ref, held_ref, q_ref, k_ref, v_ref, valid_ref, o_ref,
 
     @pl.when(kk == pl.num_programs(3) - 1)
     def _finish():
-        for g in range(group):
-            lanes = slice(g * dh, (g + 1) * dh)
-            total = l_ref[g][:, :1]
-            # a row of a query tile that ran no key tile (all of it
-            # padding) has summed nothing: it returns zeros
-            o_ref[0, :, lanes] = (
-                acc_ref[:, lanes] / jnp.where(total == 0.0, 1.0, total)
-            ).astype(o_ref.dtype)
+        _finish_softmax(o_ref, l_ref, acc_ref, group=group, dh=dh)
 
 
 # jitted so that the layers of one program share one trace of the kernel

@@ -3,12 +3,20 @@ attention of whole prompts to themselves in which every query reads only the
 blocks of keys it chose (models/transformer.py `select_blocks`), one set for
 the query heads that share a key head.
 
-It is `ops/attention.py prefill_attention` (the same tiles, the same grid,
-the same streaming softmax, scores kept in VMEM) with one more operand, the
-chosen blocks [b, kv heads, p, blocks], and one more mask, made once a tile
-pair and shared by the group's heads. A tile pair in which no query chose a
-block is still computed under its mask: what comes back is the softmax over
-the chosen blocks, and what that costs is the price of a first kernel.
+It shares with `ops/attention.py prefill_attention` what is one thing: the
+tile (`prefill_tile`), the streaming softmax's start and finish, and the
+tile body `_fold_key_tile`, which takes a key tile's scores for the group's
+heads to the running maximum, sum and accumulator. Its own are one more
+operand, the chosen blocks [b, kv heads, p, blocks], and the element mask
+it hands that body: causal order, the keys' validity and, made once a tile
+pair for the whole group, the product of the queries' chosen blocks with
+the keys' one-hot blocks. With every block chosen it returns what
+`prefill_attention` returns, bit for bit. A tile pair in which no query
+chose a block is still computed under its mask, and at the kernel's tile
+nothing is lost by that: a query takes its blocks where its own scores
+send it, so among 256 neighbouring queries every key tile under the
+diagonal is somebody's (PERF.md section 5: 0 of 4,278 tile pairs empty at
+24k tokens). What the kernel costs is its rate, and that is the body's.
 """
 
 from __future__ import annotations
@@ -22,7 +30,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from pathway_tpu.ops.attention import (
-    _MASKED, _PREFILL_VMEM, _prefill_vmem, _written_rows, prefill_tile,
+    _MASKED, _PREFILL_VMEM, _finish_softmax, _fold_key_tile, _prefill_vmem,
+    _start_softmax, _written_rows, prefill_tile,
 )
 
 
@@ -40,9 +49,7 @@ def _sparse_prefill_kernel(first_ref, held_ref, pad_ref, q_ref, k_ref, v_ref,
 
     @pl.when(kk == 0)
     def _start():
-        m_ref[...] = jnp.full(m_ref.shape, _MASKED, jnp.float32)
-        l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
-        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+        _start_softmax(m_ref, l_ref, acc_ref)
 
     # past the diagonal the clamped tile is not run again; a tile with no
     # valid key is not run at all
@@ -64,38 +71,18 @@ def _sparse_prefill_kernel(first_ref, held_ref, pad_ref, q_ref, k_ref, v_ref,
         chosen = jnp.dot(
             blocks_ref[0, 0], one_hot, preferred_element_type=jnp.float32
         ) > 0.5
-        ok = (kpos <= qpos) & (valid_ref[0] != 0) & chosen
-        k, v = k_ref[0], v_ref[0]
-        for g in range(group):
-            lanes = slice(g * dh, (g + 1) * dh)
-            s = jax.lax.dot_general(
-                q_ref[0, :, lanes], k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            ) * scale  # [t, t]
-            s = jnp.where(ok, s, _MASKED)
-            m_prev = m_ref[g]  # [t, 128], every lane the same
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-            alpha = jnp.exp(m_prev - m_new)
-            # a query none of whose chosen keys has come yet keeps its
-            # maximum at the mask's value and sums exponentials of 0; the
-            # first chosen key moves the maximum and alpha wipes them, and
-            # every real query chose its own block, on the diagonal
-            e = jnp.exp(s - m_new[:, :1])
-            l_ref[g] = alpha * l_ref[g] + jnp.sum(e, axis=1, keepdims=True)
-            m_ref[g] = m_new
-            acc_ref[:, lanes] = acc_ref[:, lanes] * alpha[:, :1] + jnp.dot(
-                e.astype(v.dtype), v, preferred_element_type=jnp.float32
-            )
+        # a query none of whose chosen keys has come yet is wiped by its
+        # first (`_fold_key_tile`), and every real query chose its own
+        # block, on the diagonal
+        _fold_key_tile(
+            q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref,
+            (kpos <= qpos) & (valid_ref[0] != 0) & chosen,
+            group=group, dh=dh, scale=scale,
+        )
 
     @pl.when(kk == pl.num_programs(3) - 1)
     def _finish():
-        for g in range(group):
-            lanes = slice(g * dh, (g + 1) * dh)
-            total = l_ref[g][:, :1]
-            # a row of a query tile that ran no key tile has summed nothing
-            o_ref[0, :, lanes] = (
-                acc_ref[:, lanes] / jnp.where(total == 0.0, 1.0, total)
-            ).astype(o_ref.dtype)
+        _finish_softmax(o_ref, l_ref, acc_ref, group=group, dh=dh)
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))

@@ -17,6 +17,7 @@ import pytest
 from pathway_tpu.models import lm_config
 from pathway_tpu.models import transformer as T
 from pathway_tpu.ops import attention as A
+from pathway_tpu.ops.sparse_attention import sparse_prefill_attention
 
 DH = 128
 TILE = 128
@@ -121,12 +122,52 @@ def test_a_skipped_tile_is_never_read(where, pad, window, tile, rows):
     assert np.isnan(np.asarray(_plain(q, k, v, valid, window)[:, rows])).all()
 
 
+def test_keys_that_came_masked_are_wiped_by_the_first_allowed():
+    """Rows of a window layer's query tile whose band begins past the
+    tile's first key tile meet that tile wholly masked: their maximum
+    stays at the mask's value and they sum exponentials of 0 over values
+    made huge here, until their first allowed key moves the maximum and
+    the rescale wipes what was summed."""
+    q, k, v, valid = _inputs(384, 7, 1, 0, jnp.float32)
+    window = 157
+    v = v.at[:, :TILE].multiply(1e4)  # the first key tile of query tile 2
+    got = A.prefill_attention(q, k, v, valid, window, interpret=True)
+    want = _plain(q, k, v, valid, window)
+    # rows 157 + 128 - 1 = 284 .. 383 attend no key of tile 0
+    late = np.asarray(got[:, 284:] - want[:, 284:])
+    assert np.abs(late).max() < 1e-4
+    assert np.abs(np.asarray(got[:, 284:])).max() < 10
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("p, heads, kv_heads, pad", [(384, 4, 2, 0), (224, 7, 1, 37)])
+def test_every_block_chosen_is_the_dense_kernel_bit_for_bit(
+    p, heads, kv_heads, pad, dtype
+):
+    """ops/sparse_attention.py `sparse_prefill_attention` runs the same
+    tile body under one more mask: with every block chosen it returns what
+    `prefill_attention` returns, to the bit in bfloat16, which the cells
+    run. In float32 the interpreter makes two XLA programs of the two
+    kernels, whose fused row sums round their own way: the last bit."""
+    q, k, v, valid = _inputs(p, heads, kv_heads, pad, dtype)
+    block = 16
+    blocks = jnp.ones((1, kv_heads, p, -(-p // block)), jnp.bool_)
+    dense = A.prefill_attention(q, k, v, valid, None, interpret=True)
+    sparse = sparse_prefill_attention(q, k, v, valid, blocks, block, interpret=True)
+    dense, sparse = (np.asarray(a[:, pad:], np.float32) for a in (dense, sparse))
+    if dtype == jnp.bfloat16:
+        assert np.array_equal(dense, sparse)
+    else:
+        assert np.abs(dense - sparse).max() < 1e-6
+
+
 def test_the_tile_is_a_function_of_the_shapes(monkeypatch):
     monkeypatch.setattr(A, "_PREFILL_TILE_MAX", 896)  # the module's own
     assert A.prefill_tile(1280, 128, 1) == 640  # rag-cerebras-6b7
     assert A.prefill_tile(10240, 128, 7) == 512  # rag-smallthinker-21b-a3b
     assert A.prefill_tile(2048, 128, 1) == 512  # the cap's rung 2016, padded
     assert A.prefill_tile(16384, 128, 7) == 512
+    assert A.prefill_tile(24576, 128, 16) == 256  # rag-minicpm-sala
     assert A.prefill_tile(128, 128, 1) == 128
     assert A.prefill_tile(896, 128, 1) == 896
     assert A.prefill_tile(1024, 128, 64) == 128  # nothing fits: the least
