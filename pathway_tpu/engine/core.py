@@ -5922,6 +5922,9 @@ class ExternalIndexNode(Node):
             for qkey in changed_queries:
                 retract(qkey)
                 self.matches.pop(qkey, None)
+        if _obs.CLOCKS:  # REST requests in flight: their search ends here
+            for qkey in searched:
+                _obs.stamp(qkey.value, _obs.STAGE_SEARCH)
         for qkey, matches in searched.items():
             qrow = self.query_state.get(qkey)
             if qrow is None:

@@ -1748,8 +1748,20 @@ def _submit_async_batch(
     Future resolving to {(key, row): value}. The caller decides whether
     to block (`_run_async_batch`) or defer (stage overlap)."""
     loop = _get_async_loop()
+    # REST requests in flight, by their row's key (one test a wave: rows
+    # of an ingest look nothing up while no request is open)
+    clocks = _obs.CLOCKS or None
 
     async def one(k: Key, r: tuple) -> Any:
+        clock = clocks.get(k.value) if clocks is not None else None
+        if clock is not None:
+            # the row has left the pump and the waves behind its staging;
+            # what follows is stamped by whoever does it (the embedder, the
+            # index, the batcher, the answering UDF), through the clock
+            # that `one`, a task of its own, carries from here
+            if clock.staged_only():
+                clock.stamp(_obs.STAGE_INGRESS)
+            _obs.clocked(clock)
         try:
             res = fn(k, r)
             if asyncio.iscoroutine(res):

@@ -20,6 +20,7 @@ import http.server
 import json
 import math
 import os
+import sys
 import threading
 import time
 from typing import Any
@@ -339,6 +340,25 @@ def render_statistics(session: Any, started_at: float) -> dict:
 
     if faults.active():
         stats["faults_fired"] = [list(x) for x in faults.fired_log()]
+    # the process's CPU seconds by the role of the thread that burnt them
+    stats["thread_cpu"] = _obs.thread_cpu()
+    # (only where a REST route can exist: the connector is not imported
+    # for a process that has none)
+    rest = sys.modules.get("pathway_tpu.io.http")
+    if rest is not None:
+        # each REST route's 200s: how long one stayed in the handler and
+        # where, by the stage of its request clock (means, ms)
+        stats["routes"] = {
+            route: {
+                "responses": r["responses"],
+                "residence_ms": 1e3 * r["residence_s"] / r["responses"],
+                "stage_ms": {
+                    stage: 1e3 * s / r["responses"]
+                    for stage, s in r["stage_s"].items()
+                },
+            }
+            for route, r in rest.route_stats().items() if r["responses"]
+        }
     return stats
 
 

@@ -49,6 +49,8 @@ dump layout: docs/observability.md.
 
 from __future__ import annotations
 
+import contextvars
+import functools
 import json
 import os
 import tempfile
@@ -75,6 +77,15 @@ __all__ = [
     "pretimes",
     "span",
     "wave_span",
+    "RequestClock",
+    "STAGES",
+    "CLOCKS",
+    "stamp",
+    "current_clock",
+    "clocked",
+    "stamp_current",
+    "CPU_ROLES",
+    "thread_cpu",
     "register_retry_policy",
     "retry_policies",
 ]
@@ -144,6 +155,157 @@ def span(name: str, **meta: Any) -> Any:
 def wave_span(node: Any) -> Any:
     """The span of one (operator, wave): one name per operator."""
     return span(SPAN_WAVE + node.describe())
+
+
+# -------------------------------------------------------- request clock
+#
+# One clock a REST request, always on: `rest_connector`'s handler makes it
+# at its entry, keeps it in `CLOCKS` under the row's key while the request
+# is in flight, and every layer the row passes stamps the end of its stage
+# on `time.monotonic()`. The stages are a closed set, follow one another
+# and sum to the handler's residence (docs/observability.md "A request's
+# clock"). `CLOCKS` is empty when no request is in flight: a wave of rows
+# that came from no REST route (an ingest) pays one truth test of it.
+STAGE_IN = "in"
+STAGE_INGRESS = "ingress"
+STAGE_EMBED = "embed"
+STAGE_SEARCH = "search"
+STAGE_PROMPT = "prompt"
+STAGE_TOKENIZE = "tokenize"
+STAGE_QUEUE = "queue"
+STAGE_FIRST = "first"
+STAGE_DECODE = "decode"
+STAGE_PAYLOAD = "payload"
+STAGE_EGRESS = "egress"
+STAGE_REPLY = "reply"
+STAGES = (
+    STAGE_IN, STAGE_INGRESS, STAGE_EMBED, STAGE_SEARCH, STAGE_PROMPT,
+    STAGE_TOKENIZE, STAGE_QUEUE, STAGE_FIRST, STAGE_DECODE, STAGE_PAYLOAD,
+    STAGE_EGRESS, STAGE_REPLY,
+)
+_STAGE_INDEX = {stage: i + 1 for i, stage in enumerate(STAGES)}
+
+
+class RequestClock:
+    """The stamps of one request: `t[0]` is the handler's entry and
+    `t[i]` the end of `STAGES[i - 1]`, 0.0 until that stage is stamped.
+    Each stage is stamped by the layer that does its work, one thread
+    after another as the request moves, never two at once, so no lock."""
+
+    __slots__ = ("key", "t")
+
+    def __init__(self) -> None:
+        self.key = 0  # the REST row's `key.value`, once the row has one
+        self.t = [0.0] * (len(STAGES) + 1)
+        self.t[0] = time.monotonic()
+
+    def stamp(self, stage: str, at: float | None = None) -> None:
+        self.t[_STAGE_INDEX[stage]] = time.monotonic() if at is None else at
+
+    def staged_only(self) -> bool:
+        """No layer behind the handler has stamped yet: the row is staged
+        and has reached none of them."""
+        return not any(self.t[2:])
+
+    def stamps(self) -> tuple:
+        """Entry and every stage's end, a stage that was not on the
+        request's path ending where the one before it did: 13 instants in
+        order, whose differences are the stages' seconds."""
+        out, last = [], self.t[0]
+        for at in self.t:
+            last = at or last
+            out.append(last)
+        return tuple(out)
+
+
+CLOCKS: dict[int, RequestClock] = {}
+_CURRENT_CLOCK: contextvars.ContextVar = contextvars.ContextVar(
+    "pathway_request_clock", default=None
+)
+
+
+def stamp(key_value: int, stage: str) -> None:
+    """End of `stage` for the request whose row has this key, if it has a
+    clock. Callers with a wave of keys test `CLOCKS` once first."""
+    clock = CLOCKS.get(key_value)
+    if clock is not None:
+        clock.stamp(stage)
+
+
+def current_clock() -> "RequestClock | None":
+    """The clock of the request on whose behalf this task runs: set by
+    the async node that called the UDF (`clocked`), None elsewhere."""
+    return _CURRENT_CLOCK.get()
+
+
+def clocked(clock: RequestClock) -> None:
+    """Makes `clock` the `current_clock()` of the calling task and of
+    whatever it awaits, for as long as the task lives."""
+    _CURRENT_CLOCK.set(clock)
+
+
+def stamp_current(stage: str) -> None:
+    """End of `stage` for the request on whose behalf this task runs, if
+    it runs for one: how a UDF stamps the stage whose work it does."""
+    clock = _CURRENT_CLOCK.get()
+    if clock is not None:
+        clock.stamp(stage)
+
+
+# ------------------------------------------------------ CPU by thread role
+#
+# Read on demand from the kernel's per-thread CPU clocks; a role is the
+# name a thread already has, and no thread registers itself.
+_THREAD_ROLES = (
+    ("batcher", ("pw-cb-",)),
+    ("engine", ("pw-engine", "pw-live-table")),
+    ("udf", ("pw-async-loop",)),
+    ("edge", ("pw-webserver",)),
+    ("pool", ("pw-device-dispatch", "pw-device-staging", "pw-worker")),
+    # the thread that starts and stops `jax.profiler` in the benchmark: the
+    # instrument's own work (`stop_trace` gathers and writes the trace on
+    # it), kept out of `foreign` so that a traced run's reads as an
+    # untraced one's does
+    ("tracer", ("bench-tracer",)),
+)
+CPU_ROLES = tuple(role for role, _ in _THREAD_ROLES) + ("foreign",)
+
+
+@functools.lru_cache(maxsize=1024)  # a name is matched once, not a read
+def _role_of(name: str) -> str:
+    for role, prefixes in _THREAD_ROLES:
+        if name.startswith(prefixes):
+            return role
+    return "foreign"
+
+
+def _cpu_clock_id(native_id: int) -> int:
+    """The id of a thread's CPU clock, made from the kernel's thread id
+    (Linux: what `time.pthread_getcpuclockid` returns for a live thread),
+    so that a thread that ended since `enumerate` is an `OSError` of
+    `clock_gettime`, not a read of its freed descriptor."""
+    return ((~native_id) << 3) | 6
+
+
+def thread_cpu() -> dict[str, float]:
+    """CPU seconds of the process by thread role: `batcher` (`pw-cb-*`),
+    `engine` (`pw-engine`: the pump), `udf` (`pw-async-loop`), `edge`
+    (`pw-webserver`), `pool` (the device plane's and the workers' pools),
+    `tracer` (a profiler's own thread), `foreign` (every other live Python
+    thread: it can hold the interpreter) and `native`, the rest of
+    `time.process_time()`: the runtime's own threads, which hold no
+    interpreter, and threads that have ended."""
+    by = dict.fromkeys(CPU_ROLES, 0.0)
+    for t in threading.enumerate():
+        tid = t.native_id
+        if tid is None:
+            continue
+        try:
+            by[_role_of(t.name)] += time.clock_gettime(_cpu_clock_id(tid))
+        except OSError:  # ended since `enumerate`
+            continue
+    by["native"] = max(0.0, time.process_time() - sum(by.values()))
+    return by
 
 
 def pretime(stage: str, seconds: float) -> None:

@@ -20,6 +20,7 @@ import math as _math
 import json as _json
 import threading
 import time as _time
+from collections import deque
 from typing import Any, Callable
 
 from pathway_tpu.engine.runtime import Connector, InputSession
@@ -46,11 +47,22 @@ _ROUTE_STATS_LOCK = _lockgraph.register_lock(
 )
 
 
+# finished request clocks a route keeps for a reader to window: the last
+# that many (a reader of a longer window has to see that it is full)
+RECENT_CLOCKS = 4096
+
+
 def route_stats() -> dict[str, dict]:
     """Snapshot of per-route ingress counters ({route: {pending,
-    max_pending, requests, responses, timeouts, residence_s}})."""
+    max_pending, requests, responses, timeouts, residence_s, stage_s,
+    recent}}): `stage_s` sums each stage of the 200s' request clocks
+    (`observability.STAGES`; they sum to `residence_s`), `recent` holds
+    the last of those clocks as `RequestClock.stamps()`."""
     with _ROUTE_STATS_LOCK:
-        return {r: dict(s) for r, s in _ROUTE_STATS.items()}
+        return {
+            r: {**s, "stage_s": dict(s["stage_s"]), "recent": list(s["recent"])}
+            for r, s in _ROUTE_STATS.items()
+        }
 
 
 class PathwayWebserver:
@@ -217,8 +229,11 @@ def rest_connector(
     stats = {
         "pending": 0, "max_pending": 0, "requests": 0, "responses": 0,
         "timeouts": 0,
-        # seconds from handler entry to the reply, summed over the 200s
+        # seconds from handler entry to the reply, summed over the 200s,
+        # and the same seconds by the stage of the request's clock
         "residence_s": 0.0,
+        "stage_s": dict.fromkeys(_obs.STAGES, 0.0),
+        "recent": deque(maxlen=RECENT_CLOCKS),
     }
     with _ROUTE_STATS_LOCK:
         _ROUTE_STATS[route] = stats
@@ -232,8 +247,17 @@ def rest_connector(
                 help="response futures currently awaiting the pipeline",
             )
 
+    def _account(clock: "_obs.RequestClock") -> None:
+        """A 200 has left: its clock into the route's sums."""
+        stamps = clock.stamps()
+        with _ROUTE_STATS_LOCK:  # a snapshot's stages sum to its residence
+            stats["residence_s"] += stamps[-1] - stamps[0]
+            for i, stage in enumerate(_obs.STAGES):
+                stats["stage_s"][stage] += stamps[i + 1] - stamps[i]
+            stats["recent"].append(stamps)
+
     async def handler(request: "web.Request") -> "web.Response":
-        t_in = _time.monotonic()
+        clock = _obs.RequestClock()
         if request.method in ("POST", "PUT", "PATCH"):
             try:
                 payload = await request.json()
@@ -273,6 +297,8 @@ def rest_connector(
                 else:
                     row.append(None)
             key = sequential_key()
+            clock.key = key.value
+            _obs.CLOCKS[key.value] = clock
             # the handler runs ON the webserver's loop: bind the future
             # there explicitly (get_event_loop is deprecated inside
             # coroutines and can pick the wrong loop under re-entrancy)
@@ -296,7 +322,9 @@ def rest_connector(
                 # and its gauge increment leak for the process lifetime
                 sess.insert(key, tuple(row))
                 inserted = True
+                clock.stamp(_obs.STAGE_IN)
                 result = await asyncio.wait_for(fut, timeout=timeout_s)
+                clock.stamp(_obs.STAGE_EGRESS)
                 stats["responses"] += 1
             except asyncio.TimeoutError:
                 stats["timeouts"] += 1
@@ -317,9 +345,12 @@ def rest_connector(
             reply = web.json_response(
                 result, dumps=lambda obj: Json.dumps(obj)
             )
-            stats["residence_s"] += _time.monotonic() - t_in
+            clock.stamp(_obs.STAGE_REPLY)
+            _account(clock)
             return reply
         finally:
+            # reply, 504 and 503 alike: no clock outlives its request
+            _obs.CLOCKS.pop(clock.key, None)
             if admitted:
                 gateway.release(route)
 

@@ -104,7 +104,17 @@ for a program ``_AHEAD`` *before* the one just dispatched. ``loop_s`` is the
 loop's own wall time, ``host_cpu_s`` the thread's CPU time outside the
 two waits for the device, and ``queue_wait_s`` / ``first_token_s`` /
 ``residence_s`` sum each request's life from ``submit`` (counted by
-``prefills`` and ``completed``). Catalog: docs/observability.md.
+``prefills`` and ``completed``); ``tokenize_s`` is the part of it that
+``submit`` itself spends tokenising on the caller's thread (counted by
+``submitted``). The five ``cpu_*_s`` keys mirror
+``observability.thread_cpu()`` over ``loop_s``: what the process's other
+threads burnt while the loop ran, by the role their names give them
+(``engine``, ``udf``, ``edge``, ``pool``, and ``foreign``: Python threads
+the program did not start), refreshed between two passes of the loop, once
+a second of its time.
+Where ``submit`` runs for a REST request (``observability.current_clock()``)
+it stamps the request's clock, and ``_finish`` copies the request's three
+instants onto it. Catalog: docs/observability.md.
 
 **The step program is loaded when the batcher is built.** It has one
 shape, known at construction, so the batcher's thread starts once with
@@ -170,6 +180,15 @@ PHASES = {
     "account_s": _obs.SPAN_CB_ACCOUNT,
 }
 _WAITS = ("admit_wait_s", "step_wait_s")  # blocked on the device
+# role of `observability.thread_cpu` -> the `stats` key that mirrors it:
+# the roles whose threads can hold the interpreter the loop waits for (the
+# batcher's own is `host_cpu_s`, which leaves the two waits out)
+CPU_KEYS = {
+    role: f"cpu_{role}_s" for role in ("engine", "udf", "edge", "pool", "foreign")
+}
+# seconds of loop time between two refreshes of the mirror: a reader windows
+# it over tens of seconds, and a refresh reads every thread's clock
+_CPU_EVERY_S = 1.0
 # programs the loop keeps dispatched and unread. One is enough where the
 # host's time a dispatch is always under the running program's; where it is
 # so only on average (a step of 8.75 ms, a host of 6 that waits for the
@@ -207,11 +226,15 @@ class _Phase:
 class _Request:
     __slots__ = (
         "row", "length", "future", "tokens", "steps_out", "slot",
-        "pad_len", "width", "id", "t_submit", "t_admit", "t_first",
+        "pad_len", "width", "id", "t_submit", "t_admit", "t_first", "clock",
     )
 
-    def __init__(self, row: list, future: Future, t_submit: float):
+    def __init__(
+        self, row: list, future: Future, t_submit: float, clock: Any = None
+    ):
         self.id = 0  # the batcher's `submitted` count when it came in
+        # the REST request's clock, where `submit` was called for one
+        self.clock = clock
         # on time.monotonic(): `submit` entered, a slot acquired, the
         # prefill's token on the host
         self.t_submit = t_submit
@@ -360,6 +383,13 @@ class ContinuousBatcher:
             "dispatched_ahead": 0,
             **dict.fromkeys(PHASES, 0.0),
             "loop_s": 0.0, "host_cpu_s": 0.0,
+            # the process's other CPU time while the loop ran, by the role
+            # of the thread that burnt it (observability.thread_cpu)
+            **dict.fromkeys(CPU_KEYS.values(), 0.0),
+            # `submit` entered until the request was queued: the prompt's
+            # tokenising, on the caller's thread; counted by `submitted`
+            # and inside `queue_wait_s`, which starts where it does
+            "tokenize_s": 0.0,
             "queue_wait_s": 0.0, "first_token_s": 0.0, "residence_s": 0.0,
             # real tokens of the admitted prompts, and their widths
             "prompt_tokens": 0, "padded_tokens": 0,
@@ -387,6 +417,10 @@ class ContinuousBatcher:
         self.pool.scheduler_stats = self.stats
         self._loop_mark = 0.0  # perf_counter at the last `loop_s` tick
         self._cpu_mark = 0.0  # thread_time at the last `host_cpu_s` tick
+        # the `cpu_*_s` mirror: `_loop_mark` at its last refresh, and what
+        # `thread_cpu()` read then
+        self._roles_mark = 0.0
+        self._roles_last: dict[str, float] = {}
         with self._lock:
             self._start_thread(preload=True)
 
@@ -397,10 +431,16 @@ class ContinuousBatcher:
         t_submit = time.monotonic()
         row = list(self.tokenizer.tokenize(prompt))[-self.budget:]
         fut: Future = Future()
-        req = _Request(row, fut, t_submit)
+        clock = _obs.current_clock()
+        req = _Request(row, fut, t_submit, clock)
+        t_queued = time.monotonic()
+        if clock is not None:
+            clock.stamp(_obs.STAGE_PROMPT, t_submit)
+            clock.stamp(_obs.STAGE_TOKENIZE, t_queued)
         with self._lock:
             self._queue.append(req)
             self.stats["submitted"] += 1
+            self.stats["tokenize_s"] += t_queued - t_submit
             req.id = self.stats["submitted"]
             self.stats["max_queue"] = max(
                 self.stats["max_queue"], len(self._queue)
@@ -515,6 +555,18 @@ class ContinuousBatcher:
         self.stats["host_cpu_s"] += now - self._cpu_mark
         self._cpu_mark = now
 
+    def _roles_tick(self, count: bool = True) -> None:
+        """Adds what each role's threads have burnt since the last refresh
+        into its `cpu_*_s`. A thread that ended in between takes its
+        seconds out of its role's sum: that refresh then adds nothing to
+        the role, rather than less than nothing."""
+        now = _obs.thread_cpu()
+        if count:
+            for role, key in CPU_KEYS.items():
+                self.stats[key] += max(0.0, now[role] - self._roles_last[role])
+        self._roles_last = now
+        self._roles_mark = self._loop_mark
+
     def _count(self, names: tuple, tail: Any) -> None:
         """Add the device counters a program sent back behind its tokens
         (none, where the block counts nothing) into `stats`."""
@@ -569,6 +621,7 @@ class ContinuousBatcher:
                         return
             self._loop_mark = time.perf_counter()
             self._cpu_mark = time.thread_time()
+            self._roles_tick(count=False)
             serving = True
             while True:
                 # ---- step boundary: re-fill ONE freed slot from the
@@ -622,6 +675,8 @@ class ContinuousBatcher:
                             self._release(slot, req)
                 self._read_behind()
                 self._loop_tick()
+                if self._loop_mark - self._roles_mark >= _CPU_EVERY_S:
+                    self._roles_tick()
         except BaseException as e:  # noqa: BLE001 — fail every waiter loudly
             with self._lock:
                 self._running = False
@@ -653,6 +708,7 @@ class ContinuousBatcher:
             if serving:
                 self._loop_tick()
                 self._cpu_tick()
+                self._roles_tick()
             # restore the cache lease ONLY if our namespace still exists:
             # a finalizer may have dropped it while this thread was
             # mid-generation, and restore() would re-create the lease
@@ -779,7 +835,14 @@ class ContinuousBatcher:
 
     def _finish(self, slot: int, req: _Request) -> None:
         """The request's last token is on the host: its reply leaves."""
-        total = time.monotonic() - req.t_submit
+        now = time.monotonic()
+        total = now - req.t_submit
+        clock = req.clock
+        if clock is not None:
+            # before the future resolves: the caller stamps on from here
+            clock.stamp(_obs.STAGE_QUEUE, req.t_admit)
+            clock.stamp(_obs.STAGE_FIRST, req.t_first)
+            clock.stamp(_obs.STAGE_DECODE, now)
         with self._lock:
             self._leaving.discard(req)
             self.stats["completed"] += 1

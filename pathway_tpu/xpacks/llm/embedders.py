@@ -248,7 +248,10 @@ class JaxEmbedder(BaseEmbedder):
         return [out[i] for i in range(len(texts))]
 
     async def __wrapped__(self, input: str, **kwargs: Any) -> np.ndarray:
-        return await self._batcher.submit(input)
+        out = await self._batcher.submit(input)
+        # a REST request's query: its clock's `embed` ends here
+        _obs.stamp_current(_obs.STAGE_EMBED)
+        return out
 
     def encode_many(self, texts: list[str]) -> list[np.ndarray]:
         """Synchronous bulk encode (used by rerankers and tests)."""

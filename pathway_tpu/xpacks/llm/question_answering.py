@@ -14,6 +14,7 @@ import asyncio
 from typing import Any
 
 import pathway_tpu as pw
+from pathway_tpu.internals import observability as _obs
 from pathway_tpu.internals.json import Json
 from pathway_tpu.internals.table import Table
 from pathway_tpu.xpacks.llm.document_store import DocumentStore
@@ -128,7 +129,10 @@ class BaseRAGQuestionAnswerer:
                     {"text": t, "metadata": m.value if isinstance(m, Json) else m}
                     for t, m in zip(texts, metas or ())
                 ]
-            return Json(payload)
+            reply = Json(payload)
+            # a REST request's answer: its clock's `payload` ends here
+            _obs.stamp_current(_obs.STAGE_PAYLOAD)
+            return reply
 
         # materialize the flag onto the docs universe first: async-apply
         # arguments may only reference their own table

@@ -65,7 +65,10 @@ class BaseRestServer:
                 pw.persistence.Config.udf_caching(cache_backend),
             )
         if threaded:
-            t = threading.Thread(target=pw.run, kwargs=kwargs, daemon=True)
+            # the name is the engine's role in observability.thread_cpu
+            t = threading.Thread(
+                target=pw.run, kwargs=kwargs, daemon=True, name="pw-engine"
+            )
             t.start()
             return t
         return pw.run(**kwargs)

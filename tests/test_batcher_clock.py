@@ -35,6 +35,10 @@ TINY = dict(
 )
 PROMPTS = ["a b c", "d", "hello world longer prompt", "x y", "q", "z z z"]
 REQUEST_CLOCKS = ("queue_wait_s", "first_token_s", "residence_s")
+# `observability.thread_cpu()` mirrored into `stats` over the loop's time
+CPU_MIRROR = (
+    "cpu_engine_s", "cpu_udf_s", "cpu_edge_s", "cpu_pool_s", "cpu_foreign_s",
+)
 
 
 @pytest.fixture(autouse=True)
@@ -100,8 +104,8 @@ def test_stats_hold_every_key_from_construction():
         "sparse_blocks_read", "sparse_blocks_visible", "linear_tokens",
     }
     clocks = (
-        set(PHASES) | {"loop_s", "host_cpu_s", "preload_s"}
-        | set(REQUEST_CLOCKS)
+        set(PHASES) | {"loop_s", "host_cpu_s", "preload_s", "tokenize_s"}
+        | set(REQUEST_CLOCKS) | set(CPU_MIRROR)
     )
     assert set(cb.stats) == counts | clocks
     assert all(v == 0 for k, v in cb.stats.items() if k != "preload_s")
@@ -259,6 +263,11 @@ def test_wave_embed_and_knn_spans_form_a_closed_set(tmp_path):
     }
     (rows,) = {st["rows"] for n, st in events if n == obs.SPAN_EMBED_ENCODE_BATCH}
     assert rows == 3
+    # the set is closed in the module too: the constants and nothing else
+    # (a request's clock adds no span: test_the_clock_writes_no_span_...)
+    assert {v for k, v in vars(obs).items() if k.startswith("SPAN_")} == (
+        set(PHASES.values()) | (ours - waves) | {obs.SPAN_WAVE}
+    )
 
 
 # ------------------------------------------------- the observability plane
@@ -353,6 +362,512 @@ def test_rest_route_sums_the_residence_of_its_200s():
     assert answered == 3 and stats["responses"] == 3
     # handler entry to reply lies inside what the client waited
     assert 0 < stats["residence_s"] <= at_client
+    # no async node, no index and no batcher on this route: a clock holds
+    # the three stages the handler itself stamps, and is gone at the reply
+    assert obs.CLOCKS == {}
+    assert {k for k, v in stats["stage_s"].items() if v} == {
+        obs.STAGE_IN, obs.STAGE_EGRESS, obs.STAGE_REPLY,
+    }
+    assert sum(stats["stage_s"].values()) == pytest.approx(
+        stats["residence_s"], abs=1e-3
+    )
+    assert len(stats["recent"]) == 3
+
+
+# ------------------------------------------------------ a request's clock
+
+
+def _post(port, route, payload, timeout=60):
+    import requests
+
+    return requests.post(
+        f"http://127.0.0.1:{port}{route}", json=payload, timeout=timeout
+    )
+
+
+@pytest.fixture(scope="module")
+def edge_run(tmp_path_factory):
+    """A tiny RAG server (documents -> `DocumentStore` -> `JaxEmbedder` ->
+    the exact index -> `BaseRAGQuestionAnswerer` over a `JaxLMChat`, so a
+    `ContinuousBatcher`) answers `/v2/answer` twice with `PLANE` off, then
+    twice with it on and a profiler session open, with one `/v1/retrieve`
+    and one `/v2/summarize` (the batcher behind a single async node, no
+    embedder and no index); what each layer left behind is what the tests
+    below look at."""
+    from conftest import free_port_base
+
+    from pathway_tpu.internals import run as run_mod
+    from pathway_tpu.internals.metrics import render_statistics
+    from pathway_tpu.internals.parse_graph import G
+    from pathway_tpu.models import embedder_config
+    from pathway_tpu.stdlib.indexing import BruteForceKnnFactory
+    from pathway_tpu.xpacks.llm.document_store import DocumentStore
+    from pathway_tpu.xpacks.llm.embedders import JaxEmbedder
+    from pathway_tpu.xpacks.llm.question_answering import (
+        BaseRAGQuestionAnswerer,
+    )
+    from pathway_tpu.xpacks.llm.servers import QASummaryRestServer
+
+    G.clear()
+    obs.disable()
+    docs = [f"passage {i} about topic{i} and thing{i % 3}" for i in range(8)]
+    embedder = JaxEmbedder(config=embedder_config(
+        vocab_size=512, d_model=32, n_heads=2, n_layers=1, d_ff=64,
+        max_len=16, embed_dim=32,
+    ))
+    chat = _chat(config=lm_config(**{**TINY, "max_len": 128}))
+    table = pw.debug.table_from_rows(
+        pw.schema_from_types(data=bytes, _metadata=object),
+        [(d.encode(), {"path": f"doc{i}.txt"}) for i, d in enumerate(docs)],
+    )
+    store = DocumentStore(table, retriever_factory=BruteForceKnnFactory(
+        dimensions=32, embedder=embedder,
+    ))
+    qa = BaseRAGQuestionAnswerer(chat, store, search_topk=2)
+    port = free_port_base()
+    # every stamp of every clock, in the order written: (key, stage)
+    written = []
+    stamp = obs.RequestClock.stamp
+
+    def counted(self, stage, at=None):
+        written.append((self.key, stage))
+        stamp(self, stage, at)
+
+    obs.RequestClock.stamp = counted
+    qa.server = QASummaryRestServer("127.0.0.1", port, qa)  # `/v2/summarize` too
+    thread = qa.run_server(
+        threaded=True, with_cache=False, terminate_on_error=True,
+    )
+    out = {"thread_name": thread.name, "written": written, "batcher": chat._cb}
+    ask = {"prompt": "what about topic3", "return_context_docs": True}
+    try:
+        deadline = time.monotonic() + 120
+        while True:  # until the index holds the documents
+            try:
+                r = _post(port, "/v1/retrieve", {"query": "topic3", "k": 2})
+                if r.status_code == 200 and len(r.json()) == 2:
+                    break
+            except OSError:
+                pass
+            assert time.monotonic() < deadline, "the server never answered"
+            time.sleep(0.1)
+        del written[:]
+        before = pw.io.http.route_stats()
+        stats_before = dict(chat._cb.stats)
+        out["off"] = [_post(port, "/v2/answer", ask) for _ in range(2)]
+        plane = obs.enable()
+
+        def work():
+            out["on"] = [_post(port, "/v2/answer", ask) for _ in range(2)]
+            out["retrieve"] = _post(port, "/v1/retrieve", {"query": "topic5", "k": 2})
+            out["summarize"] = _post(
+                port, "/v2/summarize", {"text_list": ["topic1 is one", "thing2"]}
+            )
+
+        out["events"] = _traced(tmp_path_factory.mktemp("edge"), work)
+        out["ring"] = plane.recorder.snapshot()
+        out["statistics"] = render_statistics(None, time.time())
+        after = pw.io.http.route_stats()
+        out["clocks_left"] = dict(obs.CLOCKS)
+        out["grown"] = {
+            k: v - stats_before[k] for k, v in chat._cb.stats.items()
+        }
+    finally:
+        obs.RequestClock.stamp = stamp
+        obs.disable()
+        run_mod.stop_current_run()
+        qa.server.webserver.stop()
+        thread.join(timeout=60)
+        G.clear()
+    for route in ("/v2/answer", "/v1/retrieve", "/v2/summarize"):
+        a, b = after[route], before[route]
+        out[route] = {
+            "responses": a["responses"] - b["responses"],
+            "residence_s": a["residence_s"] - b["residence_s"],
+            "stage_s": {k: v - b["stage_s"][k] for k, v in a["stage_s"].items()},
+            "recent": a["recent"][len(b["recent"]):],
+        }
+    return out
+
+
+def test_a_request_stamps_every_stage_once_in_order_and_they_sum_to_its_residence(
+    edge_run,
+):
+    assert [r.status_code for r in edge_run["off"] + edge_run["on"]] == [200] * 4
+    assert all(len(r.json()["context_docs"]) == 2 for r in edge_run["off"])
+    answers = edge_run["/v2/answer"]
+    assert answers["responses"] == 4 and len(answers["recent"]) == 4
+    # written once each, in the order of the closed set
+    by_key = {}
+    for key, stage in edge_run["written"]:
+        by_key.setdefault(key, []).append(stage)
+    whole = [stages for stages in by_key.values() if len(stages) == len(obs.STAGES)]
+    assert len(whole) == 4 and all(s == list(obs.STAGES) for s in whole)
+    # contiguous: 13 instants in order, no stage of no length
+    for stamps in answers["recent"]:
+        assert len(stamps) == len(obs.STAGES) + 1
+        assert all(end > start for start, end in zip(stamps, stamps[1:]))
+    assert all(v > 0 for v in answers["stage_s"].values())
+    assert sum(answers["stage_s"].values()) == pytest.approx(
+        answers["residence_s"], abs=1e-3
+    )
+    assert sum(s[-1] - s[0] for s in answers["recent"]) == pytest.approx(
+        answers["residence_s"], abs=1e-3
+    )
+    # the batcher's own stages are its own clocks of the same requests
+    # (with the one `/v2/summarize`, which passes the batcher too)
+    grown = edge_run["grown"]
+    summed = edge_run["/v2/summarize"]["stage_s"]
+    in_batcher = sum(
+        answers["stage_s"][k] + summed[k]
+        for k in (obs.STAGE_TOKENIZE, obs.STAGE_QUEUE, obs.STAGE_FIRST, obs.STAGE_DECODE)
+    )
+    assert grown["completed"] == 5
+    assert in_batcher == pytest.approx(grown["residence_s"], abs=1e-3)
+    assert answers["stage_s"][obs.STAGE_TOKENIZE] + summed[
+        obs.STAGE_TOKENIZE
+    ] == pytest.approx(grown["tokenize_s"], abs=1e-6)
+    assert 0 < grown["tokenize_s"] < grown["queue_wait_s"] + grown["tokenize_s"]
+
+
+def test_a_route_off_the_batchers_path_stamps_only_the_stages_it_passes(edge_run):
+    """`/v1/retrieve` has the embedder and the index on its path and no
+    answering UDF: its clock holds no stage of the batcher's, and the
+    stages it has still sum to its residence."""
+    assert edge_run["retrieve"].status_code == 200
+    retrieve = edge_run["/v1/retrieve"]
+    assert retrieve["responses"] == 1
+    passed = {k for k, v in retrieve["stage_s"].items() if v > 0}
+    assert passed == {
+        obs.STAGE_IN, obs.STAGE_INGRESS, obs.STAGE_EMBED, obs.STAGE_SEARCH,
+        obs.STAGE_EGRESS, obs.STAGE_REPLY,
+    }
+    assert sum(retrieve["stage_s"].values()) == pytest.approx(
+        retrieve["residence_s"], abs=1e-3
+    )
+
+
+def test_no_clock_outlives_its_request(edge_run):
+    assert edge_run["clocks_left"] == {} and obs.CLOCKS == {}
+
+
+def test_a_route_with_the_batcher_behind_one_async_node_has_no_stage_below_zero(
+    edge_run,
+):
+    """`/v2/summarize` has no embedder and no index: its one async node
+    calls `ContinuousBatcher.submit`. Each stage is stamped by the layer
+    that does its work, so the stages nobody does have no length, those of
+    the batcher hold the LLM's time, and none is negative."""
+    assert edge_run["summarize"].status_code == 200
+    assert "response" in edge_run["summarize"].json()
+    summarize = edge_run["/v2/summarize"]
+    assert summarize["responses"] == 1
+    (stamps,) = summarize["recent"]
+    assert all(end >= start for start, end in zip(stamps, stamps[1:]))
+    stage_s = summarize["stage_s"]
+    assert all(v >= 0 for v in stage_s.values())
+    assert {k for k, v in stage_s.items() if v > 0} == set(obs.STAGES) - {
+        obs.STAGE_EMBED, obs.STAGE_SEARCH, obs.STAGE_PAYLOAD,
+    }
+    # the answer's time is the batcher's, not the edge's
+    batcher = sum(
+        stage_s[k]
+        for k in (obs.STAGE_TOKENIZE, obs.STAGE_QUEUE, obs.STAGE_FIRST, obs.STAGE_DECODE)
+    )
+    assert batcher > stage_s[obs.STAGE_PROMPT] + stage_s[obs.STAGE_EGRESS]
+    assert sum(stage_s.values()) == pytest.approx(
+        summarize["residence_s"], abs=1e-3
+    )
+    # written once each, in the order of the closed set
+    by_key = {}
+    for key, stage in edge_run["written"]:
+        by_key.setdefault(key, []).append(stage)
+    want = [s for s in obs.STAGES if stage_s[s] > 0]
+    assert sum(stages == want for stages in by_key.values()) == 1
+
+
+def test_the_clock_writes_no_span_and_no_event_of_the_plane(edge_run):
+    """The clock's sinks are the route's `stage_s` and `recent` and nothing
+    else: with `PLANE` on and a profiler session open, three routes' 200s
+    leave no ring event and no span of their own (the reduced trace keeps
+    no per-name host totals, so nothing could read one: PERF.md, Open
+    questions)."""
+    assert [e for e in edge_run["ring"] if e["k"].startswith("edge.")] == []
+    kinds = {e["k"] for e in edge_run["ring"]}
+    assert "serving.request" in kinds  # the batcher's, as before
+    ours = {
+        n for n, _ in edge_run["events"]
+        if n.startswith(("qa.", "edge.", "cb.", "embed.", "knn."))
+    }
+    assert ours <= {v for k, v in vars(obs).items() if k.startswith("SPAN_")}
+    assert not any(n.startswith(("qa.", "edge.")) for n in ours)
+    assert "cb.submit" not in ours
+
+
+def test_statistics_show_the_routes_stages_and_the_cpu_by_role(edge_run):
+    shown = edge_run["statistics"]
+    route = shown["routes"]["/v2/answer"]
+    assert route["responses"] >= 4
+    assert set(route["stage_ms"]) == set(obs.STAGES)
+    assert sum(route["stage_ms"].values()) == pytest.approx(route["residence_ms"])
+    assert set(shown["thread_cpu"]) == set(obs.CPU_ROLES) | {"native"}
+    # the server's pump ran in a thread of that name
+    assert edge_run["thread_name"] == "pw-engine"
+    assert shown["thread_cpu"]["engine"] > 0 and shown["thread_cpu"]["edge"] > 0
+    assert shown["thread_cpu"]["udf"] > 0
+
+
+def test_the_loop_mirrors_the_cpu_by_role_into_stats(edge_run):
+    grown = edge_run["grown"]
+    assert grown["loop_s"] > 0
+    assert all(grown[k] >= 0 for k in CPU_MIRROR)
+    # the engine polled while it looped (this test's own thread, `foreign`,
+    # slept on its socket: tests/test_bench_edge.py has clients that work)
+    assert grown["cpu_engine_s"] > 0
+
+
+def _edge(route, timeout_s=120.0):
+    from conftest import free_port_base
+
+    port = free_port_base()
+    ws = pw.io.http.PathwayWebserver(host="127.0.0.1", port=port)
+    queries, writer = pw.io.http.rest_connector(
+        webserver=ws, route=route, timeout_s=timeout_s,
+        schema=pw.schema_from_types(query=str),
+    )
+    return port, ws, queries, writer
+
+
+def test_the_clock_dict_is_empty_after_a_503():
+    """No pipeline runs behind the route: the handler has made the row's
+    key and its clock by then, and drops the clock with the refusal."""
+    port, ws, _queries, _writer = _edge("/nobody")
+    ws.start()
+    try:
+        r = _post(port, "/nobody", {"query": "q"})
+    finally:
+        ws.stop()
+    assert r.status_code == 503 and obs.CLOCKS == {}
+    stats = pw.io.http.route_stats()["/nobody"]
+    assert stats["responses"] == 0 and stats["residence_s"] == 0.0
+    assert not stats["recent"] and not any(stats["stage_s"].values())
+
+
+def test_the_clock_dict_is_empty_after_a_504():
+    import threading
+
+    from pathway_tpu.internals import run as run_mod
+
+    port, ws, queries, writer = _edge("/never", timeout_s=0.3)
+    writer(queries.filter(pw.this.query == "nobody asks this").select(
+        result=pw.this.query
+    ))
+    runner = threading.Thread(target=pw.run, daemon=True)
+    runner.start()
+    try:
+        deadline = time.monotonic() + 20
+        while True:  # until the server listens and the pipeline runs
+            try:
+                r = _post(port, "/never", {"query": "q"})
+                if r.status_code != 503:
+                    break
+            except OSError:
+                pass
+            assert time.monotonic() < deadline
+            time.sleep(0.05)
+    finally:
+        run_mod.stop_current_run()
+        ws.stop()
+        runner.join(timeout=20)
+    assert r.status_code == 504 and obs.CLOCKS == {}
+    stats = pw.io.http.route_stats()["/never"]
+    assert stats["timeouts"] == 1 and not stats["recent"]
+    assert stats["residence_s"] == 0.0  # the 200s' only
+
+
+def _stamped(clock):
+    """The stages some layer has stamped on the clock, in the set's order."""
+    return [s for s, at in zip(obs.STAGES, clock.t[1:]) if at]
+
+
+class _CountingDict(dict):
+    """`CLOCKS` with its truth tests and look-ups counted."""
+
+    def __init__(self, *a):
+        super().__init__(*a)
+        self.tests = self.gets = 0
+
+    def __bool__(self):
+        self.tests += 1
+        return len(self) > 0
+
+    def get(self, *a):
+        self.gets += 1
+        return super().get(*a)
+
+
+def test_a_wave_over_an_empty_dict_tests_it_once_and_looks_nothing_up(monkeypatch):
+    """An ingest's rows come from no REST route: while no request is in
+    flight, a wave of them through an async node pays one truth test of
+    `CLOCKS` and no look-up a row; with a request in flight, a row whose
+    key has no clock looks once and stamps nothing."""
+    from pathway_tpu.engine.runtime import _run_async_batch
+
+    clocks = _CountingDict()
+    monkeypatch.setattr(obs, "CLOCKS", clocks)
+    rows = [(Key(i + 1), (i,)) for i in range(100)]
+    graph = types.SimpleNamespace(log_error=lambda msg: None)
+
+    async def double(k, r):
+        assert obs.current_clock() is None
+        return 2 * r[0]
+
+    got = _run_async_batch(double, rows, graph)
+    assert len(got) == 100 and (clocks.tests, clocks.gets) == (1, 0)
+    # a request in flight, of another key
+    other = obs.RequestClock()
+    clocks[10**9] = other
+    _run_async_batch(double, rows, graph)
+    assert (clocks.tests, clocks.gets) == (2, 100)
+    obs.stamp(5, obs.STAGE_SEARCH)  # a key without a clock
+    assert _stamped(other) == []
+    assert other.stamps() == (other.t[0],) * (len(obs.STAGES) + 1)
+
+
+def test_an_async_node_hands_its_rows_clock_to_the_function_it_calls(monkeypatch):
+    """The first async node a clocked row reaches ends `ingress`; every
+    one makes the clock the `current_clock()` of its call, and of nothing
+    else, and stamps no stage whose work is the function's: the function
+    does that (`stamp_current`)."""
+    from pathway_tpu.engine.runtime import _run_async_batch
+
+    monkeypatch.setattr(obs, "CLOCKS", {})
+    clock = obs.RequestClock()
+    clock.key = 7
+    obs.CLOCKS[7] = clock
+    seen = []
+
+    async def fn(k, r):
+        seen.append((k.value, obs.current_clock()))
+        return r[0]
+
+    async def embeds(k, r):
+        obs.stamp_current(obs.STAGE_EMBED)  # a row without a clock: nothing
+        return r[0]
+
+    graph = types.SimpleNamespace(log_error=lambda msg: None)
+    rows = [(Key(7), ("mine",)), (Key(8), ("no clock",))]
+    _run_async_batch(fn, rows, graph)
+    assert sorted(seen, key=lambda x: x[0]) == [(7, clock), (8, None)]
+    assert _stamped(clock) == [obs.STAGE_INGRESS]
+    ingress = clock.t[2]
+    _run_async_batch(embeds, rows, graph)
+    assert _stamped(clock) == [
+        obs.STAGE_INGRESS, obs.STAGE_EMBED,
+    ]
+    # a later node does not move `ingress`; nor does the first one a row
+    # reaches behind a stage that a synchronous operator stamped
+    assert clock.t[2] == ingress
+    late = obs.RequestClock()
+    late.stamp(obs.STAGE_SEARCH)
+    obs.CLOCKS[8] = late
+    _run_async_batch(fn, rows, graph)
+    assert _stamped(late) == [obs.STAGE_SEARCH]
+    assert obs.current_clock() is None
+
+
+def test_submit_stamps_the_callers_clock_and_finish_copies_the_requests():
+    """`submit` on behalf of a REST request (`current_clock()`): `prompt`
+    ends where `t_submit` is taken and `tokenize` where the request is
+    queued; `_finish` writes `queue`, `first` and `decode` from the
+    request's own instants before the future resolves."""
+    import contextvars
+
+    cb = _chat()._cb
+    clock = obs.RequestClock()
+    before = dict(cb.stats)
+
+    def submit():
+        obs.clocked(clock)
+        return cb.submit("a b c")
+
+    fut = contextvars.copy_context().run(submit)
+    fut.result(timeout=60)
+    at = dict(zip(obs.STAGES, clock.t[1:]))
+    assert _stamped(clock) == [
+        obs.STAGE_PROMPT, obs.STAGE_TOKENIZE, obs.STAGE_QUEUE, obs.STAGE_FIRST,
+        obs.STAGE_DECODE,
+    ]
+    assert clock.t[0] <= at["prompt"] < at["tokenize"] <= at["queue"]
+    assert at["queue"] < at["first"] < at["decode"]
+    cb.drain()
+    grown = {k: cb.stats[k] - before[k] for k in before}
+    assert grown["tokenize_s"] == pytest.approx(at["tokenize"] - at["prompt"])
+    assert grown["queue_wait_s"] == pytest.approx(at["queue"] - at["prompt"])
+    assert grown["residence_s"] == pytest.approx(at["decode"] - at["prompt"])
+    # a caller with no clock stamps nothing and still counts its tokenising
+    _run(cb, ["d e f"])
+    assert cb.stats["tokenize_s"] > before["tokenize_s"] + grown["tokenize_s"]
+    assert obs.current_clock() is None
+
+
+# ------------------------------------------------------ CPU by thread role
+
+
+def test_thread_cpu_names_a_busy_thread_by_the_role_its_name_gives_it():
+    import threading
+
+    stop = threading.Event()
+
+    def burn():
+        while not stop.is_set():
+            sum(range(1000))
+
+    threads = [
+        threading.Thread(target=burn, name=name, daemon=True)
+        for name in ("bench-client-0", "pw-engine", "pw-cb-cb#9", "pw-worker_3")
+    ]
+    before = obs.thread_cpu()
+    for t in threads:
+        t.start()
+    time.sleep(0.4)
+    during = obs.thread_cpu()
+    process = time.process_time()
+    stop.set()
+    for t in threads:
+        t.join()
+    grown = {k: during[k] - before[k] for k in during}
+    for role in ("foreign", "engine", "batcher", "pool"):
+        assert grown[role] > 0.02, grown
+    assert set(during) == set(obs.CPU_ROLES) | {"native"}
+    # the roles and `native` are the process's CPU time (the two were read
+    # a few microseconds apart)
+    assert sum(during.values()) == pytest.approx(process, abs=0.05)
+    assert during["native"] >= 0
+    # the threads have ended: what they burnt is `native`'s now
+    after = obs.thread_cpu()
+    assert after["engine"] < during["engine"] and after["native"] > during["native"]
+
+
+def test_the_cpu_clock_of_a_thread_is_the_one_its_native_id_names():
+    """`thread_cpu` makes a thread's CPU clock from its kernel id and not
+    from its `pthread_t` (which is freed when the thread ends): the two
+    name the same clock while the thread lives, and a role is matched by
+    the thread's name (once a name: the match is cached)."""
+    import threading
+
+    me = threading.current_thread()
+    clock_id = obs._cpu_clock_id(me.native_id)
+    assert clock_id == time.pthread_getcpuclockid(me.ident)
+    assert time.clock_gettime(clock_id) == pytest.approx(
+        time.thread_time(), abs=0.05
+    )
+    assert obs._role_of(me.name) == "foreign"  # pytest's main thread is nobody's
+    # the thread that runs the benchmark's profiler is the instrument's, not
+    # the load's: a traced run's `foreign` reads as an untraced one's
+    assert obs._role_of("bench-tracer") == "tracer"
+    assert obs._role_of("bench-client-3") == obs._role_of("bench-load") == "foreign"
+    assert obs._role_of.cache_info().currsize >= 3
 
 
 # ------------------------------------------------------ program names
@@ -418,7 +933,10 @@ def _reader(name):
 
 
 def _ctx(batcher, records=()):
-    return {"counters": {"batcher": batcher}, "records": list(records)}
+    return {
+        "counters": {"batcher": batcher}, "records": list(records),
+        "mix": {"route": WORKED_ROUTE},
+    }
 
 
 def _rec(sent, done, status=200):
@@ -434,6 +952,34 @@ WORKED = {
     "prompt_tokens": 124_200, "padded_tokens": 128_000,
 }
 RECORDS = [_rec(0.0, 3.5), _rec(1.0, 4.7), _rec(2.0, 9.0, status=500)]
+# the route whose finished clocks the table's clock readers window, and two
+# clocks whose handler entries (0.1, 1.1) lie between the first and the last
+# `sent` (0.0, 2.0), as `RequestClock.stamps()` gives them. Seconds a stage:
+#   in .01, ingress .02, embed .03 / .05, search .04 / .06, prompt .01,
+#   tokenize .02, queue 1.5, first .5, decode 1.0 / 1.1, payload .03,
+#   egress .02 / .04, reply .05
+# and one whose entry (2.5) lies after the last `sent`: in no mean
+WORKED_ROUTE = "/worked"
+
+
+def _stamps(t0, seconds):
+    out = [t0]
+    for s in seconds:
+        out.append(out[-1] + s)
+    return tuple(out)
+
+
+WORKED_CLOCKS = [
+    _stamps(0.1, (.01, .02, .03, .04, .01, .02, 1.5, .5, 1.0, .03, .02, .05)),
+    _stamps(1.1, (.01, .02, .05, .06, .01, .02, 1.5, .5, 1.1, .03, .04, .05)),
+    _stamps(2.5, (1.0,) * 12),
+]
+# the cumulative mirror of observability.thread_cpu over 51 s of loop
+WORKED_CPU = {
+    **WORKED, "submitted": 100, "tokenize_s": 0.4,
+    "cpu_engine_s": 5.1, "cpu_udf_s": 2.55, "cpu_edge_s": 1.02,
+    "cpu_pool_s": 1.53, "cpu_foreign_s": 7.65,
+}
 
 
 @pytest.mark.parametrize("name,batcher,records,want", [
@@ -459,12 +1005,120 @@ RECORDS = [_rec(0.0, 3.5), _rec(1.0, 4.7), _rec(2.0, 9.0, status=500)]
     ("prefill_pad_pct", {**WORKED, "padded_tokens": 0}, (), None),
     ("prefill_pad_pct", {"prefills": 10, "padded_tokens": 20_160}, (), None),
     ("prefill_pad_pct", {"prefills": 10}, (), None),  # the parent commit
+    # ISSUE 40's seven. The two clocks in the window: in .01 + ingress .02
+    # + embed .04 + search .05 + prompt .01
+    ("edge_inbound_ms", WORKED, RECORDS, 130.0),
+    ("edge_inbound_ms", WORKED, (), None),  # no record: no window
+    # records sent before any clock's entry: no matching clock
+    ("edge_inbound_ms", WORKED, [_rec(-2.0, 3.5), _rec(-1.0, 4.7)], None),
+    ("retrieve_wait_ms", WORKED, RECORDS, 90.0),  # embed .04 + search .05
+    ("retrieve_wait_ms", WORKED, RECORDS[:1], None),  # a window of no width
+    ("edge_outbound_ms", WORKED, RECORDS, 110.0),  # .03 + .03 + .05
+    ("edge_outbound_ms", WORKED, (), None),
+    # 3.5 and 3.7 s at the client, 3.23 and 3.39 s of them in the handler
+    ("client_side_ms", WORKED, RECORDS, 290.0),
+    # the second reply was built (4.49) after its client says it was done:
+    # that clock finds no request of its own and is in no mean
+    ("client_side_ms", WORKED, [_rec(0.0, 3.5), _rec(1.0, 4.0)], 270.0),
+    ("client_side_ms", WORKED, RECORDS[2:], None),  # no 200 to average
+    ("client_side_ms", WORKED, [_rec(-2.0, 3.5), _rec(-1.0, 4.7)], None),
+    ("tokenize_ms", WORKED_CPU, (), 4.0),  # 0.4 s over 100 prompts
+    ("tokenize_ms", {**WORKED_CPU, "submitted": 0}, (), None),
+    ("tokenize_ms", WORKED, (), None),  # the parent commit: no such key
+    # 5.1 + 2.55 + 1.02 + 1.53 = 10.2 s of 51 s
+    ("program_threads_cpu_pct", WORKED_CPU, (), 20.0),
+    ("program_threads_cpu_pct", {"cpu_engine_s": 5.1, "loop_s": 51.0}, (), 10.0),
+    ("program_threads_cpu_pct", {**WORKED_CPU, "loop_s": 0.0}, (), None),
+    ("program_threads_cpu_pct", WORKED, (), None),  # the parent commit
+    ("harness_threads_cpu_pct", WORKED_CPU, (), 15.0),  # 7.65 s of 51 s
+    ("harness_threads_cpu_pct", {"cpu_foreign_s": 1.0}, (), None),  # no loop
+    ("harness_threads_cpu_pct", WORKED, (), None),  # the parent commit
 ])
-def test_reader_worked_numbers_and_empty_divisors(name, batcher, records, want):
+def test_reader_worked_numbers_and_empty_divisors(
+    monkeypatch, name, batcher, records, want,
+):
     """A divisor of 0 and a program without the counters (the parent
-    commit: only the counts) both read None, and never raise."""
+    commit: only the counts) both read None, and never raise; so does a
+    reader of the request clocks that finds no clock in its window."""
+    from pathway_tpu.io import http
+
+    monkeypatch.setitem(
+        http._ROUTE_STATS, WORKED_ROUTE,
+        {"stage_s": {}, "recent": list(WORKED_CLOCKS)},
+    )
     got = _reader(name)(_ctx(batcher, records))
     assert got == (pytest.approx(want) if want is not None else None)
+
+
+def test_the_clock_readers_read_nothing_from_a_program_without_the_clock(
+    monkeypatch,
+):
+    """The parent commit's `route_stats()` has no `recent`, and its
+    observability no `STAGES`: the benchmark lays these readers over it."""
+    from pathway_tpu.io import http
+
+    monkeypatch.setitem(http._ROUTE_STATS, WORKED_ROUTE, {"residence_s": 6.6})
+    monkeypatch.setattr(
+        http, "route_stats", lambda: {r: dict(s) for r, s in http._ROUTE_STATS.items()}
+    )
+    names = ("edge_inbound_ms", "retrieve_wait_ms", "edge_outbound_ms",
+             "client_side_ms")
+    assert [_reader(n)(_ctx(WORKED, RECORDS)) for n in names] == [None] * 4
+    monkeypatch.delattr(obs, "STAGES")
+    assert [_reader(n)(_ctx(WORKED, RECORDS)) for n in names] == [None] * 4
+
+
+def test_a_clock_reader_reads_nothing_where_a_full_recent_may_have_dropped_a_clock(
+    monkeypatch,
+):
+    """`recent` keeps the `RECENT_CLOCKS` that finished last. Full, and its
+    oldest replied inside the window: one dropped before it may have been
+    entered inside the window too, so the mean would lean to the late ones;
+    the readers say nothing instead. Full, and its oldest replied before the
+    window opened: whatever was dropped lies outside it."""
+    from pathway_tpu.io import http
+
+    names = ("edge_inbound_ms", "retrieve_wait_ms", "edge_outbound_ms",
+             "client_side_ms")
+    monkeypatch.setitem(
+        http._ROUTE_STATS, WORKED_ROUTE,
+        {"stage_s": {}, "recent": list(WORKED_CLOCKS)},
+    )
+    monkeypatch.setattr(http, "RECENT_CLOCKS", len(WORKED_CLOCKS))
+    ctx = _ctx(WORKED, RECORDS)  # opens at 0.0; the oldest replied at 3.33
+    assert [_reader(n)(ctx) for n in names] == [None] * 4
+    # a window that opens at 4.0, behind that reply: its one clock is read
+    late = _stamps(4.5, (.01, .02, .03, .04, .01, .02, 1.5, .5, 1.0, .03, .02, .05))
+    monkeypatch.setitem(
+        http._ROUTE_STATS, WORKED_ROUTE,
+        {"stage_s": {}, "recent": [*WORKED_CLOCKS[:2], late]},
+    )
+    ctx = _ctx(WORKED, [_rec(4.0, 8.0), _rec(5.0, 9.0)])
+    assert _reader("edge_inbound_ms")(ctx) == pytest.approx(110.0)
+    assert _reader("client_side_ms")(ctx) == pytest.approx(1e3 * (4.0 - 3.23))
+    # and one entry short of full, the first window is read as it was
+    monkeypatch.setattr(http, "RECENT_CLOCKS", len(WORKED_CLOCKS) + 1)
+    assert _reader("edge_inbound_ms")(_ctx(WORKED, RECORDS)) == pytest.approx(130.0)
+
+
+def test_the_readers_identity_adds_up_to_the_clients_time():
+    """inbound + (tokenize + queue + first + decode) + outbound + the
+    client's side = the mean of `done - sent`: the three parts outside the
+    batcher are `outside_batcher_ms` where the batcher's counters and the
+    clocks cover the same requests."""
+    from pathway_tpu.io import http
+
+    http._ROUTE_STATS[WORKED_ROUTE] = {"stage_s": {}, "recent": list(WORKED_CLOCKS)}
+    try:
+        # the batcher's residence of the same two requests: 3.02 + 3.12 s
+        batcher = {**WORKED, "completed": 2, "residence_s": 6.14}
+        ctx = _ctx(batcher, RECORDS)
+        parts = sum(_reader(n)(ctx) for n in (
+            "edge_inbound_ms", "edge_outbound_ms", "client_side_ms"))
+        assert parts == pytest.approx(_reader("outside_batcher_ms")(ctx))
+        assert parts + 1e3 * 6.14 / 2 == pytest.approx(1e3 * (3.5 + 3.7) / 2)
+    finally:
+        del http._ROUTE_STATS[WORKED_ROUTE]
 
 
 def _listed(names):
@@ -476,9 +1130,42 @@ def _listed(names):
         m = entries[f"{name}.tput"]
         assert "rag-cerebras-6b7.backlog" in m["workloads"]
         assert m["moves"] == "answers_per_s"
-        assert m["source"] == "program_counter" and m["better"] == "lower"
+        # the client's clock less a counter of the program's: the former's
+        assert m["source"] == (
+            "host_clock" if name == "client_side_ms" else "program_counter"
+        )
+        assert m["better"] == "lower"
         assert (BENCH / "layer_metrics" / f"{name}.py").is_file()
     return entries
+
+
+def test_benchmark_lists_the_seven_edge_and_cpu_metrics_for_the_three_cells():
+    """ISSUE 40: appended to `per_layer`, each listing the three accepted
+    cells, in the layers `PERF.md` section 3 names."""
+    import json
+
+    names = ("edge_inbound_ms", "retrieve_wait_ms", "edge_outbound_ms",
+             "client_side_ms", "tokenize_ms", "program_threads_cpu_pct",
+             "harness_threads_cpu_pct")
+    entries = _listed(names)
+    bench = json.loads((BENCH.parent / "BENCHMARK.json").read_text())
+    cells = [w["name"] for w in bench["workloads"]]
+    assert [m["name"] for m in bench["per_layer"][-7:]] == [
+        f"{n}.tput" for n in names
+    ]
+    layers = {n: entries[f"{n}.tput"]["layer"] for n in names}
+    edge = entries["outside_batcher_ms.tput"]["layer"]
+    batching = entries["queue_wait_ms.tput"]["layer"]
+    generator = "load generator (bench/pwbench/loadgen.py)"
+    assert layers == {
+        "edge_inbound_ms": edge, "retrieve_wait_ms": edge,
+        "edge_outbound_ms": edge, "client_side_ms": generator,
+        "tokenize_ms": batching, "program_threads_cpu_pct": batching,
+        "harness_threads_cpu_pct": generator,
+    }
+    for n in names:
+        assert entries[f"{n}.tput"]["workloads"] == cells
+        assert entries[f"{n}.tput"]["unit"] == ("%" if n.endswith("pct") else "ms")
 
 
 def test_benchmark_lists_the_four_metrics_for_the_backlog_cell():
