@@ -798,15 +798,53 @@ def _cache_rows(cfg: TransformerConfig) -> list[tuple[dict[str, str], int]]:
     return out
 
 
+def _qkv_product(xin: Array, block: Params, cfg: TransformerConfig) -> Array:
+    """Normed rows times the layer's qkv matrix: [b, s, (heads + 2 kv
+    heads) * dh], the heads of q, k and v side by side."""
+    return jnp.einsum(
+        "bsd,de->bse", xin, block["qkv"].astype(cfg.dtype),
+        preferred_element_type=jnp.float32,
+    ).astype(cfg.dtype)
+
+
 def _qkv(xin: Array, block: Params, cfg: TransformerConfig, spec: LayerSpec):
     """q [b, s, heads, dh] and k, v [b, s, kv heads, dh] of normed rows."""
     b, s, _ = xin.shape
     (h, hk), dh = _mixer_heads(cfg, spec), cfg.head_dim
-    qkv = jnp.einsum(
-        "bsd,de->bse", xin, block["qkv"].astype(cfg.dtype),
-        preferred_element_type=jnp.float32,
-    ).astype(cfg.dtype)
-    q, k, v = jnp.split(qkv, [h * dh, (h + hk) * dh], axis=-1)
+    q, k, v = jnp.split(
+        _qkv_product(xin, block, cfg), [h * dh, (h + hk) * dh], axis=-1
+    )
+    return (
+        q.reshape(b, s, h, dh), k.reshape(b, s, hk, dh), v.reshape(b, s, hk, dh)
+    )
+
+
+def _qkv_rowwise(xin: Array, block: Params, cfg: TransformerConfig,
+                 spec: LayerSpec, rope, live: Array):
+    """`_qkv`, then `_rmsnorm` of q and k (`cfg.qk_norm`), `_rope` of both
+    (a rotary layer; `rope` are ops/rowwise.py `rope_tables` of the rows'
+    positions) and zeros for the keys of rows not `live` (a linear layer),
+    where `rowwise_uses_kernel` holds: q and k each in one pass of
+    ops/rowwise.py `rowwise_heads` over their lanes of the product, rounded
+    where the program a TPU runs of those functions rounds (once, behind
+    the rotation)."""
+    # imported where it is traced: Pallas loads when a program first needs it
+    from pathway_tpu.ops.rowwise import rowwise_heads
+
+    b, s, _ = xin.shape
+    (h, hk), dh = _mixer_heads(cfg, spec), cfg.head_dim
+    qkv = _qkv_product(xin, block, cfg)
+    rope = rope if spec.pos == "rotary" else None
+    with jax.named_scope("rowwise"):
+        q = rowwise_heads(
+            qkv, block["q_norm"] if cfg.qk_norm else None, rope, None,
+            first=0, heads=h, dh=dh,
+        )
+        k = rowwise_heads(
+            qkv, block["k_norm"] if cfg.qk_norm else None, rope,
+            live if spec.mixer == "linear" else None, first=h, heads=hk, dh=dh,
+        )
+    v = qkv[..., (h + hk) * dh:]
     return (
         q.reshape(b, s, h, dh), k.reshape(b, s, hk, dh), v.reshape(b, s, hk, dh)
     )
@@ -955,21 +993,27 @@ def _branch(x: Array, y: Array, cfg: TransformerConfig) -> Array:
     return x + y
 
 
-def _layer(x, block, spec, cfg, pos, live, attend, counters):
+def _layer(x, block, spec, cfg, pos, live, attend, counters, fused=False,
+           rope=None):
     """One decoder layer over rows x [b, s, d]. `attend(q, k, v)` writes the
     layer's keys and values (or its state) where they belong and returns
     the mixer's output; `pos` [b, s] are logical positions, `live` [b, s]
     the rows that count (not padding, not a free slot). An experts layer
-    appends its per-expert counts of live pairs to `counters["experts"]`."""
+    appends its per-expert counts of live pairs to `counters["experts"]`.
+    `fused`: `rowwise_uses_kernel` of the program's width, which a prefill
+    asks once, and `rope` then its `rope_tables` if it has a rotary layer."""
     if spec.ff == "experts":
         idx, w = _route(x, block, cfg)
     xin = _rmsnorm(x, block["ln1_scale"])
     with jax.named_scope("attn"):
-        q, k, v = _qkv(xin, block, cfg, spec)
-        if cfg.qk_norm:
-            q, k = _rmsnorm(q, block["q_norm"]), _rmsnorm(k, block["k_norm"])
-        if spec.pos == "rotary":
-            q, k = _rope(q, pos, cfg), _rope(k, pos, cfg)
+        if fused and _takes_rowwise(cfg, spec):
+            q, k, v = _qkv_rowwise(xin, block, cfg, spec, rope, live)
+        else:
+            q, k, v = _qkv(xin, block, cfg, spec)
+            if cfg.qk_norm:
+                q, k = _rmsnorm(q, block["q_norm"]), _rmsnorm(k, block["k_norm"])
+            if spec.pos == "rotary":
+                q, k = _rope(q, pos, cfg), _rope(k, pos, cfg)
     ctx = attend(q, k, v)
     with jax.named_scope("attn"):
         if cfg.out_gate:
@@ -1393,6 +1437,25 @@ def linear_prefill_uses_kernel(cfg: TransformerConfig, width: int) -> bool:
     return bool(cfg.n_mixer_layers("linear")) and prefill_uses_kernel(cfg, width)
 
 
+def rowwise_uses_kernel(cfg: TransformerConfig, width: int) -> bool:
+    """Whether a prefill `width` wide has layers whose q and k take
+    ops/rowwise.py `rowwise_heads` (norm, rotary positions and a pad's zero
+    in one pass over the qkv product, the arithmetic of `_rmsnorm` and
+    `_rope` as a TPU runs it) and not those functions one after the other: where
+    `prefill_uses_kernel` would hold of such a width, for a decoder with q/k
+    norms or a rotary layer. Read from the shapes and from where the process
+    runs; nothing sets it. A step (one row a slot) never does."""
+    return (
+        cfg.qk_norm or any(sp.pos == "rotary" for sp in cfg.layer_specs)
+    ) and prefill_uses_kernel(cfg, width)
+
+
+def _takes_rowwise(cfg: TransformerConfig, spec: LayerSpec) -> bool:
+    """Whether a layer of such a prefill is one of them: it has a norm or a
+    rotation to make."""
+    return cfg.qk_norm or spec.pos == "rotary"
+
+
 def sparse_prefill_uses_kernel(cfg: TransformerConfig, width: int) -> bool:
     """Whether the sparse layers of a prefill `width` wide run
     ops/sparse_attention.py `sparse_prefill_attention` over the blocks each
@@ -1490,6 +1553,12 @@ def _prefill(
             at = jnp.arange(p)
             wmask = mask & (at[None, :] > at[:, None] - window)[None, None]
     live = valid.astype(bool)
+    fused, rope = rowwise_uses_kernel(cfg, p), None
+    if fused and any(sp.pos == "rotary" for sp in cfg.layer_specs):
+        from pathway_tpu.ops.rowwise import rope_tables
+
+        with jax.named_scope("rope"):  # once, for every rotary layer
+            rope = rope_tables(pos_idx, cfg.rope_theta, cfg.head_dim)
     counters = _new_counters()
     for (names, li), spec, block in zip(
         _cache_rows(cfg), cfg.layer_specs, params["blocks"]
@@ -1497,7 +1566,8 @@ def _prefill(
         def attend(q, k, v, names=names, li=li, spec=spec, block=block):
             if spec.mixer == "linear":
                 return _prefill_linear(
-                    q, k, v, cache, names, li, live, block, cfg, counters
+                    q, k, v, cache, names, li, live, block, cfg, counters,
+                    zeroed=fused and _takes_rowwise(cfg, spec),
                 )
             if spec.mixer == "sparse":
                 return _prefill_sparse(
@@ -1536,25 +1606,35 @@ def _prefill(
                     q, kt, vt, mask if spec.window is None else wmask, cfg
                 )
 
-        x = _layer(x, block, spec, cfg, pos_idx, live, attend, counters)
+        x = _layer(
+            x, block, spec, cfg, pos_idx, live, attend, counters, fused, rope
+        )
     hlast = _rmsnorm(x[:, -1:, :], params["ln_f_scale"])
     return _lm_logits(hlast, params, cfg)[:, 0, :], cache, counters
 
 
-def _prefill_linear(q, k, v, cache, names, li, live, block, cfg, counters):
+def _prefill_linear(q, k, v, cache, names, li, live, block, cfg, counters,
+                    zeroed=False):
     """A linear layer over whole prompts: the chunked scan, and the state
-    it leaves after the last token into the layer's leaf."""
-    p = q.shape[1]
+    it leaves after the last token into the layer's leaf. `zeroed`: the
+    pads' keys are zeros already (`_qkv_rowwise`)."""
+    b, p, h, dh = q.shape
+    normed = False
     with jax.named_scope("attn"), jax.named_scope("attn_linear"):
-        k = jnp.where(live[:, :, None, None], k, jnp.zeros_like(k))  # a pad adds nothing
+        if not zeroed:  # a pad adds nothing
+            k = jnp.where(live[:, :, None, None], k, jnp.zeros_like(k))
         with jax.named_scope("scan"):
             if linear_prefill_uses_kernel(cfg, p):
                 # imported where it is traced: Pallas loads when a program
                 # first needs it
                 from pathway_tpu.ops.linear_attention import linear_prefill_attention
 
+                # the output norm in the kernel's epilogue: `_linear_out`
+                # before its cast, with nothing of the norm crossing HBM
+                normed = cfg.linear_out_norm
                 out, state = linear_prefill_attention(
-                    q, k, v, _slopes(cfg), _LINEAR_CHUNK
+                    q, k, v, _slopes(cfg), _LINEAR_CHUNK,
+                    block["o_norm"] if normed else None,
                 )
             else:
                 out, state = linear_scan(q, k, v, _slopes(cfg))
@@ -1563,6 +1643,8 @@ def _prefill_linear(q, k, v, cache, names, li, live, block, cfg, counters):
                 cache[names["state"]], state[None], (li, 0, 0, 0, 0)
             )
         counters["linear_tokens"].append(jnp.sum(live, dtype=jnp.int32))
+        if normed:
+            return out.astype(cfg.dtype).reshape(b, p, h * dh)
         return _linear_out(out, block, cfg)
 
 

@@ -9,7 +9,14 @@ Written in XLA the scan is a `lax.scan` over chunks whose float32 pairs
 [heads, chunk, chunk] and states [heads, dh, dh] cross HBM every chunk. Here
 a grid step is one chunk of one head: the pairs, their decay mask and the
 state never leave VMEM, and HBM sees q, k, v once, the output once and the
-last state once.
+last state once. A chunk's output block is a head's dh lanes, the group of
+the layer's output norm (models/transformer.py `_linear_out`), so with that
+norm's scale the kernel norms the float32 block where it lies: the norm's
+reduction, its scaling, their relayouts and the float32 arrays between them
+never cross HBM. What leaves is the normed block in float32, as the
+un-normed one did: the one rounding of `_linear_out` is its caller's, and
+on a TPU XLA makes it inside the product of the output gate, after the
+gate's multiplication (PERF.md section 6, PR 42).
 """
 
 from __future__ import annotations
@@ -23,11 +30,13 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _linear_kernel(slope_ref, q_ref, k_ref, v_ref, o_ref, last_ref, state_ref,
-                   *, chunk: int, scale: float):
+def _linear_kernel(slope_ref, q_ref, k_ref, v_ref, *rest, chunk: int,
+                   scale: float):
     """One grid step (row, head, chunk): the chunk's queries against its own
     keys under the decay mask, and against the state the chunks before it
-    left; then the state moves on by the chunk."""
+    left; then the state moves on by the chunk. `rest`: the output norm's
+    scale [1, dh] where there is one, then the outputs and the scratch."""
+    *norm_ref, o_ref, last_ref, state_ref = rest
     ci = pl.program_id(2)
     slope = slope_ref[pl.program_id(1)]  # the head's, a scalar
 
@@ -52,7 +61,11 @@ def _linear_kernel(slope_ref, q_ref, k_ref, v_ref, o_ref, last_ref, state_ref,
         q.astype(jnp.float32) * jnp.exp(-slope * (at + 1.0)), state,
         precision=high, preferred_element_type=jnp.float32,
     )
-    o_ref[0] = ((inner + carried) * scale).astype(o_ref.dtype)
+    out = (inner + carried) * scale
+    if norm_ref:  # `_rmsnorm` of the float32 block
+        var = jnp.mean(out * out, axis=-1, keepdims=True)
+        out = out * jax.lax.rsqrt(var + 1e-6) * norm_ref[0][...]
+    o_ref[0] = out
     left = k.astype(jnp.float32) * jnp.exp(-slope * (chunk - 1.0 - at))
     state = jnp.exp(-slope * chunk) * state + jax.lax.dot_general(
         left, v.astype(jnp.float32), (((0,), (0,)), ((), ())),
@@ -74,11 +87,14 @@ def linear_prefill_attention(
     v: jax.Array,  # [b, p, heads, dh]
     slopes: jax.Array,  # [heads] float32: head h decays by exp(-slopes[h])
     chunk: int = 256,
+    out_norm: jax.Array | None = None,  # [dh]: the output norm's scale
     *,
     interpret: bool = False,
 ) -> tuple[jax.Array, jax.Array]:
     """The linear mixer over whole prompts. Returns (o [b, p, heads, dh]
-    float32, the state after the last position [b, heads, dh, dh] float32).
+    float32, the state after the last position [b, heads, dh, dh] float32);
+    with `out_norm` o is RMS-normed over each head's dh and scaled by it,
+    in float32: models/transformer.py `_linear_out` before its cast.
 
     Inside a chunk (q k^T * D) v with D_ij = lambda^(i-j) for j <= i, the
     products of the inputs' dtype with float32 accumulation and the masked
@@ -105,6 +121,7 @@ def linear_prefill_attention(
     def rows(bi, hi, ci):
         return bi, ci, hi
 
+    norm = [] if out_norm is None else [out_norm.astype(jnp.float32).reshape(1, dh)]
     out, last = pl.pallas_call(
         functools.partial(_linear_kernel, chunk=chunk, scale=1.0 / math.sqrt(dh)),
         grid=(b, h, p // chunk),
@@ -113,7 +130,7 @@ def linear_prefill_attention(
             pl.BlockSpec((1, chunk, dh), rows),
             pl.BlockSpec((1, chunk, dh), rows),
             pl.BlockSpec((1, chunk, dh), rows),
-        ],
+        ] + [pl.BlockSpec((1, dh), lambda bi, hi, ci: (0, 0)) for _ in norm],
         out_specs=[
             pl.BlockSpec((1, chunk, dh), rows),
             pl.BlockSpec((1, 1, dh, dh), lambda bi, hi, ci: (bi, hi, 0, 0)),
@@ -131,5 +148,6 @@ def linear_prefill_attention(
     )(
         slopes.astype(jnp.float32),
         q.reshape(b, p, h * dh), k.reshape(b, p, h * dh), v.reshape(b, p, h * dh),
+        *norm,
     )
     return out.reshape(b, p, h, dh)[:, extra:], last

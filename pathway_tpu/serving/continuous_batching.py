@@ -136,6 +136,9 @@ likewise: a run that fell back to ``ragged_dot`` says so),
 layers ran ops/linear_attention.py's scan and whose sparse layers ran
 ops/sparse_attention.py's kernel over the blocks each query chose
 (``transformer.linear_prefill_uses_kernel``, ``sparse_prefill_uses_kernel``),
+``kernel_rowwise_prefills`` those whose q/k norm, rotary positions and pads'
+zero were ops/rowwise.py's one pass over the qkv product
+(``transformer.rowwise_uses_kernel``),
 ``kernel_steps`` the decode steps whose attention read the slot cache in
 place (``transformer.step_uses_kernel``, likewise), and
 ``kernel_sparse_steps`` those whose sparse layers read only the rows of the
@@ -399,6 +402,8 @@ class ContinuousBatcher:
             "kernel_expert_prefills": 0,
             # prefills whose linear and sparse layers ran their kernels
             "kernel_linear_prefills": 0, "kernel_sparse_prefills": 0,
+            # prefills whose q and k took ops/rowwise.py's one pass
+            "kernel_rowwise_prefills": 0,
             # decode steps whose attention ran ops/attention.py's kernel
             "kernel_steps": 0,
             # and those whose sparse layers read only their chosen blocks
@@ -805,6 +810,9 @@ class ContinuousBatcher:
                 )
                 self.stats["kernel_sparse_prefills"] += (
                     self._model.sparse_prefill_uses_kernel(self.cfg, req.width)
+                )
+                self.stats["kernel_rowwise_prefills"] += (
+                    self._model.rowwise_uses_kernel(self.cfg, req.width)
                 )
                 self.stats["queue_wait_s"] += req.t_admit - req.t_submit
                 self.stats["first_token_s"] += req.t_first - req.t_submit

@@ -98,6 +98,7 @@ def test_stats_hold_every_key_from_construction():
         "prompt_tokens", "padded_tokens", "kernel_prefills", "kernel_steps",
         "kernel_expert_prefills", "kernel_linear_prefills",
         "kernel_sparse_prefills", "kernel_sparse_steps",
+        "kernel_rowwise_prefills",
         # an experts decoder's device counters (0 for this block)
         "routed_pairs", "expert_load_max", "experts_touched", "moe_layers_run",
         # and those of a decoder with sparse or linear layers
@@ -1180,13 +1181,15 @@ def test_benchmark_lists_the_four_metrics_for_the_backlog_cell():
 @pytest.mark.parametrize("stat, predicate", [
     ("kernel_prefills", "prefill_uses_kernel"),
     ("kernel_expert_prefills", "prefill_experts_use_kernel"),
+    ("kernel_rowwise_prefills", "rowwise_uses_kernel"),
 ])
 def test_kernel_prefills_counts_what_the_predicate_says(
     monkeypatch, engages, stat, predicate
 ):
-    """`kernel_prefills` and `kernel_expert_prefills` are the model module's
-    own predicates of the width a prompt ran at, the ones `_prefill` and
-    (through `experts_use_kernel`) `_experts` branch on: with one patched
+    """`kernel_prefills`, `kernel_expert_prefills` and
+    `kernel_rowwise_prefills` are the model module's own predicates of the
+    width a prompt ran at, the ones `_prefill` (for its attention, and for the row-wise pass over q and k)
+    and (through `experts_use_kernel`) `_experts` branch on: with one patched
     true (after the programs are traced, so that the CPU still runs them)
     its count equals `prefills`; as it is off the TPU it stays 0."""
     cb = _chat()._cb

@@ -24,6 +24,7 @@ import pytest
 from pathway_tpu.models import LayerSpec, TransformerConfig, lm_config
 from pathway_tpu.models import transformer as T
 from pathway_tpu.ops import attention as A
+from pathway_tpu.ops import rowwise as R
 
 BENCH = Path(__file__).resolve().parents[1] / "bench"
 sys.path.insert(0, str(BENCH))
@@ -120,17 +121,29 @@ def test_slot_cache_through_the_prefill_kernel_matches_the_plain_reference(
     length, width, monkeypatch
 ):
     """`_prefill` with the rule saying kernel (as on a TPU; interpreted
-    here, tiles of 128): every layer kind's attention, the ring's write
-    and the steps behind it against the family's reference, which has no
-    kernel, no cache and no ring."""
+    here, tiles of 128): every layer kind's attention, the window layers'
+    rotary positions through ops/rowwise.py `rowwise_heads` (the rotary
+    part alone: this family has no q/k norm), the ring's write and the
+    steps behind it against the family's reference, which has no kernel, no
+    cache and no ring."""
     monkeypatch.setattr(T, "prefill_uses_kernel", lambda cfg, p: True)
     monkeypatch.setattr(
         A, "prefill_attention",
         functools.partial(A.prefill_attention, interpret=True),
     )
+    rotated, rowwise = [], R.rowwise_heads
+    monkeypatch.setattr(
+        R, "rowwise_heads",
+        lambda x, scale, rope, live, **kw: rotated.append((scale, live))
+        or rowwise(x, scale, rope, live, interpret=True, **kw),
+    )
     monkeypatch.setattr(A, "_PREFILL_TILE_MAX", 128)
     cfg = FAMILY.program_config(KERNEL_KEYS, jnp.float32)
+    assert T.rowwise_uses_kernel(cfg, width)
     got, toks = _served_logits(cfg, _prompt(length), width)
+    # q and k of the six window layers, and of no global one; no norm, no zero
+    assert rotated and len(rotated) % (2 * 6) == 0
+    assert all(scale is None and live is None for scale, live in rotated)
     at = range(length - 1, len(toks))
     want = FAMILY.decoder_logits(SEED, KERNEL_SIZES, [toks], [at], 512)[0]
     assert np.abs(got - want).max() < 1e-4

@@ -14,7 +14,12 @@ dense up to 32 positions.
 (b) a slot taken again by the batcher serves what a fresh pool serves;
 (c) the chunked scan is the recurrence, and a step is one more position;
 (d) the two kernels, interpreted, against the `jax.numpy` formulas;
-(e) what the selection always takes, how much, and for whom.
+(e) what the selection always takes, how much, and for whom;
+(f) the row-wise pass over q and k (ops/rowwise.py) and the scan kernel's
+    output norm round where the program a TPU runs today rounds (`_rmsnorm`,
+    `_rope` and `_linear_out` with float32 between the first two), at the
+    positions the third cell has; a table or a position in bf16 does not,
+    nor does a second rounding between the norm and the rotation.
 """
 
 from __future__ import annotations
@@ -31,6 +36,7 @@ import pytest
 from pathway_tpu.models import transformer as T
 from pathway_tpu.ops import attention as A
 from pathway_tpu.ops import linear_attention as L
+from pathway_tpu.ops import rowwise as R
 from pathway_tpu.ops import sparse_attention as S
 
 BENCH = Path(__file__).resolve().parents[1] / "bench"
@@ -149,29 +155,37 @@ def test_slot_cache_matches_the_plain_reference_in_bfloat16(length, width):
     assert diff.mean() < 0.05 and diff.max() < 0.5
 
 
-@pytest.mark.parametrize("length, width", [(20, 128), (100, 128), (150, 256)])
-def test_slot_cache_through_the_kernels_matches_the_plain_reference(
-    length, width, monkeypatch
-):
-    """`_prefill` with the rule saying kernel (as on a TPU; interpreted
-    here): the scan's kernel, the selected-block kernel past `dense_len` and
-    `prefill_attention` up to it, the state, rows and pooled keys they leave
-    and the steps behind them, against the family's reference."""
+def _interpret_the_prefill_kernels(monkeypatch):
+    """The rule says kernel, as on a TPU, and every kernel a prefill then
+    takes is interpreted (tiles of 128)."""
     monkeypatch.setattr(T, "prefill_uses_kernel", lambda cfg, p: True)
     for module, name in (
         (A, "prefill_attention"), (L, "linear_prefill_attention"),
-        (S, "sparse_prefill_attention"),
+        (S, "sparse_prefill_attention"), (R, "rowwise_heads"),
     ):
         monkeypatch.setattr(
             module, name, functools.partial(getattr(module, name), interpret=True)
         )
     monkeypatch.setattr(A, "_PREFILL_TILE_MAX", 128)
+
+
+@pytest.mark.parametrize("length, width", [(20, 128), (100, 128), (150, 256)])
+def test_slot_cache_through_the_kernels_matches_the_plain_reference(
+    length, width, monkeypatch
+):
+    """`_prefill` with the rule saying kernel (as on a TPU; interpreted
+    here): the row-wise pass over q and k, the scan's kernel with the output
+    norm in it, the selected-block kernel past `dense_len` and
+    `prefill_attention` up to it, the state, rows and pooled keys they leave
+    and the steps behind them, against the family's reference."""
+    _interpret_the_prefill_kernels(monkeypatch)
     cfg = FAMILY.program_config(
         {**KERNEL_KEYS, "sparse_config": {**KEYS["sparse_config"], "dense_len": 64}},
         jnp.float32,
     )
     assert T.linear_prefill_uses_kernel(cfg, width)
     assert T.sparse_prefill_uses_kernel(cfg, width)
+    assert T.rowwise_uses_kernel(cfg, width)
     sizes = FAMILY.sizes(
         {**KERNEL_KEYS, "sparse_config": {**KEYS["sparse_config"], "dense_len": 64}}
     )
@@ -514,3 +528,188 @@ def test_the_steps_through_the_kernels_serve_the_plain_paths_logits(monkeypatch)
     for want, (n, w) in zip(plain, ((90, 96), (150, 160))):
         got = _served_logits(cfg, _prompt(n), w)[0]
         assert np.abs(got - want).max() < 1e-4
+
+
+# ------------------- (f) the row-wise pass and the scan kernel's output norm
+
+
+def _bf16_ulps(a, b) -> np.ndarray:
+    """How many bf16 values lie between each pair of elements."""
+    def line(x):  # the bits, in the order of the values
+        bits = np.asarray(x, jnp.bfloat16).view(np.uint16).astype(np.int32)
+        return np.where(bits & 0x8000, 0x8000 - (bits & 0x7FFF), bits + 0x8000)
+
+    return np.abs(line(a) - line(b))
+
+
+def _rounds_alike(got, want) -> bool:
+    """The rule of this section: the present program's result to the bf16
+    bit, but for the rare element where another order of a float32 sum or a
+    1-ulp float32 difference crosses a rounding boundary: at most 0.5% of
+    the elements differ, none by more than one bf16 ulp. (An element of
+    values of unit spread that cancelled to under 2**-12, x1 cos = x2 sin,
+    holds the float32 rounding of its two terms and no bf16 one: it may
+    differ by 2**-20, a bf16 ulp at 2**-12.)"""
+    ulps = _bf16_ulps(got, want)
+    a, b = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    cancelled = (np.abs(b) < 2.0 ** -12) & (np.abs(a - b) <= 2.0 ** -20)
+    return bool((ulps > 0).mean() <= 0.005 and (ulps <= 1)[~cancelled].all())
+
+
+# rows at the positions the third cell has: a prompt's first, the 24,576
+# rung's last, the slot cache's last. A bf16 position is exact up to 256
+ROW_POS = np.concatenate([
+    np.arange(0, 256), np.arange(24_000, 24_576), np.arange(32_768 - 192, 32_768)
+]).astype(np.int32)[None]
+ROPE_CFG = T.lm_config(vocab_size=64, d_model=512, n_heads=4, n_layers=1,
+                       max_len=32_768, rope_theta=10_000.0, dtype=jnp.bfloat16)
+
+
+def _product(seed: int = 0):
+    """A qkv product of 4 query heads and 2 key/value heads of 128 over
+    ROW_POS, bf16, the norms' scales and the rows that are live."""
+    rng = np.random.default_rng(seed)
+    p = ROW_POS.shape[1]
+    qkv = jnp.asarray(1.5 * rng.standard_normal((1, p, 8 * 128)), jnp.bfloat16)
+    q_scale = jnp.asarray(1.0 + 0.1 * rng.standard_normal(128), jnp.bfloat16)
+    k_scale = jnp.asarray(1.8 + 0.1 * rng.standard_normal(128), jnp.bfloat16)
+    live = jnp.asarray(rng.random((1, p)) > 0.2)
+    return qkv, q_scale, k_scale, live
+
+
+def _chips_chain(x, scale, rotary, live):
+    """What `_layer` and `_prefill_linear` do to q or k [b, p, heads, dh] in
+    the program XLA compiles for a TPU: `_rmsnorm` and `_rope` themselves,
+    handed float32 so that the cast between them is none (inside a fusion
+    the TPU backend drops the pair of casts: my chip run, PR 42, a third of
+    the elements differ from the chain with the cast and none from this
+    one), and one rounding behind them."""
+    y = x.astype(jnp.float32)
+    if scale is not None:
+        y = T._rmsnorm(y, scale)
+    if rotary:
+        y = T._rope(y, jnp.asarray(ROW_POS), ROPE_CFG)
+    y = y.astype(x.dtype)
+    if live is not None:
+        y = jnp.where(live[:, :, None, None], y, jnp.zeros_like(y))
+    return y
+
+
+@pytest.mark.parametrize("norm, rotary, zero", [
+    (True, True, False), (False, True, False), (True, False, False),
+    (True, True, True), (False, False, True),
+])
+def test_the_rowwise_pass_rounds_where_the_chips_program_rounds(norm, rotary, zero):
+    """Interpreted, bf16 in and out, q (4 heads from lane 0) and k (2 heads
+    behind them) out of the product as it lies, every part on and off."""
+    qkv, q_scale, k_scale, live = _product()
+    rope = R.rope_tables(jnp.asarray(ROW_POS), 10_000.0, 128) if rotary else None
+    p = qkv.shape[1]
+    for first, heads, scale in ((0, 4, q_scale), (4, 2, k_scale)):
+        got = R.rowwise_heads(
+            qkv, scale if norm else None, rope, live if zero else None,
+            first=first, heads=heads, dh=128, interpret=True,
+        )
+        x = qkv[..., first * 128:(first + heads) * 128].reshape(1, p, heads, 128)
+        want = _chips_chain(x, scale if norm else None, rotary, live if zero else None)
+        if not (norm and rotary):  # one link: the plain functions as they are
+            plain = T._rmsnorm(x, scale) if norm else x
+            plain = T._rope(plain, jnp.asarray(ROW_POS), ROPE_CFG) if rotary else plain
+            if zero:
+                plain = jnp.where(live[:, :, None, None], plain, jnp.zeros_like(plain))
+            assert _rounds_alike(plain, want)
+        assert got.dtype == jnp.bfloat16 and got.shape == (1, p, heads * 128)
+        assert _rounds_alike(got.reshape(want.shape), want), (first, heads)
+        if zero:
+            assert not np.asarray(got, np.float32)[~np.asarray(live)].any()
+
+
+@pytest.mark.parametrize("fault", [
+    "tables_in_bf16", "positions_in_bf16", "rounded_after_the_norm",
+])
+def test_the_rule_sees_arithmetic_that_is_not_the_present_programs(fault):
+    """What a fused pass is free to get wrong and a test at 256 positions
+    would not see: cosines and sines kept in bf16, positions in bf16 (exact
+    up to 256 only), and the normed value rounded to bf16 before the
+    rotation, which is what `_rope(_rmsnorm(x))` reads like, what a CPU
+    computes from it and what PR 41 served: `logit_gap` 0.0736 of 0.05 on
+    seed 1429016925, where the chip's own chain reads 0.0072 (PERF.md)."""
+    qkv, q_scale, _, _ = _product(1)
+    p = qkv.shape[1]
+    q = qkv[..., :4 * 128].reshape(1, p, 4, 128)
+    want = _chips_chain(q, q_scale, True, None)
+    pos = jnp.asarray(ROW_POS)
+    if fault == "rounded_after_the_norm":
+        got = T._rope(T._rmsnorm(q, q_scale), pos, ROPE_CFG)
+    else:
+        if fault == "tables_in_bf16":
+            rope = tuple(
+                t.astype(jnp.bfloat16).astype(jnp.float32)
+                for t in R.rope_tables(pos, 10_000.0, 128)
+            )
+        else:
+            rope = R.rope_tables(pos.astype(jnp.bfloat16), 10_000.0, 128)
+        got = R.rowwise_heads(
+            qkv, q_scale, rope, None, first=0, heads=4, dh=128, interpret=True
+        ).reshape(want.shape)
+    assert not _rounds_alike(got, want)
+    # and at positions a bf16 holds exactly, only the table's own rounding shows
+    if fault == "positions_in_bf16":
+        assert _rounds_alike(got[:, :256], want[:, :256])
+
+
+@pytest.mark.parametrize("p, chunk, pad", [(256, 128, 0), (256, 128, 37), (200, 64, 11)])
+def test_the_scan_kernels_output_norm_is_linear_out(p, chunk, pad):
+    """Interpreted, bf16 inputs: the kernel with the norm's scale, cast as
+    `_prefill_linear` casts it, against `_linear_out` of its own un-normed
+    output (the present program) and of `linear_scan`'s (the definition), by
+    this section's rule; the state is the same kernel's."""
+    q, k, v = _qkv(2, p, 2, 128, seed=p + pad, dtype=jnp.bfloat16)
+    k = jnp.where((jnp.arange(p) >= pad)[None, :, None, None], k, jnp.zeros_like(k))
+    slopes = jnp.asarray([0.84, 0.0039], jnp.float32)
+    rng = np.random.default_rng(pad)
+    block = {"o_norm": jnp.asarray(1.0 + 0.1 * rng.standard_normal(128), jnp.bfloat16)}
+    cfg = FAMILY.program_config(KERNEL_KEYS, jnp.bfloat16)
+    assert cfg.linear_out_norm
+    got, state = L.linear_prefill_attention(
+        q, k, v, slopes, chunk, block["o_norm"], interpret=True
+    )
+    # float32 out of the kernel: the cast is `_prefill_linear`'s, as it is
+    # `_linear_out`'s, so that XLA makes it where it makes that one
+    assert got.dtype == jnp.float32 and got.shape == (2, p, 2, 128)
+    got = got.astype(jnp.bfloat16)
+    plain, plain_state = L.linear_prefill_attention(q, k, v, slopes, chunk, interpret=True)
+    assert plain.dtype == jnp.float32
+    assert jnp.array_equal(state, plain_state)
+    live = np.arange(p) >= pad  # a pad row's output is its query's alone
+    for out32 in (plain, T.linear_scan(q, k, v, slopes, chunk)[0]):
+        want = T._linear_out(out32, block, cfg).reshape(got.shape)
+        assert _rounds_alike(got[:, live], want[:, live])
+
+
+def test_a_prefill_and_steps_through_the_rowwise_pass_serve_the_plain_paths_logits(
+    monkeypatch,
+):
+    """`prefill_into_slot`'s core and its scatter and the steps behind them
+    with the rule saying kernel (interpreted): q and k through
+    `rowwise_heads` in all four layers, the output norm in the scan kernel,
+    against the plain path's logits (`_rmsnorm`, `_rope`, `_linear_out`), in
+    float32, where both are the same function."""
+    cfg = FAMILY.program_config(
+        {**KERNEL_KEYS, "sparse_config": {**KEYS["sparse_config"], "dense_len": 64}},
+        jnp.float32,
+    )
+    assert not T.rowwise_uses_kernel(cfg, 128)  # this process runs on the CPU
+    want, want_toks = _served_logits(cfg, _prompt(100), 128)
+    _interpret_the_prefill_kernels(monkeypatch)
+    calls = []
+    rowwise = R.rowwise_heads
+    monkeypatch.setattr(
+        R, "rowwise_heads", lambda *a, **kw: calls.append(kw) or rowwise(*a, **kw)
+    )
+    assert T.rowwise_uses_kernel(cfg, 128)
+    got, toks = _served_logits(cfg, _prompt(100), 128)
+    # q and k of four layers, in each of the helper's two traces of a prefill
+    assert len(calls) == 2 * 2 * 4
+    assert toks == want_toks
+    assert np.abs(got - want).max() < 1e-4

@@ -100,8 +100,10 @@ def test_a_prefill_the_rule_sends_to_the_kernel_holds_it_once_a_kind(
     the program holds one lowering of it a kind, not one a layer."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     text = _prefill_lowered(1280, chip)
-    assert text.count("tpu_custom_call") == 2
-    assert "prefill_attention" in text
+    # and the window layers' rotary positions are ops/rowwise.py's pass,
+    # one lowering for q (4 heads) and one for k (2)
+    assert text.count("tpu_custom_call") == 2 + 2
+    assert "prefill_attention" in text and "rowwise_heads" in text
 
 
 def test_a_width_under_128_takes_the_plain_attention(chip, monkeypatch):
@@ -308,6 +310,68 @@ def test_the_scan_kernel_compiles_for_v5e(b, p, heads, dtype, chip):
     assert 'custom_call_target="tpu_custom_call"' in text
 
 
+def test_the_scan_kernel_with_the_output_norm_compiles_for_v5e(chip):
+    """The third cell's linear layer with `_linear_out`'s norm in the
+    kernel's epilogue: the normed output is the kernel's own, in float32."""
+    from pathway_tpu.ops.linear_attention import linear_prefill_attention
+
+    def arg(*shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=chip)
+
+    def as_prefill_linear_calls_it(q, k, v, slopes, scale):
+        out, state = linear_prefill_attention(q, k, v, slopes, 256, scale)
+        return out.astype(jnp.bfloat16).reshape(1, 24576, 4096), state
+
+    text = jax.jit(as_prefill_linear_calls_it).lower(
+        arg(1, 24576, 32, 128), arg(1, 24576, 32, 128), arg(1, 24576, 32, 128),
+        arg(32, dt=jnp.float32), arg(128),
+    ).compile().as_text()
+    assert re.search(r"%linear_prefill_attention\S* = \(f32\[1,24576,4096\]", text)
+    # and nothing else computes a float32 array of that size: no reduction,
+    # no scaling, no relayout behind the kernel
+    others = [
+        op for dtype, dims, op in _RESULT.findall(text)
+        if dtype == "f32"
+        and functools.reduce(int.__mul__, map(int, dims.split(","))) >= 24576 * 4096
+        and op not in ("parameter", "get-tuple-element", "bitcast")
+    ]
+    assert others == []
+
+
+# ops/rowwise.py: q and k of the third cell's linear layers (32 heads each
+# out of a product of 96, norm, rotary, the pads' zero) and of its sparse
+# layer (32 and 2 of 36, norm alone), of the second cell's window layers (28
+# and 4 of 36, rotary alone), a rung under one tile of rows, and float32
+@pytest.mark.parametrize("b, p, lanes, first, heads, norm, rotary, zero, dtype", [
+    (1, 24576, 96, 0, 32, True, True, False, jnp.bfloat16),
+    (1, 24576, 96, 32, 32, True, True, True, jnp.bfloat16),
+    (1, 24576, 36, 0, 32, True, False, False, jnp.bfloat16),
+    (1, 24576, 36, 32, 2, True, False, False, jnp.bfloat16),
+    (1, 10240, 36, 0, 28, False, True, False, jnp.bfloat16),
+    (1, 10240, 36, 28, 4, False, True, False, jnp.bfloat16),
+    (1, 128, 36, 28, 4, False, True, False, jnp.bfloat16),
+    (2, 640, 12, 4, 4, True, True, True, jnp.float32),
+])
+def test_the_rowwise_pass_compiles_for_v5e(
+    b, p, lanes, first, heads, norm, rotary, zero, dtype, chip
+):
+    from pathway_tpu.ops.rowwise import rowwise_heads
+
+    def arg(*shape, dt=dtype):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=chip)
+
+    table = arg(b, p, 128, dt=jnp.float32)
+    text = jax.jit(
+        functools.partial(rowwise_heads, first=first, heads=heads, dh=128)
+    ).lower(
+        arg(b, p, lanes * 128), arg(128) if norm else None,
+        (table, table) if rotary else None, arg(b, p, dt=jnp.bool_) if zero else None,
+    ).compile().as_text()
+    # the name a device trace shows (`rowwise_heads[tpu_custom_call]`)
+    assert "%rowwise_heads" in text
+    assert 'custom_call_target="tpu_custom_call"' in text
+
+
 @pytest.mark.parametrize("b, p, heads, kv_heads, block, dtype", [
     (1, 24576, 32, 2, 64, jnp.bfloat16),
     (1, 32736, 32, 2, 64, jnp.bfloat16),
@@ -416,6 +480,86 @@ def test_the_third_cells_prefill_holds_its_three_kernels(chip, monkeypatch):
     long = lowered(10240)
     assert calls(long, "linear_prefill_attention") and calls(long, "sparse_prefill_attention")
     assert not calls(long, "prefill_attention")
+    # q and k of every layer through ops/rowwise.py's pass: four lowerings
+    # (a sparse layer's q and k, normed; a linear layer's, rotated too and
+    # k's pads zeroed), and the cosines and sines made once
+    assert T.rowwise_uses_kernel(cfg, 10240)
+    assert long.count("tpu_custom_call") == 1 + 1 + 4
+    assert len(re.findall(r"stablehlo\.cosine", long)) == 1
     short = lowered(1024)
     assert calls(short, "linear_prefill_attention") and calls(short, "prefill_attention")
     assert not calls(short, "sparse_prefill_attention")
+
+
+# ------------------------------------------------ who takes the rowwise pass
+
+
+def _two_programs(cfg, width, chip) -> tuple[str, str]:
+    """`prefill_into_slot` at `width` and `decode_step_slots` of two slots,
+    lowered for the chip."""
+    params = _shaped(
+        chip, lambda: T.init_params(jax.random.PRNGKey(0), cfg, jnp.bfloat16)
+    )
+    cache = _shaped(chip, lambda: T.init_kv_cache(cfg, 2))
+    ids = jax.ShapeDtypeStruct((1, width), jnp.int32, sharding=chip)
+    slot = jax.ShapeDtypeStruct((), jnp.int32, sharding=chip)
+    vec = jax.ShapeDtypeStruct((2,), jnp.int32, sharding=chip)
+    prefill = jax.jit(
+        functools.partial(T.prefill_into_slot, cfg=cfg), donate_argnums=(3,)
+    ).lower(params, ids, ids, cache, slot).as_text()
+    step = jax.jit(
+        functools.partial(T.decode_step_slots, cfg=cfg), donate_argnums=(1,)
+    ).lower(params, cache, vec, vec, vec).as_text()
+    return prefill, step
+
+
+def test_the_first_configurations_programs_do_not_change(chip, monkeypatch):
+    """A decoder of the first cell's kind (learned positions, no q/k norm,
+    softmax layers, heads of 128) on a TPU: `rowwise_uses_kernel` does not
+    hold, so `kernel_rowwise_prefills` stays 0, and its two programs lower
+    to the same text as where the rule cannot be asked at all."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = lm_config(
+        vocab_size=512, d_model=256, n_heads=2, n_layers=2, d_ff=512,
+        max_len=2048, dtype=jnp.bfloat16,
+    )
+    assert T.prefill_uses_kernel(cfg, 1280) and not T.rowwise_uses_kernel(cfg, 1280)
+    with_the_rule = _two_programs(cfg, 1280, chip)
+    assert "rowwise_heads" not in "".join(with_the_rule)
+
+    def never(cfg, spec):
+        raise AssertionError("a layer was asked whether it takes the pass")
+
+    monkeypatch.setattr(T, "_takes_rowwise", never)
+    monkeypatch.setattr(T, "rowwise_uses_kernel", lambda cfg, width: False)
+    assert _two_programs(cfg, 1280, chip) == with_the_rule
+
+
+def test_a_rotary_decoders_prefill_takes_the_pass_and_its_step_does_not(
+    chip, monkeypatch
+):
+    """A decoder of the second cell's kind (no q/k norm, rotary positions in
+    its window layers, 28 query heads over 4): the rule holds at a prefill's
+    width, which `kernel_rowwise_prefills` counts 1 for; the step keeps
+    `_rope`; on a CPU and with `fused_attention` off (a pool that spans a
+    mesh) nothing takes it."""
+    cfg = lm_config(dtype=jnp.bfloat16, **CELLS["rag-smallthinker-21b-a3b"])
+    assert not T.rowwise_uses_kernel(cfg, 10240)  # this process runs on the CPU
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert int(T.rowwise_uses_kernel(cfg, 10240)) == 1
+    assert not T.rowwise_uses_kernel(cfg, 64)
+    import dataclasses
+
+    assert not T.rowwise_uses_kernel(
+        dataclasses.replace(cfg, fused_attention=False), 10240
+    )
+    small = lm_config(
+        vocab_size=512, d_model=256, n_heads=4, n_kv_heads=2, head_size=128,
+        n_layers=2, d_ff=512, max_len=2048, dtype=jnp.bfloat16,
+        layers=(LayerSpec(pos="none"), LayerSpec(window=512, pos="rotary")),
+    )
+    prefill, step = _two_programs(small, 1280, chip)
+    assert "rowwise_heads" in prefill and "rowwise_heads" not in step
+    # the rotary layer's rotation is the pass's: one cosine, the tables'
+    assert len(re.findall(r"stablehlo\.cosine", prefill)) == 1
+    assert len(re.findall(r"stablehlo\.cosine", step)) >= 1
