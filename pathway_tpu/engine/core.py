@@ -15,6 +15,7 @@ the general multiset form appears inside arrangements keyed by derived
 from __future__ import annotations
 
 from collections import defaultdict
+from concurrent.futures import ThreadPoolExecutor
 from typing import Any, Callable, Iterable, Sequence
 
 import numpy as np
@@ -5710,6 +5711,13 @@ class GradualBroadcastNode(_TokTailNode):
         self.emit(time, consolidate(out))
 
 
+# The one thread that runs deferred index waves, in the order they were
+# submitted (`ExternalIndexNode.finish_time`); it starts with the first.
+_INDEX_WORKER = ThreadPoolExecutor(
+    max_workers=1, thread_name_prefix="pw-engine-index"
+)
+
+
 class ExternalIndexNode(Node):
     """Feed index-table diffs into a mutable host/device index; answer query
     rows with top-k matches, optionally augmented with data-table columns.
@@ -5765,6 +5773,10 @@ class ExternalIndexNode(Node):
         # raw matches memo: qkey -> [(doc_key, score)] — lets data-only waves
         # re-pack rows without re-running the search
         self.matches: dict[Key, list] = {}
+        # time -> Future[entries to emit] of the waves deferred to the
+        # index worker; never persisted (no checkpoint is cut while a
+        # scheduler holds a deferred wave)
+        self._inflight: dict[float, Any] = {}
 
     def index_tiers(self) -> list:
         """Tiered ANN indexes behind this node (verifier contract
@@ -5846,11 +5858,46 @@ class ExternalIndexNode(Node):
         return out
 
     def finish_time(self, time: int) -> None:
+        held = self._inflight.pop(time, None)
+        if held is not None:
+            # completion pass: the deferred wave's search has its matches
+            # (the scheduler re-fires a held time only once it is done)
+            self.emit(time, held.result())
+            return
         idx_batch = self.take_input(0)
         q_batch = self.take_input(1)
         d_batch = self.take_input(2) if len(self.inputs) > 2 else []
         if not idx_batch and not q_batch and not d_batch:
             return
+        sched = self.graph.scheduler
+        if (
+            sched is not None
+            and getattr(sched, "allow_async", False)
+            # a wave behind one in flight goes the same way: the index and
+            # this node's state are touched by one wave at a time, in order
+            and (q_batch or self._inflight)
+        ):
+            self._inflight[time] = _INDEX_WORKER.submit(
+                self._apply, idx_batch, q_batch, d_batch
+            )
+            sched.hold_async(self, time, lambda t=time: self._hold_done(t))
+            return
+        self.emit(time, self._apply(idx_batch, q_batch, d_batch))
+
+    def _hold_done(self, time: float) -> bool:
+        """A deferred wave releases when its search is done and it is the
+        earliest in flight: emissions stay in time order."""
+        held = self._inflight.get(time)
+        if held is None:
+            return True
+        return held.done() and min(self._inflight) >= time
+
+    def _apply(self, idx_batch: list, q_batch: list, d_batch: list) -> list:
+        """One wave: the index's changes, then the searches; returns what
+        the wave emits. Under a pump that defers (`allow_async`) a wave
+        with queries runs on the index worker's thread, one wave at a time
+        and in order, so the engine's thread does not stand in a search
+        whose device program waits behind the programs queued before it."""
         # Apply index mutations: removals before additions so a same-wave
         # (-old, +new) update nets to the new value, and a retraction only
         # evicts when it matches what is actually indexed (KeyedState-style
@@ -5945,4 +5992,4 @@ class ExternalIndexNode(Node):
                 self.emitted[qkey] = results
             for okey, orow in results:
                 out.append((okey, orow, 1))
-        self.emit(time, consolidate(out))
+        return consolidate(out)

@@ -289,10 +289,11 @@ def _cpu_clock_id(native_id: int) -> int:
 
 def thread_cpu() -> dict[str, float]:
     """CPU seconds of the process by thread role: `batcher` (`pw-cb-*`),
-    `engine` (`pw-engine`: the pump), `udf` (`pw-async-loop`), `edge`
-    (`pw-webserver`), `pool` (the device plane's and the workers' pools),
-    `tracer` (a profiler's own thread), `foreign` (every other live Python
-    thread: it can hold the interpreter) and `native`, the rest of
+    `engine` (`pw-engine`: the pump, and `pw-engine-index`: its index
+    worker), `udf` (`pw-async-loop`), `edge` (`pw-webserver`), `pool` (the
+    device plane's and the workers' pools), `tracer` (a profiler's own
+    thread), `foreign` (every other live Python thread: it can hold the
+    interpreter) and `native`, the rest of
     `time.process_time()`: the runtime's own threads, which hold no
     interpreter, and threads that have ended."""
     by = dict.fromkeys(CPU_ROLES, 0.0)

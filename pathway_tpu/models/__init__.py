@@ -9,6 +9,7 @@ decode path with a KV cache for on-TPU generation.
 """
 
 from pathway_tpu.models.transformer import (
+    LatentSpec,
     LayerSpec,
     TransformerConfig,
     TransformerLM,
@@ -18,6 +19,7 @@ from pathway_tpu.models.transformer import (
 )
 
 __all__ = [
+    "LatentSpec",
     "LayerSpec",
     "TransformerConfig",
     "TransformerLM",

@@ -26,12 +26,18 @@ Design notes (TPU-first):
   ReGLU experts, with key/value heads shared by groups of query heads; a
   layer's mixer is softmax attention, softmax attention over blocks of keys
   it chooses by a score over pooled keys (`sparse`), or a linear recurrence
-  with a decay a head and a state instead of rows of keys (`linear`). The
-  default list is the plain block above; see the decoding section.
+  with a decay a head and a state instead of rows of keys (`linear`), or
+  attention whose keys and values are products of one low-rank row a
+  position, which is all the cache keeps (`latent`). A routed expert branch
+  may start beside one layer's feed-forward and land beside the next's
+  (`shortcut`), and an expert layer may hold a share of the experts its
+  router chooses among. The default list is the plain block above; see the
+  decoding section.
 """
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 import math
@@ -58,8 +64,15 @@ class LayerSpec:
     ff: str = "gelu"
     # softmax (attention over the keys `window` allows) | sparse (over the
     # blocks of keys `SparseSpec` chooses for each query) | linear (no
-    # softmax: a decayed sum of k^T v, kept as a state)
+    # softmax: a decayed sum of k^T v, kept as a state) | latent (softmax
+    # attention whose keys and values are products of one low-rank row a
+    # position, `LatentSpec`, which is all the cache keeps)
     mixer: str = "softmax"
+    # a routed expert branch beside the layer's own feed-forward, over two
+    # layers: "start" computes it from this layer's normed rows (the ones
+    # its feed-forward reads) and hands it on, "land" adds what the last
+    # "start" handed on where its own feed-forward's output goes
+    shortcut: str | None = None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -84,6 +97,30 @@ class SparseSpec:
     @property
     def local_blocks(self) -> int:
         return self.window // self.block
+
+
+@dataclasses.dataclass(frozen=True)
+class LatentSpec:
+    """What a `latent` layer's attention is made of (multi-head latent
+    attention). The query of a head is `nope_dim` lanes without positions
+    and `rope_dim` rotary lanes, from a normed row of `q_rank`; a position
+    keeps one normed row of `kv_rank`, from which every head's `nope_dim`
+    key lanes and `v_dim` value lanes are products, and one rotary key of
+    `rope_dim` that all heads share. `q_scale` and `kv_scale` multiply the
+    two normed rows. Scores are over nope_dim + rope_dim lanes and scaled
+    by their root."""
+
+    q_rank: int
+    kv_rank: int
+    nope_dim: int
+    rope_dim: int
+    v_dim: int
+    q_scale: float = 1.0
+    kv_scale: float = 1.0
+
+    @property
+    def qk_dim(self) -> int:
+        return self.nope_dim + self.rope_dim
 
 
 @dataclasses.dataclass(frozen=True)
@@ -144,6 +181,28 @@ class TransformerConfig:
     embed_scale: float = 1.0
     residual_scale: float = 1.0
     logit_scale: float = 1.0
+    # What a `latent` layer reads, and what an expert layer may be beside
+    # the first kind (a softmax over the chosen logits of the layer's
+    # input, ReGLU experts, all of them here). router "all": the scores are
+    # a softmax over every output of the router, read from the normed rows
+    # the experts read; the n_active largest of score + `router_bias` (a
+    # float32 leaf) are chosen, and a chosen expert's weight is its score
+    # without the bias times router_scale, not renormalised. The router has
+    # n_experts + n_zero_experts outputs: an index past n_experts is an
+    # identity expert, whose output is its input. experts_held (first,
+    # count): the experts whose matrices are here, of the n_experts the
+    # router chooses among; a pair whose expert lies elsewhere adds nothing
+    # here. expert_act: relu | silu, the gate's activation. norm_eps: the
+    # epsilon of every RMS norm of the decoder.
+    latent: LatentSpec | None = None
+    router: str = "chosen"
+    router_bias: bool = False
+    router_scale: float = 1.0
+    n_zero_experts: int = 0
+    experts_held: tuple[int, int] | None = None
+    expert_act: str = "relu"
+    d_expert: int | None = None  # an expert's width where it is not d_ff
+    norm_eps: float = 1e-6
 
     @property
     def head_dim(self) -> int:
@@ -169,7 +228,12 @@ class TransformerConfig:
 
     @property
     def n_expert_layers(self) -> int:
-        return sum(sp.ff == "experts" for sp in self.layer_specs)
+        return sum(_has_experts(sp) for sp in self.layer_specs)
+
+    @property
+    def held(self) -> tuple[int, int]:
+        """(first, count) of the experts whose matrices are here."""
+        return self.experts_held or (0, self.n_experts)
 
     def n_mixer_layers(self, mixer: str) -> int:
         return sum(sp.mixer == mixer for sp in self.layer_specs)
@@ -188,6 +252,11 @@ class TransformerConfig:
             and self.tie_embeddings
             and not (self.qk_norm or self.out_gate)
             and self.embed_scale == self.residual_scale == self.logit_scale == 1.0
+            and self.latent is None
+            and self.router == "chosen" and not self.router_bias
+            and self.router_scale == 1.0 and not self.n_zero_experts
+            and self.experts_held is None and self.expert_act == "relu"
+            and self.d_expert is None and self.norm_eps == 1e-6
         )
 
     def __post_init__(self) -> None:
@@ -207,9 +276,15 @@ class TransformerConfig:
                 raise ValueError(f"pos must be learned|rotary|none, got {sp.pos!r}")
             if sp.ff not in ("gelu", "swiglu", "experts"):
                 raise ValueError(f"ff must be gelu|swiglu|experts, got {sp.ff!r}")
-            if sp.mixer not in ("softmax", "sparse", "linear"):
+            if sp.mixer not in ("softmax", "sparse", "linear", "latent"):
                 raise ValueError(
-                    f"mixer must be softmax|sparse|linear, got {sp.mixer!r}"
+                    f"mixer must be softmax|sparse|linear|latent, got {sp.mixer!r}"
+                )
+            if sp.shortcut not in (None, "start", "land"):
+                raise ValueError(f"shortcut must be start|land, got {sp.shortcut!r}")
+            if sp.shortcut is not None and sp.ff == "experts":
+                raise ValueError(
+                    "a shortcut's branch lies beside a dense feed-forward"
                 )
             if sp.mixer != "softmax" and sp.window is not None:
                 raise ValueError("a window is a softmax layer's")
@@ -218,8 +293,33 @@ class TransformerConfig:
         if len({sp.window for sp in specs if sp.window is not None}) > 1:
             # the window layers' rows are one stacked ring
             raise ValueError("the window layers of one decoder share one window")
-        if self.n_expert_layers and not 0 < self.n_active <= self.n_experts:
+        marks = [sp.shortcut for sp in specs if sp.shortcut is not None]
+        if marks != ["start", "land"] * (len(marks) // 2):
+            raise ValueError("every shortcut that starts lands before the next")
+        if self.n_expert_layers and not (
+            0 < self.n_active <= self.n_experts + self.n_zero_experts
+        ):
             raise ValueError("experts layers need 0 < n_active <= n_experts")
+        if self.router not in ("chosen", "all"):
+            raise ValueError(f"router must be chosen|all, got {self.router!r}")
+        if self.expert_act not in ("relu", "silu"):
+            raise ValueError(f"expert_act must be relu|silu, got {self.expert_act!r}")
+        if self.router == "chosen" and (
+            self.router_bias or self.router_scale != 1.0 or self.n_zero_experts
+        ):
+            raise ValueError(
+                "a selection bias, a scaling factor and identity experts are "
+                "router \"all\"'s"
+            )
+        first, count = self.held
+        if self.n_expert_layers and not (
+            0 <= first and 0 < count and first + count <= self.n_experts
+        ):
+            raise ValueError("experts_held (first, count) lies inside n_experts")
+        if self.n_mixer_layers("latent") and self.latent is None:
+            raise ValueError("latent layers need `latent` (a LatentSpec)")
+        if self.latent is not None and self.latent.rope_dim % 2:
+            raise ValueError("rotary lanes come in pairs")
         if self.n_mixer_layers("sparse"):
             sq = self.sparse
             if sq is None:
@@ -259,6 +359,12 @@ def lm_config(**kw) -> TransformerConfig:
 # ------------------------------------------------------------------ params
 
 
+def _has_experts(spec: LayerSpec) -> bool:
+    """Whether a layer holds a router and experts: as its feed-forward, or
+    as the branch a shortcut starts beside it."""
+    return spec.ff == "experts" or spec.shortcut == "start"
+
+
 def _mixer_heads(cfg: TransformerConfig, spec: LayerSpec) -> tuple[int, int]:
     """A layer's query heads and its key/value heads."""
     if spec.mixer == "linear":
@@ -280,12 +386,25 @@ def _init_block(
     def leaf(key: Array, shape: tuple, scale: float) -> Array:
         return (jax.random.normal(key, shape, jnp.float32) * scale).astype(dtype)
 
-    block = {
-        "qkv": leaf(ks[0], (d, hd + 2 * kv), s),
-        "o": leaf(ks[1], (hd, d), 1.0 / math.sqrt(hd)),
-        "ln1_scale": jnp.ones((d,), dtype),
-        "ln2_scale": jnp.ones((d,), dtype),
-    }
+    block = {"ln1_scale": jnp.ones((d,), dtype), "ln2_scale": jnp.ones((d,), dtype)}
+    if spec.mixer == "latent":
+        lt = cfg.latent
+        kl = jax.random.split(ks[0], 4)
+        block["q_a"] = leaf(kl[0], (d, lt.q_rank), s)
+        block["q_a_norm"] = jnp.ones((lt.q_rank,), dtype)
+        block["q_b"] = leaf(
+            kl[1], (lt.q_rank, h * lt.qk_dim), 1.0 / math.sqrt(lt.q_rank)
+        )
+        block["kv_a"] = leaf(kl[2], (d, lt.kv_rank + lt.rope_dim), s)
+        block["kv_a_norm"] = jnp.ones((lt.kv_rank,), dtype)
+        block["kv_b"] = leaf(
+            kl[3], (lt.kv_rank, h * (lt.nope_dim + lt.v_dim)),
+            1.0 / math.sqrt(lt.kv_rank),
+        )
+        block["o"] = leaf(ks[1], (h * lt.v_dim, d), 1.0 / math.sqrt(h * lt.v_dim))
+    else:
+        block["qkv"] = leaf(ks[0], (d, hd + 2 * kv), s)
+        block["o"] = leaf(ks[1], (hd, d), 1.0 / math.sqrt(hd))
     if cfg.qk_norm:
         block["q_norm"] = jnp.ones((cfg.head_dim,), dtype)
         block["k_norm"] = jnp.ones((cfg.head_dim,), dtype)
@@ -293,17 +412,25 @@ def _init_block(
         block["gate"] = leaf(jax.random.fold_in(ks[0], 1), (d, hd), s)
     if spec.mixer == "linear" and cfg.linear_out_norm:
         block["o_norm"] = jnp.ones((cfg.head_dim,), dtype)
-    if spec.ff == "experts":
-        e = cfg.n_experts
-        block["router"] = leaf(ks[4], (d, e), s)
-        block["expert_gate"] = leaf(ks[2], (e, d, f), s)
-        block["expert_up"] = leaf(ks[5], (e, d, f), s)
-        block["expert_down"] = leaf(ks[3], (e, f, d), 1.0 / math.sqrt(f))
-    elif spec.ff == "swiglu":
+    if _has_experts(spec):
+        # the matrices of the experts held here, [count, ...]; the router
+        # has an output for every expert there is, and the identity ones
+        e, fe = cfg.held[1], cfg.d_expert or f
+        n_out = cfg.n_experts + cfg.n_zero_experts
+        kg, ku, kd = ks[2], ks[5], ks[3]
+        if spec.shortcut == "start":  # the layer's own feed-forward draws from those
+            kg, ku, kd = (jax.random.fold_in(key, 1) for key in (kg, ku, kd))
+        block["router"] = leaf(ks[4], (d, n_out), s)
+        if cfg.router_bias:
+            block["router_bias"] = jnp.zeros((n_out,), jnp.float32)
+        block["expert_gate"] = leaf(kg, (e, d, fe), s)
+        block["expert_up"] = leaf(ku, (e, d, fe), s)
+        block["expert_down"] = leaf(kd, (e, fe, d), 1.0 / math.sqrt(fe))
+    if spec.ff == "swiglu":
         block["ff_gate"] = leaf(ks[2], (d, f), s)
         block["ff_up"] = leaf(ks[5], (d, f), s)
         block["ff_out"] = leaf(ks[3], (f, d), 1.0 / math.sqrt(f))
-    else:
+    elif spec.ff == "gelu":
         block["ff_in"] = leaf(ks[2], (d, f), s)
         block["ff_out"] = leaf(ks[3], (f, d), 1.0 / math.sqrt(f))
     return block
@@ -357,26 +484,30 @@ def param_specs(cfg: TransformerConfig) -> Params:
     experts layer is expert-parallel: the expert axis is the sharded one.
     """
     def block(spec: LayerSpec) -> Params:
-        out = {
-            "qkv": P(None, "model"),
-            "o": P("model", None),
-            "ln1_scale": P(None),
-            "ln2_scale": P(None),
-        }
+        out = {"o": P("model", None), "ln1_scale": P(None), "ln2_scale": P(None)}
+        if spec.mixer == "latent":
+            # the low-rank rows are whole on every chip; the heads are split
+            out["q_a"] = out["kv_a"] = P(None, None)
+            out["q_a_norm"] = out["kv_a_norm"] = P(None)
+            out["q_b"] = out["kv_b"] = P(None, "model")
+        else:
+            out["qkv"] = P(None, "model")
         if cfg.qk_norm:
             out["q_norm"] = out["k_norm"] = P(None)
         if cfg.out_gate:
             out["gate"] = P(None, "model")
         if spec.mixer == "linear" and cfg.linear_out_norm:
             out["o_norm"] = P(None)
-        if spec.ff == "experts":
+        if _has_experts(spec):
             out["router"] = P(None, None)
+            if cfg.router_bias:
+                out["router_bias"] = P(None)
             for name in ("expert_gate", "expert_up", "expert_down"):
                 out[name] = P("model", None, None)
-        elif spec.ff == "swiglu":
+        if spec.ff == "swiglu":
             out["ff_gate"] = out["ff_up"] = P(None, "model")
             out["ff_out"] = P("model", None)
-        else:
+        elif spec.ff == "gelu":
             out["ff_in"] = P(None, "model")
             out["ff_out"] = P("model", None)
         return out
@@ -426,11 +557,11 @@ def cast_params(params: Params, dtype: Any = jnp.bfloat16) -> Params:
 # operation and that changes nothing in the compiled program.
 
 
-def _rmsnorm(x: Array, scale: Array) -> Array:
+def _rmsnorm(x: Array, scale: Array, eps: float = 1e-6) -> Array:
     with jax.named_scope("norm"):
         x32 = x.astype(jnp.float32)
         var = jnp.mean(x32 * x32, axis=-1, keepdims=True)
-        return (x32 * jax.lax.rsqrt(var + 1e-6) * scale).astype(x.dtype)
+        return (x32 * jax.lax.rsqrt(var + eps) * scale).astype(x.dtype)
 
 
 def _attention(
@@ -700,10 +831,15 @@ def make_train_step(cfg: TransformerConfig, learning_rate: float = 1e-3):
 # leaf "state" is a float32 [heads, dh, dh] sum a slot, which a prefill's
 # chunked scan leaves after the last token (`linear_scan`;
 # ops/linear_attention.py where `linear_prefill_uses_kernel` holds) and a
-# step decays and adds to.
+# step decays and adds to. A `latent` layer keeps rows without a head axis,
+# "c_kv" and "k_rope", at physical positions: a prefill expands its own
+# rows' keys and values from them, a step attends them as they lie
+# (ops/latent_attention.py where `latent_prefill_uses_kernel` /
+# `latent_step_uses_kernel` hold; the section "the latent mixer" below).
 
 # what an experts decoder's two programs append to the tokens they return,
-# in this order (ContinuousBatcher adds them into its `stats`)
+# in this order (ContinuousBatcher adds them into its `stats`); the pairs
+# are those of the experts held here (`cfg.held`)
 PREFILL_COUNTERS = ("routed_pairs", "expert_load_max")
 STEP_COUNTERS = ("experts_touched", "moe_layers_run")
 # and what a decoder with sparse or linear layers appends behind those, to
@@ -712,10 +848,26 @@ STEP_COUNTERS = ("experts_touched", "moe_layers_run")
 # tokens a prefill's linear layers scanned, summed over those layers (a
 # step sends 0 there)
 MIXER_COUNTERS = ("sparse_blocks_read", "sparse_blocks_visible", "linear_tokens")
+# what a prefill appends behind those where the router chooses among more
+# than the experts held here (a share of them, or identity experts beside
+# them), summed over the real tokens and the expert layers: every pair the
+# router made (tokens x n_active), those that chose an identity expert, and
+# those whose expert lies on another chip. `routed_pairs` are then the
+# pairs computed here, and the three add up to `router_pairs`
+SHARE_COUNTERS = ("router_pairs", "zero_pairs", "absent_pairs")
+# and a step of a decoder with latent layers: the latent rows its occupied
+# slots attended, summed over those layers
+LATENT_COUNTERS = ("latent_rows_read",)
 
 
 def _has_mixers(cfg: TransformerConfig) -> bool:
-    return any(sp.mixer != "softmax" for sp in cfg.layer_specs)
+    return any(sp.mixer in ("sparse", "linear") for sp in cfg.layer_specs)
+
+
+def _has_shares(cfg: TransformerConfig) -> bool:
+    return bool(cfg.n_expert_layers) and (
+        cfg.experts_held is not None or cfg.n_zero_experts > 0
+    )
 
 
 def prefill_counters(cfg: TransformerConfig) -> tuple[str, ...]:
@@ -723,6 +875,7 @@ def prefill_counters(cfg: TransformerConfig) -> tuple[str, ...]:
     return (
         PREFILL_COUNTERS * bool(cfg.n_expert_layers)
         + MIXER_COUNTERS * _has_mixers(cfg)
+        + SHARE_COUNTERS * _has_shares(cfg)
     )
 
 
@@ -731,6 +884,7 @@ def step_counters(cfg: TransformerConfig) -> tuple[str, ...]:
     return (
         STEP_COUNTERS * bool(cfg.n_expert_layers)
         + MIXER_COUNTERS * _has_mixers(cfg)
+        + LATENT_COUNTERS * bool(cfg.n_mixer_layers("latent"))
     )
 
 
@@ -741,7 +895,12 @@ def step_counters(cfg: TransformerConfig) -> tuple[str, ...]:
 # positions; `k_sparse`/`v_sparse` hold a sparse layer's rows at LOGICAL
 # positions (the left pad taken off, so that a block of the selection is a
 # block of rows) with `k_pool`, the pooled keys, one every `stride`
-# positions; `state` is a linear layer's float32 sum, [heads, dh, dh].
+# positions; `state` is a linear layer's float32 sum, [heads, dh, dh];
+# `c_kv` [.., rows, kv_rank] and `k_rope` [.., rows, rope lanes] are a latent
+# layer's rows at physical positions, without a head axis: the normed (and
+# scaled) low-rank row every head's keys and values are products of, and
+# the one rotated key all heads share (`_rope_lanes`: its rope_dim lanes
+# in a whole lane tile, zeros behind them).
 _SLOT_AXIS = 1
 
 
@@ -756,6 +915,7 @@ _KIND_LEAVES = {
     "window": {"k": "k_win", "v": "v_win"},
     "sparse": {"k": "k_sparse", "v": "v_sparse", "pool": "k_pool"},
     "linear": {"state": "state"},
+    "latent": {"c": "c_kv", "rope": "k_rope"},
 }
 
 
@@ -783,6 +943,14 @@ def init_kv_cache(cfg: TransformerConfig, batch: int) -> Params:
     if n["linear"]:
         cache["state"] = jnp.zeros(
             (n["linear"], batch, cfg.lin_heads, dh, dh), jnp.float32
+        )
+    if n["latent"]:
+        lt = cfg.latent
+        cache["c_kv"] = jnp.zeros(
+            (n["latent"], batch, cfg.max_len, lt.kv_rank), cfg.dtype
+        )
+        cache["k_rope"] = jnp.zeros(
+            (n["latent"], batch, cfg.max_len, _rope_lanes(cfg)), cfg.dtype
         )
     return cache
 
@@ -851,10 +1019,11 @@ def _qkv_rowwise(xin: Array, block: Params, cfg: TransformerConfig,
 
 
 def _rope(x: Array, pos: Array, cfg: TransformerConfig) -> Array:
-    """Rotary positions, rotate-half over the head: x [b, s, heads, dh],
+    """Rotary positions, rotate-half over the head (x's last axis: a
+    latent layer's rotary lanes are a part of a head): x [b, s, heads, dh],
     pos [b, s] logical positions."""
     with jax.named_scope("rope"):
-        half = cfg.head_dim // 2
+        half = x.shape[-1] // 2
         freq = cfg.rope_theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
         ang = pos.astype(jnp.float32)[:, :, None, None] * freq
         cos, sin = jnp.cos(ang), jnp.sin(ang)
@@ -886,17 +1055,27 @@ def _attend(q: Array, keys: Array, vals: Array, ok: Array,
 
 
 def _route(x: Array, block: Params, cfg: TransformerConfig):
-    """The router, on the layer's input (before the attention's norm), in
-    float32: per token its n_active experts and their weights, the softmax
-    over the chosen logits."""
+    """The router, in float32: per token its n_active experts and their
+    weights. `cfg.router` "chosen": on the layer's input (before the
+    attention's norm), the weights the softmax over the chosen logits.
+    "all": on the normed rows the experts read, the scores a softmax over
+    every output; the largest of score + `router_bias` are chosen, and a
+    chosen expert's weight is its score (without the bias) times
+    `router_scale`, not renormalised."""
     with jax.named_scope("router"):
         logits = jnp.einsum(
             "bsd,de->bse", x.astype(jnp.float32),
             block["router"].astype(jnp.float32),
             precision=jax.lax.Precision.HIGHEST,
         )
-        top, idx = jax.lax.top_k(logits, cfg.n_active)
-        return idx, jax.nn.softmax(top, axis=-1)
+        if cfg.router == "chosen":
+            top, idx = jax.lax.top_k(logits, cfg.n_active)
+            return idx, jax.nn.softmax(top, axis=-1)
+        scores = jax.nn.softmax(logits, axis=-1)
+        by = scores + block["router_bias"] if cfg.router_bias else scores
+        _, idx = jax.lax.top_k(by, cfg.n_active)
+        w = jnp.take_along_axis(scores, idx, axis=-1)
+        return idx, (w * cfg.router_scale if cfg.router_scale != 1.0 else w)
 
 
 def _experts(u: Array, idx: Array, w: Array, live: Array, block: Params,
@@ -912,7 +1091,14 @@ def _experts(u: Array, idx: Array, w: Array, live: Array, block: Params,
     pair's weight in the down kernel) and the combine is its
     `combine_experts` (a token's rows fetched by index and summed);
     elsewhere three `ragged_dot` and the weighted sum. Products accumulate
-    in float32 and a token's pairs are summed in float32 on both."""
+    in float32 and a token's pairs are summed in float32 on both.
+
+    Where the router chooses among more than the experts held here
+    (`_has_shares`), `_experts_held`: the same products over the pairs of
+    the held experts alone, and what it counted beside them."""
+    if _has_shares(cfg):
+        return _experts_held(u, idx, w, live, block, cfg)
+    act = _EXPERT_ACTS[cfg.expert_act]
     with jax.named_scope("experts"):
         b, s, d = u.shape
         k, e = cfg.n_active, cfg.n_experts
@@ -946,6 +1132,7 @@ def _experts(u: Array, idx: Array, w: Array, live: Array, block: Params,
                 rows, by_expert_w, sizes,
                 *(block[name].astype(cfg.dtype)
                   for name in ("expert_gate", "expert_up", "expert_down")),
+                act=cfg.expert_act,
             )  # [pairs, d / 128, 128] float32, weighted, by expert
             y = combine_experts(y, back, cfg.dtype)
         else:
@@ -956,12 +1143,130 @@ def _experts(u: Array, idx: Array, w: Array, live: Array, block: Params,
                 )
 
             hidden = (
-                jax.nn.relu(grouped(rows, "expert_gate"))
-                * grouped(rows, "expert_up")
+                act(grouped(rows, "expert_gate")) * grouped(rows, "expert_up")
             ).astype(cfg.dtype)
             y = grouped(hidden, "expert_down")  # [pairs, d], by expert
             y = jnp.einsum("ktd,tk->td", y[back], w.reshape(-1, k))
         return y.astype(cfg.dtype).reshape(b, s, d), counts
+
+
+_EXPERT_ACTS = {"relu": jax.nn.relu, "silu": jax.nn.silu}
+# pairs a pass of `_experts_held` multiplies: more than an even router
+# sends to 16 of 768 outputs from a prompt of 10,240 tokens and 12 picks
+# (2,560), so that the first pass, which stands outside the loop, is as a
+# rule the only one; a step's few pairs all go through in it
+_HELD_CHUNK = 4096
+
+
+def _experts_held(u: Array, idx: Array, w: Array, live: Array, block: Params,
+                  cfg: TransformerConfig):
+    """This chip's share of a routed expert layer over normed rows u
+    [b, s, d]: the router chose among `n_experts` experts, of which the
+    matrices of `cfg.held` = (first, count) are here, and
+    `n_zero_experts` identity experts (an index past n_experts). A pair
+    whose expert is held is computed; an identity pick adds weight x u, with
+    no product; a pair whose expert lies on another chip adds nothing here
+    (that chip computes it), and nothing stands in for it.
+
+    The pairs are sorted by held expert, the others behind them, and only
+    the held ones are multiplied: in passes of `_HELD_CHUNK` pairs, one
+    always and then as many more as the held pairs fill (a loop whose
+    length is the router's, so no pair is dropped however uneven it is;
+    the first pass stands outside it, where a trace names its products by
+    the leaves they read). A pass is the grouped product of
+    `_experts`, by the kernels where `experts_use_kernel` holds of its
+    pairs; its rows, weighted, are added to their tokens in float32.
+
+    Returns the output and the counts [count + 3] int32 of live pairs: each
+    held expert's, then the router's pairs, the identity picks and the
+    pairs of absent experts."""
+    with jax.named_scope("experts"):
+        b, s, d = u.shape
+        k, (first, count) = cfg.n_active, cfg.held
+        t = b * s
+        flat = idx.reshape(-1)  # the pairs, token-major
+        real = flat < cfg.n_experts
+        here = real & (flat >= first) & (flat < first + count)
+        local = jnp.where(here, flat - first, count)  # count: not held here
+        _, order, by_expert_w = jax.lax.sort(
+            (local, jnp.arange(flat.size, dtype=jnp.int32), w.reshape(-1)),
+            num_keys=1, is_stable=True,
+        )
+        alive = jnp.repeat(live.reshape(-1), k)
+        hit = local[:, None] == jnp.arange(count, dtype=local.dtype)
+        sizes = jnp.sum(hit, axis=0, dtype=jnp.int32)
+        counts = jnp.sum(hit & alive[:, None], axis=0, dtype=jnp.int32)
+        n_held = jnp.sum(sizes)
+        edges = jnp.concatenate([jnp.zeros((1,), jnp.int32), jnp.cumsum(sizes)])
+        chunk = min(_HELD_CHUNK, t * k)
+        kernel = experts_use_kernel(cfg, chunk)
+        leaves = [
+            block[name].astype(cfg.dtype)
+            for name in ("expert_gate", "expert_up", "expert_down")
+        ]
+        act = _EXPERT_ACTS[cfg.expert_act]
+        flat_u = u.reshape(t, d)
+        # the sorted order, with a pass's room behind it: the last pass may
+        # hang over the pairs there are
+        order = jnp.pad(order, (0, chunk))
+        by_expert_w = jnp.pad(by_expert_w, (0, chunk))
+
+        def one(lo, acc):
+            at = jax.lax.dynamic_slice(order, (lo,), (chunk,))
+            token = at // k
+            held = lo + jnp.arange(chunk, dtype=jnp.int32) < n_held
+            wt = jnp.where(
+                held, jax.lax.dynamic_slice(by_expert_w, (lo,), (chunk,)), 0.0
+            )
+            rows = flat_u[token]
+            part = jnp.clip(edges[1:], lo, lo + chunk) - jnp.clip(
+                edges[:-1], lo, lo + chunk
+            )  # each held expert's rows of this pass
+            if kernel:
+                # imported where it is traced: Pallas loads when a program
+                # first needs it
+                from pathway_tpu.ops.experts import grouped_experts
+
+                # the kernels' groups fill their rows: what hangs over the
+                # held pairs goes to the last expert, at weight 0
+                part = part.at[-1].add(chunk - jnp.sum(part))
+                y = grouped_experts(
+                    rows, wt, part, *leaves, act=cfg.expert_act
+                ).reshape(chunk, d)  # float32, weighted
+            else:
+                def grouped(x: Array, leaf: Array) -> Array:
+                    return jax.lax.ragged_dot(
+                        x, leaf, part, preferred_element_type=jnp.float32
+                    )
+
+                hidden = (
+                    act(grouped(rows, leaves[0])) * grouped(rows, leaves[1])
+                ).astype(cfg.dtype)
+                y = grouped(hidden, leaves[2]) * wt[:, None]
+            # a token's pairs, summed in float32 where the token lies
+            return acc.at[jnp.where(held, token, t)].add(y, mode="drop")
+
+        y = one(jnp.zeros((), jnp.int32), jnp.zeros((t, d), jnp.float32))
+        if chunk < t * k:  # more pairs than a pass holds
+            _, y = jax.lax.while_loop(
+                lambda carry: carry[0] < n_held,
+                lambda carry: (carry[0] + chunk, one(*carry)),
+                (jnp.full((), chunk, jnp.int32), y),
+            )
+        if cfg.n_zero_experts:
+            with jax.named_scope("zero_experts"):
+                w_zero = jnp.sum(
+                    jnp.where(real.reshape(t, k), 0.0, w.reshape(t, k)), axis=1
+                )
+                y = y + w_zero[:, None] * flat_u.astype(jnp.float32)
+        tail = jnp.stack([
+            jnp.sum(alive, dtype=jnp.int32),
+            jnp.sum(~real & alive, dtype=jnp.int32),
+            jnp.sum(real & ~here & alive, dtype=jnp.int32),
+        ])
+        return (
+            y.astype(cfg.dtype).reshape(b, s, d), jnp.concatenate([counts, tail])
+        )
 
 
 def _lm_logits(hline: Array, params: Params, cfg: TransformerConfig) -> Array:
@@ -994,27 +1299,37 @@ def _branch(x: Array, y: Array, cfg: TransformerConfig) -> Array:
 
 
 def _layer(x, block, spec, cfg, pos, live, attend, counters, fused=False,
-           rope=None):
+           rope=None, branch=None):
     """One decoder layer over rows x [b, s, d]. `attend(q, k, v)` writes the
     layer's keys and values (or its state) where they belong and returns
-    the mixer's output; `pos` [b, s] are logical positions, `live` [b, s]
-    the rows that count (not padding, not a free slot). An experts layer
-    appends its per-expert counts of live pairs to `counters["experts"]`.
+    the mixer's output (a latent layer's `attend(xin)` makes its own
+    projections of the normed rows); `pos` [b, s] are logical positions,
+    `live` [b, s] the rows that count (not padding, not a free slot). An
+    experts layer appends its per-expert counts of live pairs to
+    `counters["experts"]`.
     `fused`: `rowwise_uses_kernel` of the program's width, which a prefill
-    asks once, and `rope` then its `rope_tables` if it has a rotary layer."""
-    if spec.ff == "experts":
+    asks once, and `rope` then its `rope_tables` if it has a rotary layer.
+    Returns the rows and the expert branch in flight: what a layer whose
+    `shortcut` is "start" computed from its normed rows, which the next
+    "land" adds beside its feed-forward (`branch` is what came in)."""
+    eps = cfg.norm_eps
+    if spec.ff == "experts" and cfg.router == "chosen":
         idx, w = _route(x, block, cfg)
-    xin = _rmsnorm(x, block["ln1_scale"])
-    with jax.named_scope("attn"):
-        if fused and _takes_rowwise(cfg, spec):
-            q, k, v = _qkv_rowwise(xin, block, cfg, spec, rope, live)
-        else:
-            q, k, v = _qkv(xin, block, cfg, spec)
-            if cfg.qk_norm:
-                q, k = _rmsnorm(q, block["q_norm"]), _rmsnorm(k, block["k_norm"])
-            if spec.pos == "rotary":
-                q, k = _rope(q, pos, cfg), _rope(k, pos, cfg)
-    ctx = attend(q, k, v)
+    xin = _rmsnorm(x, block["ln1_scale"], eps)
+    if spec.mixer == "latent":
+        ctx = attend(xin)
+    else:
+        with jax.named_scope("attn"):
+            if fused and _takes_rowwise(cfg, spec):
+                q, k, v = _qkv_rowwise(xin, block, cfg, spec, rope, live)
+            else:
+                q, k, v = _qkv(xin, block, cfg, spec)
+                if cfg.qk_norm:
+                    q = _rmsnorm(q, block["q_norm"], eps)
+                    k = _rmsnorm(k, block["k_norm"], eps)
+                if spec.pos == "rotary":
+                    q, k = _rope(q, pos, cfg), _rope(k, pos, cfg)
+        ctx = attend(q, k, v)
     with jax.named_scope("attn"):
         if cfg.out_gate:
             with jax.named_scope("gate"):
@@ -1027,19 +1342,35 @@ def _layer(x, block, spec, cfg, pos, live, attend, counters, fused=False,
             "bsd,de->bse", ctx, block["o"].astype(cfg.dtype),
             preferred_element_type=jnp.float32,
         ).astype(cfg.dtype), cfg)
-    u = _rmsnorm(x, block["ln2_scale"])
-    if spec.ff == "experts":
-        y, counts = _experts(u, idx, w, live, block, cfg)
+    u = _rmsnorm(x, block["ln2_scale"], eps)
+    if _has_experts(spec):
+        started = spec.shortcut == "start"  # the branch lands a layer on
+        with jax.named_scope("shortcut") if started else contextlib.nullcontext():
+            if cfg.router == "all":
+                idx, w = _route(u, block, cfg)
+            y, counts = _experts(u, idx, w, live, block, cfg)
+        if _has_shares(cfg):  # the held experts' counts, then the router's
+            counters["shares"].append(counts[cfg.held[1]:])
+            counts = counts[:cfg.held[1]]
         counters["experts"].append(counts)
-        return _branch(x, y, cfg)
-    return _branch(x, _ffn(u, block, cfg), cfg)
+        if not started:
+            return _branch(x, y, cfg), branch
+        branch = y
+    x = _branch(x, _ffn(u, block, cfg), cfg)
+    if spec.shortcut == "land":
+        with jax.named_scope("shortcut"):
+            x, branch = _branch(x, branch, cfg), None
+    return x, branch
 
 
 def _new_counters() -> dict[str, list]:
     """What a program's layers append to as they are traced: an experts
     layer its per-expert counts, a sparse or linear layer its share of each
     of MIXER_COUNTERS."""
-    return {"experts": [], **{name: [] for name in MIXER_COUNTERS}}
+    return {
+        "experts": [], "shares": [], "latent_rows_read": [],
+        **{name: [] for name in MIXER_COUNTERS},
+    }
 
 
 def _mixer_counts(counters: dict[str, list]) -> list:
@@ -1140,7 +1471,7 @@ def _linear_out(out: Array, block: Params, cfg: TransformerConfig) -> Array:
     """[b, s, heads, dh] float32 -> the layer's context [b, s, heads * dh]."""
     b, s, h, dh = out.shape
     if cfg.linear_out_norm:
-        out = _rmsnorm(out, block["o_norm"].astype(jnp.float32))
+        out = _rmsnorm(out, block["o_norm"].astype(jnp.float32), cfg.norm_eps)
     return out.astype(cfg.dtype).reshape(b, s, h * dh)
 
 
@@ -1257,10 +1588,16 @@ def _step_rows(
         rows, heads = jnp.arange(b)[:, None], jnp.arange(cfg.kv_heads)[None, :]
     live = (pos > 0)[:, None]  # a free slot's vectors are zeros
     counters = _new_counters()
+    branch = None  # a shortcut's expert branch, from its start to its landing
     for (names, li), spec, block in zip(
         _cache_rows(cfg), cfg.layer_specs, params["blocks"]
     ):
-        def attend(q, k, v, names=names, li=li, spec=spec, block=block):
+        def attend(q, k=None, v=None, names=names, li=li, spec=spec, block=block):
+            if spec.mixer == "latent":
+                return _step_latent(
+                    q, block, spec, cache, names, li, pos, pad_len, live, cfg,
+                    counters,
+                )
             if spec.mixer == "linear":
                 return _step_linear(q, k, v, cache, names, li, live, block, cfg)
             if spec.mixer == "sparse":
@@ -1291,8 +1628,10 @@ def _step_rows(
                     kmask if spec.window is None else wmask, cfg,
                 )
 
-        x = _layer(x, block, spec, cfg, logical, live, attend, counters)
-    hline = _rmsnorm(x, params["ln_f_scale"])
+        x, branch = _layer(
+            x, block, spec, cfg, logical, live, attend, counters, branch=branch
+        )
+    hline = _rmsnorm(x, params["ln_f_scale"], cfg.norm_eps)
     return _lm_logits(hline, params, cfg)[:, 0, :], cache, counters
 
 
@@ -1373,6 +1712,219 @@ def _step_sparse(q, k, v, cache, names, li, t, live, cfg, counters):
         return _attend(q, cache[kname][li], cache[vname][li], ok, cfg)
 
 
+# ------------------------------------------------- the latent mixer
+#
+# Multi-head latent attention (`LatentSpec`). Of normed rows h: the query's
+# low-rank row c_q = rms(h W_qa) x q_scale and each head's [nope | rope]
+# lanes c_q W_qb; [c | k_r] = h W_kva, c_kv = rms(c) x kv_scale, and each
+# head's [key nope lanes | value] = c_kv W_kvb; rotary on the rope lanes of
+# q and on k_r, which all heads share. A position keeps c_kv and the rotated
+# k_r and nothing else.
+#
+# A prefill expands its own rows' keys and values from c_kv and attends
+# them as heads of nope + rope lanes against values of v_dim. A step never
+# expands a cached row: with W_kvb,i = [W_uk,i | W_uv,i] a head's score
+# against row j is (q_n,i W_uk,i^T) . c_kv,j + q_r,i . k_r,j, and its
+# output (sum_j p_ij c_kv,j) W_uv,i: the same function, read from the
+# latent rows as they lie.
+
+# a prefill's scores [heads, queries, keys] are float32: 26.8 GB at 10,240
+# tokens and 64 heads. Where no kernel keeps them in VMEM the queries go
+# through in chunks whose scores stay under this
+_LATENT_SCORE_BYTES = 256 << 20
+
+
+def _latent_rows(xin: Array, block: Params, pos: Array, spec: LayerSpec,
+                 cfg: TransformerConfig):
+    """Of normed rows xin [b, s, d] at logical positions pos [b, s]: the
+    heads' queries (q_n [b, s, heads, nope], q_r [b, s, heads, rope], turned)
+    and what a position keeps (c_kv [b, s, kv_rank] normed and scaled, k_r
+    [b, s, rope] turned)."""
+    lt, eps = cfg.latent, cfg.norm_eps
+    b, s, _ = xin.shape
+
+    def product(x: Array, name: str) -> Array:
+        return jnp.einsum(
+            "bsd,de->bse", x, block[name].astype(cfg.dtype),
+            preferred_element_type=jnp.float32,
+        ).astype(cfg.dtype)
+
+    def gain(name: str, by: float) -> Array:
+        # the norm's scale and the rank's factor meet the row in one float32 pass
+        return block[name].astype(jnp.float32) * by
+
+    with jax.named_scope("q_down"):
+        c_q = _rmsnorm(product(xin, "q_a"), gain("q_a_norm", lt.q_scale), eps)
+    with jax.named_scope("q_up"):
+        q = product(c_q, "q_b").reshape(b, s, cfg.n_heads, lt.qk_dim)
+        q_n, q_r = q[..., :lt.nope_dim], q[..., lt.nope_dim:]
+    with jax.named_scope("kv_down"):
+        kv = product(xin, "kv_a")
+        c_kv = _rmsnorm(
+            kv[..., :lt.kv_rank], gain("kv_a_norm", lt.kv_scale), eps
+        )
+        k_r = kv[..., lt.kv_rank:]
+    if spec.pos == "rotary":
+        q_r = _rope(q_r, pos, cfg)
+        k_r = _rope(k_r[:, :, None, :], pos, cfg)[:, :, 0, :]
+    return q_n, q_r, c_kv, k_r
+
+
+def _rope_lanes(cfg: TransformerConfig) -> int:
+    """The width of the `k_rope` leaf: the rotary key's lanes rounded up to
+    a lane tile (64 -> 128). A tiled row of 64 lanes takes a tile's room in
+    the chip's memory anyway, and a leaf left 64 wide is laid out rows-minor
+    by the TPU's compiler: every row-major use of it (the step's kernel, a
+    row's write) then copies the whole leaf there and back, twice its size
+    a step (read in the compiled step, PR 43)."""
+    return -(-cfg.latent.rope_dim // 128) * 128
+
+
+def _in_rope_lanes(x: Array, cfg: TransformerConfig) -> Array:
+    """x [..., rope_dim] with zeros behind it up to the leaf's width."""
+    extra = _rope_lanes(cfg) - x.shape[-1]
+    return jnp.pad(x, ((0, 0),) * (x.ndim - 1) + ((0, extra),))
+
+
+def _kv_up(block: Params, cfg: TransformerConfig) -> Array:
+    """W_kvb as [kv_rank, heads, nope + v]: a head's W_uk beside its W_uv."""
+    lt = cfg.latent
+    return block["kv_b"].astype(cfg.dtype).reshape(
+        lt.kv_rank, cfg.n_heads, lt.nope_dim + lt.v_dim
+    )
+
+
+def _attend_latent(q: Array, k: Array, v: Array, ok: Array,
+                   cfg: TransformerConfig) -> Array:
+    """softmax(q k^T / sqrt(qk_dim)) v over the keys `ok` [b, 1, nq, s]
+    allows: q [b, nq, heads, qk_dim], k [b, s, heads, qk_dim], v [b, s,
+    heads, v_dim] -> [b, nq, heads * v_dim]."""
+    b, nq, h, _ = q.shape
+    scores = jnp.einsum(
+        "bqhd,bkhd->bhqk", q, k, preferred_element_type=jnp.float32
+    ) / math.sqrt(cfg.latent.qk_dim)
+    probs = jax.nn.softmax(jnp.where(ok, scores, -1e30), axis=-1).astype(cfg.dtype)
+    ctx = jnp.einsum("bhqk,bkhd->bqhd", probs, v, preferred_element_type=jnp.float32)
+    return ctx.astype(cfg.dtype).reshape(b, nq, h * v.shape[-1])
+
+
+def _prefill_latent(xin, block, spec, cache, names, li, valid, pos_idx, cfg):
+    """A latent layer over whole prompts [b, p]: every row's c_kv and k_r
+    into the layer's leaves at its physical position, and the prompt's own
+    keys and values expanded from c_kv for its attention."""
+    lt = cfg.latent
+    b, p, _ = xin.shape
+    h = cfg.n_heads
+    with jax.named_scope("attn"), jax.named_scope("attn_latent"):
+        q_n, q_r, c_kv, k_r = _latent_rows(xin, block, pos_idx, spec, cfg)
+        with jax.named_scope("cache_write"):
+            cache[names["c"]] = jax.lax.dynamic_update_slice(
+                cache[names["c"]], c_kv[None], (li, 0, 0, 0)
+            )
+            cache[names["rope"]] = jax.lax.dynamic_update_slice(
+                cache[names["rope"]], _in_rope_lanes(k_r, cfg)[None], (li, 0, 0, 0)
+            )
+        with jax.named_scope("kv_up"):
+            kv = jnp.einsum(
+                "bsr,rhe->bshe", c_kv, _kv_up(block, cfg),
+                preferred_element_type=jnp.float32,
+            ).astype(cfg.dtype)
+            k_n, v = kv[..., :lt.nope_dim], kv[..., lt.nope_dim:]
+        shared = jnp.broadcast_to(k_r[:, :, None, :], (b, p, h, lt.rope_dim))
+        if latent_prefill_uses_kernel(cfg, p):
+            # imported where it is traced: Pallas loads when a program
+            # first needs it
+            from pathway_tpu.ops.latent_attention import latent_prefill_attention
+
+            return latent_prefill_attention(q_n, q_r, k_n, shared, v, valid)
+        q = jnp.concatenate([q_n, q_r], axis=-1)
+        k = jnp.concatenate([k_n, shared], axis=-1)
+        chunk = p
+        while chunk > 128 and chunk % 2 == 0 and (
+            4 * b * h * chunk * p > _LATENT_SCORE_BYTES
+        ):
+            chunk //= 2
+        at = jnp.arange(p)
+        real = valid.astype(bool)[:, None, None, :]
+
+        def some(qa):  # a chunk of queries [b, chunk, heads, qk_dim], from row a
+            qc, a = qa
+            ok = real & (at[None, :] <= (a + at[:chunk])[:, None])[None, None]
+            return _attend_latent(qc, k, v, ok, cfg)
+
+        ctx = jax.lax.map(some, (
+            q.reshape(b, p // chunk, chunk, h, lt.qk_dim).transpose(1, 0, 2, 3, 4),
+            jnp.arange(0, p, chunk),
+        ))  # [chunks, b, chunk, heads * v_dim]
+        return ctx.transpose(1, 0, 2, 3).reshape(b, p, h * lt.v_dim)
+
+
+def _step_latent(xin, block, spec, cache, names, li, pos, pad_len, live, cfg,
+                 counters):
+    """A latent layer's step, every row at its physical position pos [b]
+    behind its pad: its c_kv and k_r go into row pos of its slot, and its
+    heads attend the slot's latent rows pad_len .. pos in the absorbed
+    form, no row of the cache expanded."""
+    lt = cfg.latent
+    b = xin.shape[0]
+    cname, rname = names["c"], names["rope"]
+    with jax.named_scope("attn"), jax.named_scope("attn_latent"):
+        q_n, q_r, c_kv, k_r = _latent_rows(
+            xin, block, (pos - pad_len)[:, None], spec, cfg
+        )
+        # the rotary lanes as the leaf holds them: zeros behind both
+        q_r, k_r = _in_rope_lanes(q_r[:, 0], cfg), _in_rope_lanes(k_r, cfg)
+        with jax.named_scope("cache_write"):
+            # a row a slot into the stacked leaves themselves, where they
+            # lie (a scatter of all slots' rows is the compiler's to place)
+            for slot in range(b):
+                at = (li, slot, pos[slot], 0)
+                cache[cname] = jax.lax.dynamic_update_slice(
+                    cache[cname], c_kv[slot][None, None], at
+                )
+                cache[rname] = jax.lax.dynamic_update_slice(
+                    cache[rname], k_r[slot][None, None], at
+                )
+        w_kv = _kv_up(block, cfg)
+        with jax.named_scope("absorb"):
+            q_c = jnp.einsum(
+                "bhn,rhn->bhr", q_n[:, 0], w_kv[..., :lt.nope_dim],
+                preferred_element_type=jnp.float32,
+            ).astype(cfg.dtype)
+        counters["latent_rows_read"].append(
+            jnp.sum(jnp.where(live[:, 0], pos - pad_len + 1, 0), dtype=jnp.int32)
+        )
+        if latent_step_uses_kernel(cfg):
+            from pathway_tpu.ops.latent_attention import latent_decode_attention
+
+            z = latent_decode_attention(
+                q_c, q_r, cache[cname], cache[rname], li, pos, pad_len,
+                scale=1.0 / math.sqrt(lt.qk_dim),
+            )
+        else:
+            rows_c, rows_r = cache[cname][li], cache[rname][li]
+            scores = (
+                jnp.einsum("bhr,bjr->bhj", q_c, rows_c,
+                           preferred_element_type=jnp.float32)
+                + jnp.einsum("bhe,bje->bhj", q_r, rows_r,
+                             preferred_element_type=jnp.float32)
+            ) / math.sqrt(lt.qk_dim)
+            at = jnp.arange(cfg.max_len)[None, :]
+            ok = ((at <= pos[:, None]) & (at >= pad_len[:, None]))[:, None, :]
+            probs = jax.nn.softmax(
+                jnp.where(ok, scores, -1e30), axis=-1
+            ).astype(cfg.dtype)
+            z = jnp.einsum(
+                "bhj,bjr->bhr", probs, rows_c, preferred_element_type=jnp.float32
+            ).astype(cfg.dtype)
+        with jax.named_scope("absorb"):
+            ctx = jnp.einsum(
+                "bhr,rhv->bhv", z, w_kv[..., lt.nope_dim:],
+                preferred_element_type=jnp.float32,
+            ).astype(cfg.dtype)
+        return ctx.reshape(b, 1, cfg.n_heads * lt.v_dim)
+
+
 def decode_step(
     params: Params,
     cache: Params,
@@ -1400,7 +1952,12 @@ def step_uses_kernel(cfg: TransformerConfig) -> bool:
     `decode_attention` (the stacked cache leaf read in place, only the
     tiles that hold a live row fetched) and not the plain `_attend` over
     every row the cache has room for: on a TPU, with heads of a multiple
-    of 128 lanes. Read from the shapes and from where the process runs,
+    of 128 lanes (a head's rows are the leaf's (rows, head) tiles, and a
+    lane tile is 128 wide: heads of 64 or 96 would be half-empty tiles).
+    The width asked is `cfg.head_dim`, that of a softmax or sparse layer's
+    heads, whose q, k and v are one width; a latent layer's rows have no
+    head axis and two widths, and `latent_step_uses_kernel` asks for them.
+    Read from the shapes and from where the process runs,
     as `prefill_uses_kernel`; nothing sets it, and `fused_attention` off
     keeps tensor-parallel parameters and a slot axis sharded over a mesh
     (the kernel has no partitioning rule) on `_attend`."""
@@ -1416,7 +1973,9 @@ def prefill_uses_kernel(cfg: TransformerConfig, width: int) -> bool:
     `prefill_attention` (scores kept in VMEM) and not the plain `_attend`:
     on a TPU, with heads of a multiple of 128 lanes and a width of 128 at
     least (every rung of `BucketPolicy.seq_bucket` from there up; the
-    kernel pads the cap's rung inside). Read from the shapes and from
+    kernel pads the cap's rung inside). The heads are `cfg.head_dim` wide,
+    q, k and v alike; a latent layer's are `qk_dim` against `v_dim`, and
+    `latent_prefill_uses_kernel` is their rule. Read from the shapes and from
     where the process runs; nothing sets it. Like the encoder's kernel it
     has no partitioning rule: `fused_attention` off (`TransformerLM.shard`)
     keeps tensor-parallel parameters on `_attend`."""
@@ -1447,7 +2006,49 @@ def rowwise_uses_kernel(cfg: TransformerConfig, width: int) -> bool:
     runs; nothing sets it. A step (one row a slot) never does."""
     return (
         cfg.qk_norm or any(sp.pos == "rotary" for sp in cfg.layer_specs)
-    ) and prefill_uses_kernel(cfg, width)
+    ) and prefill_uses_kernel(cfg, width) and (
+        # the pass has `_rmsnorm`'s default epsilon written in
+        not cfg.qk_norm or cfg.norm_eps == 1e-6
+    )
+
+
+def latent_prefill_uses_kernel(cfg: TransformerConfig, width: int) -> bool:
+    """Whether the latent layers of a prefill `width` wide run
+    ops/latent_attention.py `latent_prefill_attention` (the tile body of
+    `prefill_attention`, scores kept in VMEM) and not `_attend_latent` over
+    chunks of queries: on a TPU, for a decoder that has such layers, at a
+    width of 128 at least, with values of a multiple of 128 lanes. The
+    query/key width need not be one: the kernel carries nope + rope lanes
+    (192) padded with zeros to the next lane tile (256), which adds nothing
+    to a score. Read from the shapes and from where the process runs;
+    nothing sets it, and `fused_attention` off keeps tensor-parallel
+    parameters on the plain path."""
+    return (
+        bool(cfg.n_mixer_layers("latent"))
+        and cfg.fused_attention
+        and jax.default_backend() == "tpu"
+        and cfg.latent.v_dim % 128 == 0
+        and width >= 128
+    )
+
+
+def latent_step_uses_kernel(cfg: TransformerConfig) -> bool:
+    """Whether a step's latent layers run ops/latent_attention.py
+    `latent_decode_attention`, which fetches only the tiles of `c_kv` and
+    `k_rope` that hold a live row of the slot, and not products over every
+    row the cache has room for: on a TPU, for a decoder with such layers,
+    with a latent row of a multiple of 128 lanes and rows that are whole
+    tiles of `latent_decode_tile` (`k_rope` is a lane tile wide:
+    `_rope_lanes`). Read from the shapes and from where the process runs;
+    nothing sets it."""
+    if not (
+        cfg.n_mixer_layers("latent") and cfg.fused_attention
+        and jax.default_backend() == "tpu" and cfg.latent.kv_rank % 128 == 0
+    ):
+        return False
+    from pathway_tpu.ops.latent_attention import latent_decode_tile
+
+    return cfg.max_len % latent_decode_tile(cfg.max_len) == 0
 
 
 def _takes_rowwise(cfg: TransformerConfig, spec: LayerSpec) -> bool:
@@ -1496,34 +2097,48 @@ _EXPERT_KERNEL_PAIRS = 128
 # the combine kernel's row indices, one int32 a pair, ride in the chip's
 # scalar memory (1 MiB on a v5e; the compiler refuses 65,536 x 6)
 _EXPERT_KERNEL_MAX_PAIRS = 196_608
+# a visit holds one expert's gate and up matrices whole, double-buffered,
+# in the chip's fast memory (128 MiB on a v5e, of which the kernels ask
+# 100): 15.7 MB at widths of 2,560 x 768, 100.7 MB at 6,144 x 2,048, which
+# the compiler refuses. Until the kernels tile an expert's width, experts
+# that large stay on `ragged_dot`
+_EXPERT_KERNEL_MATRIX_BYTES = 64 << 20
 
 
 def experts_use_kernel(cfg: TransformerConfig, pairs: int) -> bool:
-    """Whether an experts layer over `pairs` token-expert pairs runs
-    ops/experts.py `grouped_experts` and `combine_experts` and not three
-    `ragged_dot` and a weighted sum: on a TPU, with model and expert widths
-    of a multiple of 128 lanes, `_EXPERT_KERNEL_PAIRS` pairs an expert at
-    least, which a prefill has and a decode step has not, and no more than
-    `_EXPERT_KERNEL_MAX_PAIRS` in all. Read from the shapes and from where
-    the process runs, as `prefill_uses_kernel`; nothing sets it, and
-    `fused_attention` off keeps tensor-parallel parameters and a pool that
-    spans a mesh on `ragged_dot` (the kernels have no partitioning rule)."""
+    """Whether a grouped product over `pairs` token-expert pairs of the
+    experts held here runs ops/experts.py `grouped_experts` (and, where
+    every expert is held, `combine_experts`) and not three `ragged_dot` and
+    a weighted sum: on a TPU, with model and expert widths of a multiple of
+    128 lanes, `_EXPERT_KERNEL_PAIRS` pairs an expert HELD at least
+    (`cfg.held`: all of them, or this chip's share), which a prefill has
+    and a decode step has not, no more than `_EXPERT_KERNEL_MAX_PAIRS`
+    in all, and an expert's gate and up matrices that fit the chip's fast
+    memory twice over (`_EXPERT_KERNEL_MATRIX_BYTES`). Read from the shapes and from where the process runs, as
+    `prefill_uses_kernel`; nothing sets it, and `fused_attention` off keeps
+    tensor-parallel parameters and a pool that spans a mesh on `ragged_dot`
+    (the kernels have no partitioning rule)."""
     return (
         cfg.fused_attention
         and jax.default_backend() == "tpu"
         and cfg.d_model % 128 == 0
-        and cfg.d_ff % 128 == 0
-        and _EXPERT_KERNEL_PAIRS * cfg.n_experts <= pairs <= _EXPERT_KERNEL_MAX_PAIRS
+        and (cfg.d_expert or cfg.d_ff) % 128 == 0
+        and _EXPERT_KERNEL_PAIRS * cfg.held[1] <= pairs <= _EXPERT_KERNEL_MAX_PAIRS
+        and 4 * cfg.d_model * (cfg.d_expert or cfg.d_ff)
+        * jnp.dtype(cfg.dtype).itemsize <= _EXPERT_KERNEL_MATRIX_BYTES
     )
 
 
 def prefill_experts_use_kernel(cfg: TransformerConfig, width: int) -> bool:
     """Whether the experts layers of a prefill of prompts `width` wide, one
-    row, run the kernel: `experts_use_kernel` of its pairs, for a decoder
-    that has such layers."""
-    return any(sp.ff == "experts" for sp in cfg.layer_specs) and (
-        experts_use_kernel(cfg, width * cfg.n_active)
-    )
+    row, run the kernel: `experts_use_kernel` of the pairs one grouped
+    product sees, for a decoder that has such layers. Where every expert is
+    held that is all the prefill's pairs; where a share is held
+    (`_experts_held`) a pass of `_HELD_CHUNK` pairs of the held experts."""
+    pairs = width * cfg.n_active
+    if _has_shares(cfg):
+        pairs = min(_HELD_CHUNK, pairs)
+    return bool(cfg.n_expert_layers) and experts_use_kernel(cfg, pairs)
 
 
 def _prefill(
@@ -1560,10 +2175,15 @@ def _prefill(
         with jax.named_scope("rope"):  # once, for every rotary layer
             rope = rope_tables(pos_idx, cfg.rope_theta, cfg.head_dim)
     counters = _new_counters()
+    branch = None  # a shortcut's expert branch, from its start to its landing
     for (names, li), spec, block in zip(
         _cache_rows(cfg), cfg.layer_specs, params["blocks"]
     ):
-        def attend(q, k, v, names=names, li=li, spec=spec, block=block):
+        def attend(q, k=None, v=None, names=names, li=li, spec=spec, block=block):
+            if spec.mixer == "latent":
+                return _prefill_latent(
+                    q, block, spec, cache, names, li, valid, pos_idx, cfg
+                )
             if spec.mixer == "linear":
                 return _prefill_linear(
                     q, k, v, cache, names, li, live, block, cfg, counters,
@@ -1606,10 +2226,11 @@ def _prefill(
                     q, kt, vt, mask if spec.window is None else wmask, cfg
                 )
 
-        x = _layer(
-            x, block, spec, cfg, pos_idx, live, attend, counters, fused, rope
+        x, branch = _layer(
+            x, block, spec, cfg, pos_idx, live, attend, counters, fused, rope,
+            branch,
         )
-    hlast = _rmsnorm(x[:, -1:, :], params["ln_f_scale"])
+    hlast = _rmsnorm(x[:, -1:, :], params["ln_f_scale"], cfg.norm_eps)
     return _lm_logits(hlast, params, cfg)[:, 0, :], cache, counters
 
 
@@ -1864,6 +2485,8 @@ def prefill_into_slot(
         ]
     if _has_mixers(cfg):
         tail += _mixer_counts(counts)
+    if counts["shares"]:
+        tail += list(sum(counts["shares"]))
     return (_with_counters(first, tail) if tail else first), cache
 
 
@@ -1900,6 +2523,8 @@ def decode_step_slots(
         ]
     if _has_mixers(cfg):
         tail += _mixer_counts(counts)
+    if counts["latent_rows_read"]:
+        tail.append(sum(counts["latent_rows_read"]))
     return (_with_counters(nxt, tail) if tail else nxt), cache
 
 

@@ -215,13 +215,15 @@ def _along_lanes(x, width: int):
 
 
 def _fold_key_tile(q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref, ok,
-                   *, group: int, dh: int, scale: float):
-    """The tile body of the streaming softmax, `prefill_attention`'s and
-    ops/sparse_attention.py `sparse_prefill_attention`'s: the group's query
+                   *, group: int, dh: int, scale: float, dv: int | None = None):
+    """The tile body of the streaming softmax, `prefill_attention`'s,
+    ops/sparse_attention.py `sparse_prefill_attention`'s and
+    ops/latent_attention.py `latent_prefill_attention`'s: the group's query
     tile [t, group * dh] against one key tile [t, dh], a head at a time,
     into the head's running maximum and sum ([t, 128], every lane the
     same) and its float32 accumulator. `ok` [t, t] says which pairs are
-    allowed, None that all are.
+    allowed, None that all are. `dv`: the values' width (and the
+    accumulator's, a head) where it is not the keys'.
 
     The running maximum and the rescale meet the scores and the accumulator
     as the lane-replicated arrays they are kept as (`_along_lanes`). That
@@ -232,8 +234,9 @@ def _fold_key_tile(q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref, ok,
     tried on top and dropped: the scale inside `exp2`, the row sum on the
     MXU, the next head's product issued first)."""
     k, v = k_ref[0], v_ref[0]
+    dv = dv or dh
     for g in range(group):
-        lanes = slice(g * dh, (g + 1) * dh)
+        lanes, out = slice(g * dh, (g + 1) * dh), slice(g * dv, (g + 1) * dv)
         s = jax.lax.dot_general(
             q_ref[0, :, lanes], k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -249,7 +252,7 @@ def _fold_key_tile(q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref, ok,
         e = jnp.exp(s - _along_lanes(m_new, s.shape[1]))
         l_ref[g] = alpha * l_ref[g] + jnp.sum(e, axis=1, keepdims=True)
         m_ref[g] = m_new
-        acc_ref[:, lanes] = acc_ref[:, lanes] * _along_lanes(alpha, dh) + jnp.dot(
+        acc_ref[:, out] = acc_ref[:, out] * _along_lanes(alpha, dv) + jnp.dot(
             e.astype(v.dtype), v, preferred_element_type=jnp.float32
         )
 

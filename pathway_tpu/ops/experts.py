@@ -13,8 +13,9 @@ way back into token order to three more passes over a float32
 `grouped_experts` is the layer's arithmetic in two kernels:
 
 * `expert_gate_up`: a tile of rows is loaded once, multiplied with the
-  expert's `expert_gate` and `expert_up`, and `relu(gate) * up` leaves the
-  float32 accumulators as `[pairs, ff]` in the rows' dtype;
+  expert's `expert_gate` and `expert_up`, and `act(gate) * up` (ReLU, or
+  SiLU where the experts are SwiGLU) leaves the float32 accumulators as
+  `[pairs, ff]` in the rows' dtype;
 * `expert_down`: that tile times the expert's `expert_down`, each row
   scaled in float32 by its pair's routing weight before it is written.
 
@@ -108,10 +109,13 @@ def _blocks(tile_ref, start_ref, tm: int, sub: int):
     return out
 
 
+_ACTS = {"relu": lambda gate: jnp.maximum(gate, 0.0), "silu": jax.nn.silu}
+
+
 def _gate_up_kernel(tile_ref, group_ref, start_ref, x_ref, wg_ref, wu_ref,
-                    o_ref, *, tm: int, sub: int):
+                    o_ref, *, tm: int, sub: int, act: str):
     """One visit: its rows [tm, d] against the expert's gate and up
-    matrices [d, ff], `relu(gate) * up` from the float32 accumulators."""
+    matrices [d, ff], `act(gate) * up` from the float32 accumulators."""
     del group_ref  # the index maps' business
     for rows, any_row, mine in _blocks(tile_ref, start_ref, tm, sub):
 
@@ -120,7 +124,7 @@ def _gate_up_kernel(tile_ref, group_ref, start_ref, x_ref, wg_ref, wu_ref,
             x = x_ref[rows, :]
             gate = jnp.dot(x, wg_ref[...], preferred_element_type=jnp.float32)
             up = jnp.dot(x, wu_ref[...], preferred_element_type=jnp.float32)
-            hidden = (jnp.maximum(gate, 0.0) * up).astype(o_ref.dtype)
+            hidden = (_ACTS[act](gate) * up).astype(o_ref.dtype)
             # the tile's rows of other groups keep what their own visit
             # wrote, or will be written by it
             o_ref[rows, :] = jnp.where(mine, hidden, o_ref[rows, :])
@@ -157,11 +161,11 @@ def _group_matrix(v, tile_ref, group_ref, start_ref):
 
 
 def _gate_up(visits, rows, expert_gate, expert_up, tm: int, sub: int,
-             interpret: bool) -> jax.Array:
+             act: str, interpret: bool) -> jax.Array:
     m, d = rows.shape
     ff = expert_gate.shape[2]
     return pl.pallas_call(
-        functools.partial(_gate_up_kernel, tm=tm, sub=sub),
+        functools.partial(_gate_up_kernel, tm=tm, sub=sub, act=act),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(visits[0].shape[0],),
@@ -204,7 +208,7 @@ def _down(visits, hidden, expert_down, weight, tm: int, sub: int,
 
 # jitted so that the layers of one program share one trace of the kernels
 # and one lowering to Mosaic (ops/attention.py `prefill_attention` has why)
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@functools.partial(jax.jit, static_argnames=("act", "interpret"))
 def grouped_experts(
     rows: jax.Array,  # [pairs, d]: each pair's token row, sorted by expert
     weight: jax.Array,  # [pairs] float32: each pair's routing weight, likewise
@@ -213,14 +217,15 @@ def grouped_experts(
     expert_up: jax.Array,  # [experts, d, ff]
     expert_down: jax.Array,  # [experts, ff, d]
     *,
+    act: str = "relu",  # the gate's activation: relu (ReGLU) | silu (SwiGLU)
     interpret: bool = False,
 ) -> jax.Array:
-    """weight x (relu(rows gate_e) * (rows up_e)) down_e for every pair, e
+    """weight x (act(rows gate_e) * (rows up_e)) down_e for every pair, e
     the expert whose run of `sizes` the pair's row lies in: float32
     [pairs, d / 128, 128] (a row as a slab of lane tiles, which
     `combine_experts` fetches whole), in the rows' order. Products in the
     rows' dtype with float32
-    accumulation, the ReLU product taken in float32 and rounded to the
+    accumulation, the gated product taken in float32 and rounded to the
     rows' dtype between the two kernels, the weight applied in float32: as
     models/transformer.py `_experts` states them over `ragged_dot`. Every
     pair is computed, however many an expert has.
@@ -244,7 +249,9 @@ def grouped_experts(
                              f"the kernel reads a leaf where it lies")
     tm, sub = expert_tile(m)
     visits = expert_visits(sizes.astype(jnp.int32), m, tm)
-    hidden = _gate_up(visits, rows, expert_gate, expert_up, tm, sub, interpret)
+    hidden = _gate_up(
+        visits, rows, expert_gate, expert_up, tm, sub, act, interpret
+    )
     return _down(visits, hidden, expert_down, weight, tm, sub, interpret)
 
 

@@ -139,10 +139,14 @@ ops/sparse_attention.py's kernel over the blocks each query chose
 ``kernel_rowwise_prefills`` those whose q/k norm, rotary positions and pads'
 zero were ops/rowwise.py's one pass over the qkv product
 (``transformer.rowwise_uses_kernel``),
+``kernel_latent_prefills`` those whose latent layers' attention ran
+ops/latent_attention.py's kernel (``transformer.latent_prefill_uses_kernel``),
 ``kernel_steps`` the decode steps whose attention read the slot cache in
-place (``transformer.step_uses_kernel``, likewise), and
+place (``transformer.step_uses_kernel``, likewise),
 ``kernel_sparse_steps`` those whose sparse layers read only the rows of the
-blocks they chose (``transformer.sparse_step_uses_kernel``).
+blocks they chose (``transformer.sparse_step_uses_kernel``), and
+``kernel_latent_steps`` those whose latent layers read only the tiles of
+latent rows that hold a live row (``transformer.latent_step_uses_kernel``).
 
 **A slot's whole row is the request's.** A prefill writes every leaf of the
 slot cache at its slot, whatever the leaf holds (rows of keys, pooled keys,
@@ -404,16 +408,21 @@ class ContinuousBatcher:
             "kernel_linear_prefills": 0, "kernel_sparse_prefills": 0,
             # prefills whose q and k took ops/rowwise.py's one pass
             "kernel_rowwise_prefills": 0,
+            # prefills whose latent layers ran ops/latent_attention.py's kernel
+            "kernel_latent_prefills": 0,
             # decode steps whose attention ran ops/attention.py's kernel
             "kernel_steps": 0,
             # and those whose sparse layers read only their chosen blocks
             "kernel_sparse_steps": 0,
+            # and those whose latent layers read only their live rows' tiles
+            "kernel_latent_steps": 0,
             "preload_s": 0.0,  # the step program's load at construction
             # what a decoder's programs count on the device and send back
             # behind their tokens (0 where the block has no such layer)
             **dict.fromkeys(
                 self._model.PREFILL_COUNTERS + self._model.STEP_COUNTERS
-                + self._model.MIXER_COUNTERS, 0
+                + self._model.MIXER_COUNTERS + self._model.SHARE_COUNTERS
+                + self._model.LATENT_COUNTERS, 0
             ),
         }
         # the counters this decoder's two programs append, in their order
@@ -814,6 +823,9 @@ class ContinuousBatcher:
                 self.stats["kernel_rowwise_prefills"] += (
                     self._model.rowwise_uses_kernel(self.cfg, req.width)
                 )
+                self.stats["kernel_latent_prefills"] += (
+                    self._model.latent_prefill_uses_kernel(self.cfg, req.width)
+                )
                 self.stats["queue_wait_s"] += req.t_admit - req.t_submit
                 self.stats["first_token_s"] += req.t_first - req.t_submit
                 if len(req.tokens) >= self.n_steps:  # n_steps == 1
@@ -828,6 +840,9 @@ class ContinuousBatcher:
             )
             self.stats["kernel_sparse_steps"] += (
                 self._model.sparse_step_uses_kernel(self.cfg)
+            )
+            self.stats["kernel_latent_steps"] += (
+                self._model.latent_step_uses_kernel(self.cfg)
             )
             self._count(self._step_tail, nxt[self.n_slots:])
             if _obs.PLANE is not None:

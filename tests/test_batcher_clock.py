@@ -98,11 +98,14 @@ def test_stats_hold_every_key_from_construction():
         "prompt_tokens", "padded_tokens", "kernel_prefills", "kernel_steps",
         "kernel_expert_prefills", "kernel_linear_prefills",
         "kernel_sparse_prefills", "kernel_sparse_steps",
-        "kernel_rowwise_prefills",
+        "kernel_rowwise_prefills", "kernel_latent_prefills",
+        "kernel_latent_steps",
         # an experts decoder's device counters (0 for this block)
         "routed_pairs", "expert_load_max", "experts_touched", "moe_layers_run",
         # and those of a decoder with sparse or linear layers
         "sparse_blocks_read", "sparse_blocks_visible", "linear_tokens",
+        # of one that holds a share of its experts, and of latent layers
+        "router_pairs", "zero_pairs", "absent_pairs", "latent_rows_read",
     }
     clocks = (
         set(PHASES) | {"loop_s", "host_cpu_s", "preload_s", "tokenize_s"}
@@ -1151,9 +1154,9 @@ def test_benchmark_lists_the_seven_edge_and_cpu_metrics_for_the_three_cells():
     entries = _listed(names)
     bench = json.loads((BENCH.parent / "BENCHMARK.json").read_text())
     cells = [w["name"] for w in bench["workloads"]]
-    assert [m["name"] for m in bench["per_layer"][-7:]] == [
-        f"{n}.tput" for n in names
-    ]
+    listed = [m["name"] for m in bench["per_layer"]]
+    first = listed.index(f"{names[0]}.tput")  # (later PRs append behind them)
+    assert listed[first:first + 7] == [f"{n}.tput" for n in names]
     layers = {n: entries[f"{n}.tput"]["layer"] for n in names}
     edge = entries["outside_batcher_ms.tput"]["layer"]
     batching = entries["queue_wait_ms.tput"]["layer"]

@@ -11,6 +11,7 @@ at a time may load the TPU's library.
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 import re
 
@@ -563,3 +564,78 @@ def test_a_rotary_decoders_prefill_takes_the_pass_and_its_step_does_not(
     # the rotary layer's rotation is the pass's: one cosine, the tables'
     assert len(re.findall(r"stablehlo\.cosine", prefill)) == 1
     assert len(re.findall(r"stablehlo\.cosine", step)) >= 1
+
+
+# ------------------------------------------------ the latent mixer (PR 43)
+
+
+def test_the_latent_kernels_compile_for_v5e(chip):
+    """ops/latent_attention.py at the fourth cell's widths: a prefill of
+    10,240 positions and 64 heads of 128 + 64 lanes against values of 128,
+    and a step of 8 slots over leaves of 12,288 latent rows of 512 lanes
+    with the rotary key in a lane tile."""
+    from pathway_tpu.ops.latent_attention import (
+        latent_decode_attention, latent_prefill_attention,
+    )
+
+    def arg(*shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=chip)
+
+    p, h = 10240, 64
+    text = jax.jit(latent_prefill_attention).lower(
+        arg(1, p, h, 128), arg(1, p, h, 64), arg(1, p, h, 128), arg(1, p, h, 64),
+        arg(1, p, h, 128), arg(1, p, dt=jnp.int32),
+    ).compile().as_text()
+    assert "%latent_prefill_attention" in text  # the name a device trace shows
+    vec = arg(8, dt=jnp.int32)
+    text = jax.jit(
+        functools.partial(latent_decode_attention, scale=192 ** -0.5)
+    ).lower(
+        arg(8, h, 512), arg(8, h, 128), arg(8, 8, 12288, 512),
+        arg(8, 8, 12288, 128), arg(dt=jnp.int32), vec, vec,
+    ).compile().as_text()
+    assert "%latent_decode_attention" in text
+
+
+def test_a_latent_step_leaves_the_cache_where_it_lies(chip, monkeypatch):
+    """The compiled step of a decoder with latent layers and a shortcut's
+    expert branch (heads of 128 + 64 over 128, a latent row of 512, 4 slots
+    of 2,048 rows): both kernels' names are in it, the leaves are results
+    of their own buffers, and no operation copies a leaf (a `k_rope` leaf 64
+    lanes wide was copied there and back every step: `_rope_lanes`)."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    sub = LayerSpec(mixer="latent", pos="rotary", ff="swiglu")
+    cfg = lm_config(
+        vocab_size=512, d_model=256, n_heads=4, n_layers=2, d_ff=512,
+        d_expert=256, max_len=2048, dtype=jnp.bfloat16, tie_embeddings=False,
+        norm_eps=1e-5, router="all", router_bias=True, router_scale=6.0,
+        n_experts=32, n_zero_experts=16, n_active=4, experts_held=(8, 8),
+        expert_act="silu",
+        latent=T.LatentSpec(q_rank=384, kv_rank=512, nope_dim=128, rope_dim=64,
+                            v_dim=128, q_scale=2.0, kv_scale=2.0),
+        layers=(dataclasses.replace(sub, shortcut="start"),
+                dataclasses.replace(sub, shortcut="land")),
+    )
+    assert T.latent_step_uses_kernel(cfg) and T.latent_prefill_uses_kernel(cfg, 1280)
+    params = _shaped(
+        chip, lambda: T.init_params(jax.random.PRNGKey(0), cfg, jnp.bfloat16)
+    )
+    cache = _shaped(chip, lambda: T.init_kv_cache(cfg, 4))
+    vec = jax.ShapeDtypeStruct((4,), jnp.int32, sharding=chip)
+    step = jax.jit(
+        functools.partial(T.decode_step_slots, cfg=cfg), donate_argnums=(1,)
+    ).lower(params, cache, vec, vec, vec).compile().as_text()
+    assert "%latent_decode_attention" in step
+    leaves = ("bf16[2,4,2048,512]", "bf16[2,4,2048,128]")
+    copies = [
+        line for line in step.splitlines()
+        if re.search(r"= \S+ copy\(", line) and any(s in line for s in leaves)
+    ]
+    assert not copies, copies[:2]
+    ids = jax.ShapeDtypeStruct((1, 1280), jnp.int32, sharding=chip)
+    prefill = jax.jit(
+        functools.partial(T.prefill_into_slot, cfg=cfg), donate_argnums=(3,)
+    ).lower(
+        params, ids, ids, cache, jax.ShapeDtypeStruct((), jnp.int32, sharding=chip)
+    ).compile().as_text()
+    assert "%latent_prefill_attention" in prefill
