@@ -1735,17 +1735,25 @@ _LATENT_SCORE_BYTES = 256 << 20
 
 
 def _latent_rows(xin: Array, block: Params, pos: Array, spec: LayerSpec,
-                 cfg: TransformerConfig):
+                 cfg: TransformerConfig, *, for_kernel: bool = False):
     """Of normed rows xin [b, s, d] at logical positions pos [b, s]: the
     heads' queries (q_n [b, s, heads, nope], q_r [b, s, heads, rope], turned)
     and what a position keeps (c_kv [b, s, kv_rank] normed and scaled, k_r
-    [b, s, rope] turned)."""
+    [b, s, rope] turned). `for_kernel` (a prefill that runs
+    ops/latent_attention.py `latent_prefill_attention`): q_n and q_r are a
+    product each, of W_qb's nope and rope columns, so that each leaves the
+    MXU as whole heads side by side and nothing slices, pads or copies an
+    array as large as the prompt: q_r [b, s, heads, rope lanes] then has
+    zeros behind each head's rotary lanes and is NOT turned, which is the
+    kernel's to do. A step's one row slices its product, which is small,
+    and leaves the weight as it lies. Every element is the same dot product
+    either way."""
     lt, eps = cfg.latent, cfg.norm_eps
     b, s, _ = xin.shape
 
-    def product(x: Array, name: str) -> Array:
+    def product(x: Array, w: Array) -> Array:
         return jnp.einsum(
-            "bsd,de->bse", x, block[name].astype(cfg.dtype),
+            "bsd,de->bse", x, w.astype(cfg.dtype),
             preferred_element_type=jnp.float32,
         ).astype(cfg.dtype)
 
@@ -1754,18 +1762,34 @@ def _latent_rows(xin: Array, block: Params, pos: Array, spec: LayerSpec,
         return block[name].astype(jnp.float32) * by
 
     with jax.named_scope("q_down"):
-        c_q = _rmsnorm(product(xin, "q_a"), gain("q_a_norm", lt.q_scale), eps)
+        c_q = _rmsnorm(product(xin, block["q_a"]), gain("q_a_norm", lt.q_scale), eps)
     with jax.named_scope("q_up"):
-        q = product(c_q, "q_b").reshape(b, s, cfg.n_heads, lt.qk_dim)
-        q_n, q_r = q[..., :lt.nope_dim], q[..., lt.nope_dim:]
+        if for_kernel:
+            w = block["q_b"].reshape(lt.q_rank, cfg.n_heads, lt.qk_dim)
+            # the two slices are made before the products: folded into them
+            # (XLA's TPU compiler does, left alone) a product leaves with
+            # the heads outermost and is copied, 168 MB, into rows. The
+            # rotary columns go in with zero columns behind each head's, up
+            # to a lane tile: the product then leaves the rotary lanes as
+            # the kernel reads them, and nothing pads them afterwards
+            w_n, w_r = jax.lax.optimization_barrier((
+                w[..., :lt.nope_dim].reshape(lt.q_rank, -1),
+                _in_rope_lanes(w[..., lt.nope_dim:], cfg).reshape(lt.q_rank, -1),
+            ))
+            q_n = product(c_q, w_n).reshape(b, s, cfg.n_heads, lt.nope_dim)
+            q_r = product(c_q, w_r).reshape(b, s, cfg.n_heads, _rope_lanes(cfg))
+        else:
+            q = product(c_q, block["q_b"]).reshape(b, s, cfg.n_heads, lt.qk_dim)
+            q_n, q_r = q[..., :lt.nope_dim], q[..., lt.nope_dim:]
     with jax.named_scope("kv_down"):
-        kv = product(xin, "kv_a")
+        kv = product(xin, block["kv_a"])
         c_kv = _rmsnorm(
             kv[..., :lt.kv_rank], gain("kv_a_norm", lt.kv_scale), eps
         )
         k_r = kv[..., lt.kv_rank:]
     if spec.pos == "rotary":
-        q_r = _rope(q_r, pos, cfg)
+        if not for_kernel:
+            q_r = _rope(q_r, pos, cfg)
         k_r = _rope(k_r[:, :, None, :], pos, cfg)[:, :, 0, :]
     return q_n, q_r, c_kv, k_r
 
@@ -1816,13 +1840,49 @@ def _prefill_latent(xin, block, spec, cache, names, li, valid, pos_idx, cfg):
     b, p, _ = xin.shape
     h = cfg.n_heads
     with jax.named_scope("attn"), jax.named_scope("attn_latent"):
-        q_n, q_r, c_kv, k_r = _latent_rows(xin, block, pos_idx, spec, cfg)
+        kernel = latent_prefill_uses_kernel(cfg, p)
+        q_n, q_r, c_kv, k_r = _latent_rows(
+            xin, block, pos_idx, spec, cfg, for_kernel=kernel
+        )
+        kept = _in_rope_lanes(k_r, cfg)  # the rotary key as the leaf holds it
         with jax.named_scope("cache_write"):
             cache[names["c"]] = jax.lax.dynamic_update_slice(
                 cache[names["c"]], c_kv[None], (li, 0, 0, 0)
             )
             cache[names["rope"]] = jax.lax.dynamic_update_slice(
-                cache[names["rope"]], _in_rope_lanes(k_r, cfg)[None], (li, 0, 0, 0)
+                cache[names["rope"]], kept[None], (li, 0, 0, 0)
+            )
+        if kernel:
+            # imported where they are traced: Pallas loads when a program
+            # first needs it
+            from pathway_tpu.ops.latent_attention import latent_prefill_attention
+            from pathway_tpu.ops.rowwise import rope_tables
+
+            with jax.named_scope("kv_up"):
+                # a product each for the nope keys and the values, of
+                # W_kvb's columns, with the heads outermost and the
+                # positions along the lanes: the TPU's compiler computes
+                # these products (512 deep) that way whatever is asked, and
+                # copies them (168 MB each) if rows of heads were
+                w = _kv_up(block, cfg)
+                k_n, v = (
+                    jnp.einsum(
+                        "bsr,rhe->bhes", c_kv, part,
+                        preferred_element_type=jnp.float32,
+                    ).astype(cfg.dtype)
+                    for part in (w[..., :lt.nope_dim], w[..., lt.nope_dim:])
+                )
+            with jax.named_scope("rope"):
+                if spec.pos == "rotary":
+                    cos, sin = rope_tables(pos_idx, cfg.rope_theta, lt.rope_dim)
+                else:  # a turn by no angle
+                    cos = jnp.ones((b, p, lt.rope_dim), jnp.float32)
+                    sin = jnp.zeros_like(cos)
+            # the one rotary key as the leaf's row: no head's copy of it
+            return latent_prefill_attention(
+                q_n, q_r, k_n, kept, v, valid, _in_rope_lanes(cos, cfg),
+                _in_rope_lanes(sin, cfg), scale=1.0 / math.sqrt(lt.qk_dim),
+                half=lt.rope_dim // 2,
             )
         with jax.named_scope("kv_up"):
             kv = jnp.einsum(
@@ -1831,12 +1891,6 @@ def _prefill_latent(xin, block, spec, cache, names, li, valid, pos_idx, cfg):
             ).astype(cfg.dtype)
             k_n, v = kv[..., :lt.nope_dim], kv[..., lt.nope_dim:]
         shared = jnp.broadcast_to(k_r[:, :, None, :], (b, p, h, lt.rope_dim))
-        if latent_prefill_uses_kernel(cfg, p):
-            # imported where it is traced: Pallas loads when a program
-            # first needs it
-            from pathway_tpu.ops.latent_attention import latent_prefill_attention
-
-            return latent_prefill_attention(q_n, q_r, k_n, shared, v, valid)
         q = jnp.concatenate([q_n, q_r], axis=-1)
         k = jnp.concatenate([k_n, shared], axis=-1)
         chunk = p
@@ -2015,18 +2069,21 @@ def rowwise_uses_kernel(cfg: TransformerConfig, width: int) -> bool:
 def latent_prefill_uses_kernel(cfg: TransformerConfig, width: int) -> bool:
     """Whether the latent layers of a prefill `width` wide run
     ops/latent_attention.py `latent_prefill_attention` (the tile body of
-    `prefill_attention`, scores kept in VMEM) and not `_attend_latent` over
-    chunks of queries: on a TPU, for a decoder that has such layers, at a
-    width of 128 at least, with values of a multiple of 128 lanes. The
-    query/key width need not be one: the kernel carries nope + rope lanes
-    (192) padded with zeros to the next lane tile (256), which adds nothing
-    to a score. Read from the shapes and from where the process runs;
-    nothing sets it, and `fused_attention` off keeps tensor-parallel
-    parameters on the plain path."""
+    `prefill_attention` over a block of heads a grid step, scores kept in
+    VMEM) and not `_attend_latent` over chunks of queries: on a TPU, for a
+    decoder that has such layers, at a width of 128 at least, with nope
+    lanes and values of a multiple of 128 lanes (a head's lanes are then
+    whole lane tiles of the products that make them). The rotary lanes need
+    not be one: the kernel reads them in the lane tile the `k_rope` leaf
+    keeps them in (`_rope_lanes`), as a product of their own. Read from the
+    shapes and from where the process runs; nothing sets it, and
+    `fused_attention` off keeps tensor-parallel parameters on the plain
+    path."""
     return (
         bool(cfg.n_mixer_layers("latent"))
         and cfg.fused_attention
         and jax.default_backend() == "tpu"
+        and cfg.latent.nope_dim % 128 == 0
         and cfg.latent.v_dim % 128 == 0
         and width >= 128
     )

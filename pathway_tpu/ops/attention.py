@@ -157,14 +157,23 @@ _PREFILL_TILE_MAX = 896  # no tile is wider
 _PREFILL_VMEM = 16 << 20  # what `prefill_tile`'s sum may reach
 
 
-def _prefill_vmem(t: int, dh: int, group: int, itemsize: int) -> int:
-    """VMEM of one grid step at tile t, in bytes (`prefill_tile`)."""
+def _prefill_vmem(t: int, dh: int, group: int, itemsize: int,
+                  *, own_keys: bool = False, rope: int = 0) -> int:
+    """VMEM of one grid step at tile t, in bytes (`prefill_tile`,
+    ops/latent_attention.py `latent_prefill_block`). `own_keys`: each of
+    the step's `group` heads has key and value lanes of its own, so those
+    tiles are as wide as the queries'; `rope`: the lanes of a rotary part
+    beside each head's query (as fetched, and once more turned), of the one
+    rotary key tile the heads share and of the two float32 tables."""
     qo = 2 * 2 * t * group * dh * itemsize  # query and context tiles, double-buffered
-    kv = 2 * 2 * t * dh * itemsize  # key and value tiles, double-buffered
+    kv = 2 * 2 * t * (group if own_keys else 1) * dh * itemsize  # key and value tiles, likewise
     acc = t * group * dh * 4  # float32 accumulator
     ml = 2 * group * t * 128 * 4  # running maximum and sum, lane-replicated
     live = 3 * t * t * 4  # one head's scores, their exponentials, the mask
-    return qo + kv + acc + ml + live
+    # each head's rotary lanes double-buffered and once more turned, the one
+    # rotary key double-buffered, two float32 tables double-buffered
+    turned = t * rope * ((3 * group + 2) * itemsize + 2 * 2 * 4)
+    return qo + kv + acc + ml + live + turned
 
 
 def prefill_tile(p: int, dh: int, group: int, itemsize: int = 2) -> int:
@@ -190,7 +199,11 @@ def prefill_tile(p: int, dh: int, group: int, itemsize: int = 2) -> int:
     31.1 to 34.4 at 512 x 256, 512 x 512 and 256 x 1024: under a hundredth
     of a prefill either way, so the tile stays one number. The softmax
     made a few rows at a time to stay in registers was 1.3-10 times
-    slower than the whole tile's (PR 32)."""
+    slower than the whole tile's (PR 32). A kernel whose heads share no
+    key tile chooses how many of them a grid step runs as well:
+    ops/latent_attention.py `latent_prefill_block` has that rule and its
+    table (PR 44: a step of one head ran at half the rate a group of
+    seven has here)."""
     fits = [
         t for t in range(128, min(p, _PREFILL_TILE_MAX) + 1, 128)
         if p % t == 0 and _prefill_vmem(t, dh, group, itemsize) <= _PREFILL_VMEM
@@ -215,16 +228,29 @@ def _along_lanes(x, width: int):
 
 
 def _fold_key_tile(q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref, ok,
-                   *, group: int, dh: int, scale: float, dv: int | None = None):
+                   *, group: int, dh: int, scale: float, dv: int | None = None,
+                   own_keys: bool = False, rope=None):
     """The tile body of the streaming softmax, `prefill_attention`'s,
     ops/sparse_attention.py `sparse_prefill_attention`'s and
-    ops/latent_attention.py `latent_prefill_attention`'s: the group's query
-    tile [t, group * dh] against one key tile [t, dh], a head at a time,
-    into the head's running maximum and sum ([t, 128], every lane the
-    same) and its float32 accumulator. `ok` [t, t] says which pairs are
-    allowed, None that all are. `dv`: the values' width (and the
-    accumulator's, a head) where it is not the keys'.
+    ops/latent_attention.py `latent_prefill_attention`'s: the query tile
+    [t, group * dh] of the grid step's heads against one key tile, a head at
+    a time, into the head's running maximum and sum ([t, 128], every lane
+    the same) and its float32 accumulator. The key tile is [t, dh], rows of
+    one key head that the group's query heads share (the first two
+    callers), or with `own_keys` [group * dh, t]: each head's own dh rows
+    of a tile whose lanes are the positions (the third, whose heads share
+    no key: its group is a block of heads, and its keys and values lie as
+    their products leave them, heads outermost). The value tile likewise.
+    `ok` [t, t] says which pairs are allowed, None that all are.
+    `dv`: the values' width (and the accumulator's, a head) where it is not
+    the keys'. `rope`: (a query tile [t, group * lanes], one key tile
+    [t, lanes]) of a second part of every head's score, a product of its
+    own against a key all heads share, added in float32 before the scale.
 
+    The loop is unrolled, so the compiler's scheduler sees the step's heads
+    as one stretch of straight-line code and runs one head's softmax behind
+    another's products; a grid step of one head has nothing beside it
+    (PERF.md section 6, PR 39 and PR 44).
     The running maximum and the rescale meet the scores and the accumulator
     as the lane-replicated arrays they are kept as (`_along_lanes`). That
     leaves the XLU to the two row reductions, and the softmax hides behind
@@ -233,14 +259,28 @@ def _fold_key_tile(q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref, ok,
     products in turn (PERF.md section 6, PR 39, which also has what was
     tried on top and dropped: the scale inside `exp2`, the row sum on the
     MXU, the next head's product issued first)."""
-    k, v = k_ref[0], v_ref[0]
+    if not own_keys:
+        k, v = k_ref[0], v_ref[0]
     dv = dv or dh
+    # the axis of a key tile that holds a head's lanes, and of a value tile
+    # the positions: rows of keys, or keys along the lanes
+    k_lanes, v_rows = (0, 1) if own_keys else (1, 0)
     for g in range(group):
         lanes, out = slice(g * dh, (g + 1) * dh), slice(g * dv, (g + 1) * dv)
+        if own_keys:
+            k, v = k_ref[0, lanes, :], v_ref[0, out, :]
         s = jax.lax.dot_general(
-            q_ref[0, :, lanes], k, (((1,), (1,)), ((), ())),
+            q_ref[0, :, lanes], k, (((1,), (k_lanes,)), ((), ())),
             preferred_element_type=jnp.float32,
-        ) * scale  # [t, t]
+        )
+        if rope is not None:
+            qr_ref, kr_ref = rope
+            dr = kr_ref.shape[-1]
+            s = s + jax.lax.dot_general(
+                qr_ref[0, :, g * dr:(g + 1) * dr], kr_ref[0],
+                (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+            )
+        s = s * scale  # [t, t]
         if ok is not None:
             s = jnp.where(ok, s, _MASKED)
         m_prev = m_ref[g]
@@ -252,8 +292,9 @@ def _fold_key_tile(q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref, ok,
         e = jnp.exp(s - _along_lanes(m_new, s.shape[1]))
         l_ref[g] = alpha * l_ref[g] + jnp.sum(e, axis=1, keepdims=True)
         m_ref[g] = m_new
-        acc_ref[:, out] = acc_ref[:, out] * _along_lanes(alpha, dv) + jnp.dot(
-            e.astype(v.dtype), v, preferred_element_type=jnp.float32
+        acc_ref[:, out] = acc_ref[:, out] * _along_lanes(alpha, dv) + jax.lax.dot_general(
+            e.astype(v.dtype), v, (((1,), (v_rows,)), ((), ())),
+            preferred_element_type=jnp.float32,
         )
 
 

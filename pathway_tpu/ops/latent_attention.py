@@ -10,10 +10,18 @@ tiles and whose cache leaf is rows of whole heads.
 
 * `latent_prefill_attention`: a prompt's causal attention to its own
   expanded keys and values, on `prefill_attention`'s tile body
-  (`_fold_key_tile`) with the scores kept in VMEM. The query/key width is
-  carried padded with zeros to the next lane tile (192 -> 256): a zero lane
-  adds nothing to a score, and a quarter of the first product's passes are
-  spent on it.
+  (`_fold_key_tile`) with the scores kept in VMEM. A grid step runs a
+  block of heads (`latent_prefill_block`), each against its own key and
+  value lanes, so that the body's unrolled loop has one head's softmax to
+  run behind another's products, as a group of query heads over one key
+  head has in `prefill_attention`. A head's score is two products, its
+  128 nope lanes against its own key's and its rotary lanes against the
+  one rotary key of the position, which is fetched once a grid step as
+  the row the cache keeps and is never repeated for the heads in memory;
+  q_nope, k_nope and v are read where their products leave them, and so
+  are the query's rotary lanes, which the kernel turns itself, once a
+  query tile (models/transformer.py `_rope`'s arithmetic over a lane tile
+  a head).
 * `latent_decode_attention`: a step's attention in the absorbed form. A
   slot's heads (their nope lanes already times W_uk: `kv_rank` wide) share
   every latent row, so the whole of a slot's heads meets a tile of `c_kv`
@@ -29,7 +37,6 @@ models/transformer.py `latent_prefill_uses_kernel` and
 from __future__ import annotations
 
 import functools
-import math
 
 import jax
 import jax.numpy as jnp
@@ -39,27 +46,91 @@ from jax.experimental.pallas import tpu as pltpu
 
 from pathway_tpu.ops.attention import (
     _MASKED, _PREFILL_VMEM, _finish_softmax, _fold_key_tile, _prefill_vmem,
-    _start_softmax, prefill_tile,
+    _start_softmax,
 )
 
 
-def _prefill_kernel(first_ref, held_ref, q_ref, k_ref, v_ref, valid_ref, o_ref,
-                    m_ref, l_ref, acc_ref, *, t: int, dk: int, dv: int,
+_HEAD_BLOCK = 8  # heads a grid step of the prefill kernel at most
+_BLOCK_TILE = 512  # and the tile of its queries and keys
+_BLOCK_VMEM = 24 << 20  # what `latent_prefill_block`'s sum may reach
+
+
+def latent_prefill_block(p: int, heads: int, dh: int, rope: int,
+                         itemsize: int = 2) -> tuple[int, int]:
+    """The block of `latent_prefill_attention`: (heads a grid step, the
+    tile of queries and of keys) for a width p that 128 divides. A
+    function of the shapes alone: the tile is the largest multiple of 128
+    that divides p and is at most 512; the heads are the largest count up
+    to 8 that divides the layer's heads (8 of 64; 3 of 3; 1 of 11, which
+    is the kernel of one head a grid step) and keeps the VMEM sum of a grid
+    step (ops/attention.py `_prefill_vmem`, the keys and values `hb` heads
+    wide, the rotary lanes beside them) under 24 MB: (8, 512) at
+    rag-longcat-flash-omni's (p 10,240, 64 heads of 128 nope lanes, the
+    rotary lanes in a tile of 128, bf16: 22.3 MB).
+    On the chip (PERF.md section 6, PR 44; the kernel alone at that shape,
+    a left pad of 200, ms a call and G scores computed a second), heads x
+    tile: 1 x 640 23.94 (149: the kernel as it was, one head a grid step:
+    nothing for a head's softmax to hide behind), 1 x 512 23.22, 2 x 512
+    20.66, 2 x 640 20.70, 2 x 1024 20.08, 4 x 256 27.14, 4 x 512 18.83,
+    4 x 640 19.65, 4 x 1024 19.11, 8 x 256 24.16, **8 x 512 17.92 (197)**,
+    8 x 640 18.98, 16 x 256 22.75; with an empty body (grid steps and
+    fetches alone) 6.81 at 4 x 512 and 7.04 at 1 x 640. More heads a step
+    are worth less each time (-2.6, -1.8, -0.9 ms from 1 to 2 to 4 to 8 at
+    512) and cost the compiler their unrolled body twice (3.9 s at 4, 7.1
+    at 8); past 512 a tile only adds to what the diagonal wastes, under it
+    the step's fixed cost shows."""
+    t = max(t for t in range(128, min(p, _BLOCK_TILE) + 1, 128) if p % t == 0)
+    fits = [
+        n for n in range(1, _HEAD_BLOCK + 1)
+        if heads % n == 0 and _prefill_vmem(
+            t, dh, n, itemsize, own_keys=True, rope=rope
+        ) <= _BLOCK_VMEM
+    ]
+    return fits[-1] if fits else 1, t
+
+
+def _prefill_kernel(first_ref, held_ref, q_ref, qr_ref, cos_ref, sin_ref, k_ref,
+                    kr_ref, v_ref, valid_ref, o_ref, m_ref, l_ref, acc_ref,
+                    turned_ref, *, t: int, hb: int, dn: int, dv: int, half: int,
                     scale: float):
-    """One grid step (row, head, query tile, k-th needed key tile): a head's
-    query tile [t, dk] against its key tile [t, dk] and value tile [t, dv];
-    ops/attention.py `_prefill_kernel` without a window or a group."""
+    """One grid step (row, block of heads, query tile, k-th needed key
+    tile): the block's query tiles [t, hb * dn] and their rotary lanes
+    [t, hb * dr] against the heads' own key tiles [hb * dn, t], the one
+    rotary key tile [t, dr] and the heads' value tiles [hb * dv, t];
+    ops/attention.py `_prefill_kernel` without a window, its group a block
+    of heads that share no key. The query's rotary lanes come as their
+    product left them and are turned here, once a query tile, into
+    `turned_ref`."""
     bi, qi, kk = pl.program_id(0), pl.program_id(2), pl.program_id(3)
     kt = first_ref[bi] + kk  # the key tile
     held = held_ref[bi * pl.num_programs(2) + jnp.minimum(kt, qi)]  # its valid keys
+    dr = kr_ref.shape[-1]
 
     @pl.when(kk == 0)
     def _start():
         _start_softmax(m_ref, l_ref, acc_ref)
+        # models/transformer.py `_rope` over a lane tile a head: the tables
+        # hold each cosine twice and the sines with their first half
+        # negated, zeros behind the rotary lanes, so that
+        # [x1 cos - x2 sin, x2 cos + x1 sin] = x cos + partner sin, where a
+        # lane's partner is `half` lanes up in the first half and down in
+        # the second; float32, rounded once, as `_rope` rounds
+        cos, sin = cos_ref[0], sin_ref[0]
+        first = jax.lax.broadcasted_iota(jnp.int32, (t, dr), 1) < half
+        for g in range(hb):
+            lanes = slice(g * dr, (g + 1) * dr)
+            x = qr_ref[0, :, lanes].astype(jnp.float32)
+            partner = jnp.where(
+                first, pltpu.roll(x, dr - half, 1), pltpu.roll(x, half, 1)
+            )
+            turned_ref[0, :, lanes] = (x * cos + partner * sin).astype(
+                turned_ref.dtype
+            )
 
     fold = functools.partial(
         _fold_key_tile, q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref,
-        group=1, dh=dk, scale=scale, dv=dv,
+        group=hb, dh=dn, scale=scale, dv=dv, own_keys=True,
+        rope=(turned_ref, kr_ref),
     )
     # a tile on the diagonal or with a key that is not valid takes an
     # element mask; past the diagonal the clamped tile is not run again, and
@@ -79,53 +150,80 @@ def _prefill_kernel(first_ref, held_ref, q_ref, k_ref, v_ref, valid_ref, o_ref,
 
     @pl.when(kk == pl.num_programs(3) - 1)
     def _finish():
-        _finish_softmax(o_ref, l_ref, acc_ref, group=1, dh=dv)
+        _finish_softmax(o_ref, l_ref, acc_ref, group=hb, dh=dv)
 
 
 # jitted so that the layers of one program share one trace of the kernel
 # and one lowering to Mosaic (ops/attention.py `prefill_attention` has why)
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@functools.partial(jax.jit, static_argnames=("scale", "half", "interpret"))
 def latent_prefill_attention(
     q_nope: jax.Array,  # [b, p, heads, nope]
-    q_rope: jax.Array,  # [b, p, heads, rope], turned
-    k_nope: jax.Array,  # [b, p, heads, nope]: expanded from the latent rows
-    k_rope: jax.Array,  # [b, p, heads, rope]: the one rotary key, a head each
-    v: jax.Array,  # [b, p, heads, dv]
+    q_rope: jax.Array,  # [b, p, heads, rope lanes]: NOT turned, zeros behind
+    k_nope: jax.Array,  # [b, heads, nope, p]: expanded from the latent rows
+    k_rope: jax.Array,  # [b, p, rope lanes]: the one rotary key, turned, zeros behind
+    v: jax.Array,  # [b, heads, dv, p]: likewise
     valid: jax.Array,  # [b, p] 1/0: the keys that are real
+    cos: jax.Array,  # [b, p, rope lanes] float32: `rope_tables`, zeros behind
+    sin: jax.Array,  # likewise
     *,
+    scale: float,
+    half: int,  # half of the rotary lanes: how far a lane's partner lies
     interpret: bool = False,
 ) -> jax.Array:
     """Causal attention of whole prompts to themselves, the scores kept in
     VMEM: query i of a row attends the valid keys j <= i with
-    softmax((q_n . k_n + q_r . k_r) / sqrt(nope + rope)) over them, and
-    returns the context [b, p, heads * dv]. Products in the inputs' dtype
-    with float32 accumulation, softmax in float32, as models/transformer.py
-    `_attend_latent` states them; the order of the sums is another.
+    softmax((q_n . k_n + turn(q_r) . k_r) x scale) over them, and returns
+    the context [b, p, heads * dv]. A head's score is two products in the
+    inputs' dtype with float32 accumulation, summed in float32; softmax in
+    float32, as models/transformer.py `_attend_latent` states them; the
+    order of the sums is another. turn(q_r) is `_rope` of the query's
+    rotary lanes by the tables `cos` and `sin` (ops/rowwise.py
+    `rope_tables` of the rows' positions; ones and zeros turn nothing), in
+    float32 and rounded once, as `_rope` rounds.
 
-    The two parts of a head lie side by side in one query (and key) of
-    nope + rope lanes padded with zeros to whole lane tiles; dv must be a
-    multiple of 128. A width that is no multiple of 128 is padded at the
-    end (keys never valid, queries cut off). Grid (b, heads, p / t, key
-    tiles), t from `prefill_tile` of the padded width: as
-    `prefill_attention`, a grid step past the diagonal names the tile
-    already in VMEM, and the key tiles wholly inside a row's left padding
-    are neither fetched nor run."""
+    The operands are read where the projections leave them: a head's nope
+    lanes are a block of lanes of the query product's flattened head axis;
+    its nope keys and its values lie with the heads outermost and the
+    positions along the lanes, which is how the TPU's compiler leaves the
+    two products that expand them from the latent rows (asked for rows of
+    heads it computes them so all the same and copies them, 168 MB each a
+    layer); and the rotary key is ONE row of a lane tile a position
+    (models/transformer.py `_in_rope_lanes`: what the cache keeps), fetched
+    once a grid step for all of the step's heads and never repeated in
+    memory; the query's rotary lanes lie in a lane tile a head, zeros
+    behind them as behind the key's and the tables', as their product
+    leaves them when W_qb's rotary columns go in with zero columns behind
+    (an array of 64 lanes a head takes a tile's room a head in memory
+    anyway, and XLA's passes over such an array, `_rope`'s among them,
+    move four times their bytes). nope, rope lanes and dv must be
+    multiples of 128. A width that is no multiple of 128 is padded at the
+    end (keys never valid, queries cut off). Grid (b, heads / hb, p / t, key
+    tiles), (hb, t) from `latent_prefill_block`: the tile body's loop runs
+    over the hb heads of a grid step, each against its own key and value
+    lanes. As `prefill_attention`, a grid step past the diagonal names the
+    tiles already in VMEM, and the key tiles wholly inside a row's left
+    padding are neither fetched nor run."""
     b, p0, h, dn = q_nope.shape
-    dv = v.shape[-1]
-    dk0 = dn + q_rope.shape[-1]
-    dk = -(-dk0 // 128) * 128
-    if dv % 128:
-        raise ValueError(f"latent_prefill_attention needs values of a multiple "
-                         f"of 128 lanes, got {dv}")
-    fill = jnp.zeros((b, p0, h, dk - dk0), q_nope.dtype)
-    q = jnp.concatenate([q_nope, q_rope, fill], axis=-1)
-    k = jnp.concatenate([k_nope, k_rope, fill], axis=-1)
+    dr, dv = q_rope.shape[-1], v.shape[2]
+    if dn % 128 or dr % 128 or dv % 128:
+        raise ValueError(f"latent_prefill_attention needs nope lanes, rotary "
+                         f"lanes and values of a multiple of 128 lanes each, "
+                         f"got {dn}, {dr} and {dv}")
     extra = -p0 % 128
     if extra:
-        q, k, v = (jnp.pad(a, ((0, 0), (0, extra), (0, 0), (0, 0))) for a in (q, k, v))
+        q_nope, q_rope = (
+            jnp.pad(a, ((0, 0), (0, extra), (0, 0), (0, 0))) for a in (q_nope, q_rope)
+        )
+        k_nope, v = (
+            jnp.pad(a, ((0, 0), (0, 0), (0, 0), (0, extra))) for a in (k_nope, v)
+        )
+        k_rope, cos, sin = (
+            jnp.pad(a, ((0, 0), (0, extra), (0, 0))) for a in (k_rope, cos, sin)
+        )
         valid = jnp.pad(valid, ((0, 0), (0, extra)))
     p = p0 + extra
-    t = prefill_tile(p, dk, 1, q.dtype.itemsize)
+    itemsize = q_nope.dtype.itemsize
+    hb, t = latent_prefill_block(p, h, max(dn, dv), dr, itemsize)
     n = p // t
     valid = valid.astype(jnp.int32)
     held = jnp.sum(valid.reshape(b, n, t), axis=2)  # valid keys of each key tile
@@ -135,43 +233,55 @@ def latent_prefill_attention(
         return bi, qi, j
 
     def k_block(bi, j, qi, kk, first_ref, held_ref):
-        return bi, jnp.minimum(first_ref[bi] + kk, qi), j
+        return bi, j, jnp.minimum(first_ref[bi] + kk, qi)
+
+    def shared_block(bi, j, qi, kk, first_ref, held_ref):
+        return bi, jnp.minimum(first_ref[bi] + kk, qi), 0
+
+    def table_block(bi, j, qi, kk, first_ref, held_ref):
+        return bi, qi, 0
 
     def valid_block(bi, j, qi, kk, first_ref, held_ref):
         return bi, 0, jnp.minimum(first_ref[bi] + kk, qi)
 
     out = pl.pallas_call(
         functools.partial(
-            _prefill_kernel, t=t, dk=dk, dv=dv, scale=1.0 / math.sqrt(dk0)
+            _prefill_kernel, t=t, hb=hb, dn=dn, dv=dv, half=half, scale=scale
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(b, h, n, n),
+            grid=(b, h // hb, n, n),
             in_specs=[
-                pl.BlockSpec((1, t, dk), q_block),
-                pl.BlockSpec((1, t, dk), k_block),
-                pl.BlockSpec((1, t, dv), k_block),
+                pl.BlockSpec((1, t, hb * dn), q_block),
+                pl.BlockSpec((1, t, hb * dr), q_block),
+                pl.BlockSpec((1, t, dr), table_block),
+                pl.BlockSpec((1, t, dr), table_block),
+                pl.BlockSpec((1, hb * dn, t), k_block),
+                pl.BlockSpec((1, t, dr), shared_block),
+                pl.BlockSpec((1, hb * dv, t), k_block),
                 pl.BlockSpec((1, 1, t), valid_block),
             ],
-            out_specs=pl.BlockSpec((1, t, dv), q_block),
+            out_specs=pl.BlockSpec((1, t, hb * dv), q_block),
             scratch_shapes=[
-                pltpu.VMEM((1, t, 128), jnp.float32),  # running maximum
-                pltpu.VMEM((1, t, 128), jnp.float32),  # running sum
-                pltpu.VMEM((t, dv), jnp.float32),  # accumulator
+                pltpu.VMEM((hb, t, 128), jnp.float32),  # running maximum
+                pltpu.VMEM((hb, t, 128), jnp.float32),  # running sum
+                pltpu.VMEM((t, hb * dv), jnp.float32),  # accumulator
+                pltpu.VMEM((1, t, hb * dr), q_rope.dtype),  # the rotary lanes, turned
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((b, p, h * dv), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, p, h * dv), q_nope.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
-            vmem_limit_bytes=2 * max(
-                _PREFILL_VMEM, _prefill_vmem(t, dk, 1, q.dtype.itemsize)
-            ),
+            vmem_limit_bytes=2 * max(_PREFILL_VMEM, _prefill_vmem(
+                t, max(dn, dv), hb, itemsize, own_keys=True, rope=dr
+            )),
         ),
         name="latent_prefill_attention",
         interpret=interpret,
     )(
         first, held.reshape(b * n),
-        q.reshape(b, p, h * dk), k.reshape(b, p, h * dk), v.reshape(b, p, h * dv),
+        q_nope.reshape(b, p, h * dn), q_rope.reshape(b, p, h * dr), cos, sin,
+        k_nope.reshape(b, h * dn, p), k_rope, v.reshape(b, h * dv, p),
         valid.reshape(b, 1, p),
     )
     return out[:, :p0] if extra else out

@@ -40,6 +40,7 @@ import pytest
 from pathway_tpu.models import LayerSpec, lm_config
 from pathway_tpu.models import transformer as T
 from pathway_tpu.ops import latent_attention as LA
+from pathway_tpu.ops.rowwise import rope_tables
 
 BENCH = Path(__file__).resolve().parents[1] / "bench"
 sys.path.insert(0, str(BENCH))
@@ -358,35 +359,67 @@ def test_an_identity_pick_adds_its_weight_times_the_row():
 
 # ------------------------------------------- (e) the kernels, interpreted
 
+@pytest.mark.parametrize("h", [1, 3, 4, 8, 11, 16])
 @pytest.mark.parametrize("p, pads", [(256, (0, 130)), (200, (7, 0))])
-def test_the_prefill_kernel_matches_the_jnp_attention(p, pads):
+def test_the_prefill_kernel_matches_the_jnp_attention(p, pads, h):
     """192 lanes of query and key against 128 of value, one rotary key for
-    all heads, rows left-padded by `pads`; a width that 128 does not divide
-    is padded inside."""
-    b, h, dn, dr, dv = len(pads), 3, 128, 64, 128
+    all heads (the leaf's row: 64 lanes and 64 zeros), the query's rotary
+    lanes not turned yet (the kernel turns them), the nope keys and the
+    values with the heads outermost, rows left-padded by `pads`; a width
+    that 128 does not divide is padded inside. By the heads: one (a grid
+    step of one head, the kernel as it was), three, four and eight (one
+    whole block), eleven (no block divides them: one head a grid step,
+    eleven blocks) and sixteen (two blocks of eight)."""
+    b, dn, dr, dv = len(pads), 128, 64, 128
     ks = jax.random.split(jax.random.PRNGKey(p), 5)
     dt = jnp.bfloat16
     q_n, k_n = (jax.random.normal(k, (b, p, h, dn), dt) for k in ks[:2])
     q_r = jax.random.normal(ks[2], (b, p, h, dr), dt)
-    k_r = jnp.broadcast_to(jax.random.normal(ks[3], (b, p, 1, dr), dt), (b, p, h, dr))
+    k_r = jax.random.normal(ks[3], (b, p, dr), dt)
     v = jax.random.normal(ks[4], (b, p, h, dv), dt)
     valid = (jnp.arange(p)[None, :] >= jnp.asarray(pads)[:, None]).astype(jnp.int32)
-    out = LA.latent_prefill_attention(q_n, q_r, k_n, k_r, v, valid, interpret=True)
     cfg = lm_config(
-        vocab_size=16, d_model=12, n_heads=h, n_layers=1, d_ff=8, max_len=512,
+        vocab_size=16, d_model=4 * h, n_heads=h, n_layers=1, d_ff=8, max_len=512,
         dtype=dt, layers=(LayerSpec(mixer="latent", pos="rotary"),),
         latent=T.LatentSpec(q_rank=8, kv_rank=8, nope_dim=dn, rope_dim=dr, v_dim=dv),
     )
+    # the kernel turns the query's rotary lanes itself, by `_rope`'s rule
+    pos = jnp.clip(jnp.cumsum(valid, axis=1) - 1, 0, None)
+    cos, sin = rope_tables(pos, cfg.rope_theta, dr)
+    out = LA.latent_prefill_attention(
+        q_n, T._in_rope_lanes(q_r, cfg), k_n.transpose(0, 2, 3, 1),
+        T._in_rope_lanes(k_r, cfg), v.transpose(0, 2, 3, 1), valid,
+        T._in_rope_lanes(cos, cfg), T._in_rope_lanes(sin, cfg),
+        scale=1.0 / math.sqrt(dn + dr), half=dr // 2, interpret=True,
+    )
     at = jnp.arange(p)
     ok = valid.astype(bool)[:, None, None, :] & (at[None, :] <= at[:, None])[None, None]
+    shared = jnp.broadcast_to(k_r[:, :, None, :], (b, p, h, dr))
     want = T._attend_latent(
-        jnp.concatenate([q_n, q_r], -1), jnp.concatenate([k_n, k_r], -1), v, ok, cfg
+        jnp.concatenate([q_n, T._rope(q_r, pos, cfg)], -1),
+        jnp.concatenate([k_n, shared], -1), v, ok, cfg,
     )
     real = np.asarray(valid, bool)
     np.testing.assert_allclose(
         np.asarray(out, np.float32)[real], np.asarray(want, np.float32)[real],
         atol=0.04, rtol=0,
     )
+
+
+def test_the_block_of_the_prefill_kernel_follows_the_shapes():
+    """`latent_prefill_block` is what its docstring says: at the fourth
+    cell's shape (10,240 positions, 64 heads of 128 nope lanes, the rotary
+    lanes in a tile of 128, bfloat16) and its cap's rung, at head counts no
+    block of eight divides, at a width 512 does not divide, and where the
+    heads are too wide for eight a step."""
+    assert LA.latent_prefill_block(10240, 64, 128, 128) == (8, 512)
+    assert LA.latent_prefill_block(12288, 64, 128, 128) == (8, 512)
+    assert LA.latent_prefill_block(256, 3, 128, 128) == (3, 256)
+    assert LA.latent_prefill_block(256, 11, 128, 128) == (1, 256)
+    assert LA.latent_prefill_block(256, 12, 128, 128) == (6, 256)
+    assert LA.latent_prefill_block(1280, 64, 128, 128) == (8, 256)
+    assert LA.latent_prefill_block(10240, 64, 256, 128) == (4, 512)
+    assert LA.latent_prefill_block(10240, 64, 128, 128, 4) == (4, 512)
 
 
 def test_the_step_kernel_matches_the_jnp_attention():

@@ -569,10 +569,30 @@ def test_a_rotary_decoders_prefill_takes_the_pass_and_its_step_does_not(
 # ------------------------------------------------ the latent mixer (PR 43)
 
 
+def _longcat(*, d_model: int, n_heads: int, q_rank: int, max_len: int):
+    """A double layer of the fourth cell's kind: two latent sub-layers
+    (heads of 128 + 64 over values of 128, a latent row of 512) around a
+    shortcut's expert branch; feed-forward, experts and vocabulary small."""
+    sub = LayerSpec(mixer="latent", pos="rotary", ff="swiglu")
+    return lm_config(
+        vocab_size=512, d_model=d_model, n_heads=n_heads, n_layers=2, d_ff=256,
+        d_expert=256, max_len=max_len, dtype=jnp.bfloat16, tie_embeddings=False,
+        norm_eps=1e-5, router="all", router_bias=True, router_scale=6.0,
+        n_experts=32, n_zero_experts=16, n_active=4, experts_held=(8, 8),
+        expert_act="silu", rope_theta=1e7,
+        latent=T.LatentSpec(q_rank=q_rank, kv_rank=512, nope_dim=128, rope_dim=64,
+                            v_dim=128, q_scale=2.0, kv_scale=2.0),
+        layers=(dataclasses.replace(sub, shortcut="start"),
+                dataclasses.replace(sub, shortcut="land")),
+    )
+
+
 def test_the_latent_kernels_compile_for_v5e(chip):
     """ops/latent_attention.py at the fourth cell's widths: a prefill of
-    10,240 positions and 64 heads of 128 + 64 lanes against values of 128,
-    and a step of 8 slots over leaves of 12,288 latent rows of 512 lanes
+    10,240 positions and 64 heads of 128 nope lanes and 64 rotary lanes in a
+    lane tile against one rotary key and values of 128, keys and values
+    heads-outermost, and a step of 8 slots over leaves of 12,288 latent
+    rows of 512 lanes
     with the rotary key in a lane tile."""
     from pathway_tpu.ops.latent_attention import (
         latent_decode_attention, latent_prefill_attention,
@@ -582,9 +602,12 @@ def test_the_latent_kernels_compile_for_v5e(chip):
         return jax.ShapeDtypeStruct(shape, dt, sharding=chip)
 
     p, h = 10240, 64
-    text = jax.jit(latent_prefill_attention).lower(
-        arg(1, p, h, 128), arg(1, p, h, 64), arg(1, p, h, 128), arg(1, p, h, 64),
-        arg(1, p, h, 128), arg(1, p, dt=jnp.int32),
+    text = jax.jit(
+        functools.partial(latent_prefill_attention, scale=192 ** -0.5, half=32)
+    ).lower(
+        arg(1, p, h, 128), arg(1, p, h, 128), arg(1, h, 128, p), arg(1, p, 128),
+        arg(1, h, 128, p), arg(1, p, dt=jnp.int32),
+        arg(1, p, 128, dt=jnp.float32), arg(1, p, 128, dt=jnp.float32),
     ).compile().as_text()
     assert "%latent_prefill_attention" in text  # the name a device trace shows
     vec = arg(8, dt=jnp.int32)
@@ -604,18 +627,7 @@ def test_a_latent_step_leaves_the_cache_where_it_lies(chip, monkeypatch):
     of their own buffers, and no operation copies a leaf (a `k_rope` leaf 64
     lanes wide was copied there and back every step: `_rope_lanes`)."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    sub = LayerSpec(mixer="latent", pos="rotary", ff="swiglu")
-    cfg = lm_config(
-        vocab_size=512, d_model=256, n_heads=4, n_layers=2, d_ff=512,
-        d_expert=256, max_len=2048, dtype=jnp.bfloat16, tie_embeddings=False,
-        norm_eps=1e-5, router="all", router_bias=True, router_scale=6.0,
-        n_experts=32, n_zero_experts=16, n_active=4, experts_held=(8, 8),
-        expert_act="silu",
-        latent=T.LatentSpec(q_rank=384, kv_rank=512, nope_dim=128, rope_dim=64,
-                            v_dim=128, q_scale=2.0, kv_scale=2.0),
-        layers=(dataclasses.replace(sub, shortcut="start"),
-                dataclasses.replace(sub, shortcut="land")),
-    )
+    cfg = _longcat(d_model=256, n_heads=4, q_rank=384, max_len=2048)
     assert T.latent_step_uses_kernel(cfg) and T.latent_prefill_uses_kernel(cfg, 1280)
     params = _shaped(
         chip, lambda: T.init_params(jax.random.PRNGKey(0), cfg, jnp.bfloat16)
@@ -639,3 +651,98 @@ def test_a_latent_step_leaves_the_cache_where_it_lies(chip, monkeypatch):
         params, ids, ids, cache, jax.ShapeDtypeStruct((), jnp.int32, sharding=chip)
     ).compile().as_text()
     assert "%latent_prefill_attention" in prefill
+
+
+# ------------------------- the accepted cells' attention kernels (PR 44)
+
+# sha256 (16 hex digits) of what the three accepted cells' prefill attention
+# kernels lower to for the chip, AT THE PARENT OF PR 44 (commit 8bc38a2),
+# whose tile body knew one key tile a group only. A PR that means to change
+# one of these kernels reads the new text, says so in PERF.md and pins it
+# here.
+PARENTS_KERNELS = {
+    "rag-cerebras-6b7": "4e7412658ac10281",
+    "rag-smallthinker-21b-a3b global": "55f9091f7b4e6ef4",
+    "rag-smallthinker-21b-a3b window": "6695c61284b8fdfe",
+    "rag-minicpm-sala": "8fdd4741d2b0637d",
+}
+
+
+def _kernels_lowered(cell: str, chip) -> str:
+    """The lowered text of a cell's prefill attention kernel at the cell's
+    shapes, without source locations: a Pallas kernel's serialized body
+    carries them, and they move with every line added above it."""
+    from pathway_tpu.ops.sparse_attention import sparse_prefill_attention
+
+    def arg(*shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=chip)
+
+    def dense(p, heads, kv_heads, window):
+        return jax.jit(functools.partial(prefill_attention, window=window)).lower(
+            arg(1, p, heads, 128), arg(1, p, kv_heads, 128),
+            arg(1, p, kv_heads, 128), arg(1, p, dt=jnp.int32),
+        )
+
+    def sparse(p, heads, kv_heads, block):
+        return jax.jit(functools.partial(sparse_prefill_attention, block=block)).lower(
+            arg(1, p, heads, 128), arg(1, p, kv_heads, 128),
+            arg(1, p, kv_heads, 128), arg(1, p, dt=jnp.int32),
+            arg(1, kv_heads, p, p // block, dt=jnp.bool_),
+        )
+
+    lower = {
+        "rag-cerebras-6b7": lambda: dense(1280, 32, 32, None),
+        "rag-smallthinker-21b-a3b global": lambda: dense(10240, 28, 4, None),
+        "rag-smallthinker-21b-a3b window": lambda: dense(10240, 28, 4, 4096),
+        "rag-minicpm-sala": lambda: sparse(24576, 32, 2, 64),
+    }[cell]
+    limit = jax.config.jax_traceback_in_locations_limit
+    jax.config.update("jax_traceback_in_locations_limit", 0)
+    try:
+        jax.clear_caches()  # a trace made under another limit is not this one
+        return lower().as_text()
+    finally:
+        jax.config.update("jax_traceback_in_locations_limit", limit)
+
+
+@pytest.mark.parametrize("cell", sorted(PARENTS_KERNELS))
+def test_the_accepted_cells_kernels_lower_to_the_parents_text(cell, chip):
+    """`_fold_key_tile` learned that a block's heads may each have key lanes
+    of their own (PR 44), and `_prefill_vmem` what that costs: where the
+    group shares one key tile, as in every accepted cell, the tile, the
+    VMEM limit and the kernel's body are text for text what they were."""
+    import hashlib
+
+    text = _kernels_lowered(cell, chip)
+    assert "tpu_custom_call" in text
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == PARENTS_KERNELS[cell]
+
+
+def test_the_fourth_cells_prefill_builds_no_padded_heads(chip, monkeypatch):
+    """A double layer at the fourth cell's attention widths (d 6144, 64
+    heads of 128 + 64 over values of 128, ranks 1536 and 512; feed-forward
+    and experts cut small), prefilled 10,240 wide and compiled: the kernel
+    is in it, no array of `[10240, 64, 256]` is (the parent built a head's
+    192 lanes in 256 for query and key, 335 MB each a sub-layer, and
+    repeated the rotary key for every head: its temporaries 2.02 GB, these
+    1.40), and nothing copies an operand of the kernel on its way there:
+    the query's nope lanes leave their product as rows of heads, the keys
+    and values theirs with the heads outermost, which is how the kernel
+    reads each."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = _longcat(d_model=6144, n_heads=64, q_rank=1536, max_len=12288)
+    assert T.latent_prefill_uses_kernel(cfg, 10240)
+    params = _shaped(
+        chip, lambda: T.init_params(jax.random.PRNGKey(0), cfg, jnp.bfloat16)
+    )
+    cache = _shaped(chip, lambda: T.init_kv_cache(cfg, 2))
+    ids = jax.ShapeDtypeStruct((1, 10240), jnp.int32, sharding=chip)
+    text = jax.jit(
+        functools.partial(T.prefill_into_slot, cfg=cfg), donate_argnums=(3,)
+    ).lower(
+        params, ids, ids, cache, jax.ShapeDtypeStruct((), jnp.int32, sharding=chip)
+    ).compile().as_text()
+    assert text.count("%latent_prefill_attention") >= 2
+    assert "10240,64,256]" not in text and "10240,16384]" not in text
+    copies = re.findall(r"= bf16\[1,(?:10240,8192|8192,10240)\]\S* copy\(", text)
+    assert not copies, len(copies)
