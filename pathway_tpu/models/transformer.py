@@ -823,12 +823,13 @@ def make_train_step(cfg: TransformerConfig, learning_rate: float = 1e-3):
 # of keys too, but at LOGICAL positions ("k_sparse"/"v_sparse": the prefill
 # turns the left pad behind the prompt), and a pooled key every `stride`
 # positions ("k_pool"); each query scores the pooled keys it sees whole,
-# chooses blocks of rows by them and attends those (`select_blocks`;
-# ops/sparse_attention.py where `sparse_prefill_uses_kernel` /
-# `sparse_step_uses_kernel` hold, `_attend` under the blocks' mask
-# elsewhere; up to `dense_len` positions a row attends every earlier key
-# through the attentions above). A `linear` layer keeps no rows at all: its
-# leaf "state" is a float32 [heads, dh, dh] sum a slot, which a prefill's
+# chooses blocks of rows by them and attends those (`select_blocks`, or
+# its kernel `sparse_select`; ops/sparse_attention.py where
+# `sparse_prefill_uses_kernel` / `sparse_step_uses_kernel` hold, `_attend`
+# under the blocks' mask elsewhere; up to `dense_len` positions a row
+# attends every earlier key through the attentions above). A `linear`
+# layer keeps no rows at all: its leaf "state" is a float32
+# [heads, dh, dh] sum a slot, which a prefill's
 # chunked scan leaves after the last token (`linear_scan`;
 # ops/linear_attention.py where `linear_prefill_uses_kernel` holds) and a
 # step decays and adds to. A `latent` layer keeps rows without a head axis,
@@ -2116,10 +2117,13 @@ def _takes_rowwise(cfg: TransformerConfig, spec: LayerSpec) -> bool:
 
 def sparse_prefill_uses_kernel(cfg: TransformerConfig, width: int) -> bool:
     """Whether the sparse layers of a prefill `width` wide run
-    ops/sparse_attention.py `sparse_prefill_attention` over the blocks each
-    query chose: where `prefill_uses_kernel` holds, at a width past
-    `dense_len` (up to it they run `prefill_attention` as every softmax
-    layer does), for a decoder that has such layers."""
+    ops/sparse_attention.py's two kernels, `sparse_select` for the blocks
+    each query chooses (the set `select_blocks` gives, its scores kept in
+    VMEM) and `sparse_prefill_attention` over those blocks: where
+    `prefill_uses_kernel` holds, at a width past `dense_len` (up to it they
+    run `prefill_attention` as every softmax layer does and choose
+    nothing), for a decoder that has such layers. Elsewhere, and in every
+    step, `select_blocks` chooses."""
     return (
         bool(cfg.n_mixer_layers("sparse"))
         and width > cfg.sparse.dense_len
@@ -2328,7 +2332,9 @@ def _prefill_linear(q, k, v, cache, names, li, live, block, cfg, counters,
 
 # a prefill's selection scores [heads, queries, pooled keys] are float32:
 # at 24,576 tokens 4.8 GB for the whole prompt. The queries go through in
-# chunks whose scores stay under this
+# chunks whose scores stay under this; ops/sparse_attention.py's kernel,
+# which keeps them in VMEM, takes the same chunks, so that the selection is
+# the prefill's one loop either way
 _SELECT_SCORE_BYTES = 256 << 20
 
 
@@ -2374,6 +2380,13 @@ def _prefill_sparse(q, k, v, cache, names, li, valid, pos_idx, cfg, counters,
                 return prefill_attention(q, k, v, valid, None)
             return _attend(q, k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3), mask, cfg)
         with jax.named_scope("select"):
+            select = select_blocks
+            if mask is None:
+                # the same set from ops/sparse_attention.py's kernel, which
+                # keeps the scores in VMEM: imported where it is traced
+                from pathway_tpu.ops.sparse_attention import sparse_select
+
+                select = sparse_select
             chunk = p
             while chunk > 128 and chunk % 2 == 0 and (
                 4 * b * h * chunk * pooled.shape[2] > _SELECT_SCORE_BYTES
@@ -2382,7 +2395,7 @@ def _prefill_sparse(q, k, v, cache, names, li, valid, pos_idx, cfg, counters,
             qg = q.reshape(b, p // chunk, chunk, hk, h // hk, dh)
             tq = jnp.where(real, pos_idx, -1).reshape(b, p // chunk, chunk)
             blocks = jax.lax.map(
-                lambda qt: select_blocks(qt[0], pooled, qt[1], n <= sq.dense_len, sq),
+                lambda qt: select(qt[0], pooled, qt[1], n <= sq.dense_len, sq),
                 (qg.transpose(1, 0, 2, 3, 4, 5), tq.transpose(1, 0, 2)),
             )  # [chunks, b, kv heads, chunk, blocks]
             blocks = blocks.transpose(1, 2, 0, 3, 4).reshape(b, hk, p, -1)

@@ -17,6 +17,10 @@ nothing is lost by that: a query takes its blocks where its own scores
 send it, so among 256 neighbouring queries every key tile under the
 diagonal is somebody's (PERF.md section 5: 0 of 4,278 tile pairs empty at
 24k tokens). What the kernel costs is its rate, and that is the body's.
+
+The chosen blocks themselves come from `sparse_select` below, the
+selection's arithmetic kept in VMEM, and a step's selected-block attention
+from `sparse_decode_attention`.
 """
 
 from __future__ import annotations
@@ -189,6 +193,234 @@ def sparse_prefill_attention(
         valid.reshape(b, 1, p), blocks,
     )
     return out[:, :p0] if extra else out
+
+
+# ------------------------------------------------- the prefill's selection
+#
+# Which blocks each query of a prefill attends, models/transformer.py
+# `select_blocks` as one kernel. Written in XLA, its float32 scores [heads,
+# queries, pooled keys] cross HBM several times and its ranking counts every
+# pair of blocks (PERF.md section 5: 49.8 ms of a 24k-token prefill on a
+# v5e). A grid step here holds a tile of queries, its key head's pooled keys
+# and everything between them in VMEM, and finds the topk-th score by
+# bisection. Queries are lanes; pooled keys, and the blocks, are sublanes.
+
+_SELECT_TILE = 256  # queries a grid step holds, where the chunk has them
+_SELECT_KEY_BLOCKS = 64  # blocks of pooled keys a pass over them takes
+
+
+def _select_kernel(need_ref, dense_ref, q_ref, pool_ref, t_ref, o_ref,
+                   s_ref, rel_ref, score_ref, reach_ref,
+                   *, group: int, dh: int, m: int, before: int, bc: int,
+                   sq):
+    """One grid step (row, key head, query tile): the blocks each of the
+    tile's queries chooses, [tq, blocks] 1/0. The pooled keys come in chunks
+    of `bc` blocks, and within a chunk the j-th pooled key of every block lies
+    in rows j * bc .. (j + 1) * bc - 1, so that a block's score is the
+    maximum of m slices. Only the chunks that the tile's last query sees into
+    (`need`) are scored."""
+    bi, qi = pl.program_id(0), pl.program_id(2)
+    need = need_ref[bi * pl.num_programs(2) + qi]
+    t = t_ref[0]  # [1, tq] the queries' logical positions, -1 a pad
+    tq = t.shape[1]
+    rows = m * bc
+    r = jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
+    # the pooled key of a chunk's row r, counted from the chunk's first
+    within = (r & (bc - 1)) * m + (r >> (bc.bit_length() - 1))
+    rel_ref[...] = jnp.zeros(rel_ref.shape, jnp.float32)
+
+    def seen(c):
+        return sq.stride * (c * rows + within) + sq.kernel - 1 <= t
+
+    def chunk(c):
+        return pl.ds(pl.multiple_of(c * rows, rows), rows)
+
+    # as `select_blocks`: each head's softmax over the pooled keys it sees,
+    # from scores in float32 divided by sqrt(dh), summed over the group
+    def head(hh, carry):
+        qh = q_ref[0, :, pl.ds(pl.multiple_of(hh * dh, 128), dh)]  # [tq, dh]
+
+        def scores(c, mx):
+            s = jax.lax.dot_general(
+                pool_ref[0, 0, chunk(c), :], qh, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ) / math.sqrt(dh)
+            s = jnp.where(seen(c), s, _MASKED)
+            s_ref[chunk(c), :] = s
+            return jnp.maximum(mx, jnp.max(s, axis=0, keepdims=True))
+
+        mx = jax.lax.fori_loop(
+            0, need, scores, jnp.full((1, tq), -jnp.inf, jnp.float32)
+        )
+
+        def exps(c, total):
+            e = jnp.exp(s_ref[chunk(c), :] - mx)
+            s_ref[chunk(c), :] = e
+            return total + jnp.sum(e, axis=0, keepdims=True)
+
+        total = jax.lax.fori_loop(0, need, exps, jnp.zeros((1, tq), jnp.float32))
+
+        def relevance(c, carry):
+            rel_ref[chunk(c), :] += jnp.where(seen(c), s_ref[chunk(c), :] / total, 0.0)
+            return carry
+
+        return jax.lax.fori_loop(0, need, relevance, carry)
+
+    jax.lax.fori_loop(0, group, head, 0)
+    # a block's score: the largest relevance among the pooled keys that
+    # start in it and those that reach in from the block before
+    for c in range(score_ref.shape[0] // bc):
+        part = [rel_ref[c * rows + j * bc:c * rows + (j + 1) * bc, :] for j in range(m)]
+        score_ref[c * bc:(c + 1) * bc, :] = functools.reduce(jnp.maximum, part)
+        if before:
+            reach_ref[c * bc:(c + 1) * bc, :] = functools.reduce(
+                jnp.maximum, part[m - before:]
+            )
+    nb = score_ref.shape[0]
+    blk = jax.lax.broadcasted_iota(jnp.int32, (nb, 1), 0)
+    score = score_ref[...]
+    if before:
+        reach = pltpu.roll(reach_ref[...], 1, 0)  # the block before's
+        score = jnp.maximum(score, jnp.where(blk == 0, 0.0, reach))
+    visible = blk * sq.block <= t
+    forced = (blk < sq.init_blocks) | ((blk + sq.local_blocks) * sq.block > t)
+    score = jnp.where(visible, jnp.where(forced, jnp.inf, score), -jnp.inf)
+    # the scores as int32 in the same order, and the topk-th largest of each
+    # query's by bisection over them, from the sign bit down
+    bits = jax.lax.bitcast_convert_type(score, jnp.int32)
+    key = bits ^ ((bits >> 31) & 0x7FFFFFFF)
+
+    def count(at_least):
+        return jnp.sum((key >= at_least).astype(jnp.int32), axis=0, keepdims=True)
+
+    def bisect(i, at):
+        up = at | (jnp.int32(1) << (30 - i))
+        return jnp.where(count(up) >= sq.topk, up, at)
+
+    kth = jax.lax.fori_loop(0, 31, bisect, jnp.where(
+        count(0) >= sq.topk, 0, jnp.iinfo(jnp.int32).min
+    ))
+    # every block above it, and of those equal to it the lowest: a running
+    # count of the equal ones along the blocks, as one product
+    above, tie = key > kth, key == kth
+    lower = (
+        jax.lax.broadcasted_iota(jnp.int32, (nb, nb), 1)
+        < jax.lax.broadcasted_iota(jnp.int32, (nb, nb), 0)
+    ).astype(jnp.float32).astype(jnp.bfloat16)
+    tied_before = jnp.dot(
+        lower, tie.astype(jnp.float32).astype(jnp.bfloat16),
+        preferred_element_type=jnp.float32,
+    )
+    ahead = jnp.sum(above.astype(jnp.float32), axis=0, keepdims=True)
+    chosen = (above | (tie & (ahead + tied_before < sq.topk))) & visible
+    chosen = chosen | (visible & (dense_ref[bi] != 0))  # a dense row: all it sees
+    o_ref[0, 0] = chosen.astype(jnp.int32).T
+
+
+def _select_vmem(tq: int, group: int, dh: int, n_pool: int, nb: int,
+                 itemsize: int) -> int:
+    """VMEM of one grid step of `sparse_select`, in bytes: its operands and
+    result double-buffered, its four scratch arrays, and what the ranking
+    holds at once (the blocks' keys, masks and running counts, and the
+    triangle of the count of ties)."""
+    io = 2 * (tq * group * dh * itemsize + n_pool * dh * itemsize + tq * 4 + tq * nb * 4)
+    scratch = 2 * n_pool * tq * 4 + 2 * nb * tq * 4
+    live = 6 * nb * tq * 4 + nb * nb * 6
+    return io + scratch + live
+
+
+@functools.partial(jax.jit, static_argnames=("sq", "key_blocks", "interpret"))
+def sparse_select(
+    q: jax.Array,  # [b, nq, kv heads, group, dh]
+    pooled: jax.Array,  # [b, kv heads, n_pool, dh]: models/transformer.py `pool_keys`
+    t: jax.Array,  # [b, nq] int32: the queries' logical positions, -1 a pad
+    dense: jax.Array,  # [b] bool: the rows that attend every earlier position
+    sq,  # models/transformer.py `SparseSpec`
+    *,
+    key_blocks: int = _SELECT_KEY_BLOCKS,
+    interpret: bool = False,
+) -> jax.Array:
+    """models/transformer.py `select_blocks` as one kernel: the blocks each
+    query attends, [b, kv heads, nq, n_pool * stride / block] bool, one set
+    for a group, by the same arithmetic up to the ranking (scores from the
+    inputs' dtype with float32 accumulation divided by sqrt(dh), the softmax
+    over the pooled keys a query sees in float32, summed over the group's
+    heads; a block's score the largest of its pooled keys' and the one that
+    reaches in; init and local blocks first, nothing after the query's own)
+    and the same set: the `topk` best, of equal scores the lower block. The
+    sums run in another order, so a block whose score is within float32
+    rounding of the topk-th may fall the other way.
+
+    No sort and no count over pairs of blocks: the topk-th score of each
+    query is found by bisection over an int32 key of the same order (32
+    passes of compare and count over [blocks, tile]), every block above it
+    is taken, and of those equal to it the lowest up to `topk`. A grid step
+    scores only the chunks of `key_blocks` blocks that the tile's last query
+    sees into (the causal half). dh must be a multiple of 128; queries and
+    blocks are padded inside to whole lane tiles."""
+    b, nq0, hk, group, dh = q.shape
+    n_pool = pooled.shape[2]
+    m = sq.block // sq.stride  # pooled keys that start in a block
+    before = -(-sq.kernel // sq.stride) - 1  # and those that reach in from the last
+    nb0 = n_pool // m
+    bc = key_blocks
+    if dh % 128 or 128 % bc or bc * m % 16:
+        raise ValueError(f"sparse_select needs heads of a multiple of 128 lanes "
+                         f"and chunks of whole tiles, got {dh} and {bc} x {m}")
+    nb = -(-nb0 // 128) * 128
+    rows = m * bc
+    # blocks past the prompt's: zero keys that nobody sees, in no query's set
+    pooled = jnp.pad(pooled, ((0, 0), (0, 0), (0, nb * m - n_pool), (0, 0)))
+    pooled = pooled.reshape(b, hk, nb // bc, bc, m, dh).transpose(0, 1, 2, 4, 3, 5)
+    pooled = pooled.reshape(b, hk, nb * m, dh)
+    extra = -nq0 % 128
+    q = q.reshape(b, nq0, hk * group * dh)
+    t = t.astype(jnp.int32)
+    if extra:
+        q = jnp.pad(q, ((0, 0), (0, extra), (0, 0)))
+        t = jnp.pad(t, ((0, 0), (0, extra)), constant_values=-1)
+    nq = nq0 + extra
+    tq = _SELECT_TILE if nq % _SELECT_TILE == 0 else 128
+    n = nq // tq
+    # the chunks of pooled keys whose first window ends at or before the
+    # tile's last query
+    last = jnp.max(t.reshape(b, n, tq), axis=2)
+    need = jnp.clip(
+        (last - (sq.kernel - 1)) // (sq.stride * rows) + 1, 0, nb // bc
+    ).astype(jnp.int32)
+    vmem = _select_vmem(tq, group, dh, nb * m, nb, q.dtype.itemsize)
+    out = pl.pallas_call(
+        functools.partial(
+            _select_kernel, group=group, dh=dh, m=m, before=before, bc=bc, sq=sq,
+        ),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(b, hk, n),
+            in_specs=[
+                pl.BlockSpec((1, tq, group * dh), lambda bi, j, qi, *_: (bi, qi, j)),
+                pl.BlockSpec((1, 1, nb * m, dh), lambda bi, j, qi, *_: (bi, j, 0, 0)),
+                pl.BlockSpec((1, 1, tq), lambda bi, j, qi, *_: (bi, 0, qi)),
+            ],
+            out_specs=pl.BlockSpec((1, 1, tq, nb), lambda bi, j, qi, *_: (bi, j, qi, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((nb * m, tq), jnp.float32),  # one head's scores
+                pltpu.VMEM((nb * m, tq), jnp.float32),  # the group's relevance
+                pltpu.VMEM((nb, tq), jnp.float32),  # the blocks' own maxima
+                pltpu.VMEM((nb, tq), jnp.float32),  # what reaches into the next
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((b, hk, nq, nb), jnp.int32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel"),
+            vmem_limit_bytes=2 * max(_PREFILL_VMEM, vmem),
+        ),
+        name="sparse_select",
+        interpret=interpret,
+    )(
+        need.reshape(b * n), jnp.reshape(dense, (b,)).astype(jnp.int32),
+        q, pooled, t.reshape(b, 1, nq),
+    )
+    return out[:, :, :nq0, :nb0] != 0
 
 
 # ------------------------------------------------- decode-step attention

@@ -14,7 +14,9 @@ dense up to 32 positions.
 (b) a slot taken again by the batcher serves what a fresh pool serves;
 (c) the chunked scan is the recurrence, and a step is one more position;
 (d) the two kernels, interpreted, against the `jax.numpy` formulas;
-(e) what the selection always takes, how much, and for whom;
+(e) what the selection always takes, how much, and for whom, and the
+    selection's kernel (ops/sparse_attention.py `sparse_select`),
+    interpreted, choosing `select_blocks`' set element for element;
 (f) the row-wise pass over q and k (ops/rowwise.py) and the scan kernel's
     output norm round where the program a TPU runs today rounds (`_rmsnorm`,
     `_rope` and `_linear_out` with float32 between the first two), at the
@@ -161,7 +163,7 @@ def _interpret_the_prefill_kernels(monkeypatch):
     monkeypatch.setattr(T, "prefill_uses_kernel", lambda cfg, p: True)
     for module, name in (
         (A, "prefill_attention"), (L, "linear_prefill_attention"),
-        (S, "sparse_prefill_attention"), (R, "rowwise_heads"),
+        (S, "sparse_prefill_attention"), (S, "sparse_select"), (R, "rowwise_heads"),
     ):
         monkeypatch.setattr(
             module, name, functools.partial(getattr(module, name), interpret=True)
@@ -175,8 +177,9 @@ def test_slot_cache_through_the_kernels_matches_the_plain_reference(
 ):
     """`_prefill` with the rule saying kernel (as on a TPU; interpreted
     here): the row-wise pass over q and k, the scan's kernel with the output
-    norm in it, the selected-block kernel past `dense_len` and
-    `prefill_attention` up to it, the state, rows and pooled keys they leave
+    norm in it, the selection's kernel and the selected-block kernel past
+    `dense_len` and `prefill_attention` up to it, the state, rows and pooled
+    keys they leave
     and the steps behind them, against the family's reference."""
     _interpret_the_prefill_kernels(monkeypatch)
     cfg = FAMILY.program_config(
@@ -461,6 +464,77 @@ def test_the_selection_is_the_references():
         q[None], pooled, jnp.arange(s)[None], jnp.asarray([False]), SQ
     )[0]
     assert np.array_equal(np.asarray(ours), np.asarray(theirs))
+
+
+def _selection_case(case: str):
+    """(q [b, nq, kv heads, group, 128], pooled, t [b, nq], dense [b]) bf16,
+    as `_prefill_sparse` hands a chunk to the selection: pooled keys of a
+    whole prompt of 512 positions (64 blocks) from its first real token."""
+    rng = np.random.default_rng(len(case))
+    b, p, hk, g, dh = 2, 512, 2, 2, 128
+    q = 2 * rng.standard_normal((b, p, hk, g, dh))
+    k = rng.standard_normal((b, hk, p, dh))
+    pads = np.zeros(b, np.int64)
+    dense = [False, False]
+    if case == "ties":
+        # whole numbers, so that every product is exact, and one key at
+        # every position: every pooled key a query sees scores alike to the
+        # bit, and so does every block it sees whole
+        q = rng.integers(-2, 3, (b, p, hk, g, dh))
+        k = np.broadcast_to(rng.integers(-2, 3, (b, hk, 1, dh)), (b, hk, p, dh))
+    if case == "left_pads":
+        pads = np.asarray([37, 301])
+    if case == "dense_row":
+        dense = [True, False]
+    t = np.arange(p)[None, :] - pads[:, None]
+    t = np.where(t >= 0, t, -1)
+    if case == "later_chunk":  # the third chunk of 128 queries of the prompt
+        q, t = q[:, 256:384], t[:, 256:384]
+    if case == "few_blocks":  # 40 queries: none sees topk blocks yet
+        q, t = q[:, :40], t[:, :40]
+    pooled = T.pool_keys(jnp.asarray(k, jnp.bfloat16), SQ)
+    return (
+        jnp.asarray(q, jnp.bfloat16), pooled, jnp.asarray(t, jnp.int32),
+        jnp.asarray(dense),
+    )
+
+
+@pytest.mark.parametrize(
+    "case", ["random", "left_pads", "dense_row", "later_chunk", "ties", "few_blocks"]
+)
+def test_the_selection_kernel_chooses_select_blocks_set(case):
+    """ops/sparse_attention.py `sparse_select`, interpreted, with chunks of
+    16 blocks of pooled keys (so that a query tile scores only the chunks it
+    sees into): element for element `select_blocks`' set, and what the
+    selection always takes and how much (the init block, the local blocks,
+    nothing after the query, min(topk, blocks at or before it))."""
+    q, pooled, t, dense = _selection_case(case)
+    want = np.asarray(T.select_blocks(q, pooled, t, dense, SQ))
+    got = np.asarray(
+        S.sparse_select(q, pooled, t, dense, SQ, key_blocks=16, interpret=True)
+    )
+    assert got.shape == want.shape == (*q.shape[:1], q.shape[2], q.shape[1], 64)
+    assert np.array_equal(got, want)
+    t = np.asarray(t)
+    for row in range(got.shape[0]):
+        for i, at in enumerate(t[row]):
+            chosen = got[row, :, i]
+            if at < 0:  # a pad chooses nothing
+                assert not chosen.any()
+                continue
+            own = at // SQ.block
+            assert chosen[:, 0].all()
+            assert chosen[:, max(own - SQ.local_blocks + 1, 0):own + 1].all()
+            assert not chosen[:, own + 1:].any()
+            every = bool(dense[row])
+            assert (chosen.sum(-1) == (own + 1 if every else min(SQ.topk, own + 1))).all()
+            if case == "ties" and own >= SQ.topk:
+                # the others all tie, and the lowest of them are taken
+                forced = {*range(SQ.init_blocks), *range(own - SQ.local_blocks + 1, own + 1)}
+                lowest = [blk for blk in range(own) if blk not in forced]
+                lowest = lowest[:SQ.topk - len(forced)]
+                assert {*np.flatnonzero(chosen[0])} == forced | {*lowest}
+                assert (chosen[0] == chosen[1]).all()
 
 
 # ------------------------------------ the step's kernel over chosen blocks

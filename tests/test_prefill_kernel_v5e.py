@@ -398,6 +398,41 @@ def test_the_selected_block_kernel_compiles_for_v5e(
     assert 'custom_call_target="tpu_custom_call"' in text
 
 
+# the third cell's selection: a chunk of 768 queries as `_prefill_sparse`
+# hands it over and the whole prompt at once; queries that are no whole
+# tile against 512 blocks (padded inside); two rows in float32
+@pytest.mark.parametrize("b, nq, heads, kv_heads, n_pool, dtype", [
+    (1, 768, 32, 2, 1536, jnp.bfloat16),
+    (1, 24576, 32, 2, 1536, jnp.bfloat16),
+    (1, 1023, 32, 2, 2048, jnp.bfloat16),
+    (2, 512, 4, 2, 256, jnp.float32),
+])
+def test_the_selection_kernel_compiles_for_v5e(
+    b, nq, heads, kv_heads, n_pool, dtype, chip
+):
+    """`sparse_select` at InfLLM v2's sizes (blocks of 64, pooled keys every
+    16 of 32, top-64). A grid step holds the group's 256 queries, its key
+    head's pooled keys and what lies between them: at the cell's shape
+    10.3 MB of VMEM by the kernel's own sum (`_select_vmem`), under the
+    16 MB of the prefill kernels' budget, and the limit it asks is twice
+    that budget."""
+    from pathway_tpu.ops import sparse_attention as S
+
+    def arg(*shape, dt=dtype):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=chip)
+
+    group = heads // kv_heads
+    compiled = jax.jit(functools.partial(S.sparse_select, sq=T.SparseSpec())).lower(
+        arg(b, nq, kv_heads, group, 128), arg(b, kv_heads, n_pool, 128),
+        arg(b, nq, dt=jnp.int32), arg(b, dt=jnp.bool_),
+    ).compile()
+    text = compiled.as_text()
+    assert "%sparse_select" in text
+    assert 'custom_call_target="tpu_custom_call"' in text
+    if (nq, heads, n_pool) == (24576, 32, 1536):  # a grid step of the cell's
+        assert 10 << 20 < S._select_vmem(256, 16, 128, 1536, 384, 2) < 11 << 20
+
+
 def _sala(**kw):
     """The third cell's decoder with its slot cache ([1, 8, 2, 32768, 128] x
     2 of rows, pooled keys, [3, 8, 32, 128, 128] float32 of states), its
@@ -458,9 +493,10 @@ def test_the_third_cells_step_reads_and_writes_the_cache_where_it_lies(
 def test_the_third_cells_prefill_holds_its_three_kernels(chip, monkeypatch):
     """A prefill past `dense_len` (lowered, not compiled: the feed-forward
     is cut small, the rest is the cell's) calls the scan's kernel once for
-    its three linear layers' one lowering and the selected-block kernel, and
-    no `prefill_attention`; a prefill up to `dense_len` calls
-    `prefill_attention` in the sparse layer's place."""
+    its three linear layers' one lowering, the selection's kernel and the
+    selected-block kernel, and no `prefill_attention`; a prefill up to
+    `dense_len` calls `prefill_attention` in the sparse layer's place and
+    chooses nothing."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     cfg = _sala(max_len=16384)
     params = _shaped(
@@ -481,15 +517,22 @@ def test_the_third_cells_prefill_holds_its_three_kernels(chip, monkeypatch):
     long = lowered(10240)
     assert calls(long, "linear_prefill_attention") and calls(long, "sparse_prefill_attention")
     assert not calls(long, "prefill_attention")
+    # the selection is ops/sparse_attention.py's kernel too, the body of the
+    # prefill's one loop over chunks of queries
+    assert calls(long, "sparse_select")
+    assert len(re.findall(r"stablehlo\.while", long)) == 1
     # q and k of every layer through ops/rowwise.py's pass: four lowerings
     # (a sparse layer's q and k, normed; a linear layer's, rotated too and
     # k's pads zeroed), and the cosines and sines made once
     assert T.rowwise_uses_kernel(cfg, 10240)
-    assert long.count("tpu_custom_call") == 1 + 1 + 4
+    assert long.count("tpu_custom_call") == 1 + 1 + 1 + 4
     assert len(re.findall(r"stablehlo\.cosine", long)) == 1
     short = lowered(1024)
     assert calls(short, "linear_prefill_attention") and calls(short, "prefill_attention")
     assert not calls(short, "sparse_prefill_attention")
+    # up to `dense_len` nothing is chosen: no selection, kernel or loop
+    assert not calls(short, "sparse_select")
+    assert "stablehlo.while" not in short
 
 
 # ------------------------------------------------ who takes the rowwise pass
