@@ -37,17 +37,30 @@ replaces that with a **slot scheduler**:
 fixed number of steps, so the host knows every slot's position, pad length
 and last step without reading a token. The order of one turn of the loop:
 at the boundary, dispatch the prefill of one queued request if a slot is
-free, then *read the oldest program out, if more than* ``_AHEAD`` *are*;
-prepare ``pos`` and ``pad_len``, dispatch the step, give back the slots
-whose last step that was, then read again in the same way. A result is read
-``_AHEAD`` programs late: ``np.asarray`` on program n returns when n has run,
-by which time n + 1 and n + 2 are queued behind it and the device goes from
-one to the next without the host (the thread's wake-up, the accounting, the
-lock, the next boundary's preparation and dispatch all run while n + 1
-does, and a burst in which the thread does not get the interpreter for
-longer than a step is covered by n + 2). The host still follows the device
-program for program, since every read blocks until its program has run: a
-reply leaves when its last step ends, not later. ``_AHEAD`` is two, fixed in
+free, then read what the rule below lets it read; prepare ``pos`` and
+``pad_len``, dispatch the step, give back the slots whose last step that
+was, then read again in the same way. **The rule**: the oldest program out
+is read, and read first, once more than ``_AHEAD`` are out, except a
+prefill with only steps behind it: that one is waited for once a later
+prefill is out behind it too, or once nothing is left to dispatch. A step
+is read ``_AHEAD`` programs late: ``np.asarray`` on step n returns when n
+has run, by which time n + 1 and n + 2 are queued behind it and the device
+goes from one to the next without the host (the thread's wake-up, the
+accounting, the lock, the next boundary's preparation and dispatch all run
+while n + 1 does, and a burst in which the thread does not get the
+interpreter for longer than a step is covered by n + 2). A prefill runs as
+long as dozens of steps, so the host does not stand in it with two steps
+queued: it dispatches the steps of the prefill's cycle and the next
+boundary's prefill while the prefill runs, and the device finds them
+queued when it ends, whatever the host is doing then. Where answers have
+steps, at most two prefills are out (the one waited for and the one that
+let the loop wait for it); a run of prefills alone (answers of one token)
+is read ``_AHEAD`` late like steps. With no queue the steps behind an
+unread prefill stop at the active requests' last steps. The device runs
+the same programs in the same order under any rule: only the reads move.
+The host still follows the device program for program, since every read
+blocks until its program has run: a reply leaves when its last step has
+been read, in the order the programs went out. ``_AHEAD`` is two, fixed in
 the code. What lags with the read: a request's tokens, its device
 counters, ``prefills`` / ``decode_steps`` / ``completed`` and its clocks,
 and its reply, which never leaves before its last token is on the host;
@@ -65,6 +78,10 @@ its last step out and unread) and gives every held slot back.
 before them still running (``jax.Array.is_ready()`` false on its result
 once the new one was out): over ``decode_steps + prefills`` it is near 1 in
 a backlog, and where it is low the host is the pace.
+``stats["dispatched_past_prefill"]`` counts the dispatches made while more
+than ``_AHEAD`` programs were out behind an unread prefill: those before
+which a reader of the oldest program at a depth of ``_AHEAD`` would have
+waited for the prefill.
 
 Both programs ride the device plane: the compile ledger proves a request
 joining mid-generation costs **zero new XLA compilations** (the step
@@ -99,8 +116,10 @@ pool that spans a mesh runs its programs with ``fused_attention`` off
 leaf phases (:data:`PHASES`): each opens a profiler span (visible when a
 JAX profiler session is active) and adds its wall time to a cumulative
 ``stats`` key, always on like the counts beside it. The two waits are the
-reads above, the only places the thread blocks on the device: each waits
-for a program ``_AHEAD`` *before* the one just dispatched. ``loop_s`` is the
+reads above, the only places the thread blocks on the device: a step's
+wait is for the step ``_AHEAD`` *before* the one just dispatched, a
+prefill's for a prefill with its cycle's steps and the next prefill queued
+behind it (or the last programs, when nothing is left). ``loop_s`` is the
 loop's own wall time, ``host_cpu_s`` the thread's CPU time outside the
 two waits for the device, and ``queue_wait_s`` / ``first_token_s`` /
 ``residence_s`` sum each request's life from ``submit`` (counted by
@@ -162,6 +181,7 @@ is future work and the chat constructor routes accordingly).
 
 from __future__ import annotations
 
+import itertools
 import threading
 import time
 from collections import deque
@@ -196,11 +216,15 @@ CPU_KEYS = {
 # seconds of loop time between two refreshes of the mirror: a reader windows
 # it over tens of seconds, and a refresh reads every thread's clock
 _CPU_EVERY_S = 1.0
-# programs the loop keeps dispatched and unread. One is enough where the
-# host's time a dispatch is always under the running program's; where it is
-# so only on average (a step of 8.75 ms, a host of 6 that waits for the
-# interpreter in bursts), a second carries the device over the bursts
-# (PERF.md section 6, PR 37: 0.75 -> 0.88 of the dispatches ahead, +2.7%)
+# programs the loop keeps dispatched and unread behind the oldest step. One
+# is enough where the host's time a dispatch is always under the running
+# program's; where it is so only on average (a step of 8.75 ms, a host of 6
+# that waits for the interpreter in bursts), a second carries the device
+# over the bursts (PERF.md section 6: 0.75 -> 0.88 of the dispatches ahead
+# and 2.7% more answers in a backlog). An unread prefill is not read at
+# this depth: the loop waits for it once a later prefill is out as well
+# (`_read_behind`), so that its whole cycle is queued behind it and the
+# host's bursts fall while it runs
 _AHEAD = 2
 
 
@@ -388,6 +412,9 @@ class ContinuousBatcher:
             "prefills": 0, "max_queue": 0,
             # dispatches that found the program before them still running
             "dispatched_ahead": 0,
+            # dispatches made with more than _AHEAD programs out behind an
+            # unread prefill, where a read at that depth would have waited
+            "dispatched_past_prefill": 0,
             **dict.fromkeys(PHASES, 0.0),
             "loop_s": 0.0, "host_cpu_s": 0.0,
             # the process's other CPU time while the loop ran, by the role
@@ -769,6 +796,8 @@ class ContinuousBatcher:
         one to the other without the host."""
         if self._out and not self._out[-1].out.is_ready():
             self.stats["dispatched_ahead"] += 1
+        if len(self._out) > _AHEAD and self._out[0].batch is None:
+            self.stats["dispatched_past_prefill"] += 1
         # its way to the host starts when it ends, not when it is asked for
         out.out.copy_to_host_async()
         self._out.append(out)
@@ -785,8 +814,14 @@ class ContinuousBatcher:
 
     def _read_behind(self, keep: int = _AHEAD) -> None:
         """Read the programs behind the newest `keep`, oldest first: each
-        has run, or is running with the newer ones queued behind it."""
+        has run, or is running with the newer ones queued behind it. A
+        prefill with only steps behind it is left out until `keep` is 0
+        (module docstring: the rule)."""
         while len(self._out) > keep:
+            if keep and self._out[0].batch is None and all(
+                d.batch is not None for d in itertools.islice(self._out, 1, None)
+            ):
+                return
             self._read(self._out.popleft())
 
     def _read(self, done: _Dispatched) -> None:

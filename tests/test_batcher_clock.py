@@ -94,7 +94,7 @@ def test_stats_hold_every_key_from_construction():
     cb = _chat()._cb
     counts = {
         "submitted", "completed", "decode_steps", "prefills", "max_queue",
-        "dispatched_ahead",
+        "dispatched_ahead", "dispatched_past_prefill",
         "prompt_tokens", "padded_tokens", "kernel_prefills", "kernel_steps",
         "kernel_expert_prefills", "kernel_linear_prefills",
         "kernel_sparse_prefills", "kernel_sparse_steps",

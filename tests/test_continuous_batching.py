@@ -516,14 +516,66 @@ def test_a_boundary_runs_one_prefill_and_slots_finish_a_step_apart(
 # ------------------------------------------ programs dispatched ahead of the read
 
 
+def _replayed(events):
+    """Replays a `_logged` order: the programs out (oldest first, "P" or
+    "S") just before each dispatch and just before each read, as strings,
+    and whether a dispatch came after that read. Asserts that results are
+    read in the order their programs went out."""
+    out, at_dispatch, at_read = "", [], []
+    for e in events:
+        if e[0] in "PS":
+            at_dispatch.append(out)
+            out += e[0]
+        else:
+            assert out[:1] == e.upper(), events
+            at_read.append(out)
+            out = out[1:]
+    n_sent = [i for i, e in enumerate(events) if e[0] in "PS"]
+    last_sent = n_sent[-1] if n_sent else -1
+    reads = [i for i, e in enumerate(events) if e in "ps"]
+    dispatch_after = [i < last_sent for i in reads]
+    assert out == "", events  # everything out is read
+    return at_dispatch, at_read, dispatch_after
+
+
+def _assert_the_rule(events):
+    """The read rule, both ways. Before every dispatch the programs out are
+    at most `_AHEAD`, or a prefill with only steps behind it; a read with
+    more dispatches after it is of a step with more than `_AHEAD` out, or
+    of a prefill with more than `_AHEAD` out and a later prefill among
+    them. Returns the programs out before each dispatch."""
+    at_dispatch, at_read, dispatch_after = _replayed(events)
+    for out in at_dispatch:
+        assert len(out) <= _AHEAD or (
+            out[0] == "P" and "P" not in out[1:]
+        ), (out, events)
+    for out, more in zip(at_read, dispatch_after):
+        if more:
+            assert len(out) > _AHEAD, (out, events)
+            assert out[0] == "S" or "P" in out[1:], (out, events)
+    return at_dispatch
+
+
+def _read_at_depth(cb):
+    """The reader that waits for any oldest program, a prefill too, once
+    more than `_AHEAD` are out: what the rule departs from."""
+
+    def read_behind(keep=_AHEAD):
+        while len(cb._out) > keep:
+            cb._read(cb._out.popleft())
+
+    cb._read_behind = read_behind
+
+
 def test_programs_are_dispatched_ahead_of_the_read():
-    """Three requests of three tokens over two slots, the whole order: each
-    result is read after the next two programs went out; the slot whose
-    last step is out (0, after the second step) takes the third prefill at
-    that very boundary, before that step is read; the last programs are
-    read with nothing behind them, and only then does the thread leave."""
-    assert _AHEAD == 2  # the order below is that depth's
-    chat = _chat(decode_slots=2, max_new_tokens=3)
+    """Three requests of five tokens over two slots, the whole order: a
+    step is read after the next two programs went out; the first prefill
+    is read at the second's dispatch, the second at the third's, and the
+    third, with only steps behind it and nothing queued, when nothing is
+    left to dispatch; the slot whose last step is out takes the next
+    prefill at that very boundary, before that step is read; and only
+    then does the thread leave."""
+    chat = _chat(decode_slots=2, max_new_tokens=5)
     cb = chat._cb
     cb.drain()
     events, done_at = _logged(cb)
@@ -531,11 +583,14 @@ def test_programs_are_dispatched_ahead_of_the_read():
     got = [f.result(timeout=120) for f in _held_back(cb, prompts)]
     cb.drain()
     assert got == chat._generate_batch(prompts)
-    assert " ".join(events) == "P0 S P1 p S s P0 p S s S p s s"
-    assert done_at == [2, 3, 4]
+    assert " ".join(events) == (
+        "P0 S P1 p S s S S P0 p s s S s S S S p s s s s"
+    )
+    _assert_the_rule(events)
+    assert done_at == [4, 5, 8]
     s = cb.stats
-    assert (s["prefills"], s["decode_steps"], s["completed"]) == (3, 4, 3)
-    assert 0 <= s["dispatched_ahead"] <= 6  # all but the first may be
+    assert (s["prefills"], s["decode_steps"], s["completed"]) == (3, 8, 3)
+    assert 0 <= s["dispatched_ahead"] <= 10  # all but the first may be
     assert not cb._out and not cb._leaving | set(cb._active)
     assert cb.pool.snapshot()["active"] == 0 and cb.queue_depth() == 0
 
@@ -543,10 +598,10 @@ def test_programs_are_dispatched_ahead_of_the_read():
 @pytest.mark.parametrize("new", [1, 2, 5])
 @pytest.mark.parametrize("slots", [1, 2])
 def test_reading_late_keeps_the_tokens_at_the_edges(new, slots):
-    """A queue deeper than the pool, and the edges of reading `_AHEAD`
-    programs late: an answer that is its prefill's token alone (no step is
-    ever dispatched, and its slot is free as soon as the prefill is out),
-    and one whose only step is its last."""
+    """A queue deeper than the pool, and the edges of reading late: an
+    answer that is its prefill's token alone (no step is ever dispatched,
+    and its slot is free as soon as the prefill is out), and one whose
+    only step is its last."""
     chat = _chat(decode_slots=slots, max_new_tokens=new)
     cb = chat._cb
     cb.drain()
@@ -557,17 +612,119 @@ def test_reading_late_keeps_the_tokens_at_the_edges(new, slots):
     assert got == chat._generate_batch(prompts)
     assert all(len(g.split()) == new for g in got)
     if new == 1:
-        # prefills alone, each ahead of the reads
+        # prefills alone, each ahead of the reads, read `_AHEAD` late
         assert "".join(e[0] for e in events) == "PPPpPpPppp"
         assert cb.stats["decode_steps"] == 0
-    # results are read in the order their programs went out, each after
-    # `_AHEAD` more dispatches (the last ones have none behind them)
+    # results are read in the order their programs went out (`_replayed`),
+    # none before `_AHEAD` more dispatches (the last ones have none behind
+    # them), and a prefill with only steps behind it not until a later
+    # prefill is out or nothing is left to dispatch
     sent_at = [i for i, e in enumerate(events) if e[0] in "PS"]
     read_at = [i for i, e in enumerate(events) if e in "ps"]
-    assert [events[i] for i in read_at] == [events[i][0].lower() for i in sent_at]
     assert all(n < read for n, read in zip(sent_at[_AHEAD:], read_at)), events
+    _assert_the_rule(events)
     assert cb.stats["completed"] == cb.stats["prefills"] == 5
     assert cb.pool.snapshot()["active"] == 0 and cb.queue_depth() == 0
+
+
+@pytest.mark.parametrize("requests,new", [(4, 4), (5, 6), (6, 3)])
+def test_a_prefill_is_read_once_a_later_prefill_is_out(requests, new):
+    """A backlog over two slots: each prefill but the last is read only
+    after the next prefill went out behind it, the last when nothing is
+    left to dispatch, and never are more than two prefills out at once."""
+    chat = _chat(decode_slots=2, max_new_tokens=new)
+    cb = chat._cb
+    cb.drain()
+    events, _ = _logged(cb)
+    prompts = [f"backlog prompt {i} over two slots" for i in range(requests)]
+    got = [f.result(timeout=120) for f in _held_back(cb, prompts)]
+    cb.drain()
+    assert got == chat._generate_batch(prompts)
+    _assert_the_rule(events)
+    sent = [i for i, e in enumerate(events) if e[0] == "P"]
+    read = [i for i, e in enumerate(events) if e == "p"]
+    assert len(sent) == len(read) == requests
+    for k in range(requests - 1):
+        assert sent[k + 1] < read[k], events
+    last_dispatch = max(i for i, e in enumerate(events) if e[0] in "PS")
+    assert read[-1] > last_dispatch, events
+    out = most = 0
+    for e in events:
+        out += (e[0] == "P") - (e == "p")
+        most = max(most, out)
+    assert most == 2, events
+
+
+@pytest.mark.parametrize("requests", [1, 2])
+def test_with_no_queue_the_steps_behind_a_prefill_stop_at_the_last_steps(
+    requests,
+):
+    """Every request holds a slot and none waits: the loop dispatches the
+    steps behind the unread prefill up to the requests' last steps, no
+    further, and reads everything out before the thread leaves."""
+    new = 6
+    chat = _chat(decode_slots=2, max_new_tokens=new)
+    cb = chat._cb
+    cb.drain()
+    events, _ = _logged(cb)
+    running = []
+    read = cb._read
+
+    def read_running(done):
+        running.append(cb._running)
+        return read(done)
+
+    cb._read = read_running
+    prompts = [f"no queue behind prompt {i}" for i in range(requests)]
+    got = [f.result(timeout=120) for f in _held_back(cb, prompts)]
+    cb.drain()
+    assert got == chat._generate_batch(prompts)
+    assert " ".join(events) == {
+        1: "P0 S S S S S p s s s s s",
+        2: "P0 S P1 p S s S S S S p s s s s s",
+    }[requests]
+    at_dispatch = _assert_the_rule(events)
+    # the last request's n_steps - 1 steps, and its prefill
+    assert max(map(len, at_dispatch)) + 1 == new
+    # each prefill, and the steps: the last request's after its neighbour's
+    assert len(running) == requests + (new - 1) + (requests - 1)
+    assert all(running)  # every read before the thread gave up the loop
+    assert not cb._thread.is_alive() and not cb._out
+    assert cb.stats["completed"] == requests
+
+
+@pytest.mark.parametrize(
+    "slots,requests,new,past", [(2, 3, 5, 4), (1, 5, 5, 14), (2, 1, 6, 3),
+                                (2, 5, 1, 0)],
+)
+def test_dispatched_past_prefill_counts_where_the_old_depth_would_wait(
+    slots, requests, new, past
+):
+    """The counter is the dispatches made with more than `_AHEAD` programs
+    out behind an unread prefill: the reader that waits at that depth for
+    a prefill too makes the same dispatches in the same order with the
+    same tokens, and never one of those."""
+    prompts = [f"counted prompt {i}" for i in range(requests)]
+    orders, counts = [], []
+    for depth in (False, True):
+        chat = _chat(decode_slots=slots, max_new_tokens=new)
+        cb = chat._cb
+        cb.drain()
+        if depth:
+            _read_at_depth(cb)
+        events, _ = _logged(cb)
+        got = [f.result(timeout=120) for f in _held_back(cb, prompts)]
+        cb.drain()
+        assert got == chat._generate_batch(prompts)
+        at_dispatch = _replayed(events)[0]
+        past_here = [
+            out for out in at_dispatch if len(out) > _AHEAD and out[0] == "P"
+        ]
+        assert cb.stats["dispatched_past_prefill"] == len(past_here)
+        orders.append([e for e in events if e[0] in "PS"])
+        counts.append(len(past_here))
+    assert orders[0] == orders[1]
+    assert counts == [past, 0]
 
 
 def test_queue_depth_counts_a_request_until_its_reply_leaves():
