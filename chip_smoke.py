@@ -190,7 +190,7 @@ def check_encode(embedder: Any, texts: list[str], checks: Checks) -> dict:
     import jax
     import jax.numpy as jnp
 
-    from pathway_tpu.models import transformer
+    from pathway_tpu.models import encoder
 
     got = np.stack(embedder.encode_many(texts)).astype(np.float32)
     cpu = jax.devices("cpu")[0]
@@ -204,7 +204,7 @@ def check_encode(embedder: Any, texts: list[str], checks: Checks) -> dict:
             embedder.params,
         )
         want = np.asarray(
-            transformer.encode(params, jnp.asarray(ids), jnp.asarray(mask), cfg)
+            encoder.encode(params, jnp.asarray(ids), jnp.asarray(mask), cfg)
         )
     cos = np.sum(got * want, axis=1) / (
         np.linalg.norm(got, axis=1) * np.linalg.norm(want, axis=1)

@@ -8,15 +8,16 @@ jit-compiled forward/train steps over a `jax.sharding.Mesh` (dp x tp), and a
 decode path with a KV cache for on-TPU generation.
 """
 
-from pathway_tpu.models.transformer import (
+from pathway_tpu.models import transformer  # noqa: F401  (the served decoder)
+from pathway_tpu.models.config import (
     LatentSpec,
     LayerSpec,
     TransformerConfig,
-    TransformerLM,
-    count_params,
     embedder_config,
     lm_config,
 )
+from pathway_tpu.models.encoder import TransformerLM
+from pathway_tpu.models.transformer import count_params
 
 __all__ = [
     "LatentSpec",

@@ -17,14 +17,14 @@ to itself for every layer kind of `LayerSpec` (every earlier position or
 a window; any grouping of query heads over key heads), tiled with a
 streaming softmax so that no score reaches HBM, and skipping by whole
 tiles what the causal order, the window and the padding rule out.
-models/transformer.py `prefill_uses_kernel` says which prefills run it.
+models/mixers/softmax.py `prefill_uses_kernel` says which prefills run it.
 
 `decode_attention` is the decode step's: one query a slot over the slot
 cache, whose stacked leaf ([layers, slots, kv heads, rows, head]) is the
 kernel's operand and result as it lies. It writes the step's own row and
 fetches only the tiles that hold a live row of the slot, so a step reads
 what its slots have decoded, not what the cache has room for.
-models/transformer.py `step_uses_kernel` says which steps run it.
+models/mixers/softmax.py `step_uses_kernel` says which steps run it.
 
 Reference parity: replaces the torch SDPA used by the reference's local
 embedding models (`/root/reference/python/pathway/xpacks/llm/embedders.py:270`
@@ -381,7 +381,7 @@ def prefill_attention(
     VMEM: query i of a row attends the valid keys j <= i and, in a window
     layer, j > i - window. Returns the context [b, p, heads * dh]. Products
     in the inputs' dtype with float32 accumulation, softmax in float32, as
-    models/transformer.py `_attend` states them; the order of the sums is
+    models/layers.py `attend` states them; the order of the sums is
     another. A query with no key to attend (a row of the padding) returns
     some finite vector.
 
@@ -637,7 +637,7 @@ def decode_attention(
 
     Products in the cache's dtype with float32 accumulation, softmax in
     float32, the weights cast to the cache's dtype before the second
-    product, as models/transformer.py `_attend` states them; the order of
+    product, as models/layers.py `attend` states them; the order of
     the sums is another. The `heads / kv heads` query heads of a group
     share each key tile. dh must be a multiple of 128 (a lane tile).
     Grid (slots, kv heads / hb, tiles of t rows), (hb, t) from

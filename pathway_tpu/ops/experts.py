@@ -1,6 +1,6 @@
 """Grouped products of a routed expert layer, as Pallas kernels for the TPU.
 
-A decoder with routed experts (models/transformer.py `_experts`) sorts its
+A decoder with routed experts (models/routed.py `experts`) sorts its
 token-expert pairs by expert, so that expert e multiplies the run of rows
 `offsets[e] .. offsets[e + 1]` whatever its length: no capacity, no dropped
 pair. `jax.lax.ragged_dot` states that product and gives no hold on its
@@ -32,7 +32,7 @@ not once a tile, and nothing is copied out of a leaf.
 pairs: a third kernel that fetches each token's rows by their index from
 where the down kernel wrote them and writes only the sums.
 
-models/transformer.py `experts_use_kernel` says which layers run these: a
+models/routed.py `experts_use_kernel` says which layers run these: a
 prefill's; the decode step's few pairs stay on `ragged_dot`.
 """
 
@@ -227,7 +227,7 @@ def grouped_experts(
     rows' dtype with float32
     accumulation, the gated product taken in float32 and rounded to the
     rows' dtype between the two kernels, the weight applied in float32: as
-    models/transformer.py `_experts` states them over `ragged_dot`. Every
+    models/routed.py `experts` states them over `ragged_dot`. Every
     pair is computed, however many an expert has.
 
     The matrices must be the rows' dtype: they are read where they lie.

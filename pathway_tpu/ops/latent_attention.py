@@ -1,4 +1,4 @@
-"""The attention of a latent layer (models/transformer.py `LatentSpec`:
+"""The attention of a latent layer (models/config.py `LatentSpec`:
 multi-head latent attention), as Pallas kernels for the TPU.
 
 A latent layer's head scores a query of nope + rope lanes (128 + 64 at the
@@ -20,7 +20,7 @@ tiles and whose cache leaf is rows of whole heads.
   the row the cache keeps and is never repeated for the heads in memory;
   q_nope, k_nope and v are read where their products leave them, and so
   are the query's rotary lanes, which the kernel turns itself, once a
-  query tile (models/transformer.py `_rope`'s arithmetic over a lane tile
+  query tile (models/layers.py `rope`'s arithmetic over a lane tile
   a head).
 * `latent_decode_attention`: a step's attention in the absorbed form. A
   slot's heads (their nope lanes already times W_uk: `kv_rank` wide) share
@@ -30,7 +30,7 @@ tiles and whose cache leaf is rows of whole heads.
   the tiles that hold a live row of the slot are fetched, out of the
   stacked leaves where they lie.
 
-models/transformer.py `latent_prefill_uses_kernel` and
+models/mixers/latent.py `latent_prefill_uses_kernel` and
 `latent_step_uses_kernel` say which programs run these.
 """
 
@@ -109,7 +109,7 @@ def _prefill_kernel(first_ref, held_ref, q_ref, qr_ref, cos_ref, sin_ref, k_ref,
     @pl.when(kk == 0)
     def _start():
         _start_softmax(m_ref, l_ref, acc_ref)
-        # models/transformer.py `_rope` over a lane tile a head: the tables
+        # models/layers.py `rope` over a lane tile a head: the tables
         # hold each cosine twice and the sines with their first half
         # negated, zeros behind the rotary lanes, so that
         # [x1 cos - x2 sin, x2 cos + x1 sin] = x cos + partner sin, where a
@@ -175,7 +175,7 @@ def latent_prefill_attention(
     softmax((q_n . k_n + turn(q_r) . k_r) x scale) over them, and returns
     the context [b, p, heads * dv]. A head's score is two products in the
     inputs' dtype with float32 accumulation, summed in float32; softmax in
-    float32, as models/transformer.py `_attend_latent` states them; the
+    float32, as models/mixers/latent.py `_attend_latent` states them; the
     order of the sums is another. turn(q_r) is `_rope` of the query's
     rotary lanes by the tables `cos` and `sin` (ops/rowwise.py
     `rope_tables` of the rows' positions; ones and zeros turn nothing), in
@@ -188,7 +188,7 @@ def latent_prefill_attention(
     two products that expand them from the latent rows (asked for rows of
     heads it computes them so all the same and copies them, 168 MB each a
     layer); and the rotary key is ONE row of a lane tile a position
-    (models/transformer.py `_in_rope_lanes`: what the cache keeps), fetched
+    (models/mixers/latent.py `_in_rope_lanes`: what the cache keeps), fetched
     once a grid step for all of the step's heads and never repeated in
     memory; the query's rotary lanes lie in a lane tile a head, zeros
     behind them as behind the key's and the tables', as their product

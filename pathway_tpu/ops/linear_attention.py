@@ -1,5 +1,5 @@
 """The linear mixer's prefill as a Pallas kernel: the chunked scan of
-models/transformer.py `linear_scan` with a chunk's pairs and the carried
+models/mixers/linear.py `linear_scan` with a chunk's pairs and the carried
 state kept in VMEM.
 
     o_t = (q_t / sqrt(dh)) S_t ,  S_t = lambda S_{t-1} + k_t^T v_t ,
@@ -10,7 +10,7 @@ Written in XLA the scan is a `lax.scan` over chunks whose float32 pairs
 a grid step is one chunk of one head: the pairs, their decay mask and the
 state never leave VMEM, and HBM sees q, k, v once, the output once and the
 last state once. A chunk's output block is a head's dh lanes, the group of
-the layer's output norm (models/transformer.py `_linear_out`), so with that
+the layer's output norm (models/mixers/linear.py `_linear_out`), so with that
 norm's scale the kernel norms the float32 block where it lies: the norm's
 reduction, its scaling, their relayouts and the float32 arrays between them
 never cross HBM. What leaves is the normed block in float32, as the
@@ -94,13 +94,13 @@ def linear_prefill_attention(
     """The linear mixer over whole prompts. Returns (o [b, p, heads, dh]
     float32, the state after the last position [b, heads, dh, dh] float32);
     with `out_norm` o is RMS-normed over each head's dh and scaled by it,
-    in float32: models/transformer.py `_linear_out` before its cast.
+    in float32: models/mixers/linear.py `_linear_out` before its cast.
 
     Inside a chunk (q k^T * D) v with D_ij = lambda^(i-j) for j <= i, the
     products of the inputs' dtype with float32 accumulation and the masked
     pairs cast to v's dtype before the second product; across chunks the
     state, float32, multiplied in float32 (`highest`), as models/
-    transformer.py `linear_scan` states them; the order of the sums is
+    models/mixers/linear.py `linear_scan` states them; the order of the sums is
     another. A width that `chunk` does not divide is padded in FRONT with
     zeros, which add nothing to a state of zeros and decay nothing of it;
     the batcher's own left pad is the same thing, so long as its keys are

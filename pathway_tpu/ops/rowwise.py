@@ -2,9 +2,9 @@
 Pallas pass: the q/k norm, the rotary positions and the zero of a pad's key,
 over heads of q or k read where the product left them.
 
-Written in XLA (models/transformer.py `_rmsnorm`, `_rope`, the `where` of
-`_prefill_linear`) each link is a fusion of its own over the whole tensor,
-with float32 arrays of it between them: the split of the product, the
+Written in XLA (models/layers.py `rmsnorm`, `rope`, the `where` of the
+linear kind's `prefill`) each link is a fusion of its own over the whole
+tensor, with float32 arrays of it between them: the split of the product, the
 norm's mean of squares, its scaling, two relayouts, the rotation, the zero.
 Here a grid step is a tile of rows of a few whole heads: HBM sees the
 product's lanes once and the result once.

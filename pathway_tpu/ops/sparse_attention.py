@@ -1,6 +1,6 @@
 """The sparse mixer's prefill attention as a Pallas kernel: causal softmax
 attention of whole prompts to themselves in which every query reads only the
-blocks of keys it chose (models/transformer.py `select_blocks`), one set for
+blocks of keys it chose (models/mixers/sparse.py `select_blocks`), one set for
 the query heads that share a key head.
 
 It shares with `ops/attention.py prefill_attention` what is one thing: the
@@ -104,7 +104,7 @@ def sparse_prefill_attention(
     query i of a row attends the valid keys j <= i whose block, (j - left
     pad) // `block`, is set in `blocks[row, key head, i]`. Returns the
     context [b, p, heads * dh]. Products in the inputs' dtype with float32
-    accumulation, softmax in float32, as models/transformer.py `_attend`
+    accumulation, softmax in float32, as models/layers.py `attend`
     states them; the order of the sums is another. Every real query has
     to have chosen its own block (the selection's local blocks see to it); a
     query with no key to attend (a row of the padding) returns some finite
@@ -197,7 +197,7 @@ def sparse_prefill_attention(
 
 # ------------------------------------------------- the prefill's selection
 #
-# Which blocks each query of a prefill attends, models/transformer.py
+# Which blocks each query of a prefill attends, models/mixers/sparse.py
 # `select_blocks` as one kernel. Written in XLA, its float32 scores [heads,
 # queries, pooled keys] cross HBM several times and its ranking counts every
 # pair of blocks (PERF.md section 5: 49.8 ms of a 24k-token prefill on a
@@ -332,15 +332,15 @@ def _select_vmem(tq: int, group: int, dh: int, n_pool: int, nb: int,
 @functools.partial(jax.jit, static_argnames=("sq", "key_blocks", "interpret"))
 def sparse_select(
     q: jax.Array,  # [b, nq, kv heads, group, dh]
-    pooled: jax.Array,  # [b, kv heads, n_pool, dh]: models/transformer.py `pool_keys`
+    pooled: jax.Array,  # [b, kv heads, n_pool, dh]: models/mixers/sparse.py `pool_keys`
     t: jax.Array,  # [b, nq] int32: the queries' logical positions, -1 a pad
     dense: jax.Array,  # [b] bool: the rows that attend every earlier position
-    sq,  # models/transformer.py `SparseSpec`
+    sq,  # models/config.py `SparseSpec`
     *,
     key_blocks: int = _SELECT_KEY_BLOCKS,
     interpret: bool = False,
 ) -> jax.Array:
-    """models/transformer.py `select_blocks` as one kernel: the blocks each
+    """models/mixers/sparse.py `select_blocks` as one kernel: the blocks each
     query attends, [b, kv heads, nq, n_pool * stride / block] bool, one set
     for a group, by the same arithmetic up to the ranking (scores from the
     inputs' dtype with float32 accumulation divided by sqrt(dh), the softmax

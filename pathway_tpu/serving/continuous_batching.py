@@ -94,8 +94,8 @@ counters flow into the metrics registry
 **One rule chooses the scheduler**: `JaxLMChat` builds this batcher at
 temperature 0 and the wave-aligned coalescer above it; nothing else
 selects. `JaxLMChat._generate_batch` stays callable on any chat as the
-oracle: `decode_step_slots` is the same math as the scanned
-`decode_step` with the shared scalar position replaced by a per-row
+oracle: `decode_step_slots` is the same math as `generate_serving`'s
+scanned step with the shared scalar position replaced by a per-row
 vector, so a request's tokens are byte-identical on both, pinned by
 ``tests/test_continuous_batching.py``.
 
@@ -145,34 +145,7 @@ It counts as no decode step: its time goes to ``preload_s`` alone. A
 prefill's shape follows a prompt's length, which nothing knows before
 the prompt arrives: no prefill program is loaded ahead of its first
 prompt. ``prompt_tokens`` and ``padded_tokens`` count what the admitted
-prompts held and the widths they ran at, ``kernel_prefills`` those whose
-attention kept its scores in VMEM (``transformer.prefill_uses_kernel`` of
-the width they ran at: the predicate the program itself branches on),
-``kernel_expert_prefills`` those whose routed expert layers ran
-ops/experts.py's grouped kernels (``transformer.prefill_experts_use_kernel``,
-likewise: a run that fell back to ``ragged_dot`` says so),
-``kernel_linear_prefills`` and ``kernel_sparse_prefills`` those whose linear
-layers ran ops/linear_attention.py's scan and whose sparse layers ran
-ops/sparse_attention.py's kernel over the blocks each query chose
-(``transformer.linear_prefill_uses_kernel``, ``sparse_prefill_uses_kernel``),
-``kernel_rowwise_prefills`` those whose q/k norm, rotary positions and pads'
-zero were ops/rowwise.py's one pass over the qkv product
-(``transformer.rowwise_uses_kernel``),
-``kernel_latent_prefills`` those whose latent layers' attention ran
-ops/latent_attention.py's kernel (``transformer.latent_prefill_uses_kernel``),
-``kernel_steps`` the decode steps whose attention read the slot cache in
-place (``transformer.step_uses_kernel``, likewise),
-``kernel_sparse_steps`` those whose sparse layers read only the rows of the
-blocks they chose (``transformer.sparse_step_uses_kernel``), and
-``kernel_latent_steps`` those whose latent layers read only the tiles of
-latent rows that hold a live row (``transformer.latent_step_uses_kernel``).
-
-**A slot's whole row is the request's.** A prefill writes every leaf of the
-slot cache at its slot, whatever the leaf holds (rows of keys, pooled keys,
-a linear layer's state) and however long the prompt: nothing of the slot's
-last request survives it, and the cache is donated from program to program,
-so the prefill runs after the last step that used the row even with two
-programs dispatched ahead.
+prompts held and the widths they ran at.
 
 Decoding is temperature-0 (argmax) here; sampled generation keeps the
 wave-aligned path (a per-request RNG stream inside a shared step program
@@ -427,30 +400,10 @@ class ContinuousBatcher:
             "queue_wait_s": 0.0, "first_token_s": 0.0, "residence_s": 0.0,
             # real tokens of the admitted prompts, and their widths
             "prompt_tokens": 0, "padded_tokens": 0,
-            # prefills whose attention ran ops/attention.py's kernel
-            "kernel_prefills": 0,
-            # prefills whose expert layers ran ops/experts.py's kernels
-            "kernel_expert_prefills": 0,
-            # prefills whose linear and sparse layers ran their kernels
-            "kernel_linear_prefills": 0, "kernel_sparse_prefills": 0,
-            # prefills whose q and k took ops/rowwise.py's one pass
-            "kernel_rowwise_prefills": 0,
-            # prefills whose latent layers ran ops/latent_attention.py's kernel
-            "kernel_latent_prefills": 0,
-            # decode steps whose attention ran ops/attention.py's kernel
-            "kernel_steps": 0,
-            # and those whose sparse layers read only their chosen blocks
-            "kernel_sparse_steps": 0,
-            # and those whose latent layers read only their live rows' tiles
-            "kernel_latent_steps": 0,
             "preload_s": 0.0,  # the step program's load at construction
             # what a decoder's programs count on the device and send back
             # behind their tokens (0 where the block has no such layer)
-            **dict.fromkeys(
-                self._model.PREFILL_COUNTERS + self._model.STEP_COUNTERS
-                + self._model.MIXER_COUNTERS + self._model.SHARE_COUNTERS
-                + self._model.LATENT_COUNTERS, 0
-            ),
+            **dict.fromkeys(self._model.COUNTERS, 0),
         }
         # the counters this decoder's two programs append, in their order
         self._prefill_tail = transformer.prefill_counters(cfg)
@@ -843,24 +796,6 @@ class ContinuousBatcher:
                 self._count(self._prefill_tail, first[1:])
                 self.stats["prompt_tokens"] += req.length
                 self.stats["padded_tokens"] += req.width
-                self.stats["kernel_prefills"] += (
-                    self._model.prefill_uses_kernel(self.cfg, req.width)
-                )
-                self.stats["kernel_expert_prefills"] += (
-                    self._model.prefill_experts_use_kernel(self.cfg, req.width)
-                )
-                self.stats["kernel_linear_prefills"] += (
-                    self._model.linear_prefill_uses_kernel(self.cfg, req.width)
-                )
-                self.stats["kernel_sparse_prefills"] += (
-                    self._model.sparse_prefill_uses_kernel(self.cfg, req.width)
-                )
-                self.stats["kernel_rowwise_prefills"] += (
-                    self._model.rowwise_uses_kernel(self.cfg, req.width)
-                )
-                self.stats["kernel_latent_prefills"] += (
-                    self._model.latent_prefill_uses_kernel(self.cfg, req.width)
-                )
                 self.stats["queue_wait_s"] += req.t_admit - req.t_submit
                 self.stats["first_token_s"] += req.t_first - req.t_submit
                 if len(req.tokens) >= self.n_steps:  # n_steps == 1
@@ -870,15 +805,6 @@ class ContinuousBatcher:
             nxt = np.asarray(done.out)
         with self._phase("account_s"):
             self.stats["decode_steps"] += 1
-            self.stats["kernel_steps"] += self._model.step_uses_kernel(
-                self.cfg
-            )
-            self.stats["kernel_sparse_steps"] += (
-                self._model.sparse_step_uses_kernel(self.cfg)
-            )
-            self.stats["kernel_latent_steps"] += (
-                self._model.latent_step_uses_kernel(self.cfg)
-            )
             self._count(self._step_tail, nxt[self.n_slots:])
             if _obs.PLANE is not None:
                 _obs.PLANE.metrics.counter(

@@ -190,7 +190,7 @@ class JaxEmbedder(BaseEmbedder):
 
         import jax
 
-        from pathway_tpu.models import embedder_config, transformer
+        from pathway_tpu.models import embedder_config, encoder, transformer
         from pathway_tpu.models.tokenizer import HashTokenizer
 
         self.config = config or embedder_config(
@@ -213,7 +213,7 @@ class JaxEmbedder(BaseEmbedder):
         self._plane = get_device_plane()
         self._encode = self._plane.program(
             self._plane.unique_name("embed_encode"),
-            functools.partial(transformer.encode, cfg=self.config),
+            functools.partial(encoder.encode, cfg=self.config),
         )
         self._batcher = self._plane.coalescer(
             self._encode_batch, max_batch=max_batch

@@ -95,11 +95,7 @@ def test_stats_hold_every_key_from_construction():
     counts = {
         "submitted", "completed", "decode_steps", "prefills", "max_queue",
         "dispatched_ahead", "dispatched_past_prefill",
-        "prompt_tokens", "padded_tokens", "kernel_prefills", "kernel_steps",
-        "kernel_expert_prefills", "kernel_linear_prefills",
-        "kernel_sparse_prefills", "kernel_sparse_steps",
-        "kernel_rowwise_prefills", "kernel_latent_prefills",
-        "kernel_latent_steps",
+        "prompt_tokens", "padded_tokens",
         # an experts decoder's device counters (0 for this block)
         "routed_pairs", "expert_load_max", "experts_touched", "moe_layers_run",
         # and those of a decoder with sparse or linear layers
@@ -1178,62 +1174,6 @@ def test_benchmark_lists_the_four_metrics_for_the_backlog_cell():
     PR (PERF.md section 7)."""
     _listed(("host_per_dispatch_ms", "host_stall_pct", "queue_wait_ms",
              "outside_batcher_ms"))
-
-
-@pytest.mark.parametrize("engages", [True, False])
-@pytest.mark.parametrize("stat, predicate", [
-    ("kernel_prefills", "prefill_uses_kernel"),
-    ("kernel_expert_prefills", "prefill_experts_use_kernel"),
-    ("kernel_rowwise_prefills", "rowwise_uses_kernel"),
-])
-def test_kernel_prefills_counts_what_the_predicate_says(
-    monkeypatch, engages, stat, predicate
-):
-    """`kernel_prefills`, `kernel_expert_prefills` and
-    `kernel_rowwise_prefills` are the model module's own predicates of the
-    width a prompt ran at, the ones `_prefill` (for its attention, and for the row-wise pass over q and k)
-    and (through `experts_use_kernel`) `_experts` branch on: with one patched
-    true (after the programs are traced, so that the CPU still runs them)
-    its count equals `prefills`; as it is off the TPU it stays 0."""
-    cb = _chat()._cb
-    _run(cb, ["warm up prompt"])
-    seen = []
-    if engages:
-        monkeypatch.setattr(
-            cb._model, predicate,
-            lambda cfg, width: seen.append((cfg, width)) or True,
-        )
-    before = dict(cb.stats)
-    _run(cb)
-    grown = cb.stats["prefills"] - before["prefills"]
-    assert grown == len(PROMPTS)
-    assert cb.stats[stat] == (grown if engages else 0)
-    if engages:
-        assert seen == [(cb.cfg, 16)] * len(PROMPTS)
-
-
-@pytest.mark.parametrize("engages", [True, False])
-def test_kernel_steps_counts_what_the_predicate_says(monkeypatch, engages):
-    """`kernel_steps` is the model module's own predicate, the one
-    `_step_rows` branches on: with it patched true (after the step program
-    is traced, so that the CPU still runs it) the count equals
-    `decode_steps`; as it is off the TPU it stays 0."""
-    cb = _chat()._cb
-    _run(cb, ["warm up prompt"])
-    seen = []
-    if engages:
-        monkeypatch.setattr(
-            cb._model, "step_uses_kernel", lambda cfg: seen.append(cfg) or True
-        )
-    before = dict(cb.stats)
-    _run(cb)
-    grown = cb.stats["decode_steps"] - before["decode_steps"]
-    assert grown > 0
-    assert cb.stats["kernel_steps"] - before["kernel_steps"] == (
-        grown if engages else 0
-    )
-    if engages:
-        assert seen == [cb.cfg] * grown
 
 
 def test_benchmark_lists_prefill_pad_pct_in_the_batchers_layer():

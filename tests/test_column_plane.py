@@ -472,14 +472,14 @@ def test_mesh_spanning_slot_pool_byte_identical():
 
     if len(jax.devices()) < 2:
         pytest.skip("needs a multi-device (virtual) mesh")
-    from pathway_tpu.models import transformer as tfm
+    from pathway_tpu.models import lm_config, transformer as tfm
     from pathway_tpu.serving.continuous_batching import ContinuousBatcher
 
     class Tok:
         def tokenize(self, s):
             return [2 + (ord(c) % 40) for c in s][:12]
 
-    cfg = tfm.lm_config(
+    cfg = lm_config(
         vocab_size=128, d_model=16, n_heads=2, n_layers=1, d_ff=32,
         max_len=32,
     )

@@ -1,7 +1,7 @@
 """The decode step's attention kernel (ops/attention.py `decode_attention`)
 in Pallas interpret mode on the CPU against the plain path of
 models/transformer.py `_step_rows` on the same cache: the row write
-(`.at[...].set`) and `_attend` under the masks the step builds. And the
+(`.at[...].set`) and `attend` under the masks the step builds. And the
 rule that chooses between the two (`step_uses_kernel`). The block is cut to
 one key head and 128 rows here, so that a leaf of a few hundred rows has
 tiles to skip and a tile that hangs over its end; the chip's own compiler
@@ -16,7 +16,9 @@ import numpy as np
 import pytest
 
 from pathway_tpu.models import lm_config
+from pathway_tpu.models import layers as LY
 from pathway_tpu.models import transformer as T
+from pathway_tpu.models.mixers import softmax as SM
 from pathway_tpu.ops import attention as A
 
 DH = 128
@@ -44,7 +46,7 @@ def _inputs(rows, heads, kv_heads, slots, dtype, layers=2, seed=0):
 
 def _plain(q, kn, vn, kc, vc, li, pos, pad, ring: bool):
     """What `_step_rows` does off the chip: the rows written by index, then
-    `_attend` over every row of the layer under the step's own mask."""
+    `attend` over every row of the layer under the step's own mask."""
     slots, rows = q.shape[0], kc.shape[3]
     at_row = (pos % rows if ring else pos)[:, None]
     b, hd = jnp.arange(slots)[:, None], jnp.arange(kc.shape[2])[None, :]
@@ -56,7 +58,7 @@ def _plain(q, kn, vn, kc, vc, li, pos, pad, ring: bool):
         ok = held >= pad[:, None]
     else:
         ok = (at <= pos[:, None]) & (at >= pad[:, None])
-    ctx = T._attend(
+    ctx = LY.attend(
         q[:, None], kc[li], vc[li], ok[:, None, None, :], lm_config(dtype=q.dtype)
     )
     return ctx[:, 0], kc, vc
@@ -78,9 +80,9 @@ SLOTS = {
 }
 # query heads over key heads: one to one, and seven to a key head
 GROUPS = [(2, 2), (7, 1)]
-# bfloat16 against `_attend` in bfloat16: both round the weights of the
+# bfloat16 against `attend` in bfloat16: both round the weights of the
 # value product to 8 bits, the kernel before its division by the sum and
-# `_attend` after it, over values of unit spread (as
+# `attend` after it, over values of unit spread (as
 # tests/test_prefill_attention.py: read 0.004-0.016)
 CASES = [
     (name, heads, kv_heads, dtype, tol)
@@ -175,4 +177,4 @@ GPT2_XL = dict(d_model=1600, n_heads=25, n_layers=1, d_ff=64, max_len=1024)
 ])
 def test_the_rule_that_chooses_the_steps_path(keys, backend, want, monkeypatch):
     monkeypatch.setattr(jax, "default_backend", lambda: backend)
-    assert T.step_uses_kernel(lm_config(vocab_size=64, **keys)) is want
+    assert SM.step_uses_kernel(lm_config(vocab_size=64, **keys)) is want

@@ -1,4 +1,4 @@
-"""The decoder's per-layer kinds (models/transformer.py `LayerSpec`): routed
+"""The decoder's per-layer kinds (models/config.py `LayerSpec`): routed
 ReGLU experts with the router on the layer's input, grouped-query heads,
 window layers whose cache rows are a ring, rotary and no positions, an
 untied head. Served through the slot cache (`prefill_into_slot`'s and
@@ -22,7 +22,10 @@ import numpy as np
 import pytest
 
 from pathway_tpu.models import LayerSpec, TransformerConfig, lm_config
+from pathway_tpu.models import encoder as EN
+from pathway_tpu.models import routed as RT
 from pathway_tpu.models import transformer as T
+from pathway_tpu.models.mixers import softmax as SM
 from pathway_tpu.ops import attention as A
 from pathway_tpu.ops import rowwise as R
 
@@ -126,7 +129,7 @@ def test_slot_cache_through_the_prefill_kernel_matches_the_plain_reference(
     part alone: this family has no q/k norm), the ring's write and the
     steps behind it against the family's reference, which has no kernel, no
     cache and no ring."""
-    monkeypatch.setattr(T, "prefill_uses_kernel", lambda cfg, p: True)
+    monkeypatch.setattr(SM, "prefill_uses_kernel", lambda cfg, p: True)
     monkeypatch.setattr(
         A, "prefill_attention",
         functools.partial(A.prefill_attention, interpret=True),
@@ -139,7 +142,7 @@ def test_slot_cache_through_the_prefill_kernel_matches_the_plain_reference(
     )
     monkeypatch.setattr(A, "_PREFILL_TILE_MAX", 128)
     cfg = FAMILY.program_config(KERNEL_KEYS, jnp.float32)
-    assert T.rowwise_uses_kernel(cfg, width)
+    assert SM.rowwise_uses_kernel(cfg, width)
     got, toks = _served_logits(cfg, _prompt(length), width)
     # q and k of the six window layers, and of no global one; no norm, no zero
     assert rotated and len(rotated) % (2 * 6) == 0
@@ -186,7 +189,7 @@ def test_the_steps_through_the_decode_kernel_serve_the_plain_paths_tokens(
     cfg = FAMILY.program_config(KERNEL_KEYS, jnp.float32)
     rows = [_prompt(100), _prompt(21)]
     want = _slot_tokens(cfg, rows, 128, 32)
-    monkeypatch.setattr(T, "step_uses_kernel", lambda cfg: True)
+    monkeypatch.setattr(SM, "step_uses_kernel", lambda cfg: True)
     monkeypatch.setattr(
         A, "decode_attention",
         functools.partial(A.decode_attention, interpret=True),
@@ -273,7 +276,7 @@ def test_every_token_to_one_expert_loses_none():
     idx = jnp.broadcast_to(jnp.asarray([0, 1]), (2, 12, 2))
     w = jnp.broadcast_to(jnp.asarray([0.25, 0.75]), (2, 12, 2))
     live = jnp.ones((2, 12), bool).at[0, :3].set(False)
-    y, counts = T._experts(u, idx, w, live, block, cfg)
+    y, counts = RT.experts(u, idx, w, live, block, cfg)
     want = sum(
         share * (
             jax.nn.relu(u @ block["expert_gate"][e]) * (u @ block["expert_up"][e])
@@ -359,7 +362,7 @@ def test_an_experts_decoder_sends_its_counters_behind_the_tokens():
         _params(), ids, jnp.ones_like(ids), T.init_kv_cache(cfg, 2),
         jnp.asarray(1), cfg,
     )
-    assert first.shape == (1 + len(T.PREFILL_COUNTERS),)
+    assert first.shape == (1 + len(T.prefill_counters(cfg)),)
     assert int(first[1]) == 8 * 2 * 8
     assert cache["k"].shape == (2, 2, 2, 64, 16)  # global layers: every row
     assert cache["k_win"].shape == (6, 2, 2, WINDOW, 16)  # window layers: a ring
@@ -368,7 +371,7 @@ def test_an_experts_decoder_sends_its_counters_behind_the_tokens():
         _params(), cache, tok, jnp.asarray([0, 8], jnp.int32),
         jnp.zeros((2,), jnp.int32), cfg,
     )
-    assert nxt.shape == (2 + len(T.STEP_COUNTERS),)
+    assert nxt.shape == (2 + len(T.step_counters(cfg)),)
     assert int(nxt[3]) == 8 and 2 * 8 == int(nxt[2])  # one live row: 2 a layer
 
 
@@ -429,6 +432,6 @@ def test_a_configuration_that_cannot_be_served_is_refused(kw, message):
 def test_forward_refuses_what_it_does_not_run():
     cfg = FAMILY.program_config(KEYS, jnp.float32)
     with pytest.raises(NotImplementedError):
-        T.forward(_params(), jnp.ones((1, 4), jnp.int32), jnp.ones((1, 4)), cfg)
+        EN.forward(_params(), jnp.ones((1, 4), jnp.int32), jnp.ones((1, 4)), cfg)
     assert dataclasses.replace(cfg, layers=None, n_kv_heads=None, head_size=None,
                                tie_embeddings=True).plain

@@ -2,7 +2,7 @@
 Pallas interpret mode on the CPU against the per-expert loop in float32 at
 `highest` precision, the experts layer and a prefill through the slot cache
 on the kernel path against the plain path (`ragged_dot`), and the rule that
-chooses between the two (models/transformer.py `experts_use_kernel`). The
+chooses between the two (models/routed.py `experts_use_kernel`). The
 tile is cut to 32 rows in blocks of 16 so that tiny runs of rows meet every
 case: an edge inside a block, a tile that holds three groups, an expert with
 no pair, a last tile that hangs over the rows' end.
@@ -20,6 +20,7 @@ import numpy as np
 import pytest
 
 from pathway_tpu.models import LayerSpec, lm_config
+from pathway_tpu.models import routed as RT
 from pathway_tpu.models import transformer as T
 from pathway_tpu.ops import experts as X
 
@@ -184,7 +185,7 @@ def on_the_kernel(monkeypatch):
     """The rule says kernel for a prefill's pairs (as on a TPU with enough
     of them; interpreted here) and `ragged_dot` for a step's, as it does."""
     monkeypatch.setattr(
-        T, "experts_use_kernel", lambda cfg, pairs: pairs >= 16 * cfg.n_active
+        RT, "experts_use_kernel", lambda cfg, pairs: pairs >= 16 * cfg.n_active
     )
     for name in ("grouped_experts", "combine_experts"):
         monkeypatch.setattr(
@@ -195,7 +196,7 @@ def on_the_kernel(monkeypatch):
 def _layer_inputs(cfg, b=2, s=24, seed=0):
     k = jax.random.split(jax.random.PRNGKey(seed), 3)
     u = jax.random.normal(k[0], (b, s, cfg.d_model), cfg.dtype)
-    idx, w = T._route(
+    idx, w = RT.route(
         jax.random.normal(k[1], (b, s, cfg.d_model), cfg.dtype),
         _params()["blocks"][0], cfg,
     )
@@ -208,9 +209,9 @@ def test_the_layer_on_the_kernel_is_the_layer_on_ragged_dot(on_the_kernel, monke
     block = _params()["blocks"][0]
     u, idx, w = _layer_inputs(cfg)
     live = jnp.ones((2, 24), bool).at[0, :5].set(False)
-    got, got_counts = T._experts(u, idx, w, live, block, cfg)
-    monkeypatch.setattr(T, "experts_use_kernel", lambda cfg, pairs: False)
-    want, want_counts = T._experts(u, idx, w, live, block, cfg)
+    got, got_counts = RT.experts(u, idx, w, live, block, cfg)
+    monkeypatch.setattr(RT, "experts_use_kernel", lambda cfg, pairs: False)
+    want, want_counts = RT.experts(u, idx, w, live, block, cfg)
     assert np.abs(np.asarray(got - want)).max() < 1e-5
     assert got_counts.tolist() == want_counts.tolist()
     assert int(got_counts.sum()) == (48 - 5) * cfg.n_active
@@ -228,7 +229,7 @@ def test_every_token_to_one_expert_loses_none_on_the_kernel(on_the_kernel):
     u, _, _ = _layer_inputs(cfg)
     idx = jnp.broadcast_to(jnp.asarray([0, 1]), (2, 24, 2))
     w = jnp.broadcast_to(jnp.asarray([0.25, 0.75]), (2, 24, 2))
-    y, counts = T._experts(u, idx, w, jnp.ones((2, 24), bool), block, cfg)
+    y, counts = RT.experts(u, idx, w, jnp.ones((2, 24), bool), block, cfg)
     with jax.default_matmul_precision("highest"):
         want = sum(
             share * (
@@ -287,7 +288,7 @@ def test_a_prefill_on_the_kernel_serves_the_plain_paths_logits_and_counts(
     both) decode the same tokens and count the same `experts_touched`."""
     cfg = FAMILY.program_config(KEYS, jnp.float32)
     got = _serve(cfg, _params(), _prompt(length), width)
-    monkeypatch.setattr(T, "experts_use_kernel", lambda cfg, pairs: False)
+    monkeypatch.setattr(RT, "experts_use_kernel", lambda cfg, pairs: False)
     want = _serve(cfg, _params(), _prompt(length), width)
     assert np.abs(got[0] - want[0]).max() < 2e-5
     assert got[1] == want[1] and got[1][1] == length * cfg.n_active * 4
@@ -307,7 +308,7 @@ def test_a_bfloat16_prefill_on_the_kernel_stays_within_bfloat16_of_the_plain_pat
     cfg = FAMILY.program_config(KEYS, jnp.bfloat16)
     params = _params(jnp.bfloat16)
     got = _serve(cfg, params, _prompt(128), 128, n_steps=0)
-    monkeypatch.setattr(T, "experts_use_kernel", lambda cfg, pairs: False)
+    monkeypatch.setattr(RT, "experts_use_kernel", lambda cfg, pairs: False)
     want = _serve(cfg, params, _prompt(128), 128, n_steps=0)
     assert np.abs(got[0] - want[0]).mean() < 0.02
     assert np.abs(got[0] - want[0]).max() < 0.2
@@ -339,4 +340,4 @@ ST = dict(
 def test_the_rule_that_chooses_the_experts_path(keys, width, backend, want, monkeypatch):
     monkeypatch.setattr(jax, "default_backend", lambda: backend)
     cfg = lm_config(**keys)
-    assert T.prefill_experts_use_kernel(cfg, width) is want
+    assert RT.prefill_experts_use_kernel(cfg, width) is want

@@ -1,4 +1,4 @@
-"""The `latent` mixer of the decoder (models/transformer.py `LatentSpec`:
+"""The `latent` mixer of the decoder (models/config.py `LatentSpec`:
 multi-head latent attention over a slot cache of low-rank rows), the expert
 branch that a `shortcut` starts in one layer and lands in the next, and the
 expert layer that is told which experts it holds (router "all": a softmax
@@ -27,6 +27,7 @@ experts held beside 16 identity experts, top-4.
 from __future__ import annotations
 
 import dataclasses
+import collections
 import functools
 import math
 import sys
@@ -38,7 +39,14 @@ import numpy as np
 import pytest
 
 from pathway_tpu.models import LayerSpec, lm_config
+from pathway_tpu.models import config as CF
+from pathway_tpu.models import encoder as EN
+from pathway_tpu.models import layers as LY
+from pathway_tpu.models import routed as RT
 from pathway_tpu.models import transformer as T
+from pathway_tpu.models.layers import Rows
+from pathway_tpu.models.mixers import latent as LAT
+from pathway_tpu.models.mixers import softmax as SM
 from pathway_tpu.ops import latent_attention as LA
 from pathway_tpu.ops.rowwise import rope_tables
 
@@ -155,32 +163,32 @@ def test_the_absorbed_step_is_the_expanded_form():
     n, pad = 21, 3
     x = jax.random.normal(jax.random.PRNGKey(1), (1, n, cfg.d_model), jnp.float32)
     pos_idx = jnp.maximum(jnp.arange(n) - pad, 0)[None]
-    q_n, q_r, c_kv, k_r = T._latent_rows(x, block, pos_idx, spec_, cfg)
-    kv = jnp.einsum("bsr,rhe->bshe", c_kv, T._kv_up(block, cfg))
+    q_n, q_r, c_kv, k_r = LAT._latent_rows(x, block, pos_idx, spec_, cfg)
+    kv = jnp.einsum("bsr,rhe->bshe", c_kv, LAT._kv_up(block, cfg))
     k = jnp.concatenate(
         [kv[..., :lt.nope_dim], jnp.broadcast_to(k_r[:, :, None], (1, n, h, lt.rope_dim))],
         axis=-1,
     )
     ok = (jnp.arange(n) >= pad)[None, None, None, :]
-    expanded = T._attend_latent(
+    expanded = LAT._attend_latent(
         jnp.concatenate([q_n, q_r], axis=-1)[:, -1:], k, kv[..., lt.nope_dim:], ok, cfg
     )
     # the step: rows 0 .. n - 2 in the cache, the last row its own
     cache = T.init_kv_cache(cfg, 1)
-    names, li = T._cache_rows(cfg)[0]
+    kind, li = T._cache_rows(cfg)[0]
     cache["c_kv"] = cache["c_kv"].at[li, 0, :n - 1].set(c_kv[0, :-1])
     cache["k_rope"] = cache["k_rope"].at[li, 0, :n - 1, :lt.rope_dim].set(k_r[0, :-1])
-    counters = T._new_counters()
-    stepped = T._step_latent(
-        x[:, -1:], block, spec_, cache, names, li, jnp.asarray([n - 1]),
-        jnp.asarray([pad]), jnp.asarray([[True]]), cfg, counters,
+    rows = Rows(
+        cfg=cfg, cache=cache, counters=collections.defaultdict(list),
+        at=jnp.asarray([n - 1]), pad=jnp.asarray([pad]), live=jnp.asarray([[True]]),
     )
+    stepped = kind.step(x[:, -1:], block, spec_, li, rows)
     np.testing.assert_allclose(stepped, expanded, atol=2e-5, rtol=0)
-    assert int(counters["latent_rows_read"][0]) == n - pad
+    assert int(rows.counters["latent_rows_read"][0]) == n - pad
     # and the step wrote its row
     np.testing.assert_array_equal(cache["c_kv"][li, 0, n - 1], c_kv[0, -1])
     np.testing.assert_array_equal(
-        cache["k_rope"][li, 0, n - 1], T._in_rope_lanes(k_r[0, -1], cfg)
+        cache["k_rope"][li, 0, n - 1], LAT._in_rope_lanes(k_r[0, -1], cfg)
     )
 
 
@@ -236,7 +244,6 @@ def test_a_slot_taken_again_serves_what_a_fresh_pool_serves():
     assert min(stats["zero_pairs"], stats["routed_pairs"], stats["absent_pairs"]) > 0
     assert stats["latent_rows_read"] > 0
     assert stats["moe_layers_run"] == 2 * stats["decode_steps"]
-    assert stats["kernel_latent_prefills"] == stats["kernel_latent_steps"] == 0  # a CPU
     for prompt, got in zip(prompts, served):
         (alone,), _ = _batcher_tokens([prompt], n_slots=2)
         assert got == alone
@@ -277,8 +284,8 @@ def test_the_shares_add_up_to_the_uncut_layer(count):
             w["expert_up"], whole_w["expert_up"][first:first + count]
         )
         cfg = dataclasses.replace(base, experts_held=(first, count))
-        idx, wts = T._route(u, w, cfg)
-        y, counts = T._experts(u, idx, wts, live, w, cfg)
+        idx, wts = RT.route(u, w, cfg)
+        y, counts = RT.experts(u, idx, wts, live, w, cfg)
         mine = FAMILY.moe_reference(u[0], w, sz)
         np.testing.assert_allclose(y[0], mine, atol=2e-5, rtol=0)
         total = total + (y[0] - identity)
@@ -289,7 +296,7 @@ def test_the_shares_add_up_to_the_uncut_layer(count):
     np.testing.assert_allclose(total, whole, atol=1e-4, rtol=0)
     np.testing.assert_allclose(theirs, whole, atol=1e-4, rtol=0)
     # every real pick was computed by exactly one share
-    idx, _ = T._route(u, whole_w, base)
+    idx, _ = RT.route(u, whole_w, base)
     assert pairs == int((idx < 32).sum())
 
 
@@ -300,9 +307,9 @@ def test_more_held_pairs_than_a_pass_holds_are_all_computed(monkeypatch):
     u = jax.random.normal(jax.random.PRNGKey(4), (1, 37, 64), jnp.float32)
     w, sz = _layer_leaves(8, 8)
     cfg = FAMILY.program_config(KEYS, jnp.float32)
-    idx, wts = T._route(u, w, cfg)
-    monkeypatch.setattr(T, "_HELD_CHUNK", 16)
-    y, counts = T._experts(u, idx, wts, jnp.ones((1, 37), bool), w, cfg)
+    idx, wts = RT.route(u, w, cfg)
+    monkeypatch.setattr(RT, "_HELD_CHUNK", 16)
+    y, counts = RT.experts(u, idx, wts, jnp.ones((1, 37), bool), w, cfg)
     assert int(counts[:8].sum()) > 16
     np.testing.assert_allclose(
         y[0], FAMILY.moe_reference(u[0], w, sz), atol=2e-5, rtol=0
@@ -315,8 +322,8 @@ def test_the_bias_moves_the_choice_and_not_the_weight():
     u = jax.random.normal(jax.random.PRNGKey(5), (1, 50, 64), jnp.float32)
     w, sz = _layer_leaves(8, 8)
     cfg = FAMILY.program_config(KEYS, jnp.float32)
-    idx, wts = T._route(u, w, cfg)
-    plain_idx, plain_wts = T._route(
+    idx, wts = RT.route(u, w, cfg)
+    plain_idx, plain_wts = RT.route(
         u, {**w, "router_bias": jnp.zeros_like(w["router_bias"])}, cfg
     )
     moved = np.asarray(jnp.sort(idx, -1) != jnp.sort(plain_idx, -1)).any(-1)
@@ -349,7 +356,7 @@ def test_an_identity_pick_adds_its_weight_times_the_row():
     wts = jnp.asarray([[[0.5, 0.25, 0.125, 0.125], [1.0, 1.0, 1.0, 1.0],
                         [0.5, 9.0, 0.0, 0.0]]])
     live = jnp.asarray([[True, True, False]])
-    y, counts = T._experts(u, idx, wts, live, w, cfg)
+    y, counts = RT.experts(u, idx, wts, live, w, cfg)
     np.testing.assert_allclose(y[0, 0], u[0, 0], atol=1e-6)
     np.testing.assert_array_equal(y[0, 1], jnp.zeros(64))
     np.testing.assert_allclose(y[0, 2], 0.5 * u[0, 2], atol=1e-6)
@@ -383,20 +390,20 @@ def test_the_prefill_kernel_matches_the_jnp_attention(p, pads, h):
         dtype=dt, layers=(LayerSpec(mixer="latent", pos="rotary"),),
         latent=T.LatentSpec(q_rank=8, kv_rank=8, nope_dim=dn, rope_dim=dr, v_dim=dv),
     )
-    # the kernel turns the query's rotary lanes itself, by `_rope`'s rule
+    # the kernel turns the query's rotary lanes itself, by `rope`'s rule
     pos = jnp.clip(jnp.cumsum(valid, axis=1) - 1, 0, None)
     cos, sin = rope_tables(pos, cfg.rope_theta, dr)
     out = LA.latent_prefill_attention(
-        q_n, T._in_rope_lanes(q_r, cfg), k_n.transpose(0, 2, 3, 1),
-        T._in_rope_lanes(k_r, cfg), v.transpose(0, 2, 3, 1), valid,
-        T._in_rope_lanes(cos, cfg), T._in_rope_lanes(sin, cfg),
+        q_n, LAT._in_rope_lanes(q_r, cfg), k_n.transpose(0, 2, 3, 1),
+        LAT._in_rope_lanes(k_r, cfg), v.transpose(0, 2, 3, 1), valid,
+        LAT._in_rope_lanes(cos, cfg), LAT._in_rope_lanes(sin, cfg),
         scale=1.0 / math.sqrt(dn + dr), half=dr // 2, interpret=True,
     )
     at = jnp.arange(p)
     ok = valid.astype(bool)[:, None, None, :] & (at[None, :] <= at[:, None])[None, None]
     shared = jnp.broadcast_to(k_r[:, :, None, :], (b, p, h, dr))
-    want = T._attend_latent(
-        jnp.concatenate([q_n, T._rope(q_r, pos, cfg)], -1),
+    want = LAT._attend_latent(
+        jnp.concatenate([q_n, LY.rope(q_r, pos, cfg)], -1),
         jnp.concatenate([k_n, shared], -1), v, ok, cfg,
     )
     real = np.asarray(valid, bool)
@@ -481,8 +488,8 @@ def test_the_programs_through_the_kernels_serve_the_plain_paths_logits(monkeypat
         return int(first[0]), np.stack(out)
 
     plain_first, plain = run()
-    monkeypatch.setattr(T, "latent_prefill_uses_kernel", lambda cfg, width: True)
-    monkeypatch.setattr(T, "latent_step_uses_kernel", lambda cfg: True)
+    monkeypatch.setattr(LAT, "latent_prefill_uses_kernel", lambda cfg, width: True)
+    monkeypatch.setattr(LAT, "latent_step_uses_kernel", lambda cfg: True)
     monkeypatch.setattr(LA, "latent_prefill_attention", functools.partial(
         LA.latent_prefill_attention, interpret=True))
     monkeypatch.setattr(LA, "latent_decode_attention", functools.partial(
@@ -517,12 +524,12 @@ def test_forward_and_the_encoder_refuse_the_new_kinds():
     cfg = FAMILY.program_config(KEYS, jnp.float32)
     assert not cfg.plain
     with pytest.raises(NotImplementedError):
-        T.forward(_params(), jnp.zeros((1, 4), jnp.int32), jnp.ones((1, 4), jnp.int32), cfg)
+        EN.forward(_params(), jnp.zeros((1, 4), jnp.int32), jnp.ones((1, 4), jnp.int32), cfg)
     for change in (dict(norm_eps=1e-5), dict(expert_act="silu"), dict(d_expert=8),
                    dict(router="all"), dict(router="all", router_scale=2.0),
                    dict(latent=cfg.latent)):
         with pytest.raises(ValueError, match="plain block only"):
-            T.embedder_config(vocab_size=32, d_model=16, n_heads=2, n_layers=1, **change)
+            CF.embedder_config(vocab_size=32, d_model=16, n_heads=2, n_layers=1, **change)
 
 
 def test_the_rules_ask_the_shapes_and_where_the_process_runs(monkeypatch):
@@ -530,31 +537,31 @@ def test_the_rules_ask_the_shapes_and_where_the_process_runs(monkeypatch):
     wide = dataclasses.replace(cfg, max_len=16384, latent=dataclasses.replace(
         cfg.latent, kv_rank=512, nope_dim=128, rope_dim=64, v_dim=128))
     for c in (cfg, wide):  # a CPU: no kernel, whatever the shapes
-        assert not T.latent_prefill_uses_kernel(c, 10240)
-        assert not T.latent_step_uses_kernel(c)
+        assert not LAT.latent_prefill_uses_kernel(c, 10240)
+        assert not LAT.latent_step_uses_kernel(c)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    assert T.latent_prefill_uses_kernel(wide, 10240)
-    assert not T.latent_prefill_uses_kernel(wide, 64)
-    assert T.latent_step_uses_kernel(wide)
-    assert not T.latent_prefill_uses_kernel(cfg, 10240)  # values of 16 lanes
-    assert not T.latent_step_uses_kernel(cfg)  # a latent row of 32
+    assert LAT.latent_prefill_uses_kernel(wide, 10240)
+    assert not LAT.latent_prefill_uses_kernel(wide, 64)
+    assert LAT.latent_step_uses_kernel(wide)
+    assert not LAT.latent_prefill_uses_kernel(cfg, 10240)  # values of 16 lanes
+    assert not LAT.latent_step_uses_kernel(cfg)  # a latent row of 32
     # heads of 96 (6144 / 64) keep the softmax kernels' rules false
-    assert not T.prefill_uses_kernel(wide, 10240) and not T.step_uses_kernel(wide)
+    assert not SM.prefill_uses_kernel(wide, 10240) and not SM.step_uses_kernel(wide)
     # the experts: pairs are counted against the experts HELD, and an
     # expert's gate and up matrices have to fit the fast memory twice over:
     # 2,560 x 768 do (15.7 MB), the published 6,144 x 2,048 do not (100.7)
     held = dataclasses.replace(wide, d_model=2560, d_expert=768)
     assert held.held == (8, 8)
-    assert T.experts_use_kernel(held, 128 * 8)
-    assert not T.experts_use_kernel(held, 128 * 8 - 1)
-    assert T.prefill_experts_use_kernel(held, 10240)
-    assert not T.prefill_experts_use_kernel(held, 64)
+    assert RT.experts_use_kernel(held, 128 * 8)
+    assert not RT.experts_use_kernel(held, 128 * 8 - 1)
+    assert RT.prefill_experts_use_kernel(held, 10240)
+    assert not RT.prefill_experts_use_kernel(held, 64)
     large = dataclasses.replace(wide, d_model=6144, d_expert=2048)
-    assert not T.experts_use_kernel(large, 2048)
-    assert not T.prefill_experts_use_kernel(large, 10240)
+    assert not RT.experts_use_kernel(large, 2048)
+    assert not RT.prefill_experts_use_kernel(large, 10240)
     sharded = dataclasses.replace(wide, fused_attention=False)
-    assert not T.latent_prefill_uses_kernel(sharded, 10240)
-    assert not T.latent_step_uses_kernel(sharded)
+    assert not LAT.latent_prefill_uses_kernel(sharded, 10240)
+    assert not LAT.latent_step_uses_kernel(sharded)
 
 
 # ------------------------- (g) the accepted configurations' programs

@@ -1,4 +1,4 @@
-"""The `sparse` and `linear` mixers of the decoder (models/transformer.py
+"""The `sparse` and `linear` mixers of the decoder (models/mixers/,
 `LayerSpec.mixer`, `SparseSpec`), the SwiGLU feed-forward, the norms and
 gates around a mixer and MiniCPM's three scalings, served through the slot
 cache (`prefill_into_slot`'s and `decode_step_slots`' cores, and the
@@ -18,8 +18,8 @@ dense up to 32 positions.
     selection's kernel (ops/sparse_attention.py `sparse_select`),
     interpreted, choosing `select_blocks`' set element for element;
 (f) the row-wise pass over q and k (ops/rowwise.py) and the scan kernel's
-    output norm round where the program a TPU runs today rounds (`_rmsnorm`,
-    `_rope` and `_linear_out` with float32 between the first two), at the
+    output norm round where the program a TPU runs today rounds (`rmsnorm`,
+    `rope` and `_linear_out` with float32 between the first two), at the
     positions the third cell has; a table or a position in bf16 does not,
     nor does a second rounding between the norm and the rotation.
 """
@@ -35,7 +35,13 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from pathway_tpu.models import config as CF
+from pathway_tpu.models import encoder as EN
+from pathway_tpu.models import layers as LY
 from pathway_tpu.models import transformer as T
+from pathway_tpu.models.mixers import linear as LIN
+from pathway_tpu.models.mixers import softmax as SM
+from pathway_tpu.models.mixers import sparse as SPA
 from pathway_tpu.ops import attention as A
 from pathway_tpu.ops import linear_attention as L
 from pathway_tpu.ops import rowwise as R
@@ -160,7 +166,7 @@ def test_slot_cache_matches_the_plain_reference_in_bfloat16(length, width):
 def _interpret_the_prefill_kernels(monkeypatch):
     """The rule says kernel, as on a TPU, and every kernel a prefill then
     takes is interpreted (tiles of 128)."""
-    monkeypatch.setattr(T, "prefill_uses_kernel", lambda cfg, p: True)
+    monkeypatch.setattr(SM, "prefill_uses_kernel", lambda cfg, p: True)
     for module, name in (
         (A, "prefill_attention"), (L, "linear_prefill_attention"),
         (S, "sparse_prefill_attention"), (S, "sparse_select"), (R, "rowwise_heads"),
@@ -186,9 +192,9 @@ def test_slot_cache_through_the_kernels_matches_the_plain_reference(
         {**KERNEL_KEYS, "sparse_config": {**KEYS["sparse_config"], "dense_len": 64}},
         jnp.float32,
     )
-    assert T.linear_prefill_uses_kernel(cfg, width)
-    assert T.sparse_prefill_uses_kernel(cfg, width)
-    assert T.rowwise_uses_kernel(cfg, width)
+    assert LIN.linear_prefill_uses_kernel(cfg, width)
+    assert SPA.sparse_prefill_uses_kernel(cfg, width)
+    assert SM.rowwise_uses_kernel(cfg, width)
     sizes = FAMILY.sizes(
         {**KERNEL_KEYS, "sparse_config": {**KEYS["sparse_config"], "dense_len": 64}}
     )
@@ -205,7 +211,9 @@ def test_the_slot_programs_send_the_mixers_counters_behind_their_tokens():
         _params(), jnp.asarray(ids), jnp.asarray(mask), T.init_kv_cache(cfg, slots),
         jnp.asarray(1), cfg,
     )
-    assert T.prefill_counters(cfg) == T.MIXER_COUNTERS == T.step_counters(cfg)
+    assert T.prefill_counters(cfg) == T.step_counters(cfg) == (
+        "sparse_blocks_read", "sparse_blocks_visible", "linear_tokens"
+    )
     assert first.shape == (1 + 3,)
     blocks = [min(t // 8 + 1, 5) for t in range(60)]
     visible = [t // 8 + 1 for t in range(60)]
@@ -232,8 +240,8 @@ def test_the_cache_has_a_leaf_for_each_kind_and_no_other():
         "state": ((3, 3, 4, 16, 16), "float32"),
     }
     rows = T._cache_rows(cfg)
-    assert rows[0] == ({"k": "k_sparse", "v": "v_sparse", "pool": "k_pool"}, 0)
-    assert [r for r in rows[1:]] == [({"state": "state"}, i) for i in range(3)]
+    assert rows[0] == (SPA.SPARSE, 0)
+    assert [r for r in rows[1:]] == [(LIN.LINEAR, i) for i in range(3)]
 
 
 @pytest.mark.parametrize("kw, message", [
@@ -253,7 +261,7 @@ def test_forward_refuses_the_new_kinds():
     cfg = FAMILY.program_config(KEYS, jnp.float32)
     assert not cfg.plain
     with pytest.raises(NotImplementedError):
-        T.forward(_params(), jnp.zeros((1, 8), jnp.int32), jnp.ones((1, 8), jnp.int32), cfg)
+        EN.forward(_params(), jnp.zeros((1, 8), jnp.int32), jnp.ones((1, 8), jnp.int32), cfg)
 
 
 # ------------------------------------------------ (b) a slot taken again
@@ -312,7 +320,7 @@ def _recurrence(q, k, v, slopes):
     b, p, h, dh = q.shape
     state, outs = jnp.zeros((b, h, dh, dh), jnp.float32), []
     for t in range(p):
-        out, state = T.linear_step(q[:, t], k[:, t], v[:, t], state, slopes)
+        out, state = LIN.linear_step(q[:, t], k[:, t], v[:, t], state, slopes)
         outs.append(out)
     return jnp.stack(outs, axis=1), state
 
@@ -323,7 +331,7 @@ def test_the_chunked_scan_is_the_recurrence_token_by_token(p, chunk):
     state are the same sums as one position after another (1e-4 of values
     of a few units; a width the chunk does not divide is padded in front)."""
     q, k, v = _qkv(2, p, 4, 16, seed=p)
-    out, state = T.linear_scan(q, k, v, SLOPES, chunk)
+    out, state = LIN.linear_scan(q, k, v, SLOPES, chunk)
     want, want_state = _recurrence(q, k, v, SLOPES)
     assert np.abs(np.asarray(out - want)).max() < 1e-4
     assert np.abs(np.asarray(state - want_state)).max() < 1e-4
@@ -331,9 +339,9 @@ def test_the_chunked_scan_is_the_recurrence_token_by_token(p, chunk):
 
 def test_a_step_is_one_more_position_of_the_scan():
     q, k, v = _qkv(2, 33, 4, 16, seed=3)
-    out, state = T.linear_scan(q, k, v, SLOPES, 8)
-    _, before = T.linear_scan(q[:, :32], k[:, :32], v[:, :32], SLOPES, 8)
-    one, after = T.linear_step(q[:, 32], k[:, 32], v[:, 32], before, SLOPES)
+    out, state = LIN.linear_scan(q, k, v, SLOPES, 8)
+    _, before = LIN.linear_scan(q[:, :32], k[:, :32], v[:, :32], SLOPES, 8)
+    one, after = LIN.linear_step(q[:, 32], k[:, 32], v[:, 32], before, SLOPES)
     assert np.abs(np.asarray(one - out[:, 32])).max() < 1e-5
     assert np.abs(np.asarray(after - state)).max() < 1e-5
 
@@ -344,9 +352,9 @@ def test_pads_in_front_add_nothing_and_decay_nothing():
     front = lambda a, fill: jnp.concatenate(  # noqa: E731
         [jnp.full((1, pad) + a.shape[2:], fill, a.dtype), a], axis=1
     )
-    out, state = T.linear_scan(q, k, v, SLOPES, 16)
+    out, state = LIN.linear_scan(q, k, v, SLOPES, 16)
     # a pad's key is zeroed by the layer; its query and value are anything
-    padded, padded_state = T.linear_scan(
+    padded, padded_state = LIN.linear_scan(
         front(q, 3.0), front(k, 0.0), front(v, -2.0), SLOPES, 16
     )
     assert np.abs(np.asarray(padded[:, pad:] - out)).max() < 1e-5
@@ -365,7 +373,7 @@ def test_the_scan_kernel_matches_the_jnp_scan(p, chunk, pad):
     live = (jnp.arange(p) >= pad)[None, :, None, None]
     k = jnp.where(live, k, jnp.zeros_like(k))
     slopes = jnp.asarray([0.84, 0.0039], jnp.float32)
-    want, want_state = T.linear_scan(q, k, v, slopes, chunk)
+    want, want_state = LIN.linear_scan(q, k, v, slopes, chunk)
     got, state = L.linear_prefill_attention(q, k, v, slopes, chunk, interpret=True)
     assert got.shape == want.shape and got.dtype == jnp.float32
     scale = float(jnp.abs(want).max())
@@ -376,14 +384,14 @@ def test_the_scan_kernel_matches_the_jnp_scan(p, chunk, pad):
 
 
 def _chosen(q, k, valid, sq):
-    """The blocks `_prefill_sparse` would hand the kernel."""
+    """The blocks the sparse kind's `prefill` would hand the kernel."""
     b, p, h, dh = q.shape
     hk = k.shape[2]
     n = valid.sum(axis=1)
     turn = jax.vmap(lambda a, by: jnp.roll(a, by, axis=1))
-    pooled = T.pool_keys(turn(k.transpose(0, 2, 1, 3), n - p), sq)
+    pooled = SPA.pool_keys(turn(k.transpose(0, 2, 1, 3), n - p), sq)
     at = jnp.where(valid > 0, jnp.cumsum(valid, axis=1) - 1, -1)
-    blocks = T.select_blocks(
+    blocks = SPA.select_blocks(
         q.reshape(b, p, hk, h // hk, dh), pooled, at, n <= sq.dense_len, sq
     )
     return blocks, at
@@ -392,7 +400,7 @@ def _chosen(q, k, valid, sq):
 @pytest.mark.parametrize("pads", [(0, 0), (0, 37), (130, 255)])
 def test_the_selected_block_kernel_matches_the_jnp_attention(pads, monkeypatch):
     """Interpreted, bf16 inputs, 4 query heads over 2 key heads of 128,
-    tiles of 128: the kernel against `_attend` under the mask of the same
+    tiles of 128: the kernel against `attend` under the mask of the same
     chosen blocks, on the rows that are real."""
     monkeypatch.setattr(A, "_PREFILL_TILE_MAX", 128)
     b, p = 2, 256
@@ -402,17 +410,17 @@ def test_the_selected_block_kernel_matches_the_jnp_attention(pads, monkeypatch):
     v = jnp.asarray(rng.standard_normal((b, p, 2, 128)), jnp.bfloat16)
     valid = (jnp.arange(p)[None, :] >= jnp.asarray(pads)[:, None]).astype(jnp.int32)
     blocks, at = _chosen(q, k, valid, SQ)
-    ok = T._keys_of_blocks(blocks, at, SQ) & T._build_mask(valid, causal=True)
-    cfg = T.lm_config(dtype=jnp.bfloat16)
-    want = T._attend(q, k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3), ok, cfg)
+    ok = SPA._keys_of_blocks(blocks, at, SQ) & LY.build_mask(valid, causal=True)
+    cfg = CF.lm_config(dtype=jnp.bfloat16)
+    want = LY.attend(q, k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3), ok, cfg)
     got = S.sparse_prefill_attention(q, k, v, valid, blocks, SQ.block, interpret=True)
     diff = jnp.abs(got.astype(jnp.float32) - want.astype(jnp.float32))
     assert float(jnp.where(valid[:, :, None] > 0, diff, 0.0).max()) < 0.04
     assert bool(jnp.isfinite(got.astype(jnp.float32)).all())
     # and it is not the dense attention: most rows read fewer blocks
-    dense = T._attend(
+    dense = LY.attend(
         q, k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3),
-        T._build_mask(valid, causal=True), cfg,
+        LY.build_mask(valid, causal=True), cfg,
     )
     assert float(jnp.abs(dense.astype(jnp.float32) - want.astype(jnp.float32)).max()) > 0.5
 
@@ -425,9 +433,9 @@ def test_what_the_selection_always_takes_and_how_much():
     b, p, hk, g, dh = 2, 120, 2, 2, 16
     q = jnp.asarray(3 * rng.standard_normal((b, p, hk, g, dh)), jnp.float32)
     k = jnp.asarray(rng.standard_normal((b, hk, p, dh)), jnp.float32)
-    pooled = T.pool_keys(k, SQ)
+    pooled = SPA.pool_keys(k, SQ)
     t = jnp.broadcast_to(jnp.arange(p)[None], (b, p))
-    blocks = np.asarray(T.select_blocks(q, pooled, t, jnp.asarray([False, False]), SQ))
+    blocks = np.asarray(SPA.select_blocks(q, pooled, t, jnp.asarray([False, False]), SQ))
     assert blocks.shape == (b, hk, p, p // SQ.block)  # one set a group
     own = np.arange(p) // SQ.block
     for at in range(p):
@@ -440,7 +448,7 @@ def test_what_the_selection_always_takes_and_how_much():
     # the key heads choose for themselves
     assert (blocks[:, 0] != blocks[:, 1]).any()
     # a dense row takes every block at or before the query
-    dense = np.asarray(T.select_blocks(q, pooled, t, jnp.asarray([True, False]), SQ))
+    dense = np.asarray(SPA.select_blocks(q, pooled, t, jnp.asarray([True, False]), SQ))
     assert (dense[0].sum(-1) == own + 1).all()
     assert (dense[1] == blocks[1]).all()
 
@@ -459,8 +467,8 @@ def test_the_selection_is_the_references():
     theirs = FAMILY._chosen_blocks(
         q, jnp.mean(k[at], axis=1), jnp.arange(s), False, sp, lo, hi, False
     )
-    pooled = T.pool_keys(k.transpose(1, 0, 2)[None], SQ)
-    ours = T.select_blocks(
+    pooled = SPA.pool_keys(k.transpose(1, 0, 2)[None], SQ)
+    ours = SPA.select_blocks(
         q[None], pooled, jnp.arange(s)[None], jnp.asarray([False]), SQ
     )[0]
     assert np.array_equal(np.asarray(ours), np.asarray(theirs))
@@ -468,7 +476,7 @@ def test_the_selection_is_the_references():
 
 def _selection_case(case: str):
     """(q [b, nq, kv heads, group, 128], pooled, t [b, nq], dense [b]) bf16,
-    as `_prefill_sparse` hands a chunk to the selection: pooled keys of a
+    as the sparse kind's `prefill` hands a chunk to the selection: pooled keys of a
     whole prompt of 512 positions (64 blocks) from its first real token."""
     rng = np.random.default_rng(len(case))
     b, p, hk, g, dh = 2, 512, 2, 2, 128
@@ -492,7 +500,7 @@ def _selection_case(case: str):
         q, t = q[:, 256:384], t[:, 256:384]
     if case == "few_blocks":  # 40 queries: none sees topk blocks yet
         q, t = q[:, :40], t[:, :40]
-    pooled = T.pool_keys(jnp.asarray(k, jnp.bfloat16), SQ)
+    pooled = SPA.pool_keys(jnp.asarray(k, jnp.bfloat16), SQ)
     return (
         jnp.asarray(q, jnp.bfloat16), pooled, jnp.asarray(t, jnp.int32),
         jnp.asarray(dense),
@@ -509,7 +517,7 @@ def test_the_selection_kernel_chooses_select_blocks_set(case):
     selection always takes and how much (the init block, the local blocks,
     nothing after the query, min(topk, blocks at or before it))."""
     q, pooled, t, dense = _selection_case(case)
-    want = np.asarray(T.select_blocks(q, pooled, t, dense, SQ))
+    want = np.asarray(SPA.select_blocks(q, pooled, t, dense, SQ))
     got = np.asarray(
         S.sparse_select(q, pooled, t, dense, SQ, key_blocks=16, interpret=True)
     )
@@ -544,7 +552,7 @@ def test_the_step_kernel_matches_the_jnp_attention_and_its_row_write():
     """Interpreted, bf16, 4 query heads over 2 key heads of 128, tiles of
     two blocks: a free slot, a dense row (every block before it, ten of
     them in five tiles) and a row past `dense_len` (6 chosen blocks), against
-    `_attend` under the mask of the same blocks after the indexed row write:
+    `attend` under the mask of the same blocks after the indexed row write:
     the context to bf16's last place, the leaves bit for bit."""
     slots, h, hk, dh, rows = 3, 4, 2, 128, 512
     sq = T.SparseSpec(topk=6, block=16, kernel=8, stride=4, init_blocks=1,
@@ -562,8 +570,8 @@ def test_the_step_kernel_matches_the_jnp_attention_and_its_row_write():
         for _ in range(2)
     )
     t, li = jnp.asarray([0, 150, 437], jnp.int32), 1
-    blocks = T.select_blocks(
-        q.reshape(slots, 1, hk, h // hk, dh), T.pool_keys(kc[li], sq), t[:, None],
+    blocks = SPA.select_blocks(
+        q.reshape(slots, 1, hk, h // hk, dh), SPA.pool_keys(kc[li], sq), t[:, None],
         t < sq.dense_len, sq,
     )
     assert blocks[:, :, 0].sum(-1).tolist() == [[1, 1], [10, 10], [6, 6]]
@@ -571,8 +579,8 @@ def test_the_step_kernel_matches_the_jnp_attention_and_its_row_write():
     k2 = kc.at[li, r, hd, t[:, None]].set(kn)
     v2 = vc.at[li, r, hd, t[:, None]].set(vn)
     at = jnp.broadcast_to(jnp.arange(rows)[None], (slots, rows))
-    ok = T._keys_of_blocks(blocks, at, sq) & (at <= t[:, None])[:, None, None, :]
-    want = T._attend(q[:, None], k2[li], v2[li], ok, T.lm_config(dtype=jnp.bfloat16))[:, 0]
+    ok = SPA._keys_of_blocks(blocks, at, sq) & (at <= t[:, None])[:, None, None, :]
+    want = LY.attend(q[:, None], k2[li], v2[li], ok, CF.lm_config(dtype=jnp.bfloat16))[:, 0]
     got, k3, v3 = S.sparse_decode_attention(
         q, kn, vn, kc, vc, jnp.asarray(li), t, blocks[:, :, 0], block=sq.block,
         tile=tile, steps=sq.topk, interpret=True,
@@ -591,14 +599,14 @@ def test_the_steps_through_the_kernels_serve_the_plain_paths_logits(monkeypatch)
         window_size=32, dense_len=96,
     )}
     cfg = FAMILY.program_config(keys, jnp.float32)
-    assert not T.sparse_step_uses_kernel(cfg)  # this process runs on the CPU
+    assert not SPA.sparse_step_uses_kernel(cfg)  # this process runs on the CPU
     plain = [_served_logits(cfg, _prompt(n), w)[0] for n, w in ((90, 96), (150, 160))]
-    monkeypatch.setattr(T, "step_uses_kernel", lambda cfg: True)
+    monkeypatch.setattr(SM, "step_uses_kernel", lambda cfg: True)
     monkeypatch.setattr(
         S, "sparse_decode_attention",
         functools.partial(S.sparse_decode_attention, interpret=True),
     )
-    assert T.sparse_step_uses_kernel(cfg)
+    assert SPA.sparse_step_uses_kernel(cfg)
     for want, (n, w) in zip(plain, ((90, 96), (150, 160))):
         got = _served_logits(cfg, _prompt(n), w)[0]
         assert np.abs(got - want).max() < 1e-4
@@ -635,7 +643,7 @@ def _rounds_alike(got, want) -> bool:
 ROW_POS = np.concatenate([
     np.arange(0, 256), np.arange(24_000, 24_576), np.arange(32_768 - 192, 32_768)
 ]).astype(np.int32)[None]
-ROPE_CFG = T.lm_config(vocab_size=64, d_model=512, n_heads=4, n_layers=1,
+ROPE_CFG = CF.lm_config(vocab_size=64, d_model=512, n_heads=4, n_layers=1,
                        max_len=32_768, rope_theta=10_000.0, dtype=jnp.bfloat16)
 
 
@@ -652,17 +660,17 @@ def _product(seed: int = 0):
 
 
 def _chips_chain(x, scale, rotary, live):
-    """What `_layer` and `_prefill_linear` do to q or k [b, p, heads, dh] in
-    the program XLA compiles for a TPU: `_rmsnorm` and `_rope` themselves,
+    """What `_layer` and the linear kind's `prefill` do to q or k [b, p,
+    heads, dh] in the program XLA compiles for a TPU: `rmsnorm` and `rope` themselves,
     handed float32 so that the cast between them is none (inside a fusion
     the TPU backend drops the pair of casts: my chip run, PR 42, a third of
     the elements differ from the chain with the cast and none from this
     one), and one rounding behind them."""
     y = x.astype(jnp.float32)
     if scale is not None:
-        y = T._rmsnorm(y, scale)
+        y = LY.rmsnorm(y, scale)
     if rotary:
-        y = T._rope(y, jnp.asarray(ROW_POS), ROPE_CFG)
+        y = LY.rope(y, jnp.asarray(ROW_POS), ROPE_CFG)
     y = y.astype(x.dtype)
     if live is not None:
         y = jnp.where(live[:, :, None, None], y, jnp.zeros_like(y))
@@ -687,8 +695,8 @@ def test_the_rowwise_pass_rounds_where_the_chips_program_rounds(norm, rotary, ze
         x = qkv[..., first * 128:(first + heads) * 128].reshape(1, p, heads, 128)
         want = _chips_chain(x, scale if norm else None, rotary, live if zero else None)
         if not (norm and rotary):  # one link: the plain functions as they are
-            plain = T._rmsnorm(x, scale) if norm else x
-            plain = T._rope(plain, jnp.asarray(ROW_POS), ROPE_CFG) if rotary else plain
+            plain = LY.rmsnorm(x, scale) if norm else x
+            plain = LY.rope(plain, jnp.asarray(ROW_POS), ROPE_CFG) if rotary else plain
             if zero:
                 plain = jnp.where(live[:, :, None, None], plain, jnp.zeros_like(plain))
             assert _rounds_alike(plain, want)
@@ -714,7 +722,7 @@ def test_the_rule_sees_arithmetic_that_is_not_the_present_programs(fault):
     want = _chips_chain(q, q_scale, True, None)
     pos = jnp.asarray(ROW_POS)
     if fault == "rounded_after_the_norm":
-        got = T._rope(T._rmsnorm(q, q_scale), pos, ROPE_CFG)
+        got = LY.rope(LY.rmsnorm(q, q_scale), pos, ROPE_CFG)
     else:
         if fault == "tables_in_bf16":
             rope = tuple(
@@ -735,7 +743,7 @@ def test_the_rule_sees_arithmetic_that_is_not_the_present_programs(fault):
 @pytest.mark.parametrize("p, chunk, pad", [(256, 128, 0), (256, 128, 37), (200, 64, 11)])
 def test_the_scan_kernels_output_norm_is_linear_out(p, chunk, pad):
     """Interpreted, bf16 inputs: the kernel with the norm's scale, cast as
-    `_prefill_linear` casts it, against `_linear_out` of its own un-normed
+    the linear kind's `prefill` casts it, against `_linear_out` of its own un-normed
     output (the present program) and of `linear_scan`'s (the definition), by
     this section's rule; the state is the same kernel's."""
     q, k, v = _qkv(2, p, 2, 128, seed=p + pad, dtype=jnp.bfloat16)
@@ -748,7 +756,7 @@ def test_the_scan_kernels_output_norm_is_linear_out(p, chunk, pad):
     got, state = L.linear_prefill_attention(
         q, k, v, slopes, chunk, block["o_norm"], interpret=True
     )
-    # float32 out of the kernel: the cast is `_prefill_linear`'s, as it is
+    # float32 out of the kernel: the cast is the linear kind's `prefill`'s, as it is
     # `_linear_out`'s, so that XLA makes it where it makes that one
     assert got.dtype == jnp.float32 and got.shape == (2, p, 2, 128)
     got = got.astype(jnp.bfloat16)
@@ -756,8 +764,8 @@ def test_the_scan_kernels_output_norm_is_linear_out(p, chunk, pad):
     assert plain.dtype == jnp.float32
     assert jnp.array_equal(state, plain_state)
     live = np.arange(p) >= pad  # a pad row's output is its query's alone
-    for out32 in (plain, T.linear_scan(q, k, v, slopes, chunk)[0]):
-        want = T._linear_out(out32, block, cfg).reshape(got.shape)
+    for out32 in (plain, LIN.linear_scan(q, k, v, slopes, chunk)[0]):
+        want = LIN._linear_out(out32, block, cfg).reshape(got.shape)
         assert _rounds_alike(got[:, live], want[:, live])
 
 
@@ -767,13 +775,13 @@ def test_a_prefill_and_steps_through_the_rowwise_pass_serve_the_plain_paths_logi
     """`prefill_into_slot`'s core and its scatter and the steps behind them
     with the rule saying kernel (interpreted): q and k through
     `rowwise_heads` in all four layers, the output norm in the scan kernel,
-    against the plain path's logits (`_rmsnorm`, `_rope`, `_linear_out`), in
+    against the plain path's logits (`rmsnorm`, `rope`, `_linear_out`), in
     float32, where both are the same function."""
     cfg = FAMILY.program_config(
         {**KERNEL_KEYS, "sparse_config": {**KEYS["sparse_config"], "dense_len": 64}},
         jnp.float32,
     )
-    assert not T.rowwise_uses_kernel(cfg, 128)  # this process runs on the CPU
+    assert not SM.rowwise_uses_kernel(cfg, 128)  # this process runs on the CPU
     want, want_toks = _served_logits(cfg, _prompt(100), 128)
     _interpret_the_prefill_kernels(monkeypatch)
     calls = []
@@ -781,7 +789,7 @@ def test_a_prefill_and_steps_through_the_rowwise_pass_serve_the_plain_paths_logi
     monkeypatch.setattr(
         R, "rowwise_heads", lambda *a, **kw: calls.append(kw) or rowwise(*a, **kw)
     )
-    assert T.rowwise_uses_kernel(cfg, 128)
+    assert SM.rowwise_uses_kernel(cfg, 128)
     got, toks = _served_logits(cfg, _prompt(100), 128)
     # q and k of four layers, in each of the helper's two traces of a prefill
     assert len(calls) == 2 * 2 * 4

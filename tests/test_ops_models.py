@@ -122,11 +122,11 @@ def test_encoder_mask_ignores_padding():
 
 
 def test_train_step_reduces_loss():
-    from pathway_tpu.models import transformer as tfm
+    from pathway_tpu.models import encoder, lm_config, transformer as tfm
 
-    cfg = tfm.lm_config(vocab_size=64, d_model=32, n_heads=4, n_layers=2, d_ff=64, max_len=12)
+    cfg = lm_config(vocab_size=64, d_model=32, n_heads=4, n_layers=2, d_ff=64, max_len=12)
     params = tfm.init_params(jax.random.PRNGKey(0), cfg)
-    init_opt, train_step = tfm.make_train_step(cfg, learning_rate=1e-2)
+    init_opt, train_step = encoder.make_train_step(cfg, learning_rate=1e-2)
     opt_state = init_opt(params)
     step = jax.jit(train_step)
     ids = jnp.asarray(np.random.default_rng(0).integers(2, 64, (4, 12)), jnp.int32)
@@ -139,9 +139,9 @@ def test_train_step_reduces_loss():
 
 
 def test_generate_matches_full_forward_greedy():
-    from pathway_tpu.models import transformer as tfm
+    from pathway_tpu.models import encoder, lm_config, transformer as tfm
 
-    cfg = tfm.lm_config(vocab_size=64, d_model=32, n_heads=4, n_layers=2, d_ff=64, max_len=24)
+    cfg = lm_config(vocab_size=64, d_model=32, n_heads=4, n_layers=2, d_ff=64, max_len=24)
     params = tfm.init_params(jax.random.PRNGKey(0), cfg)
     prompt = jnp.asarray([[1, 5, 9, 13]], jnp.int32)
     n_steps = 6
@@ -153,7 +153,7 @@ def test_generate_matches_full_forward_greedy():
     import functools
 
     total = 4 + n_steps
-    lgf = jax.jit(functools.partial(tfm.logits, cfg=cfg))
+    lgf = jax.jit(functools.partial(encoder.logits, cfg=cfg))
     seq = np.zeros((1, total), np.int32)
     seq[:, :4] = np.asarray(prompt)
     for cur in range(4, total):
@@ -164,9 +164,9 @@ def test_generate_matches_full_forward_greedy():
 
 
 def test_generate_guards():
-    from pathway_tpu.models import transformer as tfm
+    from pathway_tpu.models import embedder_config, encoder, lm_config, transformer as tfm
 
-    cfg = tfm.lm_config(vocab_size=64, d_model=32, n_heads=4, n_layers=2, d_ff=64, max_len=8)
+    cfg = lm_config(vocab_size=64, d_model=32, n_heads=4, n_layers=2, d_ff=64, max_len=8)
     params = tfm.init_params(jax.random.PRNGKey(0), cfg)
     prompt = jnp.asarray([[1, 5, 9, 13]], jnp.int32)
     with pytest.raises(ValueError, match="max_len"):
@@ -174,8 +174,8 @@ def test_generate_guards():
     with pytest.raises(ValueError, match="rng"):
         tfm.generate(params, prompt, n_steps=2, cfg=cfg, temperature=0.5)
     with pytest.raises(ValueError, match="causal"):
-        tfm.lm_loss(params, prompt, jnp.ones_like(prompt),
-                    tfm.embedder_config(vocab_size=64, d_model=32, n_heads=4,
+        encoder.lm_loss(params, prompt, jnp.ones_like(prompt),
+                        embedder_config(vocab_size=64, d_model=32, n_heads=4,
                                         n_layers=2, d_ff=64, max_len=8))
     with pytest.raises(ValueError, match="pool"):
         tfm.TransformerConfig(pool="menu")
@@ -195,9 +195,9 @@ def test_hash_tokenizer():
 
 
 def test_param_sharding_specs_cover_params():
-    from pathway_tpu.models import transformer as tfm
+    from pathway_tpu.models import embedder_config, transformer as tfm
 
-    cfg = tfm.embedder_config(vocab_size=64, d_model=32, n_heads=4, n_layers=2, d_ff=64, max_len=8)
+    cfg = embedder_config(vocab_size=64, d_model=32, n_heads=4, n_layers=2, d_ff=64, max_len=8)
     params = tfm.init_params(jax.random.PRNGKey(0), cfg)
     specs = tfm.param_specs(cfg)
     jax.tree.map(lambda p, s: None, params, specs)  # same treedef or raises
@@ -220,9 +220,9 @@ def test_fused_qkv_attention_matches_reference():
 
 
 def test_cast_params_bf16():
-    from pathway_tpu.models import transformer as tfm
+    from pathway_tpu.models import embedder_config, encoder, transformer as tfm
 
-    cfg = tfm.embedder_config(
+    cfg = embedder_config(
         vocab_size=64, d_model=32, n_heads=4, n_layers=2, d_ff=64, max_len=8
     )
     params = tfm.init_params(jax.random.PRNGKey(0), cfg)
@@ -232,7 +232,7 @@ def test_cast_params_bf16():
     # encode works on the cast tree
     ids = jnp.zeros((2, 8), jnp.int32)
     m = jnp.ones((2, 8), jnp.int32)
-    out = tfm.encode(cast, ids, m, cfg)
+    out = encoder.encode(cast, ids, m, cfg)
     assert out.shape == (2, 32)
 
 

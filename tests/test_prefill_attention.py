@@ -1,7 +1,7 @@
 """The prefill's attention kernel (ops/attention.py `prefill_attention`) in
-Pallas interpret mode on the CPU against the plain `_attend` with the
+Pallas interpret mode on the CPU against the plain `attend` with the
 explicit mask, and the rule that chooses between the two
-(models/transformer.py `prefill_uses_kernel`). The tile is capped at 128
+(models/mixers/softmax.py `prefill_uses_kernel`). The tile is capped at 128
 here so that a width of a few hundred has a diagonal, a band and a padded
 tile to skip; the chip's own compiler sees the real shapes in
 tests/test_prefill_kernel_v5e.py.
@@ -15,7 +15,9 @@ import numpy as np
 import pytest
 
 from pathway_tpu.models import lm_config
+from pathway_tpu.models import layers as LY
 from pathway_tpu.models import transformer as T
+from pathway_tpu.models.mixers import softmax as SM
 from pathway_tpu.ops import attention as A
 from pathway_tpu.ops.sparse_attention import sparse_prefill_attention
 
@@ -39,16 +41,16 @@ def _inputs(p, heads, kv_heads, pad, dtype, seed=0):
 
 
 def _plain(q, k, v, valid, window):
-    """`_attend` over the [heads, p, p] square with the mask `_prefill`
+    """`attend` over the [heads, p, p] square with the mask `_prefill`
     builds off the chip."""
     p = q.shape[1]
-    ok = T._build_mask(valid, causal=True)
+    ok = LY.build_mask(valid, causal=True)
     if window is not None:
         at = jnp.arange(p)
         ok = ok & (at[None, :] > at[:, None] - window)[None, None]
-    # `_attend` reads keys as the cache lies: [b, kv heads, p, dh]
+    # `attend` reads keys as the cache lies: [b, kv heads, p, dh]
     k, v = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
-    return T._attend(q, k, v, ok, lm_config(dtype=q.dtype))
+    return LY.attend(q, k, v, ok, lm_config(dtype=q.dtype))
 
 
 # width, query heads, key heads, left padding, window. One tile, several,
@@ -70,9 +72,9 @@ SHAPES = [
     (224, 2, 2, 5, None),
     (224, 2, 1, 100, 64),
 ]
-# bfloat16 against `_attend` in bfloat16: both round the weights of the
+# bfloat16 against `attend` in bfloat16: both round the weights of the
 # value product to 8 bits, the kernel before its division by the sum and
-# `_attend` after it, over values of unit spread: the largest difference
+# `attend` after it, over values of unit spread: the largest difference
 # stays under 0.05 (read: 0.008-0.016)
 CASES = [(*s, jnp.float32, 1e-4) for s in SHAPES] + [
     (*s, jnp.bfloat16, 0.05) for s in SHAPES[2::2]
@@ -197,4 +199,4 @@ GPT2_XL = dict(d_model=1600, n_heads=25, n_layers=1, d_ff=64, max_len=1024)
 def test_the_rule_that_chooses_the_path(keys, width, backend, want, monkeypatch):
     monkeypatch.setattr(jax, "default_backend", lambda: backend)
     cfg = lm_config(vocab_size=64, **keys)
-    assert T.prefill_uses_kernel(cfg, width) is want
+    assert SM.prefill_uses_kernel(cfg, width) is want

@@ -21,7 +21,11 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from pathway_tpu.models import LayerSpec, lm_config
+from pathway_tpu.models import routed as RT
 from pathway_tpu.models import transformer as T
+from pathway_tpu.models.mixers import latent as LAT
+from pathway_tpu.models.mixers import softmax as SM
+from pathway_tpu.models.mixers import sparse as SPA
 from pathway_tpu.ops.attention import decode_attention, prefill_attention
 from pathway_tpu.ops.experts import combine_experts, grouped_experts
 
@@ -228,7 +232,7 @@ def test_the_expert_kernels_compile_for_v5e(pairs, d, ff, experts, dtype, chip):
 def test_the_combine_kernel_compiles_for_v5e(tokens, k, d, chip):
     """The second cell's prefill, and the most pairs `experts_use_kernel`
     sends here: their row indices fit the chip's scalar memory."""
-    assert tokens * k <= T._EXPERT_KERNEL_MAX_PAIRS
+    assert tokens * k <= RT._EXPERT_KERNEL_MAX_PAIRS
     compiled = jax.jit(combine_experts, static_argnames=("dtype",)).lower(
         jax.ShapeDtypeStruct((tokens * k, d // 128, 128), jnp.float32, sharding=chip),
         jax.ShapeDtypeStruct((k, tokens), jnp.int32, sharding=chip),
@@ -254,7 +258,7 @@ def test_a_prefills_expert_kernels_read_the_leaves_where_they_lie(
         layers=(LayerSpec(pos="none", ff="experts"),
                 LayerSpec(window=512, pos="rotary", ff="experts")),
     )
-    assert T.prefill_experts_use_kernel(cfg, 1280)
+    assert RT.prefill_experts_use_kernel(cfg, 1280)
     params = _shaped(
         chip, lambda: T.init_params(jax.random.PRNGKey(0), cfg, jnp.bfloat16)
     )
@@ -398,7 +402,7 @@ def test_the_selected_block_kernel_compiles_for_v5e(
     assert 'custom_call_target="tpu_custom_call"' in text
 
 
-# the third cell's selection: a chunk of 768 queries as `_prefill_sparse`
+# the third cell's selection: a chunk of 768 queries as the sparse kind's `prefill`
 # hands it over and the whole prompt at once; queries that are no whole
 # tile against 512 blocks (padded inside); two rows in float32
 @pytest.mark.parametrize("b, nq, heads, kv_heads, n_pool, dtype", [
@@ -463,7 +467,7 @@ def test_the_third_cells_step_reads_and_writes_the_cache_where_it_lies(
     over the leaf, no slice of it for the pooled key's window)."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     cfg = _sala()
-    assert T.sparse_step_uses_kernel(cfg)
+    assert SPA.sparse_step_uses_kernel(cfg)
     slots = 8
     params = _shaped(
         chip, lambda: T.init_params(jax.random.PRNGKey(0), cfg, jnp.bfloat16)
@@ -524,7 +528,7 @@ def test_the_third_cells_prefill_holds_its_three_kernels(chip, monkeypatch):
     # q and k of every layer through ops/rowwise.py's pass: four lowerings
     # (a sparse layer's q and k, normed; a linear layer's, rotated too and
     # k's pads zeroed), and the cosines and sines made once
-    assert T.rowwise_uses_kernel(cfg, 10240)
+    assert SM.rowwise_uses_kernel(cfg, 10240)
     assert long.count("tpu_custom_call") == 1 + 1 + 1 + 4
     assert len(re.findall(r"stablehlo\.cosine", long)) == 1
     short = lowered(1024)
@@ -560,22 +564,22 @@ def _two_programs(cfg, width, chip) -> tuple[str, str]:
 def test_the_first_configurations_programs_do_not_change(chip, monkeypatch):
     """A decoder of the first cell's kind (learned positions, no q/k norm,
     softmax layers, heads of 128) on a TPU: `rowwise_uses_kernel` does not
-    hold, so `kernel_rowwise_prefills` stays 0, and its two programs lower
-    to the same text as where the rule cannot be asked at all."""
+    hold, and its two programs lower to the same text as where the rule
+    cannot be asked at all."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     cfg = lm_config(
         vocab_size=512, d_model=256, n_heads=2, n_layers=2, d_ff=512,
         max_len=2048, dtype=jnp.bfloat16,
     )
-    assert T.prefill_uses_kernel(cfg, 1280) and not T.rowwise_uses_kernel(cfg, 1280)
+    assert SM.prefill_uses_kernel(cfg, 1280) and not SM.rowwise_uses_kernel(cfg, 1280)
     with_the_rule = _two_programs(cfg, 1280, chip)
     assert "rowwise_heads" not in "".join(with_the_rule)
 
     def never(cfg, spec):
         raise AssertionError("a layer was asked whether it takes the pass")
 
-    monkeypatch.setattr(T, "_takes_rowwise", never)
-    monkeypatch.setattr(T, "rowwise_uses_kernel", lambda cfg, width: False)
+    monkeypatch.setattr(SM, "_takes_rowwise", never)
+    monkeypatch.setattr(SM, "rowwise_uses_kernel", lambda cfg, width: False)
     assert _two_programs(cfg, 1280, chip) == with_the_rule
 
 
@@ -584,17 +588,16 @@ def test_a_rotary_decoders_prefill_takes_the_pass_and_its_step_does_not(
 ):
     """A decoder of the second cell's kind (no q/k norm, rotary positions in
     its window layers, 28 query heads over 4): the rule holds at a prefill's
-    width, which `kernel_rowwise_prefills` counts 1 for; the step keeps
-    `_rope`; on a CPU and with `fused_attention` off (a pool that spans a
-    mesh) nothing takes it."""
+    width; the step keeps `rope`; on a CPU and with `fused_attention` off (a
+    pool that spans a mesh) nothing takes it."""
     cfg = lm_config(dtype=jnp.bfloat16, **CELLS["rag-smallthinker-21b-a3b"])
-    assert not T.rowwise_uses_kernel(cfg, 10240)  # this process runs on the CPU
+    assert not SM.rowwise_uses_kernel(cfg, 10240)  # this process runs on the CPU
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    assert int(T.rowwise_uses_kernel(cfg, 10240)) == 1
-    assert not T.rowwise_uses_kernel(cfg, 64)
+    assert int(SM.rowwise_uses_kernel(cfg, 10240)) == 1
+    assert not SM.rowwise_uses_kernel(cfg, 64)
     import dataclasses
 
-    assert not T.rowwise_uses_kernel(
+    assert not SM.rowwise_uses_kernel(
         dataclasses.replace(cfg, fused_attention=False), 10240
     )
     small = lm_config(
@@ -671,7 +674,7 @@ def test_a_latent_step_leaves_the_cache_where_it_lies(chip, monkeypatch):
     lanes wide was copied there and back every step: `_rope_lanes`)."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     cfg = _longcat(d_model=256, n_heads=4, q_rank=384, max_len=2048)
-    assert T.latent_step_uses_kernel(cfg) and T.latent_prefill_uses_kernel(cfg, 1280)
+    assert LAT.latent_step_uses_kernel(cfg) and LAT.latent_prefill_uses_kernel(cfg, 1280)
     params = _shaped(
         chip, lambda: T.init_params(jax.random.PRNGKey(0), cfg, jnp.bfloat16)
     )
@@ -774,7 +777,7 @@ def test_the_fourth_cells_prefill_builds_no_padded_heads(chip, monkeypatch):
     reads each."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     cfg = _longcat(d_model=6144, n_heads=64, q_rank=1536, max_len=12288)
-    assert T.latent_prefill_uses_kernel(cfg, 10240)
+    assert LAT.latent_prefill_uses_kernel(cfg, 10240)
     params = _shaped(
         chip, lambda: T.init_params(jax.random.PRNGKey(0), cfg, jnp.bfloat16)
     )

@@ -95,9 +95,9 @@ def test_sequence_parallel_encoder_matches_single_device():
     """encode() under shard_map with cfg.seq_axis == full-sequence encode."""
     import dataclasses
 
-    from pathway_tpu.models import transformer as tfm
+    from pathway_tpu.models import embedder_config, encoder, transformer as tfm
 
-    cfg = tfm.embedder_config(
+    cfg = embedder_config(
         vocab_size=128, d_model=32, n_heads=4, n_layers=2, d_ff=64,
         max_len=64, dtype=jnp.float32, fused_attention=False,
     )
@@ -107,13 +107,13 @@ def test_sequence_parallel_encoder_matches_single_device():
     token_ids = jnp.asarray(rng.integers(2, 128, (b, s)), jnp.int32)
     token_mask = jnp.ones((b, s), jnp.int32)
 
-    want = tfm.encode(params, token_ids, token_mask, cfg)
+    want = encoder.encode(params, token_ids, token_mask, cfg)
 
     mesh = _mesh()
     sp_cfg = dataclasses.replace(cfg, seq_axis="seq")
 
     def sp_encode(p, ids, m):
-        return tfm.encode(p, ids, m, sp_cfg)
+        return encoder.encode(p, ids, m, sp_cfg)
 
     got = jax.jit(
         jax.shard_map(
